@@ -43,5 +43,6 @@ Write-Output ""
 Write-Output "Install complete. Next steps:"
 Write-Output "  .\$venvDir\Scripts\Activate.ps1"
 Write-Output "  python -m chiaswarm_tpu.cli init      # configure hive + fetch models"
+Write-Output "  `$env:JAX_PLATFORMS = 'cpu'             # the worker refuses a backend nobody named"
 Write-Output "  python -m chiaswarm_tpu.node.smoke --all --random-weights"
 Write-Output "  python -m pytest tests\ -q            # hermetic suite (CPU)"

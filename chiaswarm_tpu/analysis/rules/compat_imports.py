@@ -1,17 +1,19 @@
 """R3 compat-import: JAX API churn crosses through core/compat.py only.
 
-The repo pins jax 0.4.37; JAX moves public surface between minors
-(``shard_map`` graduated out of experimental, ``lax.axis_size`` did not
-exist yet, ...). The seed paid for this twice: ``from jax import
-shard_map`` in a test poisoned the whole tier-1 collection, and
-``lax.axis_size`` broke every sequence-parallel path at runtime.
+The repo runs on one jax (0.9.0, floored in pyproject.toml), but JAX
+moves public surface between minors (``shard_map`` graduated out of
+experimental, ``lax.axis_size`` is young, ...). The seed paid for this
+twice under an older jax: ``from jax import shard_map`` in a test
+poisoned the whole tier-1 collection, and ``lax.axis_size`` broke every
+sequence-parallel path at runtime. Keeping every such import in ONE file
+makes the next bump a one-file edit.
 
-Policy, driven by the pinned table in ``chiaswarm_tpu/core/compat.py``:
+Policy, driven by the table in ``chiaswarm_tpu/core/compat.py``:
 
 - importing a symbol listed in ``COMPAT_TABLE`` (e.g. ``from jax import
   shard_map``, ``from jax.experimental.shard_map import shard_map``) is a
   finding anywhere outside compat.py — even inside try/except, because
-  every hand-rolled fallback is one more site to migrate on the next pin
+  every hand-rolled fallback is one more site to migrate on the next
   bump;
 - calling an attribute path listed there (``jax.lax.axis_size(...)``) is
   likewise a finding;
@@ -71,8 +73,8 @@ class CompatImport(Rule):
     code = "R3"
     name = "compat-import"
     description = ("version-sensitive jax imports must route through "
-                   "chiaswarm_tpu.core.compat (pinned jax "
-                   "compatibility table)")
+                   "chiaswarm_tpu.core.compat (the jax crossing-point "
+                   "table)")
 
     def check(self, ctx: ModuleContext) -> Iterator[Finding]:
         if ctx.relpath.endswith(_EXEMPT_SUFFIX):
@@ -88,9 +90,9 @@ class CompatImport(Rule):
                     entry = _FORBIDDEN_CALLS[resolved]
                     yield self.finding(
                         ctx, node,
-                        f"'{resolved}' is not available on the pinned jax "
-                        f"{_pinned()}; use chiaswarm_tpu.core.compat."
-                        f"{entry.symbol} ({entry.note})")
+                        f"'{resolved}' is version-sensitive; use "
+                        f"chiaswarm_tpu.core.compat.{entry.symbol} "
+                        f"({entry.note})")
 
     def _check_import_from(self, ctx: ModuleContext,
                            node: ast.ImportFrom) -> Iterator[Finding]:
@@ -102,8 +104,7 @@ class CompatImport(Rule):
                 yield self.finding(
                     ctx, node,
                     f"'from {module} import {alias.name}' is version-"
-                    f"sensitive (modern: {entry.modern}, pinned jax "
-                    f"{_pinned()}: {entry.pinned}); import "
+                    f"sensitive (installed jax: {entry.path}); import "
                     f"chiaswarm_tpu.core.compat.{entry.symbol} instead")
                 continue
             if module.startswith("jax.experimental"):
@@ -127,11 +128,7 @@ class CompatImport(Rule):
         yield self.finding(
             ctx, node,
             f"unguarded '{module}' import: jax.experimental carries no "
-            f"stability promise across the pin — wrap in try/except "
+            f"stability promise across versions — wrap in try/except "
             f"ImportError, or add a shim to chiaswarm_tpu.core.compat "
             f"(allowed without a guard: "
             f"{', '.join(sorted(ALLOWED_EXPERIMENTAL))})")
-
-
-def _pinned() -> str:
-    return _COMPAT.PINNED_JAX

@@ -1,23 +1,21 @@
-"""Pinned-version JAX compat layer — one place that knows which APIs moved.
+"""JAX crossing point — the one module that imports version-sensitive jax API.
 
-The repo pins jax 0.4.37 (pyproject.toml). JAX churns public surface
-between minors: ``shard_map`` graduated from ``jax.experimental.shard_map``
-to a top-level ``jax.shard_map`` export, Pallas modules move, and
-``jax.experimental.*`` carries no stability promise at all. The seed repo
-already paid for this twice — ``tests/test_parallel.py`` imported
-``from jax import shard_map`` (absent on 0.4.37, poisoning the whole tier-1
-collection) and ``ops/attention.py`` hand-rolled its own try/except
-fallback for the same symbol.
-
-This module is the single sanctioned crossing point:
+The repo runs on ONE installation: jax/jaxlib 0.9.0 (``pyproject.toml``
+floors jax there; sandbox and chip machine ship the same wheels). No
+branch in this file chooses between jax versions. What remains is the
+reason the module exists at all: JAX moves public surface between minors
+(``shard_map`` left ``jax.experimental``, ``io_callback`` has not left it
+yet, ``jax.experimental.*`` promises nothing), so every symbol that has
+moved — or still lives under ``jax.experimental`` — is imported HERE and
+nowhere else. The next bump then edits one file.
 
 - ``COMPAT_TABLE`` is pure data (no jax import needed to read it) and
-  drives the ``compat-import`` lint rule in ``chiaswarm_tpu.analysis`` —
-  any module outside this file that imports a shimmed symbol directly is
-  a finding.
-- The shims themselves resolve lazily via module ``__getattr__`` so that
-  importing this module (e.g. from the linter, or from a host-only tool)
-  never drags in the jax runtime.
+  drives the ``compat-import`` lint rule (R3) in
+  ``chiaswarm_tpu.analysis`` — any module outside this file that imports
+  a listed symbol directly is a finding.
+- The shims resolve lazily via module ``__getattr__`` so that importing
+  this module (from the linter, or a host-only tool) never drags in the
+  jax runtime.
 
 Usage::
 
@@ -28,19 +26,13 @@ from __future__ import annotations
 
 import dataclasses
 
-#: The jax version this repo is pinned to (pyproject.toml). The compat
-#: table below documents API surface relative to THIS version; bump them
-#: together.
-PINNED_JAX = "0.4.37"
-
 
 @dataclasses.dataclass(frozen=True)
 class CompatEntry:
-    """One symbol whose import path differs across pinned/modern jax."""
+    """One symbol that must be imported from this module."""
 
     symbol: str           # name exported by this module
-    modern: str           # import path on current jax (>= 0.6)
-    pinned: str           # import path on the pinned version
+    path: str             # where it lives on the installed jax (0.9.0)
     note: str = ""
 
 
@@ -50,102 +42,75 @@ class CompatEntry:
 COMPAT_TABLE: dict[str, CompatEntry] = {
     "jax:shard_map": CompatEntry(
         symbol="shard_map",
-        modern="jax.shard_map",
-        pinned="jax.experimental.shard_map.shard_map",
-        note="top-level export only exists on jax >= 0.6; 0.4.x raises "
-             "ImportError at collection time",
+        path="jax.shard_map",
+        note="moved out of jax.experimental in 0.6; one spelling, here",
     ),
     "jax.experimental.shard_map:shard_map": CompatEntry(
         symbol="shard_map",
-        modern="jax.shard_map",
-        pinned="jax.experimental.shard_map.shard_map",
-        note="experimental path is removed once the symbol graduates; "
-             "route through compat so the repo survives an upgrade",
+        path="jax.shard_map",
+        note="deprecated alias (warns since 0.8, slated for removal)",
     ),
     "jax.lax:axis_size": CompatEntry(
         symbol="axis_size",
-        modern="jax.lax.axis_size",
-        pinned="jax.core.axis_frame",
-        note="lax.axis_size does not exist on 0.4.x; axis_frame(name) "
-             "returns the static size there (ring_attention relied on the "
-             "modern name and broke every seq-parallel test on the pin)",
+        path="jax.lax.axis_size",
+        note="young API (absent before 0.5); kept behind one name",
     ),
-    # jax.profiler is stable across the pin, but serving code must still
-    # cross here: the shims degrade to no-ops when the profiler plugin
-    # (or jax itself) is absent, so stdlib-only observability callers
+    # jax.profiler is stable, but serving code must still cross here:
+    # the shims degrade to no-ops when the profiler plugin (or jax
+    # itself) is absent, so stdlib-only observability callers
     # (chiaswarm_tpu/obs) never crash a job because tracing is broken
     "jax.profiler:trace": CompatEntry(
         symbol="profiler_trace",
-        modern="jax.profiler.trace",
-        pinned="jax.profiler.trace",
+        path="jax.profiler.trace",
         note="route through compat.profiler_trace: degrades to a no-op "
              "context manager when the profiler backend is unavailable",
     ),
     "jax.profiler:TraceAnnotation": CompatEntry(
         symbol="trace_annotation",
-        modern="jax.profiler.TraceAnnotation",
-        pinned="jax.profiler.TraceAnnotation",
+        path="jax.profiler.TraceAnnotation",
         note="route through compat.trace_annotation: degrades to a no-op "
              "when the profiler backend is unavailable",
     ),
     "jax.profiler:start_trace": CompatEntry(
         symbol="profiler_start_trace",
-        modern="jax.profiler.start_trace",
-        pinned="jax.profiler.start_trace",
+        path="jax.profiler.start_trace",
         note="route through compat.profiler_start_trace (no-op fallback)",
     ),
     "jax.profiler:stop_trace": CompatEntry(
         symbol="profiler_stop_trace",
-        modern="jax.profiler.stop_trace",
-        pinned="jax.profiler.stop_trace",
+        path="jax.profiler.stop_trace",
         note="route through compat.profiler_stop_trace (no-op fallback)",
     ),
-    # io_callback lives under jax.experimental on the pin and graduates
-    # to jax.io_callback on modern jax — and it is the swarmlens
-    # numerics-tap emission primitive (obs/numerics.py), so serving code
-    # needs ONE sanctioned spelling that survives the move
+    # the swarmlens numerics-tap emission primitive (obs/numerics.py)
+    # still lives under jax.experimental on 0.9.0
     "jax.experimental:io_callback": CompatEntry(
         symbol="io_callback",
-        modern="jax.io_callback",
-        pinned="jax.experimental.io_callback",
-        note="graduates out of jax.experimental on modern jax; route "
-             "through compat so the numerics taps survive a pin bump",
+        path="jax.experimental.io_callback",
+        note="experimental namespace: one sanctioned import site",
     ),
 }
 
 #: ``jax.experimental`` submodules that modules may import at module scope
 #: without a try/except guard. Everything else under ``jax.experimental``
 #: must be guarded or shimmed here — the ``compat-import`` rule enforces
-#: it. Pallas is allowed because ``ops.attention`` already feature-probes
-#: the whole kernel module before use (``_flash_available``).
+#: it. Pallas is allowed because the kernels (ops/flash_attention.py,
+#: ops/ring_flash_attention.py) ARE Pallas programs: they import it at
+#: module top and a broken import must raise, not hide.
 ALLOWED_EXPERIMENTAL: frozenset[str] = frozenset({
     "jax.experimental.pallas",
 })
 
 
 def _resolve_shard_map():
-    try:  # jax >= 0.6 top-level export
-        from jax import shard_map as sm
-    except ImportError:  # pinned 0.4.x: experimental module
-        from jax.experimental.shard_map import shard_map as sm
-    if not callable(sm):  # some versions expose the MODULE at jax.shard_map
-        sm = sm.shard_map
-    return sm
+    import jax
+
+    return jax.shard_map
 
 
 def _resolve_axis_size():
     import jax
 
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size
-
-    def axis_size(axis_name):
-        """Static size of a named mesh axis inside shard_map/pmap."""
-        size = jax.core.axis_frame(axis_name)
-        # modern jax returns a frame object; 0.4.x returns the int itself
-        return getattr(size, "size", size)
-
-    return axis_size
+    return jax.lax.axis_size
 
 
 class _NoopAnnotation:
@@ -199,28 +164,19 @@ def _resolve_profiler_stop_trace():
 
 
 def _resolve_io_callback():
-    import jax
+    from jax.experimental import io_callback
 
-    if hasattr(jax, "io_callback"):  # modern jax: graduated export
-        return jax.io_callback
-    from jax.experimental import io_callback as cb
-
-    return cb
+    return io_callback
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off — required for bodies
-    containing ``pallas_call`` (no replication rule exists for it; the
-    fused ring-flash kernel and its interpret oracle both hit this).
-    The kwarg is ``check_rep`` on the 0.4.x pin and ``check_vma`` on
-    modern jax; this is the one sanctioned spelling of that fork."""
-    sm = __getattr__("shard_map")
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
+    """``shard_map`` with the varying-manual-axes check off — required
+    for bodies containing ``pallas_call`` (no replication rule exists
+    for it; the fused ring-flash kernel and its interpret oracle both
+    hit this)."""
+    return __getattr__("shard_map")(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -76,24 +76,35 @@ def local_chip_count() -> int:
     return jax.local_device_count()
 
 
-_DEFAULT_HBM_BYTES = 16 * 1024**3  # v5e-class chip; used when stats absent
-# params may take at most this fraction of a chip; the rest is activations,
-# compiled executables, coalesced-batch latents, and the resident-model
-# ledger headroom. Since ISSUE 8 this fraction is only the INITIAL budget
-# (resident_param_budget_bytes): once models load, the residency manager
-# (serving/residency.py) runs on measured footprints, and the operator
-# env override below wins outright.
+# stand-in budget for platforms WITHOUT device memory stats (the CPU test
+# meshes); a TPU must report its own limit — see device_hbm_bytes
+_DEFAULT_HBM_BYTES = 16 * 1024**3
+# Two shares of one chip's HBM, for two different questions.
+#
+# _PARAM_HBM_FRACTION — the MESH policy's bar (derive_mesh_spec): a family
+# whose params exceed it does not fit "comfortably" and is tensor-parallel
+# sharded when the slot has the chips to do so.
+#
+# _RESIDENT_HBM_FRACTION — the RESIDENCY ledger's default budget
+# (serving/residency.py): how much of a chip the whole resident set may
+# hold; the rest is activations, compiled executables and lane state.
+# The two were one number (0.35) until the first run of the normal path
+# at SDXL width showed what that meant on a one-chip slot, where there is
+# nothing to shard over: SDXL's 6.5 GiB of bf16 params exceeded 0.35 x
+# 16 GiB, so the ledger degraded the headline model to load-per-job and
+# no SDXL job could ever ride a lane. The operator env override below
+# wins outright over the residency default.
 _PARAM_HBM_FRACTION = 0.35
+_RESIDENT_HBM_FRACTION = 0.6
 
 ENV_RESIDENCY_BUDGET = "CHIASWARM_RESIDENCY_BUDGET"
 
 
 def resident_param_budget_bytes(hbm_bytes: int | None = None) -> int:
-    """Per-chip byte budget for RESIDENT model params — the single
-    source both the mesh policy (below) and the residency ledger
-    (serving/residency.py) plan against. ``CHIASWARM_RESIDENCY_BUDGET``
-    (bytes) overrides; otherwise the classic HBM fraction applies as
-    the no-model-has-loaded-yet fallback (ISSUE 8 satellite)."""
+    """Per-chip byte budget for RESIDENT model params — what the
+    residency ledger (serving/residency.py) plans against until told
+    otherwise. ``CHIASWARM_RESIDENCY_BUDGET`` (bytes) overrides;
+    otherwise ``_RESIDENT_HBM_FRACTION`` of the chip's reported HBM."""
     raw = os.environ.get(ENV_RESIDENCY_BUDGET, "").strip()
     if raw:
         try:
@@ -102,20 +113,22 @@ def resident_param_budget_bytes(hbm_bytes: int | None = None) -> int:
             pass  # malformed override: fall through to the fraction
     if hbm_bytes is None:
         hbm_bytes = device_hbm_bytes()
-    return int(_PARAM_HBM_FRACTION * hbm_bytes)
+    return int(_RESIDENT_HBM_FRACTION * hbm_bytes)
 
 
 def device_hbm_bytes(device: jax.Device | None = None) -> int:
-    """Per-chip memory budget from the runtime, with a v5e default when
-    the platform exposes no stats (CPU test meshes, some plugins)."""
+    """Per-chip memory budget from the runtime. A TPU that reports no
+    ``bytes_limit`` is an error (every budget downstream would be a
+    guess about hardware we cannot see); only stat-less non-TPU
+    platforms (CPU test meshes) get the stand-in constant."""
     device = device or jax.devices()[0]
-    try:
-        stats = device.memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return limit
-    except Exception:
-        pass
+    limit = int((device.memory_stats() or {}).get("bytes_limit", 0))
+    if limit > 0:
+        return limit
+    if device.platform == "tpu":
+        raise RuntimeError(
+            f"{device} reports no memory_stats()['bytes_limit']; refusing "
+            "to plan HBM budgets against a guessed chip size")
     return _DEFAULT_HBM_BYTES
 
 
@@ -143,7 +156,7 @@ def derive_mesh_spec(n_devices: int,
         return MeshSpec({DATA_AXIS: 1})
     if hbm_bytes is None:
         hbm_bytes = device_hbm_bytes()
-    budget = resident_param_budget_bytes(hbm_bytes)
+    budget = _PARAM_HBM_FRACTION * hbm_bytes
     tp = 1
     if heaviest_param_bytes:
         while (heaviest_param_bytes / tp > budget

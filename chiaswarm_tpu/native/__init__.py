@@ -10,8 +10,11 @@ ctypes-free call paths, so the envelope uses hashlib/base64 for them.
 
 ``load()`` compiles the shared object on first use with the system g++
 (no pip, no network — the image bakes the toolchain) into
-``~/.cache/chiaswarm_tpu/``; import never fails — callers check
-``codec() is not None`` and fall back to PIL/hashlib.
+``<checkout>/.native_cache/`` (``CHIASWARM_NATIVE_CACHE`` overrides).
+An install that ships no ``csrc/`` gets None and the PIL/hashlib path;
+a source that is present but does not BUILD raises — the worker calls
+``load()`` at start-up so a broken toolchain stops it there instead of
+quietly halving encode throughput on every job.
 """
 
 from __future__ import annotations
@@ -35,8 +38,7 @@ def _cache_dir() -> Path:
     root = os.environ.get("CHIASWARM_NATIVE_CACHE")
     if root:
         return Path(root)
-    return Path(os.environ.get("XDG_CACHE_HOME",
-                               Path.home() / ".cache")) / "chiaswarm_tpu"
+    return _SOURCE.parents[1] / ".native_cache"
 
 
 def _build(source: Path, out: Path) -> None:
@@ -59,14 +61,15 @@ def _build(source: Path, out: Path) -> None:
 
 
 def load() -> ctypes.CDLL | None:
-    """The artifact-codec library, building it on first call. None when
-    the source or toolchain is unavailable (callers use the PIL path)."""
+    """The artifact-codec library, building it on first call. None only
+    when the install ships no source (callers use the PIL path); a build
+    or load failure raises, on this and every later call."""
     global _LIB, _TRIED
     with _LOCK:
         if _LIB is not None or _TRIED:
             return _LIB
-        _TRIED = True
         if not _SOURCE.exists():
+            _TRIED = True
             log.info("native codec source not found at %s", _SOURCE)
             return None
         so = _cache_dir() / "libartifact.so"
@@ -75,10 +78,10 @@ def load() -> ctypes.CDLL | None:
                     so.stat().st_mtime < _SOURCE.stat().st_mtime):
                 _build(_SOURCE, so)
             lib = ctypes.CDLL(str(so))
-        except (OSError, subprocess.SubprocessError) as exc:
-            log.warning("native codec unavailable (%s); using Python path",
-                        exc)
-            return None
+        except subprocess.CalledProcessError as exc:
+            raise RuntimeError(
+                f"native codec build failed: {exc.stderr.decode()[-2000:]}"
+            ) from exc
 
         lib.sha256_hex.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
                                    ctypes.c_char_p]

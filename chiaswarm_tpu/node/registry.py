@@ -45,11 +45,14 @@ def _mesh_cache_key(mesh) -> tuple | None:
 
 def _place_params(params, mesh, model_name: str):
     """Put a param tree where its slot executes: tensor-parallel shardings
-    for >1-chip meshes, plain placement on the slot's chip otherwise."""
-    if mesh is None:
-        return params
+    for >1-chip meshes, plain placement on the slot's chip otherwise, the
+    default device when no slot is named. Checkpoint and host-random
+    loads arrive as host numpy — left there, every jitted call would
+    ship the whole tree again."""
     import jax
 
+    if mesh is None:
+        return jax.device_put(params)
     if mesh.devices.size > 1:
         from chiaswarm_tpu.parallel import shard_params
 
@@ -590,6 +593,11 @@ class ModelRegistry:
             if quantize:
                 bundle.params = maybe_quantize_params(
                     bundle.params, family=family, mesh=None)
+            # resident, but uncommitted: one entry serves every slot
+            # (the key carries no mesh), so jit moves it beside whichever
+            # slot's committed params it runs with
+            bundle.params = _place_params(bundle.params, None,
+                                          controlnet_name)
             return bundle
 
         return self.residency.acquire(

@@ -510,22 +510,40 @@ class Worker:
         devices = jax.devices()
         if not devices:
             raise RuntimeError("no accelerator devices present; quitting")
+        # this worker sells TPU time: any other backend is a start-up
+        # error unless the operator NAMED it through jax's own variable
+        # (JAX_PLATFORMS=cpu — dev hosts and the hermetic tests), so a
+        # chip that failed to initialize can never serve quietly on CPU
+        backend = jax.default_backend()
+        named = [p.strip() for p in
+                 (jax.config.jax_platforms or "").lower().split(",")]
+        if backend != "tpu" and backend not in named:
+            raise RuntimeError(
+                f"no TPU: jax selected backend {backend!r} "
+                f"({len(devices)} device(s)) and JAX_PLATFORMS="
+                f"{jax.config.jax_platforms!r} does not name it; set "
+                f"JAX_PLATFORMS={backend} to run a dev worker on it")
         from chiaswarm_tpu.node.settings import settings_root
 
         setup_logging(settings_root() / "logs", self.settings.log_filename,
                       self.settings.log_level)
         log.info("worker %s: %d device(s), %d slot(s), backend=%s",
                  self.settings.worker_name, len(devices), len(self.pool),
-                 jax.default_backend())
+                 backend)
         # bf16 matmuls on the MXU — the TPU analog of the reference's
         # TF32/cudnn.benchmark startup knobs (swarm/worker.py:179-181)
         jax.config.update("jax_default_matmul_precision", "bfloat16")
-        # amortize XLA compiles across worker restarts
+        # amortize XLA compiles across worker restarts; a cache that
+        # cannot be wired, or a codec that cannot build, fails HERE
+        # rather than degrading every job after
+        from chiaswarm_tpu import native
         from chiaswarm_tpu.core.compile_cache import (
             enable_persistent_compilation_cache,
         )
 
-        enable_persistent_compilation_cache()
+        log.info("persistent compile cache: %s",
+                 enable_persistent_compilation_cache())
+        native.load()
 
     def request_stop(self) -> None:
         self._stop.set()

@@ -20,14 +20,13 @@ Five implementations behind one function:
 - ``"ring_flash"`` — fused Pallas ring-flash kernel
                      (ops/ring_flash_attention.py): the flash inner loop
                      with the next hop's KV shard streaming in as an async
-                     remote DMA under the compute. The seq-mesh default on
-                     TPU; on CPU it rides Pallas interpret mode and is
-                     opt-in (explicit impl or CHIASWARM_ATTENTION) so the
-                     hermetic tier keeps the cheap ppermute lowering.
-- ``"auto"``       — ring_flash (TPU) / ring (elsewhere) when a
-                     seq-parallel mesh is active and shapes qualify, else
-                     flash on TPU when shapes qualify, else xla.
-                     CHIASWARM_ATTENTION=<kind> overrides the auto pick.
+                     remote DMA under the compute. EXPLICIT only (impl= or
+                     CHIASWARM_ATTENTION): the fused grid has not yet run
+                     on a chip, and ``auto`` never selects a kernel that
+                     has not; on CPU it rides Pallas interpret mode.
+- ``"auto"``       — ring when a seq-parallel mesh is active and shapes
+                     qualify, else flash on TPU when shapes qualify, else
+                     xla. CHIASWARM_ATTENTION=<kind> overrides the pick.
 
 All take (B, L, H, D) query / (B, S, H, D) key-value tensors and return
 (B, L, H, D). Head-batched layouts keep the last dim = head_dim (128-lane
@@ -43,7 +42,6 @@ first attention layer whose inputs lost too much.
 
 from __future__ import annotations
 
-import functools
 from typing import Literal
 
 import jax
@@ -74,11 +72,28 @@ def _env_impl() -> str | None:
     return raw if raw in _IMPLS else None
 
 
+def _bhd_spec(mesh, b: int, h: int, token_axis: str | None):
+    """(B, L, H, D) PartitionSpec on ``mesh``: batch rides ``data`` and
+    heads ride ``model`` (Megatron head sharding) whenever divisible —
+    otherwise that axis computes replicated — and tokens ride
+    ``token_axis`` (``seq`` for the ring kinds, None for local kernels)."""
+    from jax.sharding import PartitionSpec as P
+
+    from chiaswarm_tpu.core.mesh import DATA_AXIS, MODEL_AXIS
+
+    sizes = dict(mesh.shape)
+    dp, tp = sizes.get(DATA_AXIS, 1), sizes.get(MODEL_AXIS, 1)
+    return P(DATA_AXIS if dp > 1 and b % dp == 0 else None,
+             token_axis,
+             MODEL_AXIS if tp > 1 and h % tp == 0 else None,
+             None)
+
+
 def _try_ring(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, scale: float,
               impl: str) -> jnp.ndarray | None:
     """Sequence-parallel dispatch: shard tokens over the active mesh's
-    ``seq`` axis and run the ring — the fused ring-flash kernel by
-    default on TPU, the ppermute scan elsewhere. None = not eligible.
+    ``seq`` axis and run the ring — the ppermute scan unless the fused
+    ring-flash kernel is named explicitly. None = not eligible.
 
     The specs compose with the other parallel axes: batch rides ``data``
     and heads ride ``model`` (Megatron head sharding) whenever divisible,
@@ -95,34 +110,24 @@ def _try_ring(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, scale: float,
     b, l, h, _ = q.shape
     if k.shape[1] != l:
         return None  # cross-attention: tiny KV, the einsum path wins
-    from chiaswarm_tpu.core.mesh import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
+    from chiaswarm_tpu.core.mesh import SEQ_AXIS
 
-    sizes = dict(mesh.shape)
-    sp = sizes.get(SEQ_AXIS, 1)
+    sp = dict(mesh.shape).get(SEQ_AXIS, 1)
     ring_kinds = ("ring", "ring_flash")
     if l % sp or (impl not in ring_kinds and l < _ring_min_tokens()):
         return None
     from functools import partial
 
-    from jax.sharding import PartitionSpec as P
-
     from chiaswarm_tpu.core.compat import shard_map
 
-    dp, tp = sizes.get(DATA_AXIS, 1), sizes.get(MODEL_AXIS, 1)
-    spec = P(DATA_AXIS if dp > 1 and b % dp == 0 else None,
-             SEQ_AXIS,
-             MODEL_AXIS if tp > 1 and h % tp == 0 else None,
-             None)
+    spec = _bhd_spec(mesh, b, h, SEQ_AXIS)
 
-    # kind choice inside the ring family: the fused kernel is the TPU
-    # default (ROADMAP item 2 — DMA under compute); on CPU meshes auto
-    # keeps the ppermute scan so the hermetic tier's seq-parallel
-    # programs keep their cheap ppermute lowering, and the fused path is
-    # engaged explicitly (impl="ring_flash" / CHIASWARM_ATTENTION) by
-    # the parity suite, the bisect probe configs and the HLO audit.
-    use_fused = (impl == "ring_flash"
-                 or (impl != "ring" and _on_tpu(q)))
-    if use_fused:
+    # kind choice inside the ring family: auto keeps the ppermute ring
+    # on every platform. The fused kernel (DMA under compute) is engaged
+    # explicitly (impl="ring_flash" / CHIASWARM_ATTENTION) by the parity
+    # suite, the bisect probe configs and the HLO audit — its remote-DMA
+    # grid has zero executions on a chip, and a default must have run.
+    if impl == "ring_flash":
         from chiaswarm_tpu.core.compat import shard_map_unchecked
 
         from chiaswarm_tpu.ops.ring_flash_attention import (
@@ -145,6 +150,30 @@ def _try_ring(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, scale: float,
     return fn(q, k, v)
 
 
+def _flash(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+           scale: float) -> jnp.ndarray:
+    """The local flash kernel. GSPMD cannot partition a Mosaic kernel
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map."), so when the program's params live on a
+    multi-device mesh the call is shard_mapped over it with the specs
+    _try_ring uses, minus the ring."""
+    from chiaswarm_tpu.ops.flash_attention import flash_attention
+    from chiaswarm_tpu.parallel.context import active_mesh
+
+    mesh = active_mesh()
+    if mesh is None:
+        return flash_attention(q, k, v, scale=scale)
+    from functools import partial
+
+    from chiaswarm_tpu.core.compat import shard_map_unchecked
+
+    spec = _bhd_spec(mesh, q.shape[0], q.shape[2], None)
+    # pallas_call has no shard_map replication rule: checking off
+    return shard_map_unchecked(
+        partial(flash_attention, scale=scale), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
+
+
 def _xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    scale: float) -> jnp.ndarray:
     # (B, L, H, D) x (B, S, H, D) -> (B, H, L, S)
@@ -152,22 +181,6 @@ def _xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         preferred_element_type=jnp.float32)
     weights = jax.nn.softmax(logits * scale, axis=-1).astype(q.dtype)
     return jnp.einsum("bhls,bshd->blhd", weights, v)
-
-
-@functools.lru_cache(maxsize=1)
-def _flash_available() -> bool:
-    try:
-        from chiaswarm_tpu.ops import flash_attention  # noqa: F401
-        return True
-    except Exception:
-        return False
-
-
-def _on_tpu(x: jnp.ndarray) -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def attention(
@@ -248,14 +261,11 @@ def attention(
         # wins from 1024 tokens up; tiny KV (77-token text cross-attention)
         # and small spatial grids stay on the einsum path.
         use_flash = (
-            _on_tpu(q)
-            and _flash_available()
+            jax.default_backend() == "tpu"
             and q.shape[1] >= 1024
             and k.shape[1] >= 1024
         )
 
     if use_flash:
-        from chiaswarm_tpu.ops.flash_attention import flash_attention
-
-        return _out_tap(flash_attention(q, k, v, scale=scale))
+        return _out_tap(_flash(q, k, v, scale))
     return _out_tap(_xla_attention(q, k, v, scale))

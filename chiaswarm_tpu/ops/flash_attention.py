@@ -39,26 +39,9 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU builds too; guard for safety
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30  # finite stand-in: true -inf breaks exp() on fully-masked rows
-
-
-def _compiler_params(**kwargs):
-    """The Mosaic params dataclass is ``TPUCompilerParams`` on the 0.4.x
-    pin and ``CompilerParams`` on modern jax — resolve whichever ships.
-    (The old spelling here only ever ran on TPU, so CPU CI could not
-    catch the pin mismatch; ring_flash_attention shares this helper.)"""
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
 
 # block-sweep knobs (read once at import): defaults are the tuned v5e
 # values. CHIASWARM_FLASH_VMEM_MB sets the kernel-scoped VMEM cap — the
@@ -255,9 +238,9 @@ def flash_attention(
         pltpu.VMEM((block_q, dp), jnp.float32),      # output accumulator
     ]
     params = {}
-    if _HAS_PLTPU and not interpret:
+    if not interpret:
         extra = {"vmem_limit_bytes": _VMEM_MB << 20} if _VMEM_MB else {}
-        params["compiler_params"] = _compiler_params(
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             **extra,
         )

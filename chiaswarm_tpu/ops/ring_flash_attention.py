@@ -47,24 +47,16 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from chiaswarm_tpu.core.compat import axis_size
 from chiaswarm_tpu.obs import numerics as _numerics
 from chiaswarm_tpu.ops.flash_attention import (
     _LANES,
     _NEG_INF,
-    _compiler_params,
     _pad_to,
     online_softmax_block_update,
 )
-
-try:  # pltpu imports on CPU builds too; guard for safety
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
 
 
 # ---------------------------------------------------------------------------
@@ -123,8 +115,8 @@ def _hop_call(qf, kf, vf, m, l, acc, *, scale: float, kv_len: int,
     acc_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0))
 
     params = {}
-    if _HAS_PLTPU and not interpret:
-        params["compiler_params"] = _compiler_params(
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
@@ -140,7 +132,7 @@ def _hop_call(qf, kf, vf, m, l, acc, *, scale: float, kv_len: int,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, dp), jnp.float32),
-        ] if _HAS_PLTPU else None,
+        ],
         interpret=interpret,
     )(qf, kf, vf, m, l, acc)
 
@@ -276,12 +268,14 @@ def _ring_flash_fused(q, k, v, *, axis_name: str, scale: float,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bh, n),
+        # index maps receive the grid indices, then the scalar-prefetch ref
         in_specs=[
-            pl.BlockSpec((1, lp, dp), lambda b_, hop_: (b_, 0, 0)),
-            pl.BlockSpec((1, sp, dp), lambda b_, hop_: (b_, 0, 0)),
-            pl.BlockSpec((1, sp, dp), lambda b_, hop_: (b_, 0, 0)),
+            pl.BlockSpec((1, lp, dp), lambda b_, hop_, nbr_: (b_, 0, 0)),
+            pl.BlockSpec((1, sp, dp), lambda b_, hop_, nbr_: (b_, 0, 0)),
+            pl.BlockSpec((1, sp, dp), lambda b_, hop_, nbr_: (b_, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, lp, dp), lambda b_, hop_: (b_, 0, 0)),
+        out_specs=pl.BlockSpec((1, lp, dp),
+                               lambda b_, hop_, nbr_: (b_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, sp, dp), jnp.float32),   # K slots
             pltpu.VMEM((2, sp, dp), jnp.float32),   # V slots
@@ -297,7 +291,7 @@ def _ring_flash_fused(q, k, v, *, axis_name: str, scale: float,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, lp, dp), out_dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             has_side_effects=True,
             collective_id=7,
@@ -403,7 +397,7 @@ def ring_flash_attention(
         scale = float(q.shape[-1]) ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    fused = (_HAS_PLTPU and not interpret and _mode() != "scan"
+    fused = (not interpret and _mode() != "scan"
              and mesh_axis_names is not None)
     if fused:
         return _ring_flash_fused(

@@ -1,4 +1,5 @@
-"""Trace-time sequence-parallel context.
+"""Trace-time mesh context: what `ops.attention` needs to know about
+the mesh a program's params live on.
 
 The reference has no sequence-parallel serving mode (its long-input answer
 is single-GPU attention slicing, swarm/diffusion/diffusion_func.py:85-88).
@@ -8,6 +9,11 @@ the pipeline enters :func:`sequence_parallel` around its jitted program, and
 `ops.attention` reads :func:`active_seq_mesh` at TRACE time to decide the
 dispatch (a static decision — under `jax.jit` the context only needs to be
 live during the first call that traces).
+
+The same context carries ANY multi-device param mesh (:func:`active_mesh`):
+GSPMD cannot partition a Mosaic kernel, so on a dp x tp slot the local flash
+call must be wrapped in a ``shard_map`` over that mesh — the first run of a
+four-chip default pool on the chip failed every SDXL job on exactly that.
 
 A contextvar (not a global) so hermetic tests can run pipelines on
 different meshes in one process without cross-talk.
@@ -22,8 +28,17 @@ from jax.sharding import Mesh
 
 from chiaswarm_tpu.core.mesh import SEQ_AXIS
 
-_seq_mesh: contextvars.ContextVar[Mesh | None] = contextvars.ContextVar(
-    "chiaswarm_seq_mesh", default=None)
+_param_mesh: contextvars.ContextVar[Mesh | None] = contextvars.ContextVar(
+    "chiaswarm_param_mesh", default=None)
+
+
+def active_mesh() -> Mesh | None:
+    """The multi-device mesh the program being traced keeps its params
+    on, or None (single chip, or no context entered)."""
+    mesh = _param_mesh.get()
+    if mesh is not None and mesh.devices.size > 1:
+        return mesh
+    return None
 
 
 def active_seq_mesh() -> Mesh | None:
@@ -31,7 +46,7 @@ def active_seq_mesh() -> Mesh | None:
 
     Returns None unless the context is entered AND the mesh actually has a
     ``seq`` axis of size > 1 — callers need no further checks."""
-    mesh = _seq_mesh.get()
+    mesh = _param_mesh.get()
     if mesh is not None and dict(mesh.shape).get(SEQ_AXIS, 1) > 1:
         return mesh
     return None
@@ -39,28 +54,28 @@ def active_seq_mesh() -> Mesh | None:
 
 @contextlib.contextmanager
 def sequence_parallel(mesh: Mesh | None):
-    """Route qualifying attention through the ring kernel over ``mesh``.
+    """Route qualifying attention over ``mesh``: through the ring kinds
+    when it has a ``seq`` axis > 1, through the shard_mapped flash kernel
+    on any multi-device mesh.
 
-    Entering with None (or a seq=1 mesh) is a no-op, so pipelines can wrap
-    their programs unconditionally."""
-    token = _seq_mesh.set(mesh)
+    Entering with None (or a one-device mesh) is a no-op, so pipelines
+    can wrap their programs unconditionally."""
+    token = _param_mesh.set(mesh)
     try:
         yield
     finally:
-        _seq_mesh.reset(token)
+        _param_mesh.reset(token)
 
 
-def _seq_mesh_of_params(params) -> Mesh | None:
-    """The seq>1 mesh ``params`` are placed on, or None."""
+def _mesh_of_params(params) -> Mesh | None:
+    """The multi-device mesh ``params`` are placed on, or None."""
     import jax
     from jax.sharding import NamedSharding
 
     for leaf in jax.tree.leaves(params):
         s = getattr(leaf, "sharding", None)
         if isinstance(s, NamedSharding) and s.mesh.devices.size > 1:
-            if dict(s.mesh.shape).get(SEQ_AXIS, 1) > 1:
-                return s.mesh
-            return None  # one placement per param tree; first leaf decides
+            return s.mesh  # one placement per param tree; first leaf decides
     return None
 
 
@@ -94,11 +109,12 @@ def capture_ring_calls():
 def seq_parallel_wrap(jitted, params):
     """Wrap a jitted pipeline program so it traces (and re-traces, after
     executable-LRU rebuilds) under :func:`sequence_parallel` whenever
-    ``params`` live on a mesh with a ``seq`` axis > 1 — the single hook
-    every pipeline uses to make ring attention a serving path rather than
-    a demo. No-seq-mesh callers get the jitted fn back untouched (zero
-    overhead on the common path)."""
-    mesh = _seq_mesh_of_params(params)
+    ``params`` live on a multi-device mesh — the single hook every
+    pipeline uses to make ring attention (seq > 1) and the shard_map'd
+    flash kernel (any dp x tp x sp mesh) serving paths rather than demos.
+    Single-chip callers get the jitted fn back untouched (zero overhead
+    on the common path)."""
+    mesh = _mesh_of_params(params)
     if mesh is None:
         return jitted
 

@@ -23,7 +23,8 @@ sharded to match, group stats staying shard-local because tp divides the
 32 GroupNorm groups), ``conv2`` is row-parallel on input channels, and
 GSPMD emits one psum per resnet block on the residual. SD-class UNets are
 ~65% conv FLOPs (BASELINE.md op profile), so leaving convs replicated made
-tp pay 44% over ideal (MULTICHIP_r03); with the resnet pairs sharded the
+tp pay 44% over ideal (r3 dry run on the virtual CPU mesh; the record
+was deleted in PR 21); with the resnet pairs sharded the
 per-device FLOPs fraction drops to ~1/(dp*tp) + small residue (conv_in/
 out, shortcuts, up/downsamples — measured by dryrun_multichip).
 
@@ -98,7 +99,8 @@ def _spec_for(path: tuple[str, ...], ndim: int) -> P:
     # channel matmuls between a replicated activation and a norm/residual
     # that needs full channels — shard the contraction dim, GSPMD emits
     # one psum (r5; the exclusion this replaces was the last double-digit
-    # tp residue, MULTICHIP_r04 0.141 vs 0.125 ideal)
+    # tp residue: 0.141 vs 0.125 ideal in the r4 virtual-mesh dry run,
+    # record deleted in PR 21)
     if not in_ff and parent in ("proj_in", "proj_out") and leaf == "kernel":
         if ndim == 2:
             return P(MODEL_AXIS, None)

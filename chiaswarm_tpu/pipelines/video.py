@@ -13,6 +13,7 @@ bf16 + flash attention are always on.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -38,6 +39,11 @@ from chiaswarm_tpu.models.configs import (
     VAEConfig,
 )
 from chiaswarm_tpu.models.tokenizer import HashTokenizer
+from chiaswarm_tpu.pipelines.components import (
+    HOST_INIT_MIN_PARAMS,
+    materialize_host,
+    param_count,
+)
 from chiaswarm_tpu.models.vae import (
     AutoencoderKL,
     AutoencoderKLTemporalDecoder,
@@ -228,6 +234,13 @@ def _vae_init_args(family: VideoFamily):
     return (jnp.zeros((1, 16, 16, family.vae.in_channels)),)
 
 
+@functools.lru_cache(maxsize=None)
+def _unet_param_count(family: VideoFamily) -> int:
+    return param_count(jax.eval_shape(
+        make_video_unet(family).init, jax.random.PRNGKey(0),
+        *_unet_init_args(family)))
+
+
 @dataclasses.dataclass
 class VideoComponents:
     family: VideoFamily
@@ -244,8 +257,13 @@ class VideoComponents:
                model_name: str | None = None) -> "VideoComponents":
         if isinstance(family, str):
             family = VIDEO_FAMILIES[family]
-        key = jax.random.PRNGKey(seed)
         unet = make_video_unet(family)
+        # same rule as Components.random (pipelines/components.py):
+        # published widths materialize on the host, judged by the
+        # UNet's abstract parameter count
+        if _unet_param_count(family) >= HOST_INIT_MIN_PARAMS:
+            return cls.random_host(family, seed, model_name)
+        key = jax.random.PRNGKey(seed)
         vae = make_video_vae(family)
         key, k1, k2, k3 = jax.random.split(key, 4)
         params = {
@@ -277,11 +295,10 @@ class VideoComponents:
                     model_name: str | None = None,
                     dtype: str = "bfloat16") -> "VideoComponents":
         """Host-materialized random components (components.py
-        ``materialize_host``): benches load ModelScope-class weights
-        without an on-device init program."""
+        ``materialize_host``): ModelScope/SVD-class weights without an
+        on-device init program; the params stay on the host for the
+        registry (or the caller) to place."""
         import numpy as np
-
-        from chiaswarm_tpu.pipelines.components import materialize_host
 
         if isinstance(family, str):
             family = VIDEO_FAMILIES[family]

@@ -4,8 +4,8 @@ The hive dispatches a dozen model families to one node (SD 1.5/2.1/XL,
 ControlNet bundles, upscale, video, audio, caption, TTS — PAPER.md §1),
 but until ISSUE 8 the worker's residency story was implicit: the compile
 cache LRU-evicted param trees under a static byte budget guessed from
-``core/mesh.py::_PARAM_HBM_FRACTION``, and the worker *estimated*
-footprints from the largest family's bf16 size. This module owns the
+a fraction of HBM, and the worker *estimated* footprints from the
+largest family's bf16 size. This module owns the
 HBM ledger end to end:
 
 - **Measured footprints.** Every load measures the live param tree
@@ -13,9 +13,10 @@ HBM ledger end to end:
   devices — ``pipelines/components.py::measured_param_bytes``) and
   remembers it per model in ``<settings root>/residency.json``, so the
   next load — and the worker's mesh policy after a restart — plans with
-  real numbers instead of the bf16 family estimate. The old knobs
-  (``_PARAM_HBM_FRACTION``, the family estimate) remain only as the
-  initial budget / first-load fallback before anything has loaded.
+  real numbers instead of the bf16 family estimate. The HBM fraction
+  (``core/mesh.py::_RESIDENT_HBM_FRACTION``) is the default budget and
+  the family estimate the first-load fallback before anything has
+  been measured.
 
 - **Donation: evict-then-load under one reservation.** A miss reserves
   the model's remembered (or estimated) footprint FIRST, evicting
@@ -150,9 +151,8 @@ class ArrivalEwma:
 
 def default_budget_bytes() -> int:
     """Resident-param budget: ``CHIASWARM_RESIDENCY_BUDGET`` wins, else
-    the mesh policy's HBM fraction of the measured per-chip memory —
-    the ISSUE-8 satellite keeps the old knob as the initial-budget
-    fallback (core/mesh.py::resident_param_budget_bytes)."""
+    the residency share of the chip's reported HBM
+    (core/mesh.py::resident_param_budget_bytes)."""
     try:
         from chiaswarm_tpu.core.mesh import resident_param_budget_bytes
 

@@ -687,10 +687,14 @@ class Lane:
         # rows ride the 'data' axis — same GSPMD seeding the solo path
         # uses for its token inputs (pipelines/diffusion.py submit). A
         # solo job on a dp slot wastes (dp-1)/dp of the chips; a full
-        # lane keeps every data row busy. OPT-IN for now: on the pinned
-        # jax build the row-sharded step program diverges numerically
-        # from its unsharded twin (same failure smell as the seq-parallel
-        # divergence in ROADMAP) — enable once that is debugged.
+        # lane keeps every data row busy. OPT-IN for now: on the old
+        # jax build the row-sharded step program diverged numerically
+        # from its unsharded twin (ROADMAP S6 — clean on 0.9.0, not yet
+        # re-gated). With the opt-in OFF nothing pins the row arrays:
+        # on the chip (dp2 x tp2, PR 21) GSPMD hands the step outputs
+        # back sharded P('data') while the program was compiled for
+        # replicated rows — jit answers with a silent recompile of the
+        # whole step program, an AOT executable with a sharding error.
         self._mesh = None
         if os.environ.get(ENV_SHARD_ROWS, "").strip().lower() in (
                 "1", "true", "on", "yes"):

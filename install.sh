@@ -39,4 +39,9 @@ echo
 echo "Done. Next steps:"
 echo "  source $VENV_DIR/bin/activate"
 echo "  python -m chiaswarm_tpu.cli init     # configure hive + prefetch models"
-echo "  python -m chiaswarm_tpu.cli worker   # join the swarm"
+if [[ "$BACKEND" == "cpu" ]]; then
+    # the worker refuses a backend nobody named (jax's own variable)
+    echo "  JAX_PLATFORMS=cpu python -m chiaswarm_tpu.cli worker   # dev worker on the CPU"
+else
+    echo "  python -m chiaswarm_tpu.cli worker   # join the swarm"
+fi

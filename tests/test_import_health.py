@@ -1,6 +1,6 @@
 """Import health: every chiaswarm_tpu module imports cleanly on CPU.
 
-API-churn breakage (a symbol that does not exist on the pinned jax, an
+API-churn breakage (a symbol that does not exist on the installed jax, an
 import-time device query, a missing optional dep used unguarded) should
 fail ONE named test per module — not poison the whole pytest collection
 the way the seed's ``from jax import shard_map`` did. The static pass

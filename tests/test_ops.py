@@ -79,3 +79,85 @@ def test_flash_block_autopick_divisibility():
     # short sequences clamp to the 8-padded length as before
     assert _pick_block(77, 2048) == 80
     assert _pick_block(256, 2048) == 256
+
+
+# ---- cross-lowering for the TPU from the CPU (no chip needed) -----------
+# ``jax.jit(f).trace(*abstract).lower(lowering_platforms=("tpu",))`` runs
+# the Pallas -> Mosaic lowering stage without a device, so trace- and
+# lowering-stage breakage (a renamed Mosaic param class, an index map
+# with the wrong arity) fails here instead of on the first chip run.
+# Whether Mosaic then COMPILES the kernel only the chip can say
+# (chip_smoke.py's kernel pre-flight).
+
+_TPU_SELF_ATTENTION_SHAPES = [
+    (2, 4096, 10, 64),   # SDXL 1024 px, 64x64 level
+    (2, 1024, 20, 64),   # SDXL 1024 px, 32x32 level
+    (2, 4096, 8, 40),    # SD1.5 512 px, head dim 40 (lane-padded)
+    (2, 9216, 5, 64),    # SD2.1 768 px 96x96 level; SVD 576x1024 72x128
+    (2, 2304, 10, 64),   # SD2.1 768 px 48x48 level; SVD 576x1024 36x64
+    (2, 1024, 8, 160),   # SD1.5 512 px, 32x32 level, head dim 160
+]
+
+
+@pytest.mark.parametrize("shape", _TPU_SELF_ATTENTION_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_flash_cross_lowers_for_tpu(shape):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    lowered = jax.jit(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False)
+    ).trace(x, x, x).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+def test_ring_flash_fused_cross_lowers_for_tpu():
+    """The fused ring kernel (remote DMA + semaphores) on a 4-device
+    virtual seq mesh: the scalar-prefetch grid spec, the index maps and
+    the Mosaic params must at least trace and lower."""
+    from functools import partial
+
+    from jax.sharding import PartitionSpec as P
+
+    from chiaswarm_tpu.core.compat import shard_map_unchecked
+    from chiaswarm_tpu.core.mesh import MeshSpec, build_mesh
+    from chiaswarm_tpu.ops.ring_flash_attention import ring_flash_attention
+
+    mesh = build_mesh(MeshSpec({"seq": 4}), devices=jax.devices()[:4])
+    spec = P(None, "seq", None, None)
+    fn = shard_map_unchecked(
+        partial(ring_flash_attention, axis_name="seq", interpret=False,
+                mesh_axis_names=tuple(mesh.axis_names)),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    x = jax.ShapeDtypeStruct((2, 4096, 10, 64), jnp.bfloat16)
+    lowered = jax.jit(fn).trace(x, x, x).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+def test_flash_under_a_dp_tp_mesh_is_shard_mapped(monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel: a flash call traced inside
+    a program whose operands are sharded over a dp x tp mesh fails TPU
+    lowering ("Mosaic kernels cannot be automatically partitioned" — what
+    every SDXL job hit on the first four-chip run). Under the trace-time
+    mesh context the pipelines enter (parallel/context.py), ops.attention
+    shard_maps the call — batch on ``data``, heads on ``model`` — and the
+    same program lowers."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from chiaswarm_tpu.core.mesh import MeshSpec, build_mesh
+    from chiaswarm_tpu.parallel import sequence_parallel
+
+    mesh = build_mesh(MeshSpec({"data": 2, "model": 2}),
+                      devices=jax.devices()[:4])
+    x = jax.ShapeDtypeStruct(
+        (4, 1024, 20, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("data", None, "model", None)))
+    # trace what a TPU process would: auto picks flash, non-interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def lower():  # a fresh jit each time: the dispatch is a TRACE-time pick
+        return jax.jit(lambda q, k, v: attention(q, k, v)).trace(
+            x, x, x).lower(lowering_platforms=("tpu",))
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        lower()
+    with sequence_parallel(mesh):
+        assert "tpu_custom_call" in lower().as_text()

@@ -502,11 +502,8 @@ def _bench_step_collapse(*, on_tpu: bool, attn: str, iters: int) -> dict:
     size = 1024 if on_tpu else 64
     base_steps = 30  # the headline ladder — the cost term being collapsed
     few_steps = 4
-    if on_tpu:
-        c = Components.random_host(fam, seed=0)
-        c.params = jax.device_put(c.params, jax.devices()[0])
-    else:
-        c = Components.random(fam, seed=0)
+    c = Components.random(fam, seed=0)
+    c.params = jax.device_put(c.params, jax.devices()[0])
     pipe = DiffusionPipeline(c, attn_impl=attn)
 
     prompt = "a photograph of an astronaut riding a horse"
@@ -868,7 +865,7 @@ def run_configs(names: list[str], *, on_tpu: bool, iters: int,
     device = jax.devices()[0]
 
     def components(family: str) -> Components:
-        c = Components.random_host(family, seed=0)
+        c = Components.random(family, seed=0)
         c.params = jax.device_put(c.params, device)
         return c
 
@@ -913,7 +910,7 @@ def run_configs(names: list[str], *, on_tpu: bool, iters: int,
         # BASELINE.json #4: ControlNet + SDXL
         fam = "sdxl" if on_tpu else "tiny"
         c = components(fam)
-        bundle = ControlNetBundle.random_host(fam, seed=1)
+        bundle = ControlNetBundle.random(fam, seed=1)
         bundle.params = jax.device_put(bundle.params, device)
         pipe = DiffusionPipeline(c, attn_impl=attn)
         size = 1024 if on_tpu else 64
@@ -933,7 +930,7 @@ def run_configs(names: list[str], *, on_tpu: bool, iters: int,
         )
 
         fam = "svd_img2vid" if on_tpu else "tiny_svd"
-        vc = VideoComponents.random_host(fam, seed=0)
+        vc = VideoComponents.random(fam, seed=0)
         vc.params = jax.device_put(vc.params, device)
         ipipe = Img2VidPipeline(vc, attn_impl=attn)
         frames = 14 if on_tpu else 8
@@ -994,7 +991,7 @@ def run_configs(names: list[str], *, on_tpu: bool, iters: int,
         )
 
         fam = "modelscope_t2v" if on_tpu else "tiny_vid"
-        vc = VideoComponents.random_host(fam, seed=0)
+        vc = VideoComponents.random(fam, seed=0)
         vc.params = jax.device_put(vc.params, device)
         vpipe = VideoPipeline(vc, attn_impl=attn)
         frames = 16 if on_tpu else 8
@@ -1084,14 +1081,8 @@ def main() -> None:
     which = os.environ.get("CHIASWARM_BENCH_CONFIGS", "all")
 
     # ---- headline: the north-star config ----
-    if on_tpu:
-        # host-side param materialization (no init program, no fp32 copy):
-        # on-device fp32 init of SDXL-class weights OOMs a single chip and
-        # the init graph alone takes minutes to compile
-        c = Components.random_host(family, seed=0)
-        c.params = jax.device_put(c.params, jax.devices()[0])
-    else:
-        c = Components.random(family, seed=0)
+    c = Components.random(family, seed=0)
+    c.params = jax.device_put(c.params, jax.devices()[0])
     pipe = DiffusionPipeline(c, attn_impl=attn)
     headline = _bench_diffusion(pipe, size=size, steps=steps, batch=batch,
                                 iters=iters, pipelined=True)

@@ -92,25 +92,36 @@ _DEFAULT_HBM_BYTES = 16 * 1024**3
 # at SDXL width showed what that meant on a one-chip slot, where there is
 # nothing to shard over: SDXL's 6.5 GiB of bf16 params exceeded 0.35 x
 # 16 GiB, so the ledger degraded the headline model to load-per-job and
-# no SDXL job could ever ride a lane. The operator env override below
-# wins outright over the residency default.
+# no SDXL job could ever ride a lane. 0.6 is held to by chip_smoke.py,
+# which fills the ledger to this budget before its waves: the width-4
+# 1024 px SDXL lane and its decode must run above a FULL resident set,
+# or the smoke fails (CHANGES.md PR 21 quotes the peak that run saw).
 _PARAM_HBM_FRACTION = 0.35
 _RESIDENT_HBM_FRACTION = 0.6
 
 ENV_RESIDENCY_BUDGET = "CHIASWARM_RESIDENCY_BUDGET"
 
 
+def _operator_budget_bytes() -> int | None:
+    """``CHIASWARM_RESIDENCY_BUDGET`` (bytes): the operator's own figure
+    for how much of a chip params may hold. It outranks BOTH fractions
+    above — the ledger's budget and the mesh policy's bar move together
+    under it, as they did when they were one number."""
+    raw = os.environ.get(ENV_RESIDENCY_BUDGET, "").strip()
+    try:
+        return max(1, int(float(raw))) if raw else None
+    except ValueError:
+        return None  # malformed override: the fractions apply
+
+
 def resident_param_budget_bytes(hbm_bytes: int | None = None) -> int:
     """Per-chip byte budget for RESIDENT model params — what the
     residency ledger (serving/residency.py) plans against until told
-    otherwise. ``CHIASWARM_RESIDENCY_BUDGET`` (bytes) overrides;
-    otherwise ``_RESIDENT_HBM_FRACTION`` of the chip's reported HBM."""
-    raw = os.environ.get(ENV_RESIDENCY_BUDGET, "").strip()
-    if raw:
-        try:
-            return max(1, int(float(raw)))
-        except ValueError:
-            pass  # malformed override: fall through to the fraction
+    otherwise: the operator's override, else ``_RESIDENT_HBM_FRACTION``
+    of the chip's reported HBM."""
+    override = _operator_budget_bytes()
+    if override is not None:
+        return override
     if hbm_bytes is None:
         hbm_bytes = device_hbm_bytes()
     return int(_RESIDENT_HBM_FRACTION * hbm_bytes)
@@ -154,9 +165,11 @@ def derive_mesh_spec(n_devices: int,
     coalesced throughput."""
     if n_devices <= 1:
         return MeshSpec({DATA_AXIS: 1})
-    if hbm_bytes is None:
-        hbm_bytes = device_hbm_bytes()
-    budget = _PARAM_HBM_FRACTION * hbm_bytes
+    budget = _operator_budget_bytes()
+    if budget is None:
+        if hbm_bytes is None:
+            hbm_bytes = device_hbm_bytes()
+        budget = _PARAM_HBM_FRACTION * hbm_bytes
     tp = 1
     if heaviest_param_bytes:
         while (heaviest_param_bytes / tp > budget

@@ -125,8 +125,8 @@ class Settings:
     hive_outage_after: int = 3
     # ---- HBM model residency (serving/residency.py, ISSUE 8) ----
     # explicit resident-param budget in bytes; 0 = auto (the
-    # CHIASWARM_RESIDENCY_BUDGET env var, else the classic HBM fraction
-    # from core/mesh.py as the initial no-model-loaded fallback)
+    # CHIASWARM_RESIDENCY_BUDGET env var, else the residency share of
+    # the chip's reported HBM, core/mesh.py::resident_param_budget_bytes)
     residency_budget_bytes: int = 0
     # demand-driven prefetch: idle polls warm-load the hottest evicted
     # model back into free budget (CHIASWARM_RESIDENCY_PREFETCH=0 and

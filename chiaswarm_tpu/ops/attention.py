@@ -14,7 +14,7 @@ Five implementations behind one function:
                      (parallel/ring_attention.py): tokens sharded over the
                      mesh's ``seq`` axis, KV blocks rotated with ppermute.
                      Engaged when the pipeline runs under
-                     parallel.context.sequence_parallel on a seq>1 mesh —
+                     parallel.context.param_mesh on a seq>1 mesh —
                      self-attention only (cross-attention KV is 77 tokens).
                      The exactness oracle for the fused kernel.
 - ``"ring_flash"`` — fused Pallas ring-flash kernel
@@ -246,7 +246,7 @@ def attention(
             # mesh has no seq axis — they keep their local paths)
             raise ValueError(
                 f"impl={impl!r} requires an active sequence-parallel mesh "
-                "(parallel.context.sequence_parallel)")
+                "(parallel.context.param_mesh)")
         # mesh active but shape not divisible by the seq axis:
         # correctness first, fall through to the local paths
         impl = "auto"

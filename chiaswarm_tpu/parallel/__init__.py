@@ -13,7 +13,7 @@ pod is one SPMD machine, so this layer adds what the reference never had:
 - multi-host: `jax.distributed.initialize` wrapper (parallel/distributed.py)
 """
 
-from chiaswarm_tpu.parallel.context import active_seq_mesh, sequence_parallel
+from chiaswarm_tpu.parallel.context import active_seq_mesh, param_mesh
 from chiaswarm_tpu.parallel.ring_attention import ring_attention
 from chiaswarm_tpu.parallel.sharding import (
     param_partition_specs,
@@ -26,6 +26,6 @@ __all__ = [
     "ring_attention",
     "param_partition_specs",
     "param_shardings",
-    "sequence_parallel",
+    "param_mesh",
     "shard_params",
 ]

@@ -5,7 +5,7 @@ The reference has no sequence-parallel serving mode (its long-input answer
 is single-GPU attention slicing, swarm/diffusion/diffusion_func.py:85-88).
 Here, a pipeline whose params live on a mesh with a ``seq`` axis > 1 routes
 its large self-attentions through `parallel.ring_attention` automatically:
-the pipeline enters :func:`sequence_parallel` around its jitted program, and
+the pipeline enters :func:`param_mesh` around its jitted program, and
 `ops.attention` reads :func:`active_seq_mesh` at TRACE time to decide the
 dispatch (a static decision — under `jax.jit` the context only needs to be
 live during the first call that traces).
@@ -53,10 +53,11 @@ def active_seq_mesh() -> Mesh | None:
 
 
 @contextlib.contextmanager
-def sequence_parallel(mesh: Mesh | None):
-    """Route qualifying attention over ``mesh``: through the ring kinds
-    when it has a ``seq`` axis > 1, through the shard_mapped flash kernel
-    on any multi-device mesh.
+def param_mesh(mesh: Mesh | None):
+    """Declare the mesh the traced program's params live on, so that
+    qualifying attention routes over it: through the ring kinds when it
+    has a ``seq`` axis > 1, through the shard_mapped flash kernel on any
+    multi-device mesh.
 
     Entering with None (or a one-device mesh) is a no-op, so pipelines
     can wrap their programs unconditionally."""
@@ -106,20 +107,20 @@ def capture_ring_calls():
         mod.ring_attention = real
 
 
-def seq_parallel_wrap(jitted, params):
+def param_mesh_wrap(jitted, params):
     """Wrap a jitted pipeline program so it traces (and re-traces, after
-    executable-LRU rebuilds) under :func:`sequence_parallel` whenever
-    ``params`` live on a multi-device mesh — the single hook every
-    pipeline uses to make ring attention (seq > 1) and the shard_map'd
-    flash kernel (any dp x tp x sp mesh) serving paths rather than demos.
-    Single-chip callers get the jitted fn back untouched (zero overhead
-    on the common path)."""
+    executable-LRU rebuilds) under :func:`param_mesh` whenever ``params``
+    live on a multi-device mesh — the single hook every pipeline uses to
+    make ring attention (seq > 1) and the shard_map'd flash kernel (any
+    dp x tp x sp mesh) serving paths rather than demos. Single-chip
+    callers get the jitted fn back untouched (zero overhead on the
+    common path)."""
     mesh = _mesh_of_params(params)
     if mesh is None:
         return jitted
 
     def wrapped(*args):
-        with sequence_parallel(mesh):
+        with param_mesh(mesh):
             return jitted(*args)
 
     return wrapped

@@ -30,7 +30,7 @@ from chiaswarm_tpu.core.compile_cache import (
     bucket_batch,
     static_cache_key,
 )
-from chiaswarm_tpu.parallel.context import seq_parallel_wrap
+from chiaswarm_tpu.parallel.context import param_mesh_wrap
 from chiaswarm_tpu.core.rng import key_for_seed
 from chiaswarm_tpu.models.clap import ClapTextConfig, ClapTextEncoder
 from chiaswarm_tpu.models.configs import (
@@ -254,7 +254,7 @@ class AudioPipeline:
             mel = vae.apply(params["vae"], x, method=AutoencoderKL.decode)
             return voc.apply(params["vocoder"], mel[..., 0])
 
-        return seq_parallel_wrap(toplevel_jit(fn), self.c.params)
+        return param_mesh_wrap(toplevel_jit(fn), self.c.params)
 
     def _get_fn(self, **static):
         return GLOBAL_CACHE.cached_executable(

@@ -39,7 +39,7 @@ from chiaswarm_tpu.core.compile_cache import (
     bucket_batch,
     static_cache_key,
 )
-from chiaswarm_tpu.parallel.context import seq_parallel_wrap
+from chiaswarm_tpu.parallel.context import param_mesh_wrap
 from chiaswarm_tpu.core.rng import key_for_seed
 from chiaswarm_tpu.models.common import upsample2x_nearest
 from chiaswarm_tpu.models.configs import UNetConfig
@@ -276,7 +276,7 @@ class CascadePipeline:
             return (jnp.clip((y + 1.0) * 127.5 + 0.5, 0.0, 255.0)
                     ).astype(jnp.uint8)
 
-        return seq_parallel_wrap(toplevel_jit(fn), self.c.params)
+        return param_mesh_wrap(toplevel_jit(fn), self.c.params)
 
     def _get_fn(self, **static):
         return GLOBAL_CACHE.cached_executable(

@@ -7,9 +7,8 @@ Flax modules are cheap static descriptions; the params live on device.
 
 Construction paths:
 - :meth:`Components.random` — seeded random weights for hermetic tests,
-  benchmarks and the chip smoke (weights don't change FLOPs): flax's own
-  init program for the small test families, host materialization
-  (:func:`materialize_host`) from ``HOST_INIT_MIN_PARAMS`` parameters up.
+  benchmarks and the chip smoke (weights don't change FLOPs), materialized
+  on the host (:func:`materialize_host`) at every width.
 - :meth:`Components.from_checkpoint` — converted torch/safetensors weights
   via chiaswarm_tpu.convert (the initialize-time warm cache replacing
   swarm/initialize.py:62-94).
@@ -30,17 +29,6 @@ from chiaswarm_tpu.models.configs import FAMILIES, ModelFamily, get_family
 from chiaswarm_tpu.models.tokenizer import HashTokenizer, Tokenizer, load_tokenizer
 from chiaswarm_tpu.models.unet import UNet
 from chiaswarm_tpu.models.vae import AutoencoderKL
-
-
-#: Parameter count from which random weights are materialized on the HOST
-#: instead of by flax's jitted init program. The init program is fp32 and
-#: runs on the device: at published widths (SD1.5 is 1.07 B parameters,
-#: SDXL 3.47 B) it needs 2-4x the chip's share for weights before the first
-#: job and minutes of init-graph compilation. Below the bar sit only the
-#: ``tiny*`` test families (< 1 M parameters), whose CPU parity tolerances
-#: were set against flax's initializers. The rule reads the family's
-#: abstract parameter count — never the platform.
-HOST_INIT_MIN_PARAMS = 1 << 28
 
 
 def param_count(shape_tree) -> int:
@@ -103,9 +91,8 @@ def materialize_host(shape_tree, rng, dtype: str = "bfloat16"):
 def abstract_params(family: ModelFamily | str) -> dict[str, Any]:
     """Param SHAPE trees for every module of a family — pure
     ``jax.eval_shape`` tracing, no arrays and no compile (cached per
-    family: SDXL takes seconds to trace). Drives random_host
-    materialization, the host-vs-device init choice and the mesh
-    policy's size estimate."""
+    family: SDXL takes seconds to trace). Drives random-weight
+    materialization and the mesh policy's size estimate."""
     if isinstance(family, str):
         family = FAMILIES[family]
     return _trace_abstract_params(family)
@@ -194,84 +181,18 @@ class Components:
 
     @classmethod
     def random(cls, family: ModelFamily | str, seed: int = 0,
-               model_name: str | None = None) -> "Components":
+               model_name: str | None = None,
+               dtype: str = "bfloat16") -> "Components":
         """Seeded random components — what the registry serves under
-        ``allow_random``. Families of ``HOST_INIT_MIN_PARAMS`` parameters
-        or more (every published width) come from :meth:`random_host`:
-        bf16 host values, like a converted checkpoint, for the caller to
-        place. Smaller ones (the ``tiny*`` test families) run flax's
-        initializers as one jitted fp32 program per module. The choice
-        reads the family's abstract parameter count, not the platform."""
-        if isinstance(family, str):
-            family = FAMILIES[family]
-        if param_count(abstract_params(family)) >= HOST_INIT_MIN_PARAMS:
-            return cls.random_host(family, seed, model_name)
-        key = jax.random.PRNGKey(seed)
-        text_encoders = [ClipTextEncoder(cfg) for cfg in family.text_encoders]
-        tokenizers = [
-            HashTokenizer(cfg.vocab_size, cfg.max_position_embeddings,
-                          cfg.eos_token_id)
-            for cfg in family.text_encoders
-        ]
-        unet = UNet(family.unet)
-        vae = AutoencoderKL(family.vae)
-
-        # jit every init: eager flax init dispatches thousands of tiny
-        # ops; one compiled program per module is one dispatch
-        params: dict[str, Any] = {}
-        ids = jnp.zeros((1, family.text_encoders[0].max_position_embeddings),
-                        jnp.int32)
-        for i, te in enumerate(text_encoders):
-            key, sub = jax.random.split(key)
-            params[f"text_encoder_{i}"] = jax.jit(te.init)(sub, ids)
-
-        latent = jnp.zeros(
-            (1, 8, 8, family.unet.sample_channels), jnp.float32
-        )
-        ctx = jnp.zeros((1, ids.shape[1], family.unet.cross_attention_dim),
-                        jnp.float32)
-        added = None
-        if family.unet.addition_embed_dim is not None:
-            added = {
-                "time_ids": jnp.zeros((1, 6), jnp.float32),
-                "text_embeds": jnp.zeros(
-                    (1, family.unet.addition_pooled_dim), jnp.float32
-                ),
-            }
-        labels = (jnp.zeros((1,), jnp.int32)
-                  if family.unet.num_class_embeds is not None else None)
-        key, sub = jax.random.split(key)
-        params["unet"] = jax.jit(
-            lambda k, s, t, c, a, cl: unet.init(k, s, t, c, a,
-                                                class_labels=cl)
-        )(sub, latent, jnp.zeros((1,)), ctx, added, labels)
-        key, sub = jax.random.split(key)
-        params["vae"] = jax.jit(vae.init)(
-            sub, jnp.zeros((1, 16, 16, family.vae.in_channels), jnp.float32)
-        )
-        return cls(
-            family=family,
-            model_name=model_name or f"random/{family.name}",
-            tokenizers=tokenizers,
-            text_encoders=text_encoders,
-            unet=unet,
-            vae=vae,
-            params=params,
-        )
-
-    @classmethod
-    def random_host(cls, family: ModelFamily | str, seed: int = 0,
-                    model_name: str | None = None,
-                    dtype: str = "bfloat16") -> "Components":
-        """Random components built WITHOUT running any XLA program: module
+        ``allow_random`` — built WITHOUT running any XLA program: module
         param shapes come from ``jax.eval_shape`` (abstract tracing) and
-        the values from host numpy (:func:`materialize_host`). On-device
-        fp32 init of SDXL-class weights both exhausts a single chip's HBM
-        and takes minutes of init-graph compilation; this path takes well
-        under a minute and the FLOPs/memory traffic are identical to a
-        converted checkpoint. The params stay on the HOST, as
-        ``from_checkpoint``'s do — the registry (or the caller) places
-        them."""
+        the values from host numpy (:func:`materialize_host`). One path
+        for every width: flax's own jitted fp32 init would, at SDXL
+        width, exhaust a single chip's HBM and compile for minutes,
+        where this takes well under a minute and leaves FLOPs and memory
+        traffic identical to a converted checkpoint's. The params stay
+        on the HOST, as ``from_checkpoint``'s do — the registry (or the
+        caller) places them."""
         import numpy as np
 
         if isinstance(family, str):
@@ -350,54 +271,13 @@ class ControlNetBundle:
 
     @classmethod
     def random(cls, family: ModelFamily | str, seed: int = 0,
-               model_name: str | None = None) -> "ControlNetBundle":
-        from chiaswarm_tpu.models.controlnet import (
-            ControlCondEmbedding,
-            ControlNet,
-        )
-
-        if isinstance(family, str):
-            family = FAMILIES[family]
-        # same rule as Components.random, on the base family's size
-        if param_count(abstract_params(family)) >= HOST_INIT_MIN_PARAMS:
-            return cls.random_host(family, seed, model_name)
-        cfg = family.unet
-        key = jax.random.PRNGKey(seed)
-        net = ControlNet(cfg)
-        embed = ControlCondEmbedding(cfg.block_out_channels[0],
-                                     downscale=family.vae.downscale)
-        f = family.vae.downscale
-        lh = lw = 8
-        latent = jnp.zeros((1, lh, lw, cfg.sample_channels), jnp.float32)
-        cond = jnp.zeros((1, lh * f, lw * f, 3), jnp.float32)
-        ctx = jnp.zeros((1, 77, cfg.cross_attention_dim), jnp.float32)
-        added = None
-        if cfg.addition_embed_dim is not None:
-            added = {
-                "time_ids": jnp.zeros((1, 6), jnp.float32),
-                "text_embeds": jnp.zeros(
-                    (1, cfg.addition_pooled_dim), jnp.float32),
-            }
-        key, k1, k2 = jax.random.split(key, 3)
-        params = {
-            "embed": jax.jit(embed.init)(k1, cond),
-        }
-        cond_emb = embed.apply(params["embed"], cond)
-        params["net"] = jax.jit(net.init)(
-            k2, latent, jnp.zeros((1,)), ctx, cond_emb, added
-        )
-        return cls(family=family,
-                   model_name=model_name or f"random/controlnet-{family.name}",
-                   params=params)
-
-    @classmethod
-    def random_host(cls, family: ModelFamily | str, seed: int = 0,
-                    model_name: str | None = None,
-                    dtype: str = "bfloat16") -> "ControlNetBundle":
-        """Host-materialized random bundle (see ``materialize_host``) —
-        SDXL-class control branches without an on-device init program.
-        Unlike flax's init, the zero convs come out NON-zero: a random
-        bundle is for exercising the branch, not for a no-op start."""
+               model_name: str | None = None,
+               dtype: str = "bfloat16") -> "ControlNetBundle":
+        """Seeded random bundle, host-materialized like
+        :meth:`Components.random`. The output ("zero") convs come out
+        NON-zero, unlike an untrained ControlNet's: a random bundle
+        stands in for a trained checkpoint, to exercise the branch — a
+        zero head would hide whatever runs under it."""
         import numpy as np
 
         from chiaswarm_tpu.models.controlnet import (

@@ -42,7 +42,7 @@ from chiaswarm_tpu.obs import numerics as _numerics
 from chiaswarm_tpu.obs import trace as obs_trace
 from chiaswarm_tpu.obs.profiling import annotate
 from chiaswarm_tpu.obs.trace import span
-from chiaswarm_tpu.parallel.context import seq_parallel_wrap
+from chiaswarm_tpu.parallel.context import param_mesh_wrap
 from chiaswarm_tpu.convert.quantize import (
     dequantize_tree,
     fake_quant_activation,
@@ -614,7 +614,7 @@ class DiffusionPipeline:
         # seq>1 param meshes trace under the sequence-parallel context so
         # ops.attention routes the large spatial self-attentions through
         # the ppermute ring (parallel/ring_attention.py)
-        return seq_parallel_wrap(toplevel_jit(fn), self.c.params)
+        return param_mesh_wrap(toplevel_jit(fn), self.c.params)
 
     def _get_fn(self, **static: Any):
         return GLOBAL_CACHE.cached_executable(
@@ -689,7 +689,7 @@ class DiffusionPipeline:
                 ctx_u, pooled_u = encode_text(params, neg_ids)
                 return ctx_u, ctx_c, pooled_u, pooled_c
 
-            return seq_parallel_wrap(toplevel_jit(fn), self.c.params)
+            return param_mesh_wrap(toplevel_jit(fn), self.c.params)
 
         return GLOBAL_CACHE.cached_executable(
             static_cache_key(id(self.c), "stepper_encode",
@@ -870,7 +870,7 @@ class DiffusionPipeline:
                             cache_u_next, cache_c_next)
                 return x_next, keys, idx_next, new_old
 
-            return seq_parallel_wrap(toplevel_jit(fn), self.c.params)
+            return param_mesh_wrap(toplevel_jit(fn), self.c.params)
 
         # the reuse flag joins the static key only when set, so every
         # pre-existing lane bucket keeps its historical key (and cached
@@ -922,7 +922,7 @@ class DiffusionPipeline:
                 return (jnp.clip((img + 1.0) * 127.5 + 0.5, 0.0, 255.0)
                         ).astype(jnp.uint8)
 
-            return seq_parallel_wrap(toplevel_jit(fn), self.c.params)
+            return param_mesh_wrap(toplevel_jit(fn), self.c.params)
 
         return GLOBAL_CACHE.cached_executable(
             static_cache_key(id(self.c), "stepper_decode",

@@ -34,7 +34,7 @@ from chiaswarm_tpu.core.compile_cache import (
     bucket_image_size,
     static_cache_key,
 )
-from chiaswarm_tpu.parallel.context import seq_parallel_wrap
+from chiaswarm_tpu.parallel.context import param_mesh_wrap
 from chiaswarm_tpu.core.rng import key_for_seed
 from chiaswarm_tpu.models.common import upsample2x_nearest
 from chiaswarm_tpu.models.vae import AutoencoderKL, tiled_decode
@@ -134,7 +134,7 @@ class LatentUpscalePipeline:
             return (jnp.clip((img + 1.0) * 127.5 + 0.5, 0.0, 255.0)
                     ).astype(jnp.uint8)
 
-        return seq_parallel_wrap(toplevel_jit(fn), self.c.params)
+        return param_mesh_wrap(toplevel_jit(fn), self.c.params)
 
     def _get_fn(self, **static):
         return GLOBAL_CACHE.cached_executable(
@@ -297,7 +297,7 @@ class Upscale4xPipeline:
             return (jnp.clip((img + 1.0) * 127.5 + 0.5, 0.0, 255.0)
                     ).astype(jnp.uint8)
 
-        return seq_parallel_wrap(toplevel_jit(fn), self.c.params)
+        return param_mesh_wrap(toplevel_jit(fn), self.c.params)
 
     def _get_fn(self, **static):
         return GLOBAL_CACHE.cached_executable(

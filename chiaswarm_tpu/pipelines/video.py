@@ -13,7 +13,6 @@ bf16 + flash attention are always on.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import jax
@@ -26,7 +25,7 @@ from chiaswarm_tpu.core.compile_cache import (
     bucket_image_size,
     static_cache_key,
 )
-from chiaswarm_tpu.parallel.context import seq_parallel_wrap
+from chiaswarm_tpu.parallel.context import param_mesh_wrap
 from chiaswarm_tpu.core.rng import key_for_seed
 from chiaswarm_tpu.models.clip import (
     ClipTextEncoder,
@@ -39,11 +38,7 @@ from chiaswarm_tpu.models.configs import (
     VAEConfig,
 )
 from chiaswarm_tpu.models.tokenizer import HashTokenizer
-from chiaswarm_tpu.pipelines.components import (
-    HOST_INIT_MIN_PARAMS,
-    materialize_host,
-    param_count,
-)
+from chiaswarm_tpu.pipelines.components import materialize_host
 from chiaswarm_tpu.models.vae import (
     AutoencoderKL,
     AutoencoderKLTemporalDecoder,
@@ -234,13 +229,6 @@ def _vae_init_args(family: VideoFamily):
     return (jnp.zeros((1, 16, 16, family.vae.in_channels)),)
 
 
-@functools.lru_cache(maxsize=None)
-def _unet_param_count(family: VideoFamily) -> int:
-    return param_count(jax.eval_shape(
-        make_video_unet(family).init, jax.random.PRNGKey(0),
-        *_unet_init_args(family)))
-
-
 @dataclasses.dataclass
 class VideoComponents:
     family: VideoFamily
@@ -254,50 +242,12 @@ class VideoComponents:
 
     @classmethod
     def random(cls, family: VideoFamily | str, seed: int = 0,
-               model_name: str | None = None) -> "VideoComponents":
-        if isinstance(family, str):
-            family = VIDEO_FAMILIES[family]
-        unet = make_video_unet(family)
-        # same rule as Components.random (pipelines/components.py):
-        # published widths materialize on the host, judged by the
-        # UNet's abstract parameter count
-        if _unet_param_count(family) >= HOST_INIT_MIN_PARAMS:
-            return cls.random_host(family, seed, model_name)
-        key = jax.random.PRNGKey(seed)
-        vae = make_video_vae(family)
-        key, k1, k2, k3 = jax.random.split(key, 4)
-        params = {
-            "unet": jax.jit(unet.init)(k2, *_unet_init_args(family)),
-            "vae": jax.jit(vae.init)(k3, *_vae_init_args(family)),
-        }
-        te = tokenizer = image_encoder = None
-        if family.image_conditioned:
-            image_encoder = ClipVisionEncoder(family.vision)
-            s = family.vision.image_size
-            params["image_encoder"] = jax.jit(image_encoder.init)(
-                k1, jnp.zeros((1, s, s, 3)))
-        else:
-            te = ClipTextEncoder(family.text_encoder)
-            tokenizer = HashTokenizer(
-                family.text_encoder.vocab_size,
-                family.text_encoder.max_position_embeddings,
-                family.text_encoder.eos_token_id)
-            ids = jnp.zeros(
-                (1, family.text_encoder.max_position_embeddings), jnp.int32)
-            params["text_encoder"] = jax.jit(te.init)(k1, ids)
-        return cls(family=family,
-                   model_name=model_name or f"random/{family.name}",
-                   tokenizer=tokenizer, text_encoder=te, unet=unet, vae=vae,
-                   params=params, image_encoder=image_encoder)
-
-    @classmethod
-    def random_host(cls, family: VideoFamily | str, seed: int = 0,
-                    model_name: str | None = None,
-                    dtype: str = "bfloat16") -> "VideoComponents":
-        """Host-materialized random components (components.py
-        ``materialize_host``): ModelScope/SVD-class weights without an
-        on-device init program; the params stay on the host for the
-        registry (or the caller) to place."""
+               model_name: str | None = None,
+               dtype: str = "bfloat16") -> "VideoComponents":
+        """Seeded random components, host-materialized at every width
+        (components.py ``materialize_host``): no on-device init program;
+        the params stay on the host for the registry (or the caller) to
+        place."""
         import numpy as np
 
         if isinstance(family, str):
@@ -601,7 +551,7 @@ class VideoPipeline:
             return (jnp.clip((img + 1.0) * 127.5 + 0.5, 0.0, 255.0)
                     ).astype(jnp.uint8)   # (F, H, W, 3) uint8
 
-        return seq_parallel_wrap(toplevel_jit(fn), self.c.params)
+        return param_mesh_wrap(toplevel_jit(fn), self.c.params)
 
     def _get_fn(self, **static):
         return GLOBAL_CACHE.cached_executable(
@@ -756,7 +706,7 @@ class Img2VidPipeline:
             return (jnp.clip((img + 1.0) * 127.5 + 0.5, 0.0, 255.0)
                     ).astype(jnp.uint8)   # (F, H, W, 3)
 
-        return seq_parallel_wrap(toplevel_jit(fn), self.c.params)
+        return param_mesh_wrap(toplevel_jit(fn), self.c.params)
 
     def _get_fn(self, **static):
         return GLOBAL_CACHE.cached_executable(

@@ -73,7 +73,6 @@ from chiaswarm_tpu.obs import metrics as obs_metrics
 
 log = logging.getLogger("chiaswarm.residency")
 
-ENV_BUDGET = "CHIASWARM_RESIDENCY_BUDGET"
 ENV_HARD_LIMIT = "CHIASWARM_RESIDENCY_HARD_LIMIT"
 ENV_PREFETCH = "CHIASWARM_RESIDENCY_PREFETCH"
 
@@ -152,17 +151,12 @@ class ArrivalEwma:
 def default_budget_bytes() -> int:
     """Resident-param budget: ``CHIASWARM_RESIDENCY_BUDGET`` wins, else
     the residency share of the chip's reported HBM
-    (core/mesh.py::resident_param_budget_bytes)."""
-    try:
-        from chiaswarm_tpu.core.mesh import resident_param_budget_bytes
+    (core/mesh.py::resident_param_budget_bytes). A chip that reports no
+    memory limit raises there, and the error is the caller's — a ledger
+    planned against a guessed chip is how a broken device looks fine."""
+    from chiaswarm_tpu.core.mesh import resident_param_budget_bytes
 
-        return resident_param_budget_bytes()
-    except Exception:  # no jax / no devices: the old CompileCache default
-        raw = os.environ.get(ENV_BUDGET, "").strip()
-        if raw:
-            with contextlib.suppress(ValueError):
-                return max(1, int(float(raw)))
-        return 24 * 1024**3
+    return resident_param_budget_bytes()
 
 
 def default_hard_limit_bytes(budget: int) -> int:
@@ -173,12 +167,9 @@ def default_hard_limit_bytes(budget: int) -> int:
     if raw:
         with contextlib.suppress(ValueError):
             return max(int(budget), int(float(raw)))
-    try:
-        from chiaswarm_tpu.core.mesh import device_hbm_bytes
+    from chiaswarm_tpu.core.mesh import device_hbm_bytes
 
-        return max(int(budget), int(0.9 * device_hbm_bytes()))
-    except Exception:
-        return int(budget) * 2
+    return max(int(budget), int(0.9 * device_hbm_bytes()))
 
 
 def prefetch_enabled_default() -> bool:

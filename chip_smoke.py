@@ -14,12 +14,16 @@ with seeded random weights, and checks what comes out. In order:
 2. kernel pre-flight: the Pallas flash kernel compiles under Mosaic and
    agrees with the einsum reference at SDXL's two self-attention shapes;
 3. main path: two waves of three txt2img jobs (steps 30, 30, 20 — only
-   lanes can merge mixed step counts) through a real ``Worker``;
+   lanes can merge mixed step counts) through a real ``Worker``, with the
+   residency ledger filled to its default budget first, so the lane and
+   the decode run above as many resident bytes as the worker will ever
+   hold by itself;
 4. asserts, each fatal: every job ok with a lane stamp and a finite,
-   non-constant 1024x1024 PNG whose sha256 matches; the lane step program
-   holds one Mosaic custom call per >=1024-token self-attention; wave 2
-   compiles nothing; no OOM halving, watchdog condemnation or timeout;
-   the native codec loaded; the worker drains and the lanes stop.
+   non-constant 1024x1024 PNG whose sha256 matches; every lane step
+   program holds Mosaic custom calls for its flash self-attentions; wave
+   2 compiles nothing; nothing was evicted; no OOM halving, watchdog
+   condemnation or timeout; the native codec loaded; the worker drains
+   and the lanes stop.
 
 No phase sits in a try/except: the first failure is the exit. On success
 the LAST line of stdout is one JSON object ``{"ok": true, "device": {...},
@@ -28,8 +32,8 @@ sanity value of this run — NOT a benchmark metric (the repo's speed
 numbers come from the benchmark, not from here).
 
 ``--chips N`` (N > 1) runs the same waves on the worker's own default pool
-over an N-device host and additionally checks that every device holds
-param shards and ran the lane program.
+over a host of at least N devices and additionally checks that every
+device holds param shards and ran the lane program.
 """
 
 from __future__ import annotations
@@ -60,8 +64,8 @@ SDXL_ATTN_SHAPES = ((2, 4096, 10, 64), (2, 1024, 20, 64))
 ATTN_TOLERANCE = 2.0 ** -6
 #: per wave; two rows retire on one boundary, the third earlier
 WAVE_STEPS = (30, 30, 20)
-#: the flash kernel's engagement bar in ops/attention.py ``auto``
-FLASH_MIN_TOKENS = 1024
+#: the ledger entry that fills the residency budget (see fill_residency)
+BALLAST = "chip-smoke/ballast"
 
 
 def log(msg: str) -> None:
@@ -91,13 +95,12 @@ def device_facts(require_tpu: bool, chips: int) -> dict:
         log(f"no TPU: jax.devices() = {devices}, JAX_PLATFORMS = "
             f"{os.environ.get('JAX_PLATFORMS')!r}")
         raise SystemExit(2)
-    if chips > 1 and len(devices) != chips:
-        # the N-chip run tests what a stock worker does with this HOST
-        # (Worker._default_pool takes every device jax reports)
-        log(f"--chips {chips} needs exactly {chips} devices, jax reports "
-            f"{len(devices)}: {devices}")
+    if len(devices) < chips:
+        log(f"--chips {chips}: jax reports {len(devices)}: {devices}")
         raise SystemExit(2)
     stats = dev0.memory_stats() or {}
+    require(stats.get("bytes_limit") or dev0.platform != "tpu",
+            f"{dev0} reports no memory_stats()['bytes_limit']: {stats}")
     facts = {
         "platform": dev0.platform,
         "kind": dev0.device_kind,
@@ -143,20 +146,33 @@ def kernel_preflight(shapes) -> list[dict]:
     return rows
 
 
-def expected_flash_calls(unet_cfg, lh: int, lw: int) -> int:
-    """How many self-attentions of ``models/unet.py`` see at least
-    FLASH_MIN_TOKENS tokens at a (lh, lw) latent grid — each must be one
-    Mosaic custom call in a program compiled for the TPU."""
-    depths = list(unet_cfg.transformer_depth)
-    per_level = 2 * unet_cfg.layers_per_block + 1   # down + up blocks
-    n = 0
-    for level, depth in enumerate(depths):
-        if (lh >> level) * (lw >> level) >= FLASH_MIN_TOKENS:
-            n += depth * per_level
-    last = len(depths) - 1
-    if (lh >> last) * (lw >> last) >= FLASH_MIN_TOKENS:
-        n += max(depths) or 1                       # mid block
-    return n
+def fill_residency(registry, family: str, mesh) -> int:
+    """Make the ledger FULL before the first job: a resident entry of
+    (default budget - the model's footprint estimate) bytes on every chip
+    of the slot. What the worker may keep resident by itself is a share
+    of HBM chosen in ``core/mesh.py``; this is what holds that share to
+    the chip — a lane or decode that no longer fits above a full ledger
+    fails here (OOM halving, an error envelope) instead of in service.
+    The model's own load must then fit EXACTLY, evicting nothing."""
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from chiaswarm_tpu.pipelines.components import (
+        estimate_family_bytes,
+        measured_param_bytes,
+    )
+
+    ledger = registry.residency
+    rows = (ledger.budget_bytes - estimate_family_bytes(family)) // 4096
+    require(rows > 0, f"{family} alone exceeds the residency budget "
+                      f"({ledger.budget_bytes} bytes)")
+    ledger.acquire(
+        BALLAST,
+        lambda: jax.device_put(np.zeros((rows, 1024), np.float32),
+                               NamedSharding(mesh, PartitionSpec())),
+        model=BALLAST, size_of=measured_param_bytes)
+    return rows * 4096
 
 
 def check_result(result: dict, size: int) -> dict:
@@ -217,7 +233,8 @@ async def settle(hive, run: asyncio.Task, n_total: int,
     return arrived
 
 
-async def drive(worker, hive, model: str, size: int, steps) -> dict:
+async def drive(worker, hive, capture, model: str, size: int,
+                steps) -> dict:
     """Phase 3: two waves through ``Worker.run()``, then a graceful stop."""
     from chiaswarm_tpu.obs.metrics import REGISTRY
 
@@ -229,8 +246,12 @@ async def drive(worker, hive, model: str, size: int, steps) -> dict:
                  "content_type": "image/png"}
                 for i, count in enumerate(steps)]
 
-    def compiles() -> dict:
-        return dict(REGISTRY.snapshot()["chiaswarm_compiles_total"]["values"])
+    def compiles() -> tuple[dict, int]:
+        # the counter sees first calls of compile-cache entries; the
+        # capture sees every new input signature of an entry that exists
+        # (what plain jit would answer with a silent retrace)
+        counters = REGISTRY.snapshot()["chiaswarm_compiles_total"]["values"]
+        return dict(counters), len(capture.executables)
 
     run = asyncio.create_task(worker.run())
     t0 = time.monotonic()
@@ -247,7 +268,8 @@ async def drive(worker, hive, model: str, size: int, steps) -> dict:
     arrived = await settle(hive, run, 2 * len(steps), timeout=300.0)
     compiles_after = compiles()
     require(compiles_after == compiles_before,
-            f"wave 2 compiled: {compiles_before} -> {compiles_after}")
+            f"wave 2 compiled (counters, programs): {compiles_before} -> "
+            f"{compiles_after}")
 
     health = worker.health()
     worker.request_stop()
@@ -255,7 +277,7 @@ async def drive(worker, hive, model: str, size: int, steps) -> dict:
     return {"wave1_s": round(wave1_s, 1),
             "wave2_job_s": {job_id: round(t - t1, 2)
                             for job_id, t in sorted(arrived.items())},
-            "compiles": compiles_after, "health": health}
+            "compiles": compiles_after[0], "health": health}
 
 
 def run_smoke(family: str, size: int, *, require_tpu: bool, chips: int = 1,
@@ -287,6 +309,7 @@ def run_smoke(family: str, size: int, *, require_tpu: bool, chips: int = 1,
     )
     from chiaswarm_tpu.obs.metrics import REGISTRY
     from chiaswarm_tpu.pipelines import diffusion as diffusion_mod
+    from chiaswarm_tpu.serving.residency import ResidencyManager
 
     cache_dir = Path(enable_persistent_compilation_cache())
 
@@ -302,8 +325,10 @@ def run_smoke(family: str, size: int, *, require_tpu: bool, chips: int = 1,
     out_dir.mkdir(parents=True)
     os.environ["SWARM_TPU_ROOT"] = str(out_dir / "root")
     model = f"smoke/{family}"
+    # a ledger made like the process-wide default one, but after the
+    # settings root moved (a test process may hold an older default)
     registry = ModelRegistry(catalog=[{"name": model, "family": family}],
-                             allow_random=True)
+                             allow_random=True, residency=ResidencyManager())
     # one chip is the shape every number in ROADMAP assumes, whatever the
     # host holds; N > 1 is the stock worker's own default pool
     pool = (ChipPool(n_slots=1, devices=jax.devices()[:1])
@@ -324,9 +349,11 @@ def run_smoke(family: str, size: int, *, require_tpu: bool, chips: int = 1,
                 poll_busy_s=0.25, poll_idle_s=0.25, job_deadline_s=1000.0,
                 install_signal_handlers=False),
             registry=registry, pool=pool)
-        driven = await drive(worker, hive, model, size, steps)
+        ballast = fill_residency(registry, family, worker.pool.slots[0].mesh)
+        driven = await drive(worker, hive, capture, model, size, steps)
         await hive.stop()
-        return {"hive": hive, "worker": worker, **driven}
+        return {"hive": hive, "worker": worker, "ballast": ballast,
+                **driven}
 
     with capture.patching(diffusion_mod):
         ran = asyncio.run(scenario())
@@ -355,13 +382,13 @@ def run_smoke(family: str, size: int, *, require_tpu: bool, chips: int = 1,
                               for st in steppers),
             f"lanes outlived the worker's drain: {alive}")
     require(native.load() is not None, "native codec not loaded")
+    ledger = registry.residency.snapshot()
+    require(ledger["resident_models"] == sorted([BALLAST, model])
+            and not ledger["evictions"] and not ledger["degraded_loads"],
+            f"the full ledger did not hold: {ledger}")
 
     # the census obs/hlocost already takes of a compiled program: every
     # lane step executable this run built, flash custom calls counted
-    pipe = registry.pipeline(model, mesh=worker.pool.slots[0].mesh)
-    lh, lw = pipe._latent_hw(size, size)
-    want_flash = (expected_flash_calls(pipe.c.family.unet, lh, lw)
-                  if device["platform"] == "tpu" else 0)
     flash_calls = []
     with open(out_dir / "mosaic_calls.txt", "w") as dump:
         for i, compiled in enumerate(capture.executables):
@@ -372,18 +399,18 @@ def run_smoke(family: str, size: int, *, require_tpu: bool, chips: int = 1,
             dump.writelines(f"program {i}: {line.strip()[:400]}\n"
                             for line in text.splitlines()
                             if "tpu_custom_call" in line)
-    if want_flash:
+    if device["platform"] == "tpu":
         # only the UNet step programs (one per lane width) attend over
-        # >= FLASH_MIN_TOKENS tokens through ops.attention's auto pick
-        require(any(flash_calls)
-                and all(n in (0, want_flash) for n in flash_calls),
-                f"compiled programs hold {flash_calls} flash custom calls; "
-                f"{want_flash} self-attentions of the lane step program "
-                f"see >= {FLASH_MIN_TOKENS} tokens")
+        # enough tokens for ops.attention's auto pick to take the kernel
+        step_programs = int(ran["compiles"]["stepper_step"])
+        require(sum(1 for n in flash_calls if n) >= step_programs >= 1,
+                f"{step_programs} lane step programs compiled, flash "
+                f"custom calls by program: {flash_calls}")
 
     mesh = worker.pool.slots[0].mesh
     pool_devices = list(mesh.devices.flatten())
     if chips > 1:
+        pipe = registry.pipeline(model, mesh=mesh)
         holders = {shard.device
                    for leaf in jax.tree.leaves(pipe.c.params)
                    for shard in leaf.addressable_shards}
@@ -392,9 +419,10 @@ def run_smoke(family: str, size: int, *, require_tpu: bool, chips: int = 1,
         for dev in pool_devices:
             # activations above the resident shards: the device ran work
             stats = dev.memory_stats()
-            require(not stats
-                    or stats["peak_bytes_in_use"] > stats["bytes_in_use"],
-                    f"{dev} never held more than its params: {stats}")
+            if stats or dev.platform == "tpu":
+                require(stats and (stats["peak_bytes_in_use"]
+                                   > stats["bytes_in_use"]),
+                        f"{dev} never held more than its params: {stats}")
 
     snapshot = REGISTRY.snapshot()
     compile_s = {tag: round(v["sum"], 2) for tag, v in
@@ -414,7 +442,6 @@ def run_smoke(family: str, size: int, *, require_tpu: bool, chips: int = 1,
             "cache_dir": str(cache_dir),
             "cache_entries_before": cache_before,
             "cache_entries_after": cache_entries(),
-            "flash_calls_expected": want_flash,
             "flash_calls_by_program": flash_calls,
         },
         "sanity": {
@@ -422,6 +449,9 @@ def run_smoke(family: str, size: int, *, require_tpu: bool, chips: int = 1,
             "attention_preflight": attn,
             "wave1_seconds_cold": ran["wave1_s"],
             "wave2_job_seconds": ran["wave2_job_s"],
+            "residency": {"budget_bytes": ledger["budget_bytes"],
+                          "resident_bytes": ledger["resident_bytes"],
+                          "ballast_bytes": ran["ballast"]},
             "peak_bytes_in_use": [
                 (dev.memory_stats() or {}).get("peak_bytes_in_use")
                 for dev in pool_devices],

@@ -33,17 +33,29 @@ def _cond_image():
 
 
 def test_zero_init_controlnet_is_noop(tiny_pipeline, tiny_controlnet):
-    """Freshly-initialized ControlNet has zero output convs: generation
-    must match plain txt2img exactly (the zero-conv design invariant)."""
+    """A ControlNet starts training with zero output convs, and with them
+    generation must match plain txt2img exactly (the zero-conv design
+    invariant). A random bundle's heads are non-zero — it stands in for
+    a trained checkpoint — so this zeroes them."""
+    import dataclasses
+
+    import jax
+
+    net = dict(tiny_controlnet.params["net"]["params"])
+    for key in net:
+        if key.startswith("controlnet_"):
+            net[key] = jax.tree.map(np.zeros_like, net[key])
+    untrained = dataclasses.replace(
+        tiny_controlnet, model_name="untrained/controlnet",
+        params={**tiny_controlnet.params, "net": {"params": net}})
+
     base = GenerateRequest(prompt="a fox", steps=3, height=64, width=64,
                           seed=5, guidance_scale=5.0)
     plain, _ = tiny_pipeline(base)
-    import dataclasses
-
     controlled, config = tiny_pipeline(dataclasses.replace(
-        base, controlnet=tiny_controlnet, control_image=_cond_image()))
+        base, controlnet=untrained, control_image=_cond_image()))
     assert np.array_equal(plain, controlled)
-    assert config["controlnet"] == tiny_controlnet.model_name
+    assert config["controlnet"] == untrained.model_name
 
 
 def test_trained_controlnet_steers(tiny_pipeline, tiny_controlnet):

@@ -60,7 +60,8 @@ def test_converted_checkpoint_generates(tmp_path):
 
 
 def test_lora_merge_diffusers_format():
-    src = Components.random("tiny", seed=1)
+    # fp32 weights: the merge arithmetic is checked to 1e-5
+    src = Components.random("tiny", seed=1, dtype="float32")
     kernel_path = ("down_0_attentions_0", "transformer_blocks_0", "attn1",
                    "to_q", "kernel")
     tree = src.params["unet"]["params"]
@@ -87,7 +88,7 @@ def test_lora_merge_diffusers_format():
 
 
 def test_lora_merge_peft_format():
-    src = Components.random("tiny", seed=2)
+    src = Components.random("tiny", seed=2, dtype="float32")
     tree = src.params["unet"]["params"]
     orig = np.asarray(tree["mid_attention"]["transformer_blocks_0"]
                       ["attn2"]["to_v"]["kernel"])

@@ -33,12 +33,23 @@ def test_mesh_bad_shape_raises():
         build_mesh(MeshSpec({"data": -1, "model": -1}))
 
 
-def test_derive_mesh_spec_policy():
+def test_derive_mesh_spec_policy(monkeypatch):
     """Default dp x tp policy: tp engages exactly when the heaviest
     family's params exceed the per-chip budget; everything else is dp."""
-    from chiaswarm_tpu.core.mesh import derive_mesh_spec
+    from chiaswarm_tpu.core.mesh import (
+        derive_mesh_spec,
+        resident_param_budget_bytes,
+    )
 
     gib = 1024**3
+    # the operator's byte figure outranks both HBM fractions: the tp bar
+    # and the ledger's budget move together under it
+    monkeypatch.setenv("CHIASWARM_RESIDENCY_BUDGET", str(12 * gib))
+    assert derive_mesh_spec(8, 7 * gib, hbm_bytes=16 * gib).shape == \
+        {"data": 8, "model": 1}
+    assert resident_param_budget_bytes(16 * gib) == 12 * gib
+    monkeypatch.delenv("CHIASWARM_RESIDENCY_BUDGET")
+    assert resident_param_budget_bytes(10 * gib) == 6 * gib
     # single chip: trivially dp=1
     assert derive_mesh_spec(1, 100 * gib).shape == {"data": 1}
     # small model on 8 chips: dp-only

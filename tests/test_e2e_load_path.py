@@ -83,7 +83,7 @@ def test_sd15_snapshot_to_artifact_envelope(tmp_path, monkeypatch):
                for k, v in text_model.state_dict().items()},
               str(root / "text_encoder" / "model.safetensors"))
 
-    src = Components.random_host(SD15, seed=0)
+    src = Components.random(SD15, seed=0)
     for sub, state in (
         ("unet", export_unet(src.params["unet"], 4)),
         ("vae", export_vae(src.params["vae"], 4)),

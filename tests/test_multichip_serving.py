@@ -64,7 +64,7 @@ def test_multichip_matches_single_chip_output():
 def test_seq_parallel_serving_matches_single_chip(monkeypatch):
     """latency_mode serving: params on a seq=4 mesh route the UNet's
     spatial self-attention through ring attention (ops/attention.py
-    _try_ring via parallel/context.py::seq_parallel_wrap) and the
+    _try_ring via parallel/context.py::param_mesh_wrap) and the
     pixels match the single-chip run."""
     from chiaswarm_tpu.parallel.context import capture_ring_calls
     from chiaswarm_tpu.pipelines import GenerateRequest

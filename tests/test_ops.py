@@ -143,7 +143,7 @@ def test_flash_under_a_dp_tp_mesh_is_shard_mapped(monkeypatch):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from chiaswarm_tpu.core.mesh import MeshSpec, build_mesh
-    from chiaswarm_tpu.parallel import sequence_parallel
+    from chiaswarm_tpu.parallel import param_mesh
 
     mesh = build_mesh(MeshSpec({"data": 2, "model": 2}),
                       devices=jax.devices()[:4])
@@ -159,5 +159,5 @@ def test_flash_under_a_dp_tp_mesh_is_shard_mapped(monkeypatch):
 
     with pytest.raises(NotImplementedError, match="shard_map"):
         lower()
-    with sequence_parallel(mesh):
+    with param_mesh(mesh):
         assert "tpu_custom_call" in lower().as_text()

@@ -42,11 +42,11 @@ def test_ring_attention_matches_dense():
 
 
 def test_attention_auto_routes_through_ring(monkeypatch):
-    """ops.attention dispatch: under sequence_parallel on a seq>1 mesh,
+    """ops.attention dispatch: under param_mesh on a seq>1 mesh,
     auto/ring route self-attention through the shard_map ring and match
     the dense path; cross-attention (S != L) stays local."""
     from chiaswarm_tpu.ops.attention import attention
-    from chiaswarm_tpu.parallel import sequence_parallel
+    from chiaswarm_tpu.parallel import param_mesh
 
     monkeypatch.setenv("CHIASWARM_RING_MIN_TOKENS", "1")
     mesh = build_mesh(MeshSpec({"seq": 4}), devices=jax.devices()[:4])
@@ -57,7 +57,7 @@ def test_attention_auto_routes_through_ring(monkeypatch):
     v = jax.random.normal(kv, (b, l, h, d), jnp.float32)
     ref = _xla_attention(q, k, v, d ** -0.5)
 
-    with sequence_parallel(mesh):
+    with param_mesh(mesh):
         ringed = attention(q, k, v, impl="ring")
         auto = attention(q, k, v, impl="auto")
         # cross-attention: small KV must not take the ring
@@ -84,7 +84,7 @@ def test_ring_composes_with_dp_and_tp(monkeypatch):
     """dp x seq x tp mesh: batch on 'data', heads on 'model', tokens on
     'seq' — one spec, no resharding beyond the ring."""
     from chiaswarm_tpu.ops.attention import attention
-    from chiaswarm_tpu.parallel import sequence_parallel
+    from chiaswarm_tpu.parallel import param_mesh
 
     monkeypatch.setenv("CHIASWARM_RING_MIN_TOKENS", "1")
     mesh = build_mesh(MeshSpec({"data": 2, "seq": 2, "model": 2}))
@@ -93,7 +93,7 @@ def test_ring_composes_with_dp_and_tp(monkeypatch):
     q = jax.random.normal(kq, (b, l, h, d), jnp.float32)
     k = jax.random.normal(kk, (b, l, h, d), jnp.float32)
     v = jax.random.normal(kv, (b, l, h, d), jnp.float32)
-    with sequence_parallel(mesh):
+    with param_mesh(mesh):
         got = attention(q, k, v, impl="ring")
     ref = _xla_attention(q, k, v, d ** -0.5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),

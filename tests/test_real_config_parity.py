@@ -362,7 +362,7 @@ def test_full_config_unet_conversion_roundtrip(family):
 
     from tests.torch_export import export_unet
 
-    src = Components.random_host(family, seed=0)
+    src = Components.random(family, seed=0)
     exported = export_unet(src.params["unet"],
                            len(family.unet.block_out_channels))
     converted = convert_unet(exported, family.unet)
@@ -393,7 +393,7 @@ def test_full_config_controlnet_conversion_roundtrip(family):
 
     from tests.torch_export import export_controlnet
 
-    src = ControlNetBundle.random_host(family.name, seed=2)
+    src = ControlNetBundle.random(family.name, seed=2)
     exported = export_controlnet(src.params,
                                  len(family.unet.block_out_channels))
     converted = convert_controlnet(exported, family.unet)
@@ -462,7 +462,7 @@ def test_full_config_vae_conversion_roundtrip(family):
 
     from tests.torch_export import export_vae
 
-    src = Components.random_host(family, seed=1)
+    src = Components.random(family, seed=1)
     exported = export_vae(src.params["vae"],
                           len(family.vae.block_out_channels))
     converted = convert_vae(exported, family.vae)

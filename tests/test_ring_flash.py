@@ -113,18 +113,18 @@ def test_ring_flash_inner_blocking():
 
 
 def test_dispatch_impl_ring_flash(monkeypatch):
-    """ops.attention dispatch: impl='ring_flash' under sequence_parallel
+    """ops.attention dispatch: impl='ring_flash' under param_mesh
     routes the fused kernel and matches dense; without a mesh the
     explicit impl= contract still raises."""
     from chiaswarm_tpu.ops.attention import attention
-    from chiaswarm_tpu.parallel import sequence_parallel
+    from chiaswarm_tpu.parallel import param_mesh
 
     monkeypatch.setenv("CHIASWARM_RING_MIN_TOKENS", "1")
     mesh = build_mesh(MeshSpec({"seq": 4}), devices=jax.devices()[:4])
     b, l, h, d = 2, 64, 2, 16
     q, k, v = _qkv(6, b, l, h, d)
     ref = _xla_attention(q, k, v, d ** -0.5)
-    with sequence_parallel(mesh):
+    with param_mesh(mesh):
         got = attention(q, k, v, impl="ring_flash")
         # cross-attention (tiny KV) stays local even for ring kinds
         cross = attention(q, k[:, :7], v[:, :7], impl="ring_flash")
@@ -141,7 +141,7 @@ def test_env_override_is_advisory(monkeypatch):
     fleet-wide env roll reaches workers with no seq axis) — those fall
     back to the local paths."""
     from chiaswarm_tpu.ops.attention import attention
-    from chiaswarm_tpu.parallel import sequence_parallel
+    from chiaswarm_tpu.parallel import param_mesh
 
     monkeypatch.setenv("CHIASWARM_RING_MIN_TOKENS", "1")
     monkeypatch.setenv("CHIASWARM_ATTENTION", "ring_flash")
@@ -149,7 +149,7 @@ def test_env_override_is_advisory(monkeypatch):
     b, l, h, d = 2, 64, 2, 16
     q, k, v = _qkv(7, b, l, h, d)
     ref = _xla_attention(q, k, v, d ** -0.5)
-    with sequence_parallel(mesh):
+    with param_mesh(mesh):
         got = attention(q, k, v)  # auto, env-overridden
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=RTOL, atol=ATOL)

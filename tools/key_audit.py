@@ -22,7 +22,7 @@ Programs (all CPU-hermetic, 8 virtual devices, interpret-mode Pallas):
                 default; ``CHIASWARM_ATTENTION=flash`` swaps in the
                 interpret-mode flash kernel (different HLO).
 - ``ringmesh``  the same call traced under a seq=4 mesh
-                (``parallel.context.sequence_parallel``) — local einsum
+                (``parallel.context.param_mesh``) — local einsum
                 by default (l=64 is under the ring threshold);
                 ``CHIASWARM_RING_MIN_TOKENS=16`` engages the ppermute
                 ring (different HLO).
@@ -95,14 +95,14 @@ def _hlo_ringmesh() -> str:
     from chiaswarm_tpu.core.mesh import MeshSpec, build_mesh
     from chiaswarm_tpu.obs.hlocost import compiled_hlo_text
     from chiaswarm_tpu.ops.attention import attention
-    from chiaswarm_tpu.parallel.context import sequence_parallel
+    from chiaswarm_tpu.parallel.context import param_mesh
 
     mesh = build_mesh(MeshSpec({"seq": 4}), devices=jax.devices()[:4])
 
     def f(q, k, v):
         return attention(q, k, v)
 
-    with sequence_parallel(mesh):  # dispatch resolves at TRACE time
+    with param_mesh(mesh):  # dispatch resolves at TRACE time
         compiled = jax.jit(f).lower(*_probe_args()).compile()
     return compiled_hlo_text(compiled)
 

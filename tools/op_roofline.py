@@ -102,7 +102,7 @@ def main() -> None:
 
         with capture.patching(diffusion_mod, video_mod):
             fam = "svd_img2vid" if on_tpu else "tiny_svd"
-            vc = VideoComponents.random_host(fam, seed=0)
+            vc = VideoComponents.random(fam, seed=0)
             vc.params = jax.device_put(vc.params, jax.devices()[0])
             ipipe = Img2VidPipeline(vc)
             height = size
@@ -124,7 +124,7 @@ def main() -> None:
     family = args.family if on_tpu else "tiny"
 
     with capture.patching(diffusion_mod):
-        c = Components.random_host(family, seed=0)
+        c = Components.random(family, seed=0)
         c.params = jax.device_put(c.params, jax.devices()[0])
         pipe = DiffusionPipeline(c)
         controlnet = control_image = None
@@ -133,7 +133,7 @@ def main() -> None:
 
             from chiaswarm_tpu.pipelines.components import ControlNetBundle
 
-            controlnet = ControlNetBundle.random_host(family, seed=1)
+            controlnet = ControlNetBundle.random(family, seed=1)
             controlnet.params = jax.device_put(controlnet.params,
                                                jax.devices()[0])
             control_image = np.random.default_rng(0).integers(

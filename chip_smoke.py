@@ -25,11 +25,13 @@ with seeded random weights, and checks what comes out. In order:
    condemnation or timeout; the native codec loaded; the worker drains
    and the lanes stop.
 
-No phase sits in a try/except: the first failure is the exit. On success
-the LAST line of stdout is one JSON object ``{"ok": true, "device": {...},
-...}``; everything beside ``ok`` and ``device`` is a set-up fact or a
-sanity value of this run — NOT a benchmark metric (the repo's speed
-numbers come from the benchmark, not from here).
+No phase sits in a try/except: the first failure is the exit, and nothing
+is printed to stdout. On success stdout holds exactly two lines, each one
+JSON object. The first, ``{"setup": {...}, "sanity": {...}}``, carries the
+set-up facts and sanity values of this run — NOT benchmark metrics (the
+repo's speed numbers come from the benchmark, not from here). The LAST is
+the verdict and nothing else: ``{"ok": true, "device": {"platform": ...,
+"kind": ..., "count": ...}}``, the device as jax reports it.
 
 ``--chips N`` (N > 1) runs the same waves on the worker's own default pool
 over a host of at least N devices and additionally checks that every
@@ -468,7 +470,10 @@ def main(argv: list[str] | None = None) -> int:
                              "worker's default pool on an N-device host")
     args = parser.parse_args(argv)
     result = run_smoke("sdxl", 1024, require_tpu=True, chips=args.chips)
-    print(json.dumps(result), flush=True)
+    verdict = {key: result.pop(key) for key in ("ok", "device")}
+    print(json.dumps(result))
+    # the last line is the verdict alone: exactly ``ok`` and ``device``
+    print(json.dumps(verdict), flush=True)
     return 0
 
 

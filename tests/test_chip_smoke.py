@@ -55,6 +55,26 @@ def test_smoke_function_passes_on_cpu_at_tiny_size(tmp_path, monkeypatch):
     assert (tmp_path / "out" / "result.json").exists()
 
 
+def test_last_stdout_line_is_the_verdict_alone(monkeypatch, capsys):
+    """What reads the smoke parses its LAST stdout line and accepts only
+    ``{"ok", "device": {"platform", "kind", "count"}}`` — the set-up
+    facts and sanity values go on the line before it."""
+    import json
+
+    chip_smoke = _import_chip_smoke()
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    monkeypatch.setattr(
+        chip_smoke, "run_smoke",
+        lambda *a, **kw: {"ok": True, "device": dict(device),
+                          "setup": {"model": "sdxl"},
+                          "sanity": {"jobs_ok": 6}})
+    assert chip_smoke.main([]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert [json.loads(line) for line in lines] == [
+        {"setup": {"model": "sdxl"}, "sanity": {"jobs_ok": 6}},
+        {"ok": True, "device": device}]
+
+
 def test_smoke_script_fails_fast_without_a_tpu():
     """``JAX_PLATFORMS=cpu python chip_smoke.py``: non-zero within
     seconds, the missing TPU named on stderr, no result on stdout."""

@@ -351,7 +351,11 @@ def synchronous_do_work(job: dict[str, Any], slot,
     # "execute" phase (chiaswarm_tpu/obs/trace.py). The checkpoint scope
     # binds the worker's spool so the solo path can record its coarse
     # phase markers (workloads/diffusion.py; lanes snapshot themselves).
-    with obs_trace.activate(obs_trace.job_trace(job)), \
+    trace = obs_trace.job_trace(job)
+    if trace is not None:
+        # execute phase start -> this thread running: the hand-over
+        trace.gap("handover")
+    with obs_trace.activate(trace), \
             checkpoint_scope(getattr(slot, "_checkpoint_spool", None),
                              job.get("id")):
         formatted, fatal = _format(job, registry)
@@ -485,7 +489,10 @@ def synchronous_do_work_batch(jobs: list[dict[str, Any]], slot,
     for i, job in enumerate(jobs):
         log.info("processing job %s (burst of %d)", job.get("id"),
                  len(jobs))
-        with obs_trace.activate(_job_trace(i)):
+        trace = _job_trace(i)
+        if trace is not None:
+            trace.gap("handover")
+        with obs_trace.activate(trace):
             formatted, fatal = _format(job, registry)
             if formatted is None:
                 results[i] = fatal

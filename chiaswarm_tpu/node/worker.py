@@ -1155,8 +1155,9 @@ class Worker:
                 # watching _stop, so a full queue can never stall shutdown
                 while self.work_queue.full() and not self._stop.is_set():
                     try:
-                        await asyncio.wait_for(self._stop.wait(),
-                                               timeout=1.0)
+                        with obs_trace.span("poll.backpressure"):
+                            await asyncio.wait_for(self._stop.wait(),
+                                                   timeout=1.0)
                     except asyncio.TimeoutError:
                         pass
                 if self._stop.is_set():
@@ -1173,8 +1174,9 @@ class Worker:
                     if throttle > 0:
                         self.stats.polls_backpressured += 1
                         try:
-                            await asyncio.wait_for(self._stop.wait(),
-                                                   timeout=throttle)
+                            with obs_trace.span("poll.backpressure"):
+                                await asyncio.wait_for(self._stop.wait(),
+                                                       timeout=throttle)
                         except asyncio.TimeoutError:
                             pass
                         continue
@@ -1184,7 +1186,11 @@ class Worker:
                 # flush, device quarantine (mesh shrink), restart
                 self._apply_heal_rungs()
                 try:
-                    await asyncio.wait_for(self._stop.wait(), timeout=delay)
+                    # poll_busy_s / poll_idle_s (or the error backoff):
+                    # a job submitted now waits this out at the hive
+                    with obs_trace.span("poll.delay"):
+                        await asyncio.wait_for(self._stop.wait(),
+                                               timeout=delay)
                 except asyncio.TimeoutError:
                     pass
 
@@ -1200,7 +1206,8 @@ class Worker:
         shard = shard if shard is not None else self.shards[0]
         t_poll = time.perf_counter()
         try:
-            jobs = await shard.client.get_work(session)
+            with obs_trace.span("poll.request"):
+                jobs = await shard.client.get_work(session)
         except BadWorkerError as exc:
             # the hive ANSWERED (flagged us): reachable, not an outage
             self._note_hive_ok(shard)
@@ -1693,7 +1700,9 @@ class Worker:
                         await asyncio.sleep(0)
                     self._hungry_slots += 1
                     try:
-                        job = await self._next_job()
+                        # the slot idle, waiting for a polled job
+                        with obs_trace.span("slot.wait"):
+                            job = await self._next_job()
                     finally:
                         self._hungry_slots -= 1
                     if job is None:  # draining and the queue is dry
@@ -2031,6 +2040,9 @@ class Worker:
         # hive ignores the extra field
         result.setdefault("worker_name", self.settings.worker_name)
         if trace is not None:
+            # executor thread done -> this task running: loop wake-up,
+            # outcome bookkeeping, the result queue
+            trace.gap("result.wait")
             trace.phase("upload")
             # swarmdurable (ISSUE 14): echo the grant's hive-epoch
             # stamp so a recovered hive can tell a pre-crash grant's

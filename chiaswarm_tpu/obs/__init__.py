@@ -8,10 +8,11 @@ Five layers, one vocabulary (ISSUE 4 + ISSUE 11):
 - ``trace``     — Dapper-style per-job span trees on ``perf_counter``
                   (poll -> execute -> encode/step/decode -> upload),
                   kept in a bounded ring and exported as
-                  Perfetto-loadable JSON at ``/debug/traces``.
+                  Perfetto-loadable JSON at ``/debug/traces``; every
+                  span is also a ``TraceAnnotation`` ``swarm.<name>``
+                  on the profiler's clock (one primitive, two clocks).
 - ``profiling`` — ``jax.profiler`` behind ``core/compat.py``:
-                  ``TraceAnnotation`` names for the serving hot paths
-                  and on-demand XLA captures (``/debug/profile``,
+                  on-demand XLA captures (``/debug/profile``,
                   ``CHIASWARM_PROFILE_DIR``).
 - ``numerics``  — the swarmlens flight recorder (ISSUE 11): named
                   probes compiled INTO jitted programs behind
@@ -54,7 +55,6 @@ from chiaswarm_tpu.obs.trace import (  # noqa: F401
 )
 from chiaswarm_tpu.obs.profiling import (  # noqa: F401
     PROFILE_DIR_ENV,
-    annotate,
     capture,
     job_profile,
     profiler_available,

@@ -1,21 +1,20 @@
-"""TPU profiler hooks: XLA traces on demand, named device regions.
+"""TPU profiler hooks: XLA traces on demand.
 
 The metrics registry says *how often* and the span tracer says *where
 in the worker* — this module answers *what the chip did*: it wraps
 ``jax.profiler`` (routed through ``core/compat.py`` so everything
 degrades to a no-op when jax or the profiler plugin is absent) into
 
-- :func:`annotate` — ``TraceAnnotation`` regions naming the serving
-  hot paths (lane step / lane decode / solo generate) inside an XLA
-  trace, so an XProf/Perfetto timeline reads in serving vocabulary
-  instead of raw HLO module names; always-on and free outside an
-  active capture;
 - :func:`capture` — a one-shot, duration-bounded trace capture backing
   the worker's ``/debug/profile?seconds=N`` endpoint (node/worker.py);
   output lands under the directory named by :data:`PROFILE_DIR_ENV`
   (or an explicit ``?dir=``/``out=``);
 - :func:`job_profile` — the per-job opt-in trace the executor runs
   when :data:`PROFILE_DIR_ENV` is set.
+
+The names a capture's host plane reads in (``swarm.lane.step``,
+``swarm.png``, ...) are not made here: every ``obs.trace`` span is also
+a ``TraceAnnotation`` (one primitive on both clocks, obs/trace.py).
 
 The profiler is a process-global singleton, so one :data:`_CAPTURE_LOCK`
 serializes all of the above: a busy profiler yields an explicit
@@ -56,29 +55,6 @@ def profiler_available() -> bool:
         return hasattr(jax, "profiler")
     except Exception:
         return False
-
-
-@contextlib.contextmanager
-def annotate(name: str, **kwargs: Any) -> Iterator[None]:
-    """Name a device region inside an XLA trace (no-op when no trace is
-    recording, and a full no-op without jax). Cheap enough to stay
-    always-on around lane steps and decodes."""
-    try:
-        from chiaswarm_tpu.core import compat
-
-        annotation = compat.trace_annotation(name, **kwargs)
-        annotation.__enter__()
-    except Exception:
-        # profiling must never fail the job it is observing
-        annotation = None
-    try:
-        yield
-    finally:
-        if annotation is not None:
-            try:
-                annotation.__exit__(None, None, None)
-            except Exception:
-                pass
 
 
 def default_profile_dir() -> str:

@@ -26,10 +26,22 @@ Mechanics:
   exported as Perfetto/chrome-tracing JSON by ``/debug/traces``
   (node/worker.py) — load the body at https://ui.perfetto.dev.
 
-Everything is stdlib; a ``span()`` outside any active trace times into
-a detached throwaway Span, so library code can instrument
-unconditionally (allocation-light: one small object per span, none per
-lookup).
+One primitive, two clocks: every live :class:`Span` — a phase, a
+``span()``, the job's root — also holds a profiler ``TraceAnnotation``
+named ``swarm.<name>`` (through ``core/compat.trace_annotation``,
+resolved once) from its creation to its ``end()``. A name in a job's
+span tree and a name in the profiler's host plane are therefore the
+same thing by construction: a device-idle gap in an XLA trace reads in
+the vocabulary ``/debug/traces`` prints. The annotation is free outside
+an active capture (one C++ enabled-check). Spans built from explicit
+stamps after the fact (:meth:`Span.child_at`) are on the job's clock
+only — the profiler cannot be told about the past.
+
+Everything is stdlib plus that lazy compat lookup; a ``span()`` outside
+any active trace times into a detached throwaway Span and still
+annotates, so library code (the lane driver thread, the worker's poll
+loop) can instrument unconditionally (allocation-light: one small
+object per span, none per lookup).
 """
 
 from __future__ import annotations
@@ -52,22 +64,55 @@ ENV_RING_CAPACITY = "CHIASWARM_TRACE_RING"
 _CURRENT: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
     "chiaswarm_obs_span", default=None)
 
+#: prefix of every span's name on the profiler's clock
+ANNOTATION_PREFIX = "swarm."
+
+_annotation_cls: Any = None
+
+
+def _annotate(name: str) -> Any:
+    """Enter a profiler annotation ``swarm.<name>``; None when it cannot
+    be had (observability never fails the job it observes)."""
+    global _annotation_cls
+    try:
+        if _annotation_cls is None:
+            from chiaswarm_tpu.core import compat
+
+            _annotation_cls = compat.trace_annotation
+        annotation = _annotation_cls(ANNOTATION_PREFIX + name)
+        annotation.__enter__()
+        return annotation
+    except Exception:
+        return None
+
 
 class Span:
-    """One timed region; children nest. Durations on perf_counter."""
+    """One timed region; children nest. Durations on perf_counter; a
+    live span (no explicit ``t1``) is also a profiler annotation."""
 
-    __slots__ = ("name", "meta", "t0", "t1", "children")
+    __slots__ = ("name", "meta", "t0", "t1", "children", "_annotation")
 
     def __init__(self, name: str, meta: dict[str, Any] | None = None,
-                 t0: float | None = None) -> None:
+                 t0: float | None = None, t1: float | None = None) -> None:
         self.name = str(name)
         self.meta = dict(meta or {})
         self.t0 = time.perf_counter() if t0 is None else float(t0)
-        self.t1: float | None = None
+        self.t1: float | None = None if t1 is None else float(t1)
         self.children: list[Span] = []
+        self._annotation = _annotate(self.name) \
+            if t0 is None and t1 is None else None
 
     def child(self, name: str, **meta: Any) -> "Span":
         span = Span(name, meta)
+        self.children.append(span)
+        return span
+
+    def child_at(self, name: str, t0: float, t1: float,
+                 **meta: Any) -> "Span":
+        """A closed child from two ``perf_counter`` stamps taken
+        elsewhere (the lane reports a job's time inside it this way):
+        on the job's clock only, never annotated."""
+        span = Span(name, meta, t0=t0, t1=max(float(t0), float(t1)))
         self.children.append(span)
         return span
 
@@ -77,6 +122,12 @@ class Span:
         unbounded durations."""
         if self.t1 is None:
             self.t1 = time.perf_counter()
+        annotation, self._annotation = self._annotation, None
+        if annotation is not None:
+            try:
+                annotation.__exit__(None, None, None)
+            except Exception:
+                pass
         for child in self.children:
             if child.t1 is None:
                 child.t1 = self.t1
@@ -116,10 +167,14 @@ class Span:
 
 @contextlib.contextmanager
 def span(name: str, **meta: Any) -> Iterator[Span]:
-    """Time a region under the currently active span (contextvar).
+    """Time a region under the currently active span (contextvar), and
+    name it ``swarm.<name>`` on the profiler's clock for as long.
 
-    With no active trace the span is detached and discarded — safe to
-    sprinkle through library code unconditionally."""
+    With no active trace the span is detached and discarded (the
+    annotation still lands in a recording XLA trace) — safe to sprinkle
+    through library code unconditionally. In a coroutine, wrap the wait
+    itself: a task's context does not nest across its awaits the way a
+    thread's stack does."""
     parent = _CURRENT.get()
     current = parent.child(name, **meta) if parent is not None \
         else Span(name, meta)
@@ -165,6 +220,21 @@ class JobTrace:
             if child.open:
                 child.end()
         return self.root.child(name, **meta)
+
+    def gap(self, name: str, **meta: Any) -> Span | None:
+        """Name what the open phase has not named yet: a closed child
+        from the later of the phase's start and its last closed child's
+        end to NOW. The worker's hand-overs between threads are read
+        this way, from stamps that are already there (``handover``: the
+        executor thread's first act; ``result.wait``: the upload task's)
+        — on the job's clock only (see :meth:`Span.child_at`)."""
+        phase = next((c for c in reversed(self.root.children) if c.open),
+                     None)
+        if phase is None:
+            return None
+        since = max([phase.t0] + [c.t1 for c in phase.children
+                                  if c.t1 is not None])
+        return phase.child_at(name, since, time.perf_counter(), **meta)
 
     def tail(self) -> Span:
         """Deepest open span — where library spans should attach."""

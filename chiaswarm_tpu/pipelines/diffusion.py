@@ -40,7 +40,6 @@ from chiaswarm_tpu.core.compile_cache import (
 )
 from chiaswarm_tpu.obs import numerics as _numerics
 from chiaswarm_tpu.obs import trace as obs_trace
-from chiaswarm_tpu.obs.profiling import annotate
 from chiaswarm_tpu.obs.trace import span
 from chiaswarm_tpu.parallel.context import param_mesh_wrap
 from chiaswarm_tpu.convert.quantize import (
@@ -313,9 +312,13 @@ class PendingImages:
     requested_batch: int
 
     def wait(self) -> np.ndarray:
-        # the "decode" span: under async dispatch the denoise + VAE
-        # decode + device->host transfer all settle HERE, so for a solo
-        # job this is where the chip time shows in the trace
+        # the "decode" span. SOLO: under async dispatch the denoise +
+        # VAE decode + device->host transfer all settle HERE, so this is
+        # where the chip time shows in the trace. LANE: the driver has
+        # already waited the decode out before it resolved the job's
+        # future (Lane._flush_handoff), so the span times the FETCH of a
+        # finished uint8 array and the un-bucket crop only — the decode's
+        # device time is the job's ``lane.handoff`` span
         with span("decode", batch=self.requested_batch):
             return self._wait()
 
@@ -411,9 +414,14 @@ class DiffusionPipeline:
             raise ValueError("DeepCache reuse supports the plain "
                              "txt2img/img2img/inpaint programs only")
 
-        def fn(params, ids, neg_ids, sample_keys, guidance, init_latent,
-               mask, control_params, control_cond, control_scale,
-               image_guidance, noise_override, reuse_tab=None):
+        # every jitted program is a local def NAMED for its cache-key tag
+        # (generate / stepper_encode / stepper_init / stepper_step /
+        # stepper_ctrl_embed / stepper_decode): jax calls the HLO module
+        # jit_<name>, so a device trace splits its time by program
+        def generate(params, ids, neg_ids, sample_keys, guidance,
+                     init_latent, mask, control_params, control_cond,
+                     control_scale, image_guidance, noise_override,
+                     reuse_tab=None):
             # int8 weight residency (convert/quantize.py): dequantize AT
             # USE, inside the traced program — HBM holds the int8 codes,
             # XLA fuses the casts into the consumers. No-op on fp trees.
@@ -614,7 +622,7 @@ class DiffusionPipeline:
         # seq>1 param meshes trace under the sequence-parallel context so
         # ops.attention routes the large spatial self-attentions through
         # the ppermute ring (parallel/ring_attention.py)
-        return param_mesh_wrap(toplevel_jit(fn), self.c.params)
+        return param_mesh_wrap(toplevel_jit(generate), self.c.params)
 
     def _get_fn(self, **static: Any):
         return GLOBAL_CACHE.cached_executable(
@@ -683,13 +691,14 @@ class DiffusionPipeline:
         def build():
             encode_text = _make_text_encode(text_encoders)
 
-            def fn(params, ids, neg_ids):
+            def stepper_encode(params, ids, neg_ids):
                 params = dequantize_tree(params)
                 ctx_c, pooled_c = encode_text(params, ids)
                 ctx_u, pooled_u = encode_text(params, neg_ids)
                 return ctx_u, ctx_c, pooled_u, pooled_c
 
-            return param_mesh_wrap(toplevel_jit(fn), self.c.params)
+            return param_mesh_wrap(toplevel_jit(stepper_encode),
+                                   self.c.params)
 
         return GLOBAL_CACHE.cached_executable(
             static_cache_key(id(self.c), "stepper_encode",
@@ -704,7 +713,7 @@ class DiffusionPipeline:
         lh, lw = self._latent_hw(height, width)
 
         def build():
-            def fn(sample_keys, sigma0):
+            def stepper_init(sample_keys, sigma0):
                 both = jax.vmap(jax.random.split)(sample_keys)
                 carry, nkeys = both[:, 0], both[:, 1]
                 noise = jax.vmap(lambda k: jax.random.normal(
@@ -712,7 +721,7 @@ class DiffusionPipeline:
                 )(nkeys)
                 return carry, noise * sigma0.reshape(-1, 1, 1, 1)
 
-            return toplevel_jit(fn)
+            return toplevel_jit(stepper_init)
 
         return GLOBAL_CACHE.cached_executable(
             static_cache_key(id(self.c), "stepper_init",
@@ -772,11 +781,12 @@ class DiffusionPipeline:
             control_net = ControlNet(fam.unet)
 
         def build():
-            def fn(params, ctx_u, ctx_c, pooled_u, pooled_c, x, carry_keys,
-                   idx, start_idx, sigmas_tab, ts_tab, guidance,
-                   old_denoised, active, known, mask, mask_on,
-                   control_params, cond, cscale,
-                   cache_u=None, cache_c=None, reuse_now=None):
+            def stepper_step(params, ctx_u, ctx_c, pooled_u, pooled_c, x,
+                             carry_keys, idx, start_idx, sigmas_tab,
+                             ts_tab, guidance, old_denoised, active,
+                             known, mask, mask_on, control_params, cond,
+                             cscale, cache_u=None, cache_c=None,
+                             reuse_now=None):
                 params = dequantize_tree(params)
                 control_params = dequantize_tree(control_params)
                 sched_rows = SamplingSchedule(sigmas=sigmas_tab,
@@ -870,7 +880,7 @@ class DiffusionPipeline:
                             cache_u_next, cache_c_next)
                 return x_next, keys, idx_next, new_old
 
-            return param_mesh_wrap(toplevel_jit(fn), self.c.params)
+            return param_mesh_wrap(toplevel_jit(stepper_step), self.c.params)
 
         # the reuse flag joins the static key only when set, so every
         # pre-existing lane bucket keeps its historical key (and cached
@@ -898,11 +908,11 @@ class DiffusionPipeline:
                 fam.unet.block_out_channels[0],
                 downscale=fam.vae.downscale)
 
-            def fn(embed_params, cond):
+            def stepper_ctrl_embed(embed_params, cond):
                 return control_embed.apply(dequantize_tree(embed_params),
                                            cond)
 
-            return toplevel_jit(fn)
+            return toplevel_jit(stepper_ctrl_embed)
 
         return GLOBAL_CACHE.cached_executable(
             static_cache_key(id(self.c), "stepper_ctrl_embed",
@@ -915,14 +925,15 @@ class DiffusionPipeline:
         vae = self.c.vae
 
         def build():
-            def fn(params, x):
+            def stepper_decode(params, x):
                 params = dequantize_tree(params)
                 img = vae.apply(params["vae"], x,
                                 method=AutoencoderKL.decode)
                 return (jnp.clip((img + 1.0) * 127.5 + 0.5, 0.0, 255.0)
                         ).astype(jnp.uint8)
 
-            return param_mesh_wrap(toplevel_jit(fn), self.c.params)
+            return param_mesh_wrap(toplevel_jit(stepper_decode),
+                                   self.c.params)
 
         return GLOBAL_CACHE.cached_executable(
             static_cache_key(id(self.c), "stepper_decode",
@@ -1122,8 +1133,7 @@ class DiffusionPipeline:
             raise
         if enc_span is not None:
             enc_span.end()
-        with span("step", steps=steps, batch=batch), \
-                annotate("swarm.generate"):
+        with span("step", steps=steps, batch=batch), span("generate"):
             # ``reuse`` joins the static set only when ON: every plain
             # request keeps its historical cache key (and executable)
             fn = self._get_fn(

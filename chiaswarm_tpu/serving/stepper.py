@@ -91,6 +91,7 @@ from __future__ import annotations
 
 import base64
 import collections
+import contextlib
 import dataclasses
 import logging
 import os
@@ -114,7 +115,6 @@ from chiaswarm_tpu.obs.metrics import (
     unet_evals_counter,
     unet_evals_per_image_histogram,
 )
-from chiaswarm_tpu.obs.profiling import annotate
 from chiaswarm_tpu.obs.trace import span
 
 # swarmguard (ISSUE 10): the in-flight step watchdog, per-row output
@@ -152,10 +152,28 @@ _LANE_OCCUPANCY = lane_occupancy_histogram()
 # at — the fleet-level proof that redelivery resumes instead of
 # restarting (obs/metrics.py documents the tuning story)
 _RESUME_STEP = resume_step_histogram()
-_CKPT_SECONDS = REGISTRY.histogram(
-    "chiaswarm_stepper_checkpoint_seconds",
-    "wall time of one lane checkpoint snapshot (device->host + spool)",
-    buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0))
+# the lane driver's boundary, part by part (ISSUE 26): everything the
+# driver thread does that is not the step dispatch above — each part is
+# also a ``lane.<part>`` span (obs/trace.py), so the same name reads on
+# the profiler's clock. ``drain`` (the depth-2 window wait) lies INSIDE
+# the step timer; ``checkpoint`` took over what
+# chiaswarm_stepper_checkpoint_seconds measured (nothing read it).
+# Sum and count are what is read (perfbench: lane_host_ms.lat). Parts:
+# admit, drain, retire, checkpoint, handoff, idle.
+_BOUNDARY_SECONDS = REGISTRY.histogram(
+    "chiaswarm_stepper_boundary_seconds",
+    "lane driver wall time outside the step dispatch, by part",
+    labelnames=("part",),
+    buckets=(0.0001, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0))
+
+
+@contextlib.contextmanager
+def _lane_part(part: str):
+    """One part of the driver's boundary: a ``lane.<part>`` span on both
+    clocks, observed into the boundary histogram."""
+    with span("lane." + part) as timed:
+        yield
+    _BOUNDARY_SECONDS.observe(timed.duration_s, part=part)
 # adaptive-width control loop (ISSUE 7c): resize actions, the demand
 # EWMA, and per-workload admission breadth — declared in obs/metrics.py
 _LANE_RESIZES = lane_resizes_counter()
@@ -182,6 +200,10 @@ ENV_STEP_DELAY = "CHIASWARM_STEPPER_STEP_DELAY_S"
 
 #: lane workload kinds (the ``workload`` label vocabulary)
 WORKLOADS = ("txt2img", "img2img", "inpaint", "controlnet")
+
+#: lane-info key carrying the job's perf_counter stamps inside the lane
+#: (process-local: never reaches ``pipeline_config`` or the wire)
+LANE_STAMPS_KEY = "_stamps"
 
 # pre-seed every label vocabulary at import so the control-loop families
 # render zeroes from the FIRST /metrics scrape (dashboards need the
@@ -628,8 +650,10 @@ class Lane:
                     while True:
                         if self._stop:
                             raise LaneRetired("lane stopped")
-                        self._resize_locked(width_limit, rate, hint_rows)
-                        self._admit_locked(admit_cap)
+                        with _lane_part("admit"):
+                            self._resize_locked(width_limit, rate,
+                                                hint_rows)
+                            self._admit_locked(admit_cap)
                         if self._h_active.any():
                             idle_since = None
                             break
@@ -658,14 +682,19 @@ class Lane:
                             return
                         # woken by try_enqueue/stop notify; the timeout
                         # only bounds the idle grace itself
-                        self._cond.wait(
-                            timeout=max(0.05, idle_s - (now - idle_since)))
+                        with _lane_part("idle"):
+                            self._cond.wait(timeout=max(
+                                0.05, idle_s - (now - idle_since)))
                 self._flush_counts()
                 self._sched._maybe_fault(self)
-                self._dispatch_step()
-                self._retire_rows()
-                self._maybe_checkpoint()
-                self._flush_handoff(block=not self._h_active.any())
+                with span("lane.step"):
+                    self._dispatch_step()
+                with _lane_part("retire"):
+                    self._retire_rows()
+                with _lane_part("checkpoint"):
+                    self._maybe_checkpoint()
+                with _lane_part("handoff"):
+                    self._flush_handoff(block=not self._h_active.any())
         except BaseException as exc:  # noqa: BLE001 — containment seam
             self._fail_all(exc)
         finally:
@@ -1044,25 +1073,24 @@ class Lane:
         t0 = time.perf_counter()
         fired = False
         try:
-            with annotate("swarm.lane.step"):
-                base_args = (
-                    self.pipe.c.params,
-                    dev["ctx_u"], dev["ctx_c"], dev["pooled_u"],
-                    dev["pooled_c"],
-                    dev["x"], dev["keys"], dev["idx"],
-                    dev["start"], dev["sig"], dev["ts"], dev["guid"],
-                    dev["old"], dev["active"],
-                    dev["known"], dev["mask"], dev["mask_on"],
-                    ctrl_params, dev["cond"], dev["cscale"],
-                )
-                if self.reuse:
-                    (dev["x"], dev["keys"], dev["idx"], dev["old"],
-                     dev["cache_u"], dev["cache_c"]) = fn(
-                        *base_args, dev["cache_u"], dev["cache_c"],
-                        jnp.asarray(reuse_now))
-                else:
-                    dev["x"], dev["keys"], dev["idx"], dev["old"] = fn(
-                        *base_args)
+            base_args = (
+                self.pipe.c.params,
+                dev["ctx_u"], dev["ctx_c"], dev["pooled_u"],
+                dev["pooled_c"],
+                dev["x"], dev["keys"], dev["idx"],
+                dev["start"], dev["sig"], dev["ts"], dev["guid"],
+                dev["old"], dev["active"],
+                dev["known"], dev["mask"], dev["mask_on"],
+                ctrl_params, dev["cond"], dev["cscale"],
+            )
+            if self.reuse:
+                (dev["x"], dev["keys"], dev["idx"], dev["old"],
+                 dev["cache_u"], dev["cache_c"]) = fn(
+                    *base_args, dev["cache_u"], dev["cache_c"],
+                    jnp.asarray(reuse_now))
+            else:
+                dev["x"], dev["keys"], dev["idx"], dev["old"] = fn(
+                    *base_args)
             wedge_s = chaos.wedge_at(chaos_step)
             if wedge_s > 0:  # scripted wedged-compiled-call stand-in
                 log.warning("chaos: wedging lane %d step %d for %.1fs",
@@ -1075,7 +1103,9 @@ class Lane:
             # try of the driver loop
             self._window.append(dev["x"])
             if len(self._window) > 2:
-                self._window.popleft().block_until_ready()
+                # the host legitimately waiting on the chip
+                with _lane_part("drain"):
+                    self._window.popleft().block_until_ready()
         finally:
             if ticket is not None:
                 fired = _guard.WATCHDOG.disarm(ticket)
@@ -1189,7 +1219,7 @@ class Lane:
                                         bucket - job.n_rows, axis=0)])
             decode = self.pipe.stepper_decode_fn(
                 batch=bucket, height=self.height, width=self.width_px)
-            with annotate("swarm.lane.decode"):
+            with span("lane.decode"):
                 images = decode(self.pipe.c.params, rows_x)
             pending = PendingImages(
                 device_images=images,
@@ -1209,6 +1239,15 @@ class Lane:
                 "splice_wait_s": round(
                     max(0.0, job.admitted_t - job.submitted_t), 6)
                 if job.admitted_t else 0.0,
+                # the job's time inside the lane, on this process's
+                # perf_counter: stamps the driver already passes (no
+                # new synchronisation). ``resolved`` lands when the
+                # future does; workloads.stepper_finish pops the key
+                # and turns it into the lane.wait / lane.steps /
+                # lane.handoff children of the job's step span
+                LANE_STAMPS_KEY: {"submitted": job.submitted_t,
+                                  "admitted": job.admitted_t,
+                                  "retired": time.perf_counter()},
             }
             # per-image UNet-eval accounting (ISSUE 12): full evals this
             # row actually paid over its WHOLE trajectory (the skipped
@@ -1279,7 +1318,6 @@ class Lane:
         jobs = {id(j): j for j in self._rows if j is not None}
         if not jobs:
             return
-        t0 = time.perf_counter()
         # one transfer for the whole lane, sliced per job below
         x = np.asarray(self._dev["x"])
         keys = old = cache_u = cache_c = None
@@ -1355,7 +1393,6 @@ class Lane:
             self._poison_rows(job)
         if written:
             self._sched._count(checkpoints_written=written)
-            _CKPT_SECONDS.observe(time.perf_counter() - t0)
 
     def _poison_rows(self, job: _RowJob) -> None:
         """Retire ONE job's rows as numerically poisoned (swarmguard,
@@ -1394,7 +1431,13 @@ class Lane:
             images.block_until_ready()
             self._handoff.popleft()
             if not job.future.cancelled():
-                job.future.set_result((pending, info))
+                self._resolve(job, pending, info)
+
+    @staticmethod
+    def _resolve(job: _RowJob, pending, info: dict[str, Any]) -> None:
+        """Hand a retired job its decoded images, stamping when."""
+        info[LANE_STAMPS_KEY]["resolved"] = time.perf_counter()
+        job.future.set_result((pending, info))
 
     def _release_rows(self, job: _RowJob) -> None:
         for s in job.slots:
@@ -1413,7 +1456,7 @@ class Lane:
             try:
                 pending.device_images.block_until_ready()
                 if not job.future.done():
-                    job.future.set_result((pending, info))
+                    self._resolve(job, pending, info)
             except Exception:
                 if not job.future.done():
                     job.future.set_exception(err)
@@ -1753,9 +1796,13 @@ class StepScheduler:
             lane_rows = max(rows, limit)
         self._note_arrival(rows)
 
-        sched = make_sampling_schedule(pipe.noise_schedule, steps, sampler)
-        sig = np.asarray(sched.sigmas, np.float32)
-        ts = np.asarray(sched.timesteps, np.float32)
+        # the job's sigma ladder: a handful of eager jnp calls whose
+        # np.asarray waits on the device (behind any step in flight)
+        with span("schedule"):
+            sched = make_sampling_schedule(pipe.noise_schedule, steps,
+                                           sampler)
+            sig = np.asarray(sched.sigmas, np.float32)
+            ts = np.asarray(sched.timesteps, np.float32)
 
         resume_step = 0
         restored = None
@@ -1774,8 +1821,7 @@ class StepScheduler:
                 resume_step, restored = 0, None
 
         t_prep = time.perf_counter()
-        with span("encode", rows=rows, steps=steps), \
-                annotate("swarm.lane.encode"):
+        with span("encode", rows=rows, steps=steps), span("lane.encode"):
             eb = bucket_batch(rows)
             ids = [jnp.asarray(i)
                    for i in pipe._tokenize([prompt or ""] * eb)]

@@ -159,16 +159,18 @@ def diffusion_callback(slot, model_name: str, *, seed: int,
     # "completed" black frame (serving/guard.py)
     from chiaswarm_tpu.serving.guard import screen_images
 
-    screen_images(images, context="solo decode")
+    with span("screen"):
+        screen_images(images, context="solo decode")
 
     proc = OutputProcessor(content_type)
-    proc.add_images(images)
-    if control_image is not None and save_preprocessed_input:
-        # echo the preprocessed conditioning image back as an extra
-        # artifact (swarm/diffusion/diffusion_func.py:36-39)
-        proc.add_images(np.asarray(control_image, dtype=np.uint8),
-                        key="preprocessed_input")
-    artifacts = proc.get_results()
+    with span("png"):  # encode, thumbnail, base64
+        proc.add_images(images)
+        if control_image is not None and save_preprocessed_input:
+            # echo the preprocessed conditioning image back as an extra
+            # artifact (swarm/diffusion/diffusion_func.py:36-39)
+            proc.add_images(np.asarray(control_image, dtype=np.uint8),
+                            key="preprocessed_input")
+        artifacts = proc.get_results()
 
     if textual_inversion is not None:
         config["textual_inversion"] = textual_inversion
@@ -177,7 +179,8 @@ def diffusion_callback(slot, model_name: str, *, seed: int,
         config["cross_attention_scale"] = float(cross_attention_scale)
     from chiaswarm_tpu.workloads.safety import check_images
 
-    _, safety_fields = check_images(images, model_name)
+    with span("safety"):
+        _, safety_fields = check_images(images, model_name)
     config.update(safety_fields)
     config.update({
         "images_per_sec": round(images.shape[0] / max(elapsed, 1e-9), 4),
@@ -406,14 +409,37 @@ def stepper_submit(slot, registry: ModelRegistry, kwargs: dict[str, Any],
         controlnet_scale=cscale)
 
 
+def _lane_children(step_span, stamps: dict[str, float] | None) -> None:
+    """The job's time inside the lane as children of its ``step`` span:
+    ``lane.wait`` (submit -> admitted), ``lane.steps`` (admitted ->
+    retire dispatch: the host's view of the job's steps) and
+    ``lane.handoff`` (retire dispatch -> future resolved: the in-flight
+    steps draining plus the VAE decode on the device)."""
+    if not stamps:
+        return
+    marks = [stamps.get(k) for k in ("submitted", "admitted", "retired",
+                                     "resolved")]
+    for name, t0, t1 in zip(("lane.wait", "lane.steps", "lane.handoff"),
+                            marks, marks[1:]):
+        if t0 and t1:
+            step_span.child_at(name, t0, t1)
+
+
 def stepper_finish(ticket: StepperTicket):
     """Block on the lane rows, then postprocess exactly like the solo
     callback (un-bucket crop, safety, artifact encode)."""
     # the job's "step" span: admission wait + its rows' residency in the
     # lane's denoise loop (the lane-side timeline rides in as metadata)
+    from chiaswarm_tpu.serving.stepper import LANE_STAMPS_KEY
+
     with span("step", steps=ticket.steps, rows=ticket.rows) as step_span:
         pending, lane_info = ticket.future.result()
+        # the lane reports the job's time inside it as perf_counter
+        # stamps it passed anyway (no extra synchronisation): three
+        # children of "step" with explicit start and end
+        stamps = lane_info.pop(LANE_STAMPS_KEY, None)
         step_span.meta.update(lane_info)
+        _lane_children(step_span, stamps)
     # the lane decodes at the compiled bucket; un-bucket to the request
     pending.requested_hw = ticket.req_hw
     images = pending.wait()
@@ -423,11 +449,13 @@ def stepper_finish(ticket: StepperTicket):
     # invalid_output, the garbage frame never uploads
     from chiaswarm_tpu.serving.guard import screen_images
 
-    screen_images(images, context="lane decode")
+    with span("screen"):
+        screen_images(images, context="lane decode")
     elapsed = time.perf_counter() - ticket.t0
 
     proc = OutputProcessor(ticket.content_type)
-    proc.add_images(images)
+    with span("png"):
+        proc.add_images(images)
     config = {
         "model_name": ticket.model_name,
         "family": ticket.family,
@@ -454,7 +482,8 @@ def stepper_finish(ticket: StepperTicket):
                                            else float(scale))
     from chiaswarm_tpu.workloads.safety import check_images
 
-    _, safety_fields = check_images(images, ticket.model_name)
+    with span("safety"):
+        _, safety_fields = check_images(images, ticket.model_name)
     config.update(safety_fields)
     config.update({
         "images_per_sec": round(images.shape[0] / max(elapsed, 1e-9), 4),
@@ -463,7 +492,9 @@ def stepper_finish(ticket: StepperTicket):
                  if hasattr(ticket.slot, "descriptor")
                  else str(ticket.slot)),
     })
-    return proc.get_results(), config
+    with span("png"):  # encode, thumbnail, base64
+        artifacts = proc.get_results()
+    return artifacts, config
 
 
 def diffusion_coalesced_callback(slot, model_name: str, *, seed: int,
@@ -561,7 +592,10 @@ def diffusion_coalesced_callback(slot, model_name: str, *, seed: int,
     # screen while healthy peers complete.
     from chiaswarm_tpu.serving.guard import screen_images
 
-    screen_images(images, context="coalesced decode")
+    # (no job trace is active here — one program serves the group — so
+    # screen / png / safety below name the profiler's clock only)
+    with span("screen"):
+        screen_images(images, context="coalesced decode")
 
     from chiaswarm_tpu.workloads.safety import check_images
 
@@ -571,7 +605,8 @@ def diffusion_coalesced_callback(slot, model_name: str, *, seed: int,
         imgs = images[offset:offset + n]
         offset += n
         proc = OutputProcessor(job.get("content_type", "image/png"))
-        proc.add_images(imgs)
+        with span("png"):
+            proc.add_images(imgs)
         config = dict(base_config)
         config["seed"] = int(job["seed"])
         config["batch"] = n
@@ -582,7 +617,8 @@ def diffusion_coalesced_callback(slot, model_name: str, *, seed: int,
             config["lora"] = shared["lora"]
             config["cross_attention_scale"] = float(
                 opt("cross_attention_scale", 1.0))
-        _, safety_fields = check_images(imgs, model_name)
+        with span("safety"):
+            _, safety_fields = check_images(imgs, model_name)
         config.update(safety_fields)
         config.update({
             "coalesced": len(jobs),
@@ -596,5 +632,7 @@ def diffusion_coalesced_callback(slot, model_name: str, *, seed: int,
             "slot": (slot.descriptor() if hasattr(slot, "descriptor")
                      else str(slot)),
         })
-        results.append((proc.get_results(), config))
+        with span("png"):
+            artifacts = proc.get_results()
+        results.append((artifacts, config))
     return results

@@ -245,6 +245,133 @@ def test_trace_rides_job_dicts_via_attach_detach():
 
 
 # ---------------------------------------------------------------------------
+# one primitive, two clocks (ISSUE 26): a span IS a profiler annotation
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """``compat.trace_annotation`` stubbed: every enter / exit, by name,
+    in order. The primitive resolves the class once, so it is reset."""
+    from chiaswarm_tpu.core import compat
+
+    log = []
+
+    class Stub:
+        def __init__(self, name, **kwargs):
+            assert not kwargs
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+            return False
+
+    monkeypatch.setitem(compat._cache, "trace_annotation", Stub)
+    monkeypatch.setattr(obs_trace, "_annotation_cls", None)
+    return log
+
+
+def test_span_enters_one_annotation_named_for_it(annotations):
+    # outside any job trace (the lane driver thread, the poll loop): the
+    # span is a throwaway on the job's clock and still annotates
+    with span("lane.step") as timed:
+        assert annotations == [("enter", "swarm.lane.step")]
+    assert annotations == [("enter", "swarm.lane.step"),
+                           ("exit", "swarm.lane.step")]
+    assert timed.duration_s > 0 and obs_trace.current_span() is None
+    # closed on exception, once, and end() stays idempotent
+    del annotations[:]
+    with pytest.raises(RuntimeError):
+        with span("png") as broken:
+            raise RuntimeError("encode failed")
+    broken.end()
+    assert annotations == [("enter", "swarm.png"), ("exit", "swarm.png")]
+
+
+def test_every_name_in_the_job_tree_is_a_name_on_the_profilers_clock(
+        annotations):
+    trace = JobTrace("job", id="both-clocks")
+    trace.phase("poll")
+    trace.phase("execute")  # closes poll on both clocks
+    with obs_trace.activate(trace):
+        with span("encode"), span("lane.encode"):
+            pass
+        with span("step") as step:
+            # built from stamps after the fact: the job's clock only
+            step.child_at("lane.wait", step.t0, step.t0 + 0.001)
+    trace.phase("upload")
+    trace.finish(TraceRing(capacity=1))
+    entered = [name for kind, name in annotations if kind == "enter"]
+    exited = [name for kind, name in annotations if kind == "exit"]
+    assert entered == ["swarm.job", "swarm.poll", "swarm.execute",
+                       "swarm.encode", "swarm.lane.encode", "swarm.step",
+                       "swarm.upload"]
+    assert sorted(exited) == sorted(entered)
+    assert annotations.index(("exit", "swarm.poll")) \
+        < annotations.index(("enter", "swarm.execute"))
+    in_tree = set()
+
+    def walk(node):
+        in_tree.add("swarm." + node.name)
+        for child in node.children:
+            walk(child)
+
+    walk(trace.root)
+    assert in_tree - set(entered) == {"swarm.lane.wait"}
+
+
+def test_a_failing_annotation_never_fails_the_span(monkeypatch):
+    class Broken:
+        def __init__(self, name):
+            raise RuntimeError("no profiler")
+
+    monkeypatch.setattr(obs_trace, "_annotation_cls", Broken)
+    with span("decode") as timed:
+        pass
+    assert timed.duration_s > 0 and not timed.open
+
+
+def test_children_from_explicit_stamps_export_their_offsets():
+    from chiaswarm_tpu.obs.flight import span_digest
+
+    trace = JobTrace("job", id="stamps", trace_id="t", span_id="t.1")
+    trace.phase("poll")
+    execute = trace.phase("execute")
+    assert trace.gap("handover").t0 == execute.t0  # nothing named yet
+    with obs_trace.activate(trace):
+        with span("step") as step:
+            t = step.t0
+            step.child_at("lane.wait", t - 0.002, t + 0.010)
+            step.child_at("lane.steps", t + 0.010, t + 0.250, rows=1)
+            step.child_at("lane.handoff", t + 0.250, t + 0.400)
+            backwards = step.child_at("never.negative", t + 0.5, t + 0.1)
+    waited = trace.gap("result.wait")  # from the last child's end to now
+    assert waited.t0 == step.t1 and waited.t1 >= waited.t0
+    assert backwards.duration_s == 0.0
+    trace.phase("upload")
+    digest = span_digest(trace, worker_name="w")
+    spans = {entry["name"]: entry for entry in digest["spans"]}
+    base = round(step.t0 - trace.root.t0, 6)
+    assert spans["lane.wait"]["t0_s"] == pytest.approx(base - 0.002,
+                                                       abs=2e-6)
+    assert spans["lane.wait"]["dur_s"] == pytest.approx(0.012, abs=2e-6)
+    assert spans["lane.steps"]["t0_s"] == pytest.approx(base + 0.010,
+                                                        abs=2e-6)
+    assert spans["lane.steps"]["dur_s"] == pytest.approx(0.240, abs=2e-6)
+    assert spans["lane.steps"]["meta"] == {"rows": 1}
+    assert spans["lane.handoff"]["dur_s"] == pytest.approx(0.150, abs=2e-6)
+    assert {spans[n]["phase"] for n in (
+        "handover", "step", "lane.wait", "result.wait")} == {"execute"}
+    assert trace.gap("after.the.job") is not None  # upload is open
+    trace.finish(TraceRing(capacity=1))
+    assert trace.gap("nothing.open") is None
+
+
+# ---------------------------------------------------------------------------
 # profiler hooks (unit level; the capture endpoint is covered below)
 # ---------------------------------------------------------------------------
 

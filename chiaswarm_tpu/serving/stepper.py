@@ -31,9 +31,14 @@ each lane carries a :class:`LaneWidthController` that follows the
 scheduler's arrival-rate EWMA (fed by submissions) plus the worker
 poll loop's short-lived row hints, and the lane's occupancy EWMA — the
 same signal the
-``chiaswarm_stepper_lane_occupancy_ratio`` histogram exports. Lanes
-grow when pending rows cannot fit (or occupancy stays high while
-arrivals continue) and shrink when occupancy stays low, ONLY at step
+``chiaswarm_stepper_lane_occupancy_ratio`` histogram exports. A lane
+is as wide as the smallest lattice bucket that holds the rows it has
+evidence for (resident + pending + what a fresh poll hint still
+announces) and no wider: it OPENS at its first job's bucket
+(``StepScheduler.initial_width`` — a lone one-image job rides a
+width-1 lane, ISSUE 27), grows when pending rows cannot fit (or
+occupancy stays high while arrivals continue) and shrinks when
+occupancy stays low — down to 1 under a lone row — ONLY at step
 boundaries, and only onto the pow2 width lattice the compile cache
 already buckets by — so a resize reuses (or compiles once, bounded) a
 lattice program, and admission itself still never compiles.
@@ -264,6 +269,13 @@ def adaptive_enabled() -> bool:
         "0", "false", "off", "no")
 
 
+def shard_rows_enabled() -> bool:
+    """Lane rows ride the mesh's data axis only by opt-in
+    (``CHIASWARM_STEPPER_SHARD_ROWS=1``; ``Lane._alloc_dev`` says why)."""
+    return os.environ.get(ENV_SHARD_ROWS, "").strip().lower() in (
+        "1", "true", "on", "yes")
+
+
 class LaneReject(RuntimeError):
     """The job cannot ride a lane (too many rows, steps beyond the
     capacity lattice, ...) — run it through the ordinary path."""
@@ -358,6 +370,18 @@ class LaneWidthController:
       ``patience`` consecutive boundaries with nothing pending halves
       the width — padding rows are batched UNet FLOPs burned, the
       exact waste BENCH r05's 0.33 padding ratio measures.
+    - **a share is only read where it is at least one row** (ISSUE
+      27): ``shrink_at`` x width is half a row at width 2, so no
+      occupancy a resident row can produce ever meets it, and
+      ``grow_at`` x width is under one row at width 1, so every
+      resident row meets it. There the test is the rows themselves:
+      a lane whose resident + waiting rows have fitted the next
+      narrower bucket for ``patience`` boundaries halves (2 -> 1 under
+      a lone job), and a lane that has never held two rows does not
+      double on the bet that arrivals will overlap — only rows it can
+      see (pending, hinted) widen it. At every width where both
+      shares are a row or more (>= 4 with the shipped gains) the
+      decisions are the share rules above, unchanged.
     - bounds are clamped per decision, so an OOM width-limit recorded
       by the scheduler (``note_oom`` halving) is respected even when it
       arrives between boundaries.
@@ -387,6 +411,9 @@ class LaneWidthController:
         # from birth too — only the pending-cannot-fit burst reaction
         # is allowed to act immediately
         self._boundaries_since_resize = 0
+        # consecutive boundaries at which every row the lane knows of
+        # fitted the next narrower bucket (the shrink test in rows)
+        self._boundaries_fitting_narrower = 0
 
     def decide(self, width: int, occupied: int, pending_rows: int,
                rate: float, *, max_width: int | None = None) -> int:
@@ -403,21 +430,34 @@ class LaneWidthController:
         self._boundaries_since_resize += 1
         target = width
         need = occupied + pending_rows
+        if need <= width // 2:
+            self._boundaries_fitting_narrower += 1
+        else:
+            self._boundaries_fitting_narrower = 0
         if need > width:
             # burst reaction: pending rows must not queue behind a full
             # lane when a wider lattice program can hold them now
             target = bucket_batch(min(need, hi))
         elif self._boundaries_since_resize >= self.patience:
-            if (self.occ_ewma >= self.grow_at and rate > 0.0
+            # a share threshold under one row at this width cannot tell
+            # a lone row from a full lane (grow) or from an empty one
+            # (shrink): there the rows decide, see the class docstring
+            if self.shrink_at * width >= 1.0:
+                low = self.occ_ewma <= self.shrink_at
+            else:
+                low = self._boundaries_fitting_narrower >= self.patience
+            if (self.grow_at * width >= 1.0
+                    and self.occ_ewma >= self.grow_at and rate > 0.0
                     and width * 2 <= hi):
                 target = width * 2
-            elif (self.occ_ewma <= self.shrink_at and pending_rows == 0
+            elif (low and pending_rows == 0
                     and occupied <= width // 2 and width > lo):
                 target = width // 2
         target = max(lo, min(hi, bucket_batch(max(1, target))))
         target = max(target, bucket_batch(max(1, occupied)))
         if target != width:
             self._boundaries_since_resize = 0
+            self._boundaries_fitting_narrower = 0
             # re-seed the EWMA at the post-resize ratio so one resize
             # does not immediately argue for the next
             self.occ_ewma = occupied / max(1, target)
@@ -642,21 +682,21 @@ class Lane:
                 # scheduler-side control signals, read OUTSIDE the lane
                 # lock (sched._lock nests inside submitters holding it
                 # while they wait on this lane's cond — taking it under
-                # self._cond would invert the order and deadlock)
+                # self._cond would invert the order and deadlock).
+                # Read anew on every pass, a wake from the idle wait
+                # included: a poll hint read before the wait announces
+                # the very job whose enqueue ends it, and counting it
+                # beside that job would widen the lane for nothing
                 width_limit = self._sched.width_limit_for(self.key)
                 rate, hint_rows = self._sched.demand_signal()
                 admit_cap = self._sched.admission_cap()
                 with self._cond:
-                    while True:
-                        if self._stop:
-                            raise LaneRetired("lane stopped")
-                        with _lane_part("admit"):
-                            self._resize_locked(width_limit, rate,
-                                                hint_rows)
-                            self._admit_locked(admit_cap)
-                        if self._h_active.any():
-                            idle_since = None
-                            break
+                    if self._stop:
+                        raise LaneRetired("lane stopped")
+                    with _lane_part("admit"):
+                        self._resize_locked(width_limit, rate, hint_rows)
+                        self._admit_locked(admit_cap)
+                    if not self._h_active.any():
                         if self._retire_asap and not self._pending:
                             # eviction retire: the model left the HBM
                             # ledger and the row file is drained — free
@@ -685,6 +725,8 @@ class Lane:
                         with _lane_part("idle"):
                             self._cond.wait(timeout=max(
                                 0.05, idle_s - (now - idle_since)))
+                        continue
+                    idle_since = None
                 self._flush_counts()
                 self._sched._maybe_fault(self)
                 with span("lane.step"):
@@ -725,8 +767,7 @@ class Lane:
         # replicated rows — jit answers with a silent recompile of the
         # whole step program, an AOT executable with a sharding error.
         self._mesh = None
-        if os.environ.get(ENV_SHARD_ROWS, "").strip().lower() in (
-                "1", "true", "on", "yes"):
+        if shard_rows_enabled():
             mesh = _params_mesh(self.pipe.c.params)
             if mesh is not None and self.width % mesh.shape["data"] == 0:
                 self._mesh = mesh
@@ -1556,16 +1597,23 @@ class StepScheduler:
         return min(lo, hi), max(lo, hi)
 
     def initial_width(self, rows: int, height: int, width: int) -> int:
-        """A fresh lane opens just big enough for its first job (plus
-        headroom for one more) and lets the controller follow demand
-        from there — idle-start lanes must not pay a saturation-sized
-        padding bill while traffic ramps."""
+        """A fresh lane opens at the smallest lattice bucket that holds
+        the rows there is evidence for: its first job's, plus what a
+        fresh poll hint still announces beyond the arrivals that burned
+        it (so call this AFTER the job's own ``_note_arrival``). No
+        constant headroom: a lone one-image job rides a width-1 lane
+        and pays for no padding row (ISSUE 27); the controller follows
+        demand from there. Row-sharded lanes never resize, so they open
+        at a width the mesh's data axis divides."""
         from chiaswarm_tpu.core.compile_cache import bucket_batch
 
         lo, hi = self.width_bounds(height, width)
         if not adaptive_enabled():
             return hi
-        return max(lo, min(hi, bucket_batch(max(2, int(rows)))))
+        want = int(rows) + self.demand_signal()[1]
+        if shard_rows_enabled():
+            want = max(want, int(getattr(self.slot, "data_width", 1)))
+        return max(lo, bucket_batch(max(1, min(hi, want))))
 
     def row_deadline_s(self) -> float:
         return float(os.environ.get(ENV_ROW_DEADLINE, "600") or 600)
@@ -1790,11 +1838,11 @@ class StepScheduler:
         key = (id(pipe.c), height, width, cap, sampler,
                None if controlnet is None else id(controlnet),
                bool(reuse))
+        self._note_arrival(rows)
         lane_rows = self.initial_width(rows, height, width)
         limit = self._width_limits.get(key)
         if limit is not None and limit < lane_rows:
             lane_rows = max(rows, limit)
-        self._note_arrival(rows)
 
         # the job's sigma ladder: a handful of eager jnp calls whose
         # np.asarray waits on the device (behind any step in flight)

@@ -931,6 +931,43 @@ def test_resume_rejects_workload_mismatch(tiny_pipe):
 # ---------------------------------------------------------------------------
 
 
+def _recorded_lane_trace(start: int, seed: int, boundaries: int = 160):
+    """The seeded (occupied share, waiting rows, arrival rate) sequence
+    ``_PR26_DECISIONS`` was recorded on."""
+    import random
+
+    rng = random.Random(f"issue27:{start}:{seed}")
+    out = []
+    share = rng.random()
+    for _ in range(boundaries):
+        if rng.random() < 0.15:           # regime change
+            share = rng.choice((0.0, 0.1, 0.2, 0.25, 0.3, 0.5, 0.75, 0.8,
+                                1.0))
+        pending = (rng.choice((0, 0, 0, 0, 1, 2, 5, 40))
+                   if rng.random() < 0.2 else 0)
+        rate = rng.choice((0.0, 0.5, 3.0))
+        out.append((share, pending, rate))
+    return out
+
+
+#: (start width, seed) -> every (boundary, new width) PR 26's controller
+#: decided on ``_recorded_lane_trace`` while the lane was 4 rows or wider
+_PR26_DECISIONS = {
+    (4, 0): ((0, 64), (6, 32), (23, 64), (26, 128), (36, 64), (42, 32), (48, 16), (54, 8), (60, 4), (66, 2)),
+    (4, 1): ((11, 8), (17, 4), (25, 2)),
+    (8, 0): ((5, 64), (11, 32), (14, 64), (25, 32), (31, 16), (55, 8), (71, 64), (77, 32), (83, 16), (91, 32), (97, 64), (103, 128), (153, 64), (159, 32)),
+    (8, 1): ((9, 64), (15, 32), (21, 16), (25, 64), (44, 32), (50, 16), (56, 8), (78, 16), (84, 32), (88, 64), (99, 32), (104, 64), (110, 128), (142, 64), (150, 32), (156, 16)),
+    (16, 0): ((6, 8), (23, 16), (27, 64), (33, 128), (54, 64), (60, 32), (68, 64), (76, 32), (81, 128), (131, 64), (137, 32), (143, 16), (156, 8)),
+    (16, 1): ((10, 8), (16, 4), (18, 8), (23, 16), (53, 8), (59, 4), (61, 8), (86, 64), (92, 32), (113, 64), (114, 128), (151, 64), (157, 32)),
+    (32, 0): ((5, 16), (12, 64), (26, 128), (39, 64), (45, 32), (48, 64), (54, 32), (57, 128), (75, 64), (153, 32), (159, 16)),
+    (32, 1): ((40, 64), (49, 128), (58, 64), (74, 128), (104, 64), (114, 128), (132, 64), (138, 32), (144, 16), (150, 8), (156, 4)),
+    (64, 0): ((5, 32), (15, 128), (94, 64), (116, 32)),
+    (64, 1): ((5, 32), (11, 16), (17, 8), (23, 4), (29, 2)),
+    (128, 0): ((5, 64), (8, 128), (15, 64), (21, 32), (27, 16), (32, 64), (38, 32), (50, 64), (78, 128), (94, 64), (100, 32), (109, 64), (115, 32), (121, 64), (128, 32), (134, 16), (140, 8), (153, 64), (159, 128)),
+    (128, 1): ((17, 64), (27, 32), (31, 64), (41, 128), (120, 64), (126, 32), (142, 16), (149, 32), (155, 16)),
+}
+
+
 class TestLaneWidthController:
     """Pure host-arithmetic units for the closed loop (no lanes, no jax):
     grow under burst, shrink under trickle, patience gating, OOM width
@@ -985,6 +1022,80 @@ class TestLaneWidthController:
         # the floor is the bucket holding every occupied row
         ctl = self._ctl()
         assert ctl.decide(8, 5, 0, rate=0.0, max_width=2) == 8
+
+    # ---- ISSUE 27: the width follows the rows there is evidence for ----
+
+    @pytest.mark.parametrize("patience", [3, 6])
+    def test_lone_row_at_width_2_returns_to_1_after_patience(self, patience):
+        # one row of two is a share of 0.5: shrink_at (0.25) can never
+        # hold while it is resident, so at width 2 the rows decide —
+        # after ``patience`` boundaries of fitting width 1, not sooner
+        ctl = self._ctl(patience=patience)
+        for _ in range(patience - 1):
+            assert ctl.decide(2, 1, 0, rate=1.0) == 2
+        assert ctl.decide(2, 1, 0, rate=1.0) == 1
+        # re-armed: the next decisions at width 1 hold
+        assert ctl.decide(1, 1, 0, rate=1.0) == 1
+
+    def test_second_row_seen_restarts_the_count_to_1(self):
+        # a waiting (pending or hinted) row is evidence for width 2:
+        # the count of fitting boundaries starts over after it
+        ctl = self._ctl(patience=3)
+        assert ctl.decide(2, 1, 0, rate=0.0) == 2
+        assert ctl.decide(2, 1, 0, rate=0.0) == 2
+        assert ctl.decide(2, 1, 1, rate=0.0) == 2   # a row is waiting
+        assert ctl.decide(2, 2, 0, rate=0.0) == 2   # ... and admitted
+        assert ctl.decide(2, 1, 0, rate=0.0) == 2   # alone again: 1 of 3
+        assert ctl.decide(2, 1, 0, rate=0.0) == 2
+        assert ctl.decide(2, 1, 0, rate=0.0) == 1
+
+    def test_lone_row_at_width_1_never_doubles_on_occupancy(self):
+        # one row of one is a share of 1.0 >= grow_at whatever the
+        # traffic: a lane that never held two rows has no evidence that
+        # arrivals overlap, so only rows it can see widen it
+        ctl = self._ctl(patience=2)
+        for _ in range(40):
+            assert ctl.decide(1, 1, 0, rate=5.0) == 1
+        assert ctl.decide(1, 1, 1, rate=5.0) == 2   # a row it can see
+
+    @pytest.mark.parametrize("width,occupied,k", [
+        (1, 1, 1), (1, 1, 2), (1, 0, 3), (1, 1, 7), (2, 1, 2),
+        (2, 2, 5), (4, 4, 1), (8, 3, 30)])
+    def test_burst_of_k_rows_jumps_straight_to_their_bucket(
+            self, width, occupied, k):
+        from chiaswarm_tpu.core.compile_cache import bucket_batch
+
+        ctl = self._ctl(max_width=128)
+        assert ctl.decide(width, occupied, k, rate=1.0) == \
+            bucket_batch(occupied + k)
+
+    def test_two_rows_of_four_hold_width_4(self):
+        # the rows rule is for widths where shrink_at is under one row
+        # only: at width 4 two rows fit width 2, and the share rule
+        # (0.5 > 0.25) keeps the lane as PR 26 did
+        ctl = self._ctl(patience=2, alpha=1.0)
+        for _ in range(40):
+            assert ctl.decide(4, 2, 0, rate=0.0) == 4
+
+    @pytest.mark.parametrize("start,seed", sorted(_PR26_DECISIONS))
+    def test_decisions_at_widths_4_to_128_are_pr26s(self, start, seed):
+        """Recorded from commit 370c296 (PR 26) with the shipped gains,
+        bounds 1..128: every resize of a seeded occupancy / pending /
+        rate sequence, up to the first width under 4."""
+        from chiaswarm_tpu.serving.stepper import LaneWidthController
+
+        ctl = LaneWidthController(min_width=1, max_width=128)
+        width, changes = start, []
+        for b, (share, pending, rate) in enumerate(
+                _recorded_lane_trace(start, seed)):
+            if width < 4:
+                break
+            new = ctl.decide(width, min(width, int(round(share * width))),
+                             pending, rate)
+            if new != width:
+                changes.append((b, new))
+                width = new
+        assert tuple(changes) == _PR26_DECISIONS[(start, seed)]
 
 
 def test_adaptive_lane_grows_midflight_and_rows_stay_solo_exact(
@@ -1056,6 +1167,144 @@ def test_adaptive_resize_compiles_only_new_lattice_widths(
     one_pass()
     after = GLOBAL_CACHE.executables.stats["misses"]
     assert after == before, (before, after)
+
+
+# ---- ISSUE 27: a lone job rides a lane of its own bucket ----------------
+
+
+@pytest.mark.parametrize("rows", [1, 2])
+def test_lone_job_opens_its_own_bucket_and_pays_no_padding(
+        tiny_pipe, monkeypatch, rows):
+    """A fresh lane is as wide as its first job's bucket: a one-image
+    job rides width 1 (a two-image job width 2), no row-step of the
+    whole job is padding, the lane never resizes, and the images are
+    the solo run's."""
+    monkeypatch.delenv("CHIASWARM_STEPPER_LANE_WIDTH", raising=False)
+    monkeypatch.delenv("CHIASWARM_STEPPER_MIN_WIDTH", raising=False)
+    sched = StepScheduler()
+    assert sched.initial_width(rows, 64, 64) == rows
+    pending, info = sched.submit_request(
+        tiny_pipe, prompt="alone", steps=9, guidance_scale=7.5,
+        height=64, width=64, rows=rows, seed=271).result(timeout=300)
+    imgs = pending.wait()
+    stats = sched.stats()
+    sched.shutdown()
+    assert info["lane_width"] == rows
+    assert stats.get("row_steps_padded", 0) == 0, stats
+    assert stats["row_steps_active"] == 9 * rows
+    assert stats.get("lane_resizes", 0) == 0
+    solo, _ = tiny_pipe(GenerateRequest(
+        prompt="alone", steps=9, guidance_scale=7.5, height=64, width=64,
+        batch=rows, seed=271))
+    _close(imgs, solo)
+
+
+def test_lane_widens_1_to_2_for_a_second_job_and_returns_to_1(
+        tiny_pipe, monkeypatch):
+    """A second job arriving mid-flight grows the width-1 lane to 2 at
+    the next boundary; once it has retired and the first has been alone
+    for ``patience`` boundaries the lane is back at 1 — and across both
+    rebuilds of the row file each job's image is its solo run's."""
+    monkeypatch.delenv("CHIASWARM_STEPPER_LANE_WIDTH", raising=False)
+    monkeypatch.delenv("CHIASWARM_STEPPER_MIN_WIDTH", raising=False)
+    sched = StepScheduler()
+    base = sched.stats().get("steps_executed", 0)
+    fa = sched.submit_request(
+        tiny_pipe, prompt="first", steps=16, guidance_scale=7.5,
+        height=64, width=64, rows=1, seed=272)
+    _wait_steps(sched, base + 1)
+    fb = sched.submit_request(
+        tiny_pipe, prompt="second", steps=3, guidance_scale=5.0,
+        height=64, width=64, rows=1, seed=273)
+    pending_b, info_b = fb.result(timeout=300)
+    pending_a, info_a = fa.result(timeout=300)
+    img_a, img_b = pending_a.wait(), pending_b.wait()
+    stats = sched.stats()
+    sched.shutdown()
+    assert info_a["lane"] == info_b["lane"]
+    assert info_b["lane_width"] == 2 and info_a["lane_width"] == 1
+    assert stats["lane_resizes"] == 2, stats   # 1 -> 2, then 2 -> 1
+    # padding only while the first job was alone at width 2: the steps
+    # between the ``patience`` boundaries the way back waits for
+    from chiaswarm_tpu.serving.stepper import LaneWidthController
+
+    patience = LaneWidthController().patience
+    assert patience - 1 <= stats["row_steps_padded"] < 16 - 3, stats
+    solo_a, _ = tiny_pipe(GenerateRequest(
+        prompt="first", steps=16, guidance_scale=7.5, height=64,
+        width=64, seed=272))
+    solo_b, _ = tiny_pipe(GenerateRequest(
+        prompt="second", steps=3, guidance_scale=5.0, height=64,
+        width=64, seed=273))
+    _close(img_a, solo_a)
+    _close(img_b, solo_b)
+
+
+def test_stale_poll_hint_does_not_widen_the_lane_it_announced(
+        tiny_pipe, monkeypatch):
+    """The poll that announces a job leaves a hint of one row; the
+    job's own arrival burns it. A lane driver that read the hint before
+    its idle wait must not count it beside that very job when the
+    enqueue wakes it: the signals are read anew after every wake."""
+    monkeypatch.delenv("CHIASWARM_STEPPER_LANE_WIDTH", raising=False)
+    monkeypatch.delenv("CHIASWARM_STEPPER_MIN_WIDTH", raising=False)
+    sched = StepScheduler()
+    first = sched.submit_request(
+        tiny_pipe, prompt="warm", steps=3, guidance_scale=7.5,
+        height=64, width=64, rows=1, seed=274)
+    real_signal = sched.demand_signal
+    announced = []
+
+    def poll_lands_as_the_driver_reads(now=None):
+        # the next job's poll returns exactly when the driver comes
+        # back around after the first job resolved: the read that
+        # precedes the idle wait sees the fresh hint
+        if first.done() and not announced:
+            announced.append(True)
+            sched.note_poll(1)
+        return real_signal(now)
+
+    monkeypatch.setattr(sched, "demand_signal",
+                        poll_lands_as_the_driver_reads)
+    first.result(timeout=300)[0].wait()
+    end = time.monotonic() + 30
+    while not announced and time.monotonic() < end:
+        time.sleep(0.005)
+    assert announced
+    time.sleep(0.1)     # the driver is in its idle wait by now
+    _pending, info = sched.submit_request(
+        tiny_pipe, prompt="announced", steps=3, guidance_scale=7.5,
+        height=64, width=64, rows=1, seed=275).result(timeout=300)
+    stats = sched.stats()
+    sched.shutdown()
+    assert info["lane_width"] == 1
+    assert stats.get("lane_resizes", 0) == 0, stats
+    assert stats.get("row_steps_padded", 0) == 0, stats
+
+
+class _MeshSlot:
+    data_width = 2
+
+
+@pytest.mark.parametrize("hint,arrived,age_s,shard_rows,want", [
+    (0, 0, 0.0, False, 1),    # nothing announced: the job's own bucket
+    (3, 1, 0.0, False, 4),    # a poll of 3, this job the first: 1 + 2
+    (1, 1, 0.0, False, 1),    # the hint announced only this job
+    (3, 1, 5.0, False, 1),    # a hint older than its 2 s says nothing
+    (0, 0, 0.0, True, 2),     # row-sharded: the data axis divides it
+])
+def test_initial_width_holds_the_rows_in_evidence(
+        monkeypatch, hint, arrived, age_s, shard_rows, want):
+    monkeypatch.delenv("CHIASWARM_STEPPER_LANE_WIDTH", raising=False)
+    monkeypatch.delenv("CHIASWARM_STEPPER_MIN_WIDTH", raising=False)
+    if shard_rows:
+        monkeypatch.setenv("CHIASWARM_STEPPER_SHARD_ROWS", "1")
+    sched = StepScheduler(_MeshSlot())
+    if hint:
+        sched.note_poll(hint, now=time.monotonic() - age_s)
+    if arrived:
+        sched._note_arrival(arrived)
+    assert sched.initial_width(1, 1024, 1024) == want
 
 
 # ---- overload hooks (ISSUE 9): eviction retire + admission cap ---------

@@ -3,8 +3,11 @@
 The system under test is driven only through its public entry: jobs go
 to an in-process ``MiniHive``; a real ``Worker`` (one-slot ``ChipPool``
 on the first device, its default ``ResidencyManager``, lanes on) polls,
-runs lanes, decodes, encodes PNG and uploads. All times are on the
-hive's clock (``time.monotonic``). Nothing here calls a pipeline.
+runs them and uploads their artifacts. All times are on the hive's
+clock (``time.monotonic``). Nothing here calls a pipeline, and nothing
+here knows what a job is: the weights and the registry, the jobs, the
+comparison, the program modules to capture and the work of a job are
+the configuration's kind's (``perfbench/kinds/``).
 
 Order of a run: imports -> device check -> seeded weights on the device
 -> worker up -> warm-up of the mix's shapes (solo jobs, then one burst)
@@ -16,9 +19,8 @@ plain reference over a sample of the window's jobs -> result line.
 from __future__ import annotations
 
 import asyncio
-import base64
 import gc
-import io
+import importlib
 import json
 import os
 import shutil
@@ -26,6 +28,7 @@ import sys
 import time
 from pathlib import Path
 
+from perfbench import kinds
 from perfbench import traffic as traffic_mod
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -94,54 +97,6 @@ class CompileCounter:
 
         values = REGISTRY.snapshot()["chiaswarm_compiles_total"]["values"]
         return float(sum(values.values())), self.events
-
-
-def build_components(config: dict, seed: int, device):
-    """The program's ``Components`` around weights made here from the
-    seed (``perfbench/weights.py``); returns (components, params)."""
-    from chiaswarm_tpu.models.clip import ClipTextEncoder
-    from chiaswarm_tpu.models.configs import FAMILIES
-    from chiaswarm_tpu.models.tokenizer import HashTokenizer
-    from chiaswarm_tpu.models.unet import UNet
-    from chiaswarm_tpu.models.vae import AutoencoderKL
-    from chiaswarm_tpu.pipelines.components import (
-        Components,
-        abstract_params,
-    )
-
-    from perfbench.weights import make_params
-
-    family = FAMILIES[config["program_family"]]
-    params = make_params(abstract_params(family), seed,
-                         dtype=config["serving"]["dtype"], device=device)
-    components = Components(
-        family=family, model_name=f"bench/{config['name']}",
-        tokenizers=[HashTokenizer(cfg.vocab_size,
-                                  cfg.max_position_embeddings,
-                                  cfg.eos_token_id)
-                    for cfg in family.text_encoders],
-        text_encoders=[ClipTextEncoder(cfg)
-                       for cfg in family.text_encoders],
-        unet=UNet(family.unet), vae=AutoencoderKL(family.vae),
-        params=params)
-    return components, params
-
-
-def make_registry(config: dict, components):
-    """A ``ModelRegistry`` whose checkpoint loader hands out the
-    benchmark's seeded weights; everything after the load (quantize
-    hook, placement, pipeline, residency ledger) is the program's own."""
-    from chiaswarm_tpu.node.registry import ModelRegistry
-    from chiaswarm_tpu.serving.residency import ResidencyManager
-
-    class SeededRegistry(ModelRegistry):
-        def _load_components(self, model_name):
-            return components
-
-    return SeededRegistry(
-        catalog=[{"name": components.model_name,
-                  "family": config["program_family"]}],
-        residency=ResidencyManager())
 
 
 class Window:
@@ -232,43 +187,28 @@ async def run_closed(win: Window, hive, clients: int, make, seconds: float):
             "settled": settled, "attempted": len(settled)}
 
 
-def decode_artifact(result: dict):
-    """The uploaded PNG as uint8 pixels (H, W, 3)."""
-    import numpy as np
-    from PIL import Image
-
-    blob = base64.b64decode(result["artifacts"]["primary"]["blob"])
-    return np.asarray(Image.open(io.BytesIO(blob)).convert("RGB"))
-
-
 def run_control(*, workload: dict, config: dict, mix: dict, seed: int,
                 n_jobs: int, require_tpu: bool = True) -> dict:
     """The comparison that has to fail, at the cell's own size: no
     worker; the window's first ``n_jobs`` jobs as the reference one
-    precision down would have served them, through ``compare.check``.
+    precision down would have served them, through the kind's ``check``.
     The result has a run's keys, ``correct`` false if the limit holds."""
     import jax
 
     from chiaswarm_tpu.core.compile_cache import (
         enable_persistent_compilation_cache,
     )
-    from chiaswarm_tpu.models.configs import FAMILIES
-    from chiaswarm_tpu.pipelines.components import abstract_params
 
-    from perfbench import compare
-    from perfbench.weights import make_params
-
+    kind = kinds.of(config)
     device = device_facts(int(workload["chips"]), require_tpu)
     enable_persistent_compilation_cache()
     dev0 = jax.devices()[0]
-    params = make_params(
-        abstract_params(FAMILIES[config["program_family"]]), seed,
-        dtype=config["serving"]["dtype"], device=dev0)
-    counts = traffic_mod.step_counts(mix, n_jobs, seed)
-    jobs = [traffic_mod.make_job(i, counts[i], seed, config,
+    params = kind.seeded_params(config, seed, dev0)
+    work = traffic_mod.units(mix, kind.UNIT, n_jobs, seed)
+    jobs = [traffic_mod.make_job(kind, i, work[i], seed, config,
                                  f"bench/{config['name']}")
             for i in range(n_jobs)]
-    verdict = compare.control(params, config, jobs, seed=seed)
+    verdict = kind.control(params, config, jobs, seed=seed)
     for row in verdict["jobs"]:
         log(f"control {verdict['precision']} {row}")
     stats = dev0.memory_stats() or {}
@@ -306,11 +246,11 @@ def run_cell(*, workload: dict, config: dict, mix: dict, benchmark: dict,
     from chiaswarm_tpu.node.settings import Settings
     from chiaswarm_tpu.node.worker import Worker
     from chiaswarm_tpu.obs.metrics import REGISTRY
-    from chiaswarm_tpu.pipelines import diffusion as diffusion_mod
 
-    from perfbench import compare, hlo
+    from perfbench import hlo
     from perfbench.attribution import phases_of
 
+    kind = kinds.of(config)
     device = device_facts(int(workload["chips"]), require_tpu)
     lap("imports_and_device_s")
     enable_persistent_compilation_cache()
@@ -320,19 +260,16 @@ def run_cell(*, workload: dict, config: dict, mix: dict, benchmark: dict,
     (SCRATCH / "root").mkdir(parents=True, exist_ok=True)
     os.environ["SWARM_TPU_ROOT"] = str(SCRATCH / "root")
     dev0 = jax.devices()[0]
-    components, params = build_components(config, seed, dev0)
+    registry, params, model = kind.build(config, seed, dev0)
     jax.block_until_ready(params)
     lap("weights_on_device_s")
-    model = components.model_name
-    registry = make_registry(config, components)
-    del components
     pool = ChipPool(n_slots=1, devices=[dev0])
     capture = hlo.ProgramCapture() if trace else None
-    solo, burst = traffic_mod.warm_jobs(mix, seed, config, model)
-    counts = traffic_mod.step_counts(mix, 4096, seed)
+    solo, burst = traffic_mod.warm_jobs(kind, mix, seed, config, model)
+    work = traffic_mod.units(mix, kind.UNIT, 4096, seed)
 
     def make(index: int) -> dict:
-        return traffic_mod.make_job(index, counts[index], seed, config,
+        return traffic_mod.make_job(kind, index, work[index], seed, config,
                                     model)
 
     async def scenario() -> dict:
@@ -344,22 +281,22 @@ def run_cell(*, workload: dict, config: dict, mix: dict, benchmark: dict,
         run = asyncio.create_task(worker.run())
         win = Window(hive, run)
         # ---- warm-up: set-up, not traffic -------------------------------
-        for job in solo:
+        for unit, job in solo:
             t = time.monotonic()
             hive.submit(job)
             got = await win.settle_all({job["id"]}, 1500.0)
             if not got or not got[0]["ok"]:
                 raise RuntimeError(f"warm-up job failed: {got}")
-            steps = job["num_inference_steps"]
-            split[f"warm_solo_{steps}_s"] = round(time.monotonic() - t, 3)
-            split[f"warm_solo_{steps}_phases"] = {
+            label = traffic_mod.unit_label(unit)
+            split[f"warm_solo_{label}_s"] = round(time.monotonic() - t, 3)
+            split[f"warm_solo_{label}_phases"] = {
                 k: round(v, 3) for k, v in
                 (phases_of(got[0]["record"]) or {}).items() if v >= 0.001}
         mark[0] = time.monotonic()
         if burst:
-            for job in burst:
+            for _unit, job in burst:
                 hive.submit(job)
-            got = await win.settle_all({j["id"] for j in burst}, 1500.0)
+            got = await win.settle_all({j["id"] for _, j in burst}, 1500.0)
             if len(got) != len(burst) or not all(g["ok"] for g in got):
                 raise RuntimeError("warm-up burst failed")
             lap("warm_burst_s")
@@ -410,7 +347,8 @@ def run_cell(*, workload: dict, config: dict, mix: dict, benchmark: dict,
         return ran
 
     if capture is not None:
-        with capture.patching(diffusion_mod):
+        with capture.patching(*(importlib.import_module(name)
+                                for name in kind.PROGRAM_MODULES)):
             ran = asyncio.run(scenario())
     else:
         ran = asyncio.run(scenario())
@@ -433,11 +371,10 @@ def run_cell(*, workload: dict, config: dict, mix: dict, benchmark: dict,
                               ran["before"]["compiles"]))
 
     # ---- correct: the plain reference over a sample of the window -------
-    verdict = compare.check(
-        params, config, good, ran["sent"], seed=seed,
-        n_jobs=compare_jobs, decode=decode_artifact)
-    # a program compiled inside the window (a lane width or a decode
-    # that the warm-up missed) voids the run; eager one-off operations
+    verdict = kind.check(params, config, good, ran["sent"], seed=seed,
+                         n_jobs=compare_jobs)
+    # a program compiled inside the window (a shape that the warm-up
+    # missed) voids the run; eager one-off operations
     # (lane bookkeeping at a new width) are counted and reported
     verdict["numbers"]["programs_compiled_in_window"] = {
         "value": float(compiled_in_window[0]), "limit": 0.0}

@@ -5,9 +5,9 @@
 
 Run by hand in the sandbox (the third rehearsal of the on-chip-measurement
 guide; a whole SDXL step compiles for a minute or more, so this is no
-tier-1 test). For each configuration it lowers, for one described
-``v5e`` chip: the lane step program at the cells' lane widths, the
-batch-1 decode and encode, the seeded-weights fill, and the plain
+tier-1 test). For each configuration of the diffusion kind it lowers,
+for one described ``v5e`` chip: the lane step program at the cells' lane
+widths, the batch-1 decode and encode, the seeded-weights fill, and the plain
 reference's step and decode. It prints ``memory_analysis()`` of each and
 the reckoning the ``model-configs`` floor needs: resident weights plus
 the widest lane step's or the decode's temporaries, as a share of the
@@ -25,8 +25,10 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
-#: lane widths the cells reach, by configuration (PERF.md, Cells)
-LANE_WIDTHS = {"sdxl-1024": (2, 4), "sd15-512": (2, 4, 8, 16)}
+#: lane widths the cells reach, by configuration (PERF.md, Cells): a lone
+#: job rides width 1 (both cells today); the wider ones are what a
+#: backlog would grow the lane to
+LANE_WIDTHS = {"sdxl-1024": (1, 2, 4), "sd15-512": (1, 2, 4, 8, 16)}
 
 
 class Lowered:

@@ -42,6 +42,13 @@ class Context:
         self._form = self._costs = None
 
     @property
+    def kind(self):
+        """The module of the configuration's kind (``perfbench/kinds``)."""
+        from perfbench import kinds
+
+        return kinds.of(self.config)
+
+    @property
     def peaks(self) -> dict:
         return peaks_for(self.device["kind"])
 
@@ -72,17 +79,16 @@ class Context:
     @property
     def costs(self) -> dict:
         """Static cost of every operation of every program captured,
-        keyed by (operation name, result shape); flash calls at the
-        attention sizes the configuration states."""
+        keyed by (operation name, result shape); Mosaic calls at the
+        kernel sites the configuration's kind states."""
         if self._costs is None:
-            from perfbench import flops, hlo
+            from perfbench import hlo
 
-            serving = self.config["serving"]
-            sites = flops.attention_sites(
-                self.config, serving["height"], serving["width"])
+            executables = self.capture.executables if self.capture else ()
+            sites = (self.kind.kernel_sites(self.config)
+                     if executables else None)
             self._costs = {}
-            for compiled in (self.capture.executables
-                             if self.capture else ()):
+            for compiled in executables:
                 parsed = hlo.parse_hlo_text(
                     hlo.compiled_hlo_text(compiled), sites)
                 for name, cost in parsed.items():

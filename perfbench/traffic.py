@@ -7,18 +7,20 @@ Keys of a mix:
   more than the worker holds in flight is a backlog). An open loop
   (arrivals on a schedule) comes with the benchmark PR that proves its
   first cell on the chip;
-- ``steps``: ``[[num_inference_steps, share], ...]``, shares exact over
-  each block of ``steps_block`` jobs (default 10), order from the seed;
+- the kind's unit of work (``kinds/<kind>.py::UNIT``: ``steps`` where a
+  job is so many denoising steps; prompt and output token counts for a
+  text kind), as ``[[unit, share], ...]``, shares exact over each block
+  of ``steps_block`` jobs (default 10), order from the seed;
 - ``warm_solo`` / ``warm_burst``: the warm-up the mix's shapes need, as
-  ``[[num_inference_steps, count], ...]``: solo jobs run one after the
+  ``[[unit, count], ...]``: solo jobs run one after the
   other, the burst is submitted at once (so lanes grow to the widths
   the window will use: the worker sizes a lane by how many jobs one
   poll brought, so the burst is as large as the window's first poll).
   Warm-up is set-up, not traffic.
 
-Every seed gets the SAME multiset of step counts, in another order, plus
-its own prompts and noise seeds: the seed must not change the amount of
-work, only its arrangement.
+Every seed gets the SAME multiset of units, in another order, plus its
+own job fields (the kind draws them from the job's RNG stream): the seed
+must not change the amount of work, only its arrangement.
 """
 
 from __future__ import annotations
@@ -29,7 +31,7 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 
-#: prompts are lower-case a-z words (the reference's tokenizer contract)
+#: lower-case a-z words a kind may draw its prompts from
 WORDS = (
     "amber harbor dusk lantern river stone garden violet mountain fog "
     "copper tower meadow winter glass orchard silver bridge ember forest "
@@ -51,17 +53,17 @@ def _rng(seed: int, stream: str) -> random.Random:
     return random.Random(f"{int(seed)}:{stream}")
 
 
-def step_counts(mix: dict, n: int, seed: int) -> list[int]:
-    """``n`` step counts in exact shares per block, shuffled per block."""
+def units(mix: dict, key: str, n: int, seed: int) -> list:
+    """``n`` units of ``mix[key]`` in exact shares per block, shuffled
+    per block (RNG stream ``key``)."""
     block = int(mix.get("steps_block", 10))
-    shares = [(int(s), float(w)) for s, w in mix["steps"]]
-    base: list[int] = []
-    for steps, share in shares:
-        base += [steps] * round(share * block)
+    base: list = []
+    for unit, share in mix[key]:
+        base += [unit] * round(float(share) * block)
     if len(base) != block:
-        raise ValueError(f"shares {shares} do not fill a block of {block}")
-    rng = _rng(seed, "steps")
-    out: list[int] = []
+        raise ValueError(f"shares {mix[key]} do not fill a block of {block}")
+    rng = _rng(seed, key)
+    out: list = []
     while len(out) < n:
         chunk = list(base)
         rng.shuffle(chunk)
@@ -69,35 +71,28 @@ def step_counts(mix: dict, n: int, seed: int) -> list[int]:
     return out[:n]
 
 
-def make_job(index: int, steps: int, seed: int, config: dict,
+def make_job(kind, index: int, unit, seed: int, config: dict,
              model_name: str, tag: str = "w") -> dict:
-    """One hive job. Prompt words and the noise seed come from
-    (``--seed``, index) alone."""
-    rng = _rng(seed, f"job:{tag}:{index}")
-    serving = config["serving"]
-    return {
-        "id": f"{tag}{index:05d}",
-        "model_name": model_name,
-        "workflow": serving["workflow"],
-        "prompt": " ".join(rng.choice(WORDS) for _ in range(8)),
-        "seed": rng.randrange(2 ** 31),
-        "num_inference_steps": int(steps),
-        "guidance_scale": float(serving["guidance_scale"]),
-        "height": int(serving["height"]),
-        "width": int(serving["width"]),
-        "content_type": serving["content_type"],
-    }
+    """One hive job of the kind. Whatever it draws comes from the RNG
+    stream of (``--seed``, tag, index) alone."""
+    return kind.job(_rng(seed, f"job:{tag}:{index}"), f"{tag}{index:05d}",
+                    unit, config, model_name)
 
 
-def warm_jobs(mix: dict, seed: int, config: dict, model_name: str):
-    """(solo jobs, burst jobs) of the mix's warm-up."""
+def unit_label(unit) -> str:
+    """``30`` -> ``"30"``, ``[512, 64]`` -> ``"512_64"``."""
+    parts = unit if isinstance(unit, (list, tuple)) else [unit]
+    return "_".join(str(part) for part in parts)
+
+
+def warm_jobs(kind, mix: dict, seed: int, config: dict, model_name: str):
+    """(solo, burst) of the mix's warm-up, each a list of (unit, job)."""
     def expand(key, tag):
-        jobs, i = [], 0
-        for steps, count in mix.get(key, []):
+        jobs = []
+        for unit, count in mix.get(key, []):
             for _ in range(int(count)):
-                jobs.append(make_job(i, int(steps), seed, config,
-                                     model_name, tag=tag))
-                i += 1
+                jobs.append((unit, make_job(kind, len(jobs), unit, seed,
+                                            config, model_name, tag=tag)))
         return jobs
 
     return expand("warm_solo", "ws"), expand("warm_burst", "wb")

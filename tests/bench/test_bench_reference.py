@@ -12,7 +12,8 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from perfbench import compare, flops, hlo, reference, weights  # noqa: E402
+from perfbench import flops, hlo, reference, weights  # noqa: E402
+from perfbench.kinds import diffusion  # noqa: E402
 
 JOB = dict(prompt="amber harbor dusk lantern", seed=77, steps=17,
            guidance=7.5, height=64, width=64)
@@ -31,10 +32,9 @@ def served(request):
         DiffusionPipeline,
         GenerateRequest,
     )
-    from perfbench.cell import build_components
-
     config = load(request.param)
-    components, params = build_components(config, 2 ** 31 + 9, None)
+    components, params = diffusion.build_components(
+        config, 2 ** 31 + 9, None)
     image, _ = DiffusionPipeline(components)(GenerateRequest(
         prompt=JOB["prompt"], steps=JOB["steps"], seed=JOB["seed"],
         guidance_scale=JOB["guidance"], height=64, width=64))
@@ -46,7 +46,7 @@ def test_reference_agrees_with_the_program_to_rounding(served):
     want = reference.generate(params, config, **JOB)
     # float32 on both sides: what is left is the uint8 rounding (0.5)
     assert np.abs(image.astype(np.float32) - want).max() < 1.0
-    assert compare.image_gap(image, want) \
+    assert diffusion.image_gap(image, want) \
         < config["compare"]["image_gap_limit"]
 
 
@@ -58,10 +58,10 @@ def test_control_one_precision_down_comes_out_not_correct(served):
     control = reference.generate(params, config, precision="bfloat16",
                                  **JOB)
     limit = config["compare"]["image_gap_limit"]
-    assert compare.image_gap(np.clip(np.round(control), 0, 255), want) \
+    assert diffusion.image_gap(np.clip(np.round(control), 0, 255), want) \
         > 1.2 * limit
     lower = reference.generate(params, config, precision="fp8", **JOB)
-    assert compare.image_gap(lower, want) > 10 * limit
+    assert diffusion.image_gap(lower, want) > 10 * limit
 
 
 def test_same_seed_same_weights_and_no_zero_leaf():

@@ -82,7 +82,7 @@ def test_traced_cpu_run_names_no_device_metric(monkeypatch):
     named = set(result["metrics"])
     assert named, "host-side per-layer metrics are still read"
     assert named <= {"hive_queue_s.lat", "upload_s.lat", "admission_s.lat",
-                     "decode_s.lat", "lane_fill_pct.lat", "step_ms.lat"}
+                     "lane_fill_pct.lat"}
     assert not {n for n in named if "mfu" in n or "roofline" in n
                 or "idle" in n}
     assert "busy_s" not in result["device"] and "breakdown" not in result
@@ -127,16 +127,20 @@ def test_the_control_run_comes_out_not_correct(name):
 
 
 def test_the_sample_holds_the_first_job_and_one_that_followed_it():
+    from perfbench.kinds.diffusion import job_size as size
+
     good = [{"id": f"w{i:05d}", "t": float(i)} for i in range(7)]
     sent = {g["id"]: {"job": {"num_inference_steps": 30}} for g in good}
     for seed in (1, 2, 2 ** 31 + 7):
-        ids = [g["id"] for g in compare.pick(good, sent, seed, 3)]
+        ids = [g["id"] for g in compare.pick(good, sent, seed, 3, size)]
         assert ids[:2] == ["w00006", "w00000"] and len(set(ids)) == 3
-        assert ids == [g["id"] for g in compare.pick(good, sent, seed, 3)]
-    assert {compare.pick(good, sent, seed, 3)[2]["id"]
+        assert ids == [g["id"]
+                       for g in compare.pick(good, sent, seed, 3, size)]
+    assert {compare.pick(good, sent, seed, 3, size)[2]["id"]
             for seed in range(20)} > {"w00003"}
     sent["w00002"]["job"]["num_inference_steps"] = 50  # the longest
-    assert [g["id"] for g in compare.pick(good, sent, 1, 2)] \
+    assert [g["id"] for g in compare.pick(good, sent, 1, 2, size)] \
         == ["w00002", "w00000"]
-    assert [g["id"] for g in compare.pick(good[:1], sent, 1, 3)] == ["w00000"]
-    assert compare.pick([], sent, 1, 3) == []
+    assert [g["id"] for g in compare.pick(good[:1], sent, 1, 3, size)] \
+        == ["w00000"]
+    assert compare.pick([], sent, 1, 3, size) == []

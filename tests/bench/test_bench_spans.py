@@ -15,7 +15,8 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from perfbench import cell, compare, readers, traffic  # noqa: E402
+from perfbench import cell, readers, traffic  # noqa: E402
+from perfbench.kinds import diffusion  # noqa: E402
 from perfbench import spans as digests  # noqa: E402
 from perfbench.attribution import phases_of  # noqa: E402
 
@@ -42,7 +43,7 @@ def settled_jobs():
     workload = {"name": "sd15-512.single", "config": "tiny-64",
                 "traffic": "single", "chips": 1}
     kept = {}
-    real = compare.check
+    real = diffusion.check
 
     def keeping(params, config, good, sent, **kw):
         kept["good"], kept["sent"] = good, sent
@@ -50,7 +51,7 @@ def settled_jobs():
 
     patch = pytest.MonkeyPatch()
     patch.setenv("SWARM_TPU_ROOT", os.environ["SWARM_TPU_ROOT"])
-    patch.setattr(compare, "check", keeping)
+    patch.setattr(diffusion, "check", keeping)
     try:
         result = cell.run_cell(
             workload=workload, config=config,

@@ -109,7 +109,8 @@ def _is_sd_generation_model(model: dict[str, Any]) -> bool:
             or "blip" in name.lower():
         return False
     workflow = str((model.get("parameters") or {}).get("workflow", ""))
-    return workflow not in ("txt2audio", "img2txt", "txt2vid", "vid2vid")
+    return workflow not in ("txt2audio", "img2txt", "txt2txt", "txt2vid",
+                            "vid2vid")
 
 
 def _prefetch_safety_checker(models: list[dict[str, Any]],

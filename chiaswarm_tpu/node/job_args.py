@@ -82,6 +82,17 @@ def format_args(job: dict[str, Any], registry: ModelRegistry) -> FormatResult:
             )
         return caption_callback, args
 
+    if workflow == "txt2txt":
+        from chiaswarm_tpu.workloads.text import text_callback
+
+        # the sampling knobs may ride the hive's ``parameters`` dict
+        parameters = args.pop("parameters", None) or {}
+        for name in ("max_new_tokens", "num_return_sequences",
+                     "temperature", "logprobs"):
+            if name in parameters:
+                args.setdefault(name, parameters[name])
+        return text_callback, args
+
     if workflow == "vid2vid":
         from chiaswarm_tpu.workloads.video import vid2vid_callback
 

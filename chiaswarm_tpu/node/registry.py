@@ -549,6 +549,55 @@ class ModelRegistry:
             priority=self._priority_for(model_name),
         )
 
+    def text_pipeline(self, model_name: str, mesh=None):
+        """Resident Ling-class text generator (models/ling.py +
+        pipelines/text.py): the first model that takes most of a chip
+        (10.7 GB at the benchmark's cut), so its entry is what the
+        ledger's budget is sized around. No checkpoint converter exists
+        yet: a node serves it from ``_load_text_components`` (seeded
+        weights in the benchmark) or, under ``allow_random``, at the
+        tiny preset."""
+        from chiaswarm_tpu.pipelines.text import TextPipeline
+
+        self._check_quarantine(model_name)
+        mesh_key = _mesh_cache_key(mesh)
+
+        def build():
+            components = self._load_text_components(model_name)
+            if mesh is not None:
+                # expert and vocabulary shares are per chip by
+                # construction: pin to the slot's lead chip
+                import jax
+
+                device = mesh.devices.flatten()[0]
+                log.info("placing %s params on %s", model_name, device)
+                components.params = jax.device_put(components.params,
+                                                   device)
+            entry = self._catalog.get(model_name) or {}
+            serving = {key: int(entry[key])
+                       for key in ("prefill_chunk", "max_context")
+                       if key in entry}
+            return TextPipeline(components, **serving)
+
+        return self.residency.acquire(
+            ("text", model_name, mesh_key), build, model=model_name,
+            size_of=lambda pipe: pipe.c.param_bytes(),
+            priority=self._priority_for(model_name),
+        )
+
+    def _load_text_components(self, model_name: str):
+        """The text model's weights and tokenizer. Overridable seam, as
+        ``_load_components`` is for the diffusion families."""
+        from chiaswarm_tpu.pipelines.text import TextComponents
+
+        if self.allow_random:
+            log.warning("no checkpoint loader for text model %s; using "
+                        "random tiny weights", model_name)
+            return TextComponents.random(model_name=model_name)
+        raise ValueError(
+            f"text model {model_name!r} is not available on this node "
+            f"(no checkpoint at {model_dir(model_name)})")
+
     def controlnet(self, controlnet_name: str, family: ModelFamily,
                    mesh=None):
         """Resident ControlNetBundle (the per-job ControlNetModel load of

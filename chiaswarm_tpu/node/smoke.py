@@ -61,6 +61,17 @@ SMOKE_JOBS: dict[str, dict[str, Any]] = {
         "content_type": "application/json",
         "_inject_image": True,
     },
+    "txt2txt": {
+        # words of the tiny preset's vocabulary (pipelines/text.py)
+        "id": "smoke-txt2txt",
+        "workflow": "txt2txt",
+        "model_name": "random/ling_tiny",
+        "prompt": "ab cd ab ba",
+        "max_new_tokens": 4,
+        "num_return_sequences": 2,
+        "logprobs": True,
+        "content_type": "application/json",
+    },
     "tts": {
         # the reference's bark smoke job (swarm/test.py:45-51)
         "id": "smoke-tts",

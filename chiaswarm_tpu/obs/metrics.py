@@ -915,5 +915,47 @@ def planner_worker_hours_counter(
         "worker-hours accumulated under the planner's watch")
 
 
+# ---- text-generation families (ISSUE 29, pipelines/text.py) ----
+#
+# Process-global like the compile-cache families, and fed after a job
+# from values its two programs RETURN (token counts are shapes; the
+# routing counts come back as scalars of the prefill and decode
+# programs) - no host callback runs inside a jit.
+
+#: tokens through the text programs: prompt tokens prefilled, and rows x
+#: new tokens decoded (bucket padding included: it is computed)
+TEXT_TOKENS = REGISTRY.counter(
+    "chiaswarm_text_tokens_total",
+    "tokens through the text programs, by phase",
+    labelnames=("phase",))
+
+#: (token, expert) pairs the router chose, split by whether the expert
+#: is held on this chip (the others' share is left out by design)
+MOE_ROUTED_PAIRS = REGISTRY.counter(
+    "chiaswarm_moe_routed_pairs_total",
+    "routed (token, expert) pairs, by phase and by whether this chip "
+    "holds the expert",
+    labelnames=("phase", "held"))
+
+#: distinct held experts with at least one token, summed over decode
+#: steps and expert layers: with the held decode pairs it gives the
+#: tokens an expert sees a step, and the expert weights a step reads
+MOE_EXPERTS_HIT = REGISTRY.counter(
+    "chiaswarm_moe_experts_hit_total",
+    "distinct held experts hit, summed over decode steps and layers")
+
+#: what the hit count was summed over: decode steps x expert layers (hits
+#: over this = distinct held experts one layer reads in one step)
+MOE_LAYER_STEPS = REGISTRY.counter(
+    "chiaswarm_moe_layer_steps_total",
+    "decode steps x expert layers the experts-hit count was summed over")
+
+#: bytes of the two kinds of cache the last decode held
+TEXT_CACHE_BYTES = REGISTRY.gauge(
+    "chiaswarm_text_cache_bytes",
+    "cache bytes of the last text decode, by kind (latent / recurrent)",
+    labelnames=("kind",))
+
+
 #: the Prometheus text exposition content type
 CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"

@@ -1,0 +1,250 @@
+"""The Ling-3.0-flash-class decoder (models/ling.py) against the tests'
+plain float32 reference (tests/ling_reference.py) on seeded weights at
+the tiny size: hidden 64, 2 dense + 6 expert layers (KDA x 7, MLA x 1),
+16 experts in 4 groups of which 4 are held, float32 weights.
+
+Tolerances. Program and reference compute the same function in float32
+in another order (chunks, absorbed products, grouped experts), so they
+differ by rounding: logits of magnitude ~3 agree to a few 1e-6. The
+limits sit a decade above that, and each test shows that the same
+computation with bfloat16-rounded operands (relative step 2^-8) misses
+its limit by a wide margin: a lower precision cannot hide inside them.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chiaswarm_tpu.models import ling
+
+import ling_reference as ref
+
+CFG = ling.LING_TINY
+LOGIT_TOL = 5e-5      # |logit| ~ 3: a few float32 roundings, ~10x room
+LAYER_TOL = 2e-5      # one layer's output, magnitude ~1
+
+
+def bf16(tree):
+    return jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16).astype(x.dtype)
+        if jnp.issubdtype(x.dtype, jnp.floating) else x, tree)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return ling.random_params(CFG, seed=3)
+
+
+@pytest.fixture(scope="module")
+def sizes():
+    return ref.sizes_of(CFG)
+
+
+def kda_operands(seed, t, strong_decay=False):
+    rng = np.random.RandomState(seed)
+    h, d = CFG.num_attention_heads, CFG.head_dim
+    q, k, v = (jnp.asarray(rng.randn(1, t, h, d), jnp.float32)
+               for _ in range(3))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    low = -5.0 if strong_decay else -0.5
+    g = jnp.asarray(rng.uniform(low, 0.0, (1, t, h, d)), jnp.float32)
+    b = jnp.asarray(rng.uniform(0.0, 1.0, (1, t, h)), jnp.float32)
+    state = jnp.asarray(rng.randn(1, h, d, d), jnp.float32)
+    return q, k, v, g, b, state
+
+
+@pytest.mark.parametrize("strong_decay", [False, True])
+def test_chunked_kda_is_the_recurrence(strong_decay):
+    """UT-form chunks (4 tokens each) against the token-by-token
+    recurrence of the reference AND of the program's own decode step;
+    with every gate near e^-5 a chunk's running decay reaches e^-20,
+    which a ratio of exponentials would lose and the pairwise form
+    keeps."""
+    q, k, v, g, b, state = kda_operands(5, 16, strong_decay)
+    o, s = ling.kda_chunked(q, k, v, g, b, state, chunk=4)
+    with jax.default_matmul_precision("highest"):
+        want_o, want_s = ref.kda_recurrence(q[0], k[0], v[0], g[0], b[0],
+                                            state[0])
+    assert np.abs(np.asarray(o[0]) - want_o).max() < LAYER_TOL
+    assert np.abs(np.asarray(s[0]) - want_s).max() < LAYER_TOL
+    step_s, outs = state, []
+    for t in range(16):
+        out, step_s = ling.kda_recurrent_step(q[:, t], k[:, t], v[:, t],
+                                              g[:, t], b[:, t], step_s)
+        outs.append(out)
+    assert np.abs(np.asarray(jnp.stack(outs, 1)) - want_o).max() < LAYER_TOL
+    # bfloat16 operands miss the limit
+    o16, _ = ling.kda_chunked(*bf16((q, k, v)), g, b, state, chunk=4)
+    assert np.abs(np.asarray(o16[0]) - want_o).max() > 10 * LAYER_TOL
+
+
+def prefill(params, cfg, ids, chunk, capacity=64):
+    caches = ling.empty_prefill_caches(cfg, capacity)
+    fn = jax.jit(lambda p, i, c, pos, n: ling.prefill_chunk(
+        p, cfg, i, c, pos, n))
+    for pos in range(0, len(ids), chunk):
+        part = np.zeros((1, chunk), np.int32)
+        n = min(chunk, len(ids) - pos)
+        part[0, :n] = ids[pos:pos + n]
+        logits, caches, stats = fn(params, jnp.asarray(part), caches,
+                                   jnp.int32(pos), jnp.int32(n))
+    return logits, caches, stats
+
+
+def test_prefill_then_cached_decode_is_the_full_forward(params, sizes):
+    """21 prompt tokens in chunks of 8 (the last one part padding), then
+    6 teacher-forced tokens on two rows through both caches: every
+    position's logits against one uncached pass of the reference."""
+    rng = np.random.RandomState(0)
+    n_prompt, n_new = 21, 6
+    ids = rng.randint(0, CFG.vocab_size, n_prompt + n_new)
+    want = np.asarray(ref.forward(params, sizes, ids))
+    logits, caches, _ = prefill(params, CFG, ids[:n_prompt], chunk=8)
+    assert np.abs(np.asarray(logits[0]) - want[n_prompt - 1]).max() \
+        < LOGIT_TOL
+    caches = ling.decode_caches(CFG, caches, 2, n_new)
+    step = jax.jit(lambda p, t, c, n, s: ling.decode_step(p, CFG, t, c, n, s))
+    for t in range(n_new):
+        token = jnp.asarray([ids[n_prompt + t]] * 2, jnp.int32)
+        logits, caches, _ = step(params, token, caches,
+                                 jnp.int32(n_prompt), jnp.int32(t))
+        for row in range(2):
+            assert np.abs(np.asarray(logits[row])
+                          - want[n_prompt + t]).max() < LOGIT_TOL
+    # the same pass with bfloat16-rounded weights misses the limit
+    logits16, _, _ = prefill(bf16(params), CFG, ids[:n_prompt], chunk=8)
+    assert np.abs(np.asarray(logits16[0]) - want[n_prompt - 1]).max() \
+        > 10 * LOGIT_TOL
+
+
+def test_a_whole_chunk_and_a_padded_one_leave_the_same_caches(params):
+    """Padding past ``n_valid`` writes no state: 8 tokens as one whole
+    chunk of 8 against the same 8 in a chunk of 16."""
+    ids = np.random.RandomState(1).randint(0, CFG.vocab_size, 8)
+    la, ca, _ = prefill(params, CFG, ids, chunk=8)
+    lb, cb, _ = prefill(params, CFG, ids, chunk=16)
+    assert np.abs(np.asarray(la) - np.asarray(lb)).max() < LOGIT_TOL
+    for a, b in zip(jax.tree.leaves(ca["kda"]), jax.tree.leaves(cb["kda"])):
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() < LAYER_TOL
+    assert np.abs(np.asarray(ca["mla"][0][:, :8])
+                  - np.asarray(cb["mla"][0][:, :8])).max() < LAYER_TOL
+
+
+def test_absorbed_mla_is_the_up_projected_mla(params, sizes):
+    """The decode path (key up-projection folded into the query, value
+    up-projection after the softmax, latents shared and own) against the
+    prefill path and against the reference's uncached layer, for the
+    token that follows a 12-token prompt."""
+    layer = params["layers"][CFG.mla_layers[0]]["attn"]
+    rng = np.random.RandomState(2)
+    x = jnp.asarray(rng.randn(1, 16, CFG.hidden_size), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref.mla_layer(layer, sizes, x[0, :13]))
+    cache = jnp.zeros((1, 32, CFG.latent_width), jnp.float32)
+    y_pre, cache = ling.mla_prefill(layer, CFG, x[:, :12], cache, 0)
+    assert np.abs(np.asarray(y_pre[0]) - want[:12]).max() < LAYER_TOL
+    suffix = jnp.zeros((3, 4, CFG.latent_width), jnp.float32)
+    y, suffix = ling.mla_decode(
+        layer, CFG, jnp.broadcast_to(x[:, 12:13], (3, 1, CFG.hidden_size)),
+        cache, jnp.int32(12), suffix, jnp.int32(0))
+    assert np.abs(np.asarray(y[:, 0]) - want[12]).max() < LAYER_TOL
+    # and through the prefill path at offset 12, one 4-token chunk
+    y_chunk, _ = ling.mla_prefill(layer, CFG, x[:, 12:16], cache, 12)
+    assert np.abs(np.asarray(y_chunk[0, 0]) - want[12]).max() < LAYER_TOL
+    y16, _ = ling.mla_decode(
+        bf16(layer), CFG, jnp.broadcast_to(x[:, 12:13],
+                                           (3, 1, CFG.hidden_size)),
+        cache, jnp.int32(12), jnp.zeros_like(suffix), jnp.int32(0))
+    assert np.abs(np.asarray(y16[:, 0]) - want[12]).max() > 10 * LAYER_TOL
+
+
+def test_the_four_expert_shares_add_up_to_the_uncut_layer(sizes):
+    """Four chips of four experts each: the parts their held experts
+    give, with the shared expert (which every chip computes alike)
+    counted once, are the whole layer of the uncut reference."""
+    whole = dataclasses.replace(CFG, experts_held=(0, CFG.num_experts))
+    layer = ling.random_params(whole, seed=4)["layers"][3]["mlp"]
+    x = jnp.asarray(np.random.RandomState(6).randn(24, CFG.hidden_size),
+                    jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref.moe_layer(layer, sizes, x, held=(0, 16)))
+        shared = np.asarray(ref.swiglu(layer["shared"], x))
+    total = np.zeros_like(want)
+    pairs_held = 0
+    for first in range(0, CFG.num_experts, 4):
+        cfg = dataclasses.replace(CFG, experts_held=(first, first + 4))
+        share = dict(layer, experts={
+            name: mat[first:first + 4]
+            for name, mat in layer["experts"].items()})
+        y, stats = ling.moe(share, cfg, x)
+        # one share alone is what the reference gives for that share
+        with jax.default_matmul_precision("highest"):
+            alone = np.asarray(ref.moe_layer(share, sizes, x,
+                                             held=(first, first + 4)))
+        assert np.abs(np.asarray(y) - alone).max() < LAYER_TOL
+        total += np.asarray(y) - shared
+        pairs_held += int(stats["pairs_held"])
+        assert int(stats["pairs"]) == 24 * CFG.num_experts_per_tok
+    assert np.abs(total + shared - want).max() < LAYER_TOL
+    assert pairs_held == 24 * CFG.num_experts_per_tok
+    # a layer that skipped its largest share would miss by far more
+    assert np.abs(total - want).max() > 100 * LAYER_TOL
+
+
+def test_the_router_keeps_its_groups_and_its_weights_sum(sizes):
+    layer = ling.random_params(CFG, seed=4)["layers"][2]["mlp"]
+    x = jnp.asarray(np.random.RandomState(7).randn(40, CFG.hidden_size),
+                    jnp.float32)
+    chosen, weight = ling.route(layer, CFG, x)
+    want_chosen, want_weight = ref.route(layer, sizes, x)
+    assert np.array_equal(np.sort(np.asarray(chosen), -1),
+                          np.sort(want_chosen, -1))
+    per_group = CFG.num_experts // CFG.n_group
+    groups = np.asarray(chosen) // per_group
+    assert all(len(set(row)) <= CFG.topk_group for row in groups)
+    assert np.allclose(np.asarray(weight).sum(-1),
+                       CFG.routed_scaling_factor, atol=1e-5)
+    assert np.allclose(np.sort(np.asarray(weight), -1),
+                       np.sort(want_weight, -1), atol=1e-6)
+
+
+def test_causal_attention_option_matches_the_masked_einsum():
+    """ops.attention(causal=True, q_offset=...) with keys wider than
+    values, against a plain masked softmax."""
+    from chiaswarm_tpu.ops.attention import attention
+
+    rng = np.random.RandomState(8)
+    q = jnp.asarray(rng.randn(1, 8, 2, 24), jnp.float32)
+    k = jnp.asarray(rng.randn(1, 20, 2, 24), jnp.float32)
+    v = jnp.asarray(rng.randn(1, 20, 2, 16), jnp.float32)
+    got = attention(q, k, v, causal=True, q_offset=jnp.int32(5))
+    scores = jnp.einsum("blhd,bshd->bhls", q, k) * 24 ** -0.5
+    visible = np.arange(20)[None, :] <= (5 + np.arange(8))[:, None]
+    want = jnp.einsum("bhls,bshd->blhd", jax.nn.softmax(
+        jnp.where(visible, scores, -jnp.inf), -1), v)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+    # no kernel behind ``impl`` masks causally: asked for, it is refused
+    with pytest.raises(ValueError, match="no causal mask"):
+        attention(q, k, v, causal=True, impl="flash")
+    # the option off is the call as it was
+    plain = attention(q, k[..., :24], jnp.pad(v, ((0, 0),) * 3 + ((0, 8),)))
+    assert plain.shape == (1, 8, 2, 24)
+
+
+def test_the_layout_is_the_published_pattern():
+    cfg = ling.LingConfig(num_hidden_layers=8, vocab_size=39296,
+                          experts_held=(0, 128))
+    assert cfg.mla_layers == [5] and len(cfg.kda_layers) == 7
+    shapes = ling.param_shapes(cfg)
+    count = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+    # 6 x 128 experts of 3 x 2560 x 768, ~0.61 B outside them, 0.20 B of
+    # embedding and head: 10.7 GB in bfloat16
+    assert 5.3e9 < count < 5.4e9
+    experts = shapes["layers"][2]["mlp"]["experts"]
+    assert experts["gate"].shape == (128, 2560, 768)
+    assert shapes["layers"][2]["mlp"]["router"].shape == (2560, 512)
+    assert shapes["layers"][5]["attn"]["wdkv"].shape == (2560, 576)
+    assert "experts" not in shapes["layers"][1]["mlp"]

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from chiaswarm_tpu.ops.attention import attention
+from chiaswarm_tpu.ops.causal_flash_attention import key_block
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG_INF = -1e30
@@ -410,23 +411,51 @@ def mla_scale(cfg: LingConfig) -> float:
 
 def mla_prefill(p, cfg: LingConfig, x, cache, pos):
     """x (B, T, d) at positions [pos, pos + T); ``cache`` (B, S, latent
-    + Dr) holds every earlier token's entry. Up-projects the whole cache
-    to keys and values and attends causally (``ops.attention``)."""
+    + Dr) holds every earlier token's entry. Up-projects the latents to
+    keys and values block by block, as far as the cache is written and
+    no further, and attends causally (``ops.attention``) over the same
+    blocks; the rotary key goes in as it lies in the cache, one for all
+    heads."""
     b_, t, _ = x.shape
-    h = cfg.num_attention_heads
+    h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
     q_n, q_r, entry = _mla_query_and_latent(p, cfg, x,
                                             pos + jnp.arange(t))
     cache = jax.lax.dynamic_update_slice_in_dim(cache, entry, pos, axis=1)
     s = cache.shape[1]
-    kv = _proj(cache[..., :cfg.kv_lora_rank], p["wukv"]).reshape(
-        b_, s, h, -1)
-    k_n, v = kv[..., :cfg.qk_nope_head_dim], kv[..., cfg.qk_nope_head_dim:]
-    k_r = jnp.broadcast_to(cache[:, :, None, cfg.kv_lora_rank:],
-                           (b_, s, h, cfg.qk_rope_head_dim))
-    o = attention(jnp.concatenate([q_n, q_r], -1),
-                  jnp.concatenate([k_n, k_r], -1), v,
-                  scale=mla_scale(cfg), causal=True, q_offset=pos)
+    block = key_block(t, s)
+    wukv = p["wukv"].reshape(rank, h, -1)
+    w_uk, w_uv = (w.reshape(rank, -1) for w in (
+        wukv[..., :cfg.qk_nope_head_dim], wukv[..., cfg.qk_nope_head_dim:]))
+
+    def up_project(i, kv):
+        latents = jax.lax.dynamic_slice_in_dim(
+            cache, i * block, block, axis=1)[..., :rank]
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                whole, _proj(latents, w), i * block, axis=1)
+            for whole, w in zip(kv, (w_uk, w_uv)))
+
+    # blocks past the written length stay zero and are never read
+    k_n, v = jax.lax.fori_loop(
+        0, (pos + t + block - 1) // block, up_project,
+        tuple(jnp.zeros((b_, s, w.shape[1]), x.dtype)
+              for w in (w_uk, w_uv)))
+    o = attention(q_n, k_n.reshape(b_, s, h, -1), v.reshape(b_, s, h, -1),
+                  scale=mla_scale(cfg), causal=True, q_offset=pos,
+                  shared_key=(q_r, cache[..., rank:]))
     return _mla_out(p, cfg, x, o), cache
+
+
+def prefill_key_blocks(cfg: LingConfig, prompt_tokens: int, chunk: int,
+                       capacity: int) -> tuple[int, int]:
+    """(key blocks ``mla_prefill`` reads over a prompt's chunks, key
+    blocks of the whole capacity over the same chunks), summed over the
+    latent-attention layers: host integers, for the counter."""
+    block = key_block(chunk, capacity)
+    starts = range(0, prompt_tokens, chunk)
+    layers = len(cfg.mla_layers)
+    return (layers * sum(-(-(pos + chunk) // block) for pos in starts),
+            layers * len(starts) * -(-capacity // block))
 
 
 def mla_decode(p, cfg: LingConfig, x, prompt_cache, prompt_len, suffix,

@@ -950,6 +950,16 @@ MOE_LAYER_STEPS = REGISTRY.counter(
     "chiaswarm_moe_layer_steps_total",
     "decode steps x expert layers the experts-hit count was summed over")
 
+#: key blocks of the latent-attention prefill: those the causal kernel's
+#: bound admits at each chunk's position ("yes") against the rest of the
+#: cache's capacity ("no"), per latent-attention layer; from what the
+#: host knows of a job (positions, chunk, capacity, the kernel's block)
+TEXT_PREFILL_KEY_BLOCKS = REGISTRY.counter(
+    "chiaswarm_text_prefill_key_blocks_total",
+    "key blocks of the latent-attention prefill, by whether the causal "
+    "kernel's bound admits them or they lie past the written cache",
+    labelnames=("read",))
+
 #: bytes of the two kinds of cache the last decode held
 TEXT_CACHE_BYTES = REGISTRY.gauge(
     "chiaswarm_text_cache_bytes",

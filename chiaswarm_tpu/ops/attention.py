@@ -28,6 +28,11 @@ Five implementations behind one function:
                      qualify, else flash on TPU when shapes qualify, else
                      xla. CHIASWARM_ATTENTION=<kind> overrides the pick.
 
+``causal=True`` is a second entry, not a sixth kind: a chunk of a
+prefill against a partly written cache goes to the causal flash kernel
+(ops/causal_flash_attention.py) whatever the backend, and none of the
+calls above can reach it.
+
 All take (B, L, H, D) query / (B, S, H, D) key-value tensors and return
 (B, L, H, D). Head-batched layouts keep the last dim = head_dim (128-lane
 friendly) and let the kernel tile L/S onto the MXU.
@@ -183,36 +188,6 @@ def _xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return jnp.einsum("bhls,bshd->blhd", weights, v)
 
 
-_CAUSAL_BLOCK_Q = 512  # query rows whose logits are live at once
-
-
-def _xla_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                          scale: float, q_offset) -> jnp.ndarray:
-    """Causal einsum attention in query blocks: query ``l`` (absolute
-    position ``q_offset + l``) sees keys ``s <= q_offset + l``. The
-    (H, block, S) float32 logits of one block are live at a time, so a
-    2048-query chunk against a 16k-entry cache stays near 1 GB."""
-    b, l, h, _ = q.shape
-    block = min(l, _CAUSAL_BLOCK_Q)
-    if l % block:
-        raise ValueError(f"causal attention takes a query length that is "
-                         f"a multiple of {block}, got {l}")
-    kpos = jnp.arange(k.shape[1])
-
-    def one(args):
-        qb, start = args
-        logits = jnp.einsum("blhd,bshd->bhls", qb, k,
-                            preferred_element_type=jnp.float32) * scale
-        qpos = q_offset + start + jnp.arange(block)
-        logits = jnp.where(kpos[None, :] <= qpos[:, None], logits, -1e30)
-        weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhls,bshd->blhd", weights, v)
-
-    blocks = q.reshape(b, l // block, block, h, -1).swapaxes(0, 1)
-    out = jax.lax.map(one, (blocks, jnp.arange(l // block) * block))
-    return out.swapaxes(0, 1).reshape(b, l, h, -1)
-
-
 def attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -222,25 +197,39 @@ def attention(
     impl: AttentionImpl = "auto",
     causal: bool = False,
     q_offset=0,
+    shared_key: tuple[jnp.ndarray, jnp.ndarray] | None = None,
 ) -> jnp.ndarray:
     """Multi-head scaled dot-product attention, (B, L, H, D) layout.
 
     ``causal``: query ``l`` sits at absolute position ``q_offset + l``
     (``q_offset`` may be traced: one chunk of a longer prefill against
-    the cache so far) and sees keys at positions up to its own. It takes
-    the einsum path in query blocks: the flash kernel masks block padding
-    only, and the ring kinds rotate whole unmasked shards, so any ``impl``
-    but ``"auto"`` or ``"xla"`` is refused with it (``CHIASWARM_ATTENTION``
-    does not apply). Keys and values may differ in head size there."""
+    the cache so far) and sees keys at positions up to its own; key
+    slots from ``q_offset + L`` on are not read. One path serves it, the
+    causal flash kernel (ops/causal_flash_attention.py; Pallas interpret
+    mode off the chip): the einsum masks nothing, the local flash kernel
+    block padding only, and the ring kinds rotate whole unmasked shards,
+    so any ``impl`` but ``"auto"`` or ``"flash"`` is refused with it
+    (``CHIASWARM_ATTENTION`` does not apply). Keys and values may differ
+    in head size there, and ``shared_key`` = (q_shared (B, L, H, R),
+    k_shared (B, S, R)) adds a key part that every head shares to the
+    logits (``scale`` then defaults to ``(D + R) ** -0.5``)."""
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError(f"expected (B, L, H, D) tensors, got {q.shape}")
+    if causal:
+        if impl not in ("auto", "flash"):
+            raise ValueError(f"attention impl {impl!r} has no causal mask; "
+                             "causal=True takes 'auto' or 'flash'")
+        from chiaswarm_tpu.ops.causal_flash_attention import (
+            causal_flash_attention,
+        )
+
+        return causal_flash_attention(q, k, v, q_offset, shared_key,
+                                      scale=scale)
+    if shared_key is not None:
+        raise ValueError("shared_key is the causal kernel's operand; "
+                         "pass causal=True with it")
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if causal:
-        if impl not in ("auto", "xla"):
-            raise ValueError(f"attention impl {impl!r} has no causal mask; "
-                             "causal=True takes 'auto' or 'xla'")
-        return _xla_causal_attention(q, k, v, scale, q_offset)
     env_forced = False
     if impl == "auto":
         env = _env_impl()

@@ -65,24 +65,48 @@ _LANES = 128
 
 
 def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
-                                scale: float, kv_len: int, col_offset):
+                                scale: float, kv_len, col_offset,
+                                row_offset=None, shared=None):
     """One KV block of the running-softmax recurrence, shared by the
-    local flash kernel below and the fused ring kernel
-    (ops/ring_flash_attention.py). All operands are plain arrays (the
+    local flash kernel below, the fused ring kernel
+    (ops/ring_flash_attention.py) and the causal prefill kernel
+    (ops/causal_flash_attention.py). All operands are plain arrays (the
     callers own the scratch refs): q (bq, d), k/v (bkv, d), m/l (bq, 1)
     running max/denominator, acc (bq, d) fp32 accumulator. ``col_offset``
     is the block's first GLOBAL kv column (masks padding past
     ``kv_len``); it may be a traced scalar in the ring kernel, where the
     hop index is a grid coordinate. Returns (m_next, l_next, acc_next)
-    — bit-identical math to the pre-refactor inline version."""
+    — bit-identical math to the pre-refactor inline version.
+
+    The causal kernel's two additions, both off by default: ``shared`` =
+    (q_s (bq, r), k_s (bkv, r)), a second pair whose products join the
+    logits (a key part that every head shares), and ``row_offset``, the
+    block's first GLOBAL query row: column ``c`` is visible to row ``r``
+    when ``c <= r``; it takes the padding mask's place (a causal caller's
+    rows end before its keys do). With ``kv_len`` None too, the block is
+    not masked at all."""
     logits = jax.lax.dot_general(
         q, k, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * scale
+    )
+    if shared is not None:
+        logits = logits + jax.lax.dot_general(
+            shared[0], shared[1],
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    logits = logits * scale
 
-    # mask KV positions past the true sequence length (block padding)
-    col = col_offset + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    logits = jnp.where(col < kv_len, logits, _NEG_INF)
+    if kv_len is not None or row_offset is not None:
+        col = col_offset + jax.lax.broadcasted_iota(jnp.int32,
+                                                    logits.shape, 1)
+        if row_offset is None:
+            # KV positions past the true sequence length (block padding)
+            visible = col < kv_len
+        else:
+            visible = col <= row_offset + jax.lax.broadcasted_iota(
+                jnp.int32, logits.shape, 0)
+        logits = jnp.where(visible, logits, _NEG_INF)
 
     m_cur = jnp.max(logits, axis=-1, keepdims=True)
     m_next = jnp.maximum(m_prev, m_cur)

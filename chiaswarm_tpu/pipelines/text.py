@@ -248,6 +248,10 @@ class TextPipeline:
                                          phase=phase, held="no")
         metrics.MOE_EXPERTS_HIT.inc(int(stats["experts_hit"]))
         cfg = self.c.config
+        read, total = ling.prefill_key_blocks(
+            cfg, prompt_tokens, self.prefill_chunk, self.max_context)
+        metrics.TEXT_PREFILL_KEY_BLOCKS.inc(read, read="yes")
+        metrics.TEXT_PREFILL_KEY_BLOCKS.inc(total - read, read="no")
         metrics.MOE_LAYER_STEPS.inc((new - 1) * sum(
             cfg.is_moe(i) for i in range(cfg.num_hidden_layers)))
         for kind, size in ling.cache_bytes(

@@ -120,17 +120,22 @@ def test_prefill_then_cached_decode_is_the_full_forward(params, sizes):
         > 10 * LOGIT_TOL
 
 
-def test_a_whole_chunk_and_a_padded_one_leave_the_same_caches(params):
-    """Padding past ``n_valid`` writes no state: 8 tokens as one whole
-    chunk of 8 against the same 8 in a chunk of 16."""
-    ids = np.random.RandomState(1).randint(0, CFG.vocab_size, 8)
-    la, ca, _ = prefill(params, CFG, ids, chunk=8)
-    lb, cb, _ = prefill(params, CFG, ids, chunk=16)
+@pytest.mark.parametrize("n, whole, padded", [(8, 8, 16), (20, 4, 8)],
+                         ids=["one-chunk", "third-chunk-at-16"])
+def test_a_whole_chunk_and_a_padded_one_leave_the_same_caches(
+        params, n, whole, padded):
+    """Padding past ``n_valid`` writes no state: ``n`` tokens in whole
+    chunks against the same tokens in chunks whose last one is part
+    padding (8 in a chunk of 16; 20 in chunks of 8, the padded one at
+    position 16 behind two that the causal kernel reads again)."""
+    ids = np.random.RandomState(1).randint(0, CFG.vocab_size, n)
+    la, ca, _ = prefill(params, CFG, ids, chunk=whole)
+    lb, cb, _ = prefill(params, CFG, ids, chunk=padded)
     assert np.abs(np.asarray(la) - np.asarray(lb)).max() < LOGIT_TOL
     for a, b in zip(jax.tree.leaves(ca["kda"]), jax.tree.leaves(cb["kda"])):
         assert np.abs(np.asarray(a) - np.asarray(b)).max() < LAYER_TOL
-    assert np.abs(np.asarray(ca["mla"][0][:, :8])
-                  - np.asarray(cb["mla"][0][:, :8])).max() < LAYER_TOL
+    assert np.abs(np.asarray(ca["mla"][0][:, :n])
+                  - np.asarray(cb["mla"][0][:, :n])).max() < LAYER_TOL
 
 
 def test_absorbed_mla_is_the_up_projected_mla(params, sizes):
@@ -226,12 +231,152 @@ def test_causal_attention_option_matches_the_masked_einsum():
     want = jnp.einsum("bhls,bshd->blhd", jax.nn.softmax(
         jnp.where(visible, scores, -jnp.inf), -1), v)
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
-    # no kernel behind ``impl`` masks causally: asked for, it is refused
-    with pytest.raises(ValueError, match="no causal mask"):
-        attention(q, k, v, causal=True, impl="flash")
+    # the causal flash kernel is the one path: named, it is the same call,
+    # and an ``impl`` that masks nothing causally is refused
+    named = attention(q, k, v, causal=True, q_offset=jnp.int32(5),
+                      impl="flash")
+    assert np.array_equal(np.asarray(named), np.asarray(got))
+    for impl in ("xla", "ring", "ring_flash"):
+        with pytest.raises(ValueError, match="no causal mask"):
+            attention(q, k, v, causal=True, impl=impl)
     # the option off is the call as it was
     plain = attention(q, k[..., :24], jnp.pad(v, ((0, 0),) * 3 + ((0, 8),)))
     assert plain.shape == (1, 8, 2, 24)
+
+
+def causal_operands(seed, l, s, shared, dk=24, dv=16, r=8):
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(1, l, 2, dk), jnp.float32)
+    k = jnp.asarray(rng.randn(1, s, 2, dk), jnp.float32)
+    v = jnp.asarray(rng.randn(1, s, 2, dv), jnp.float32)
+    pair = (jnp.asarray(rng.randn(1, l, 2, r), jnp.float32),
+            jnp.asarray(rng.randn(1, s, r), jnp.float32)) if shared else None
+    return q, k, v, pair
+
+
+def dense_causal(q, k, v, q_offset, shared=None):
+    """The plain masked softmax over every slot, in float32."""
+    scores = jnp.einsum("blhd,bshd->bhls", q, k, precision=ling.HIGHEST)
+    width = q.shape[-1]
+    if shared is not None:
+        scores = scores + jnp.einsum("blhr,bsr->bhls", *shared,
+                                     precision=ling.HIGHEST)
+        width += shared[0].shape[-1]
+    visible = np.arange(k.shape[1])[None, :] \
+        <= (q_offset + np.arange(q.shape[1]))[:, None]
+    weights = jax.nn.softmax(
+        jnp.where(visible, scores * width ** -0.5, -jnp.inf), -1)
+    return np.asarray(jnp.einsum("bhls,bshd->blhd", weights, v,
+                                 precision=ling.HIGHEST))
+
+
+#: (queries, capacity, q_offset, block_q, block_kv): blocks None = the
+#: kernel's own pick through ``ops.attention``
+CAUSAL_CASES = {
+    "first-chunk": (8, 64, 0, None, None),
+    "one-block": (16, 16, 0, None, None),
+    "mid-cache": (8, 64, 24, None, None),
+    "last-chunk": (8, 64, 56, None, None),
+    "mid-cache-two-query-blocks": (16, 64, 16, 8, 8),
+    "last-chunk-wide-key-blocks": (16, 64, 48, 8, 16),
+    "off-the-block-grid": (8, 40, 13, None, None),
+}
+
+
+def run_causal(q, k, v, shared, q_offset, block_q, block_kv):
+    from chiaswarm_tpu.ops.attention import attention
+    from chiaswarm_tpu.ops.causal_flash_attention import (
+        causal_flash_attention,
+    )
+
+    if block_q is None:
+        return np.asarray(attention(q, k, v, causal=True,
+                                    q_offset=jnp.int32(q_offset),
+                                    shared_key=shared))
+    return np.asarray(causal_flash_attention(
+        q, k, v, jnp.int32(q_offset), shared, block_q=block_q,
+        block_kv=block_kv, interpret=True))
+
+
+@pytest.mark.parametrize("case", CAUSAL_CASES)
+def test_causal_kernel_is_the_dense_masked_einsum(case):
+    """The key-blocked running softmax against the masked softmax over
+    every slot, float32, at the first chunk, a cache of one block, the
+    middle of the cache and its last chunk; 0.02 of a key's logit moved
+    (what bfloat16 operands do) misses the limit."""
+    l, s, q_offset, block_q, block_kv = CAUSAL_CASES[case]
+    q, k, v, _ = causal_operands(11, l, s, shared=False)
+    got = run_causal(q, k, v, None, q_offset, block_q, block_kv)
+    want = dense_causal(q, k, v, q_offset)
+    assert np.abs(got - want).max() < 1e-5
+    got16 = run_causal(*bf16((q, k, v)), None, q_offset, block_q, block_kv)
+    assert np.abs(got16 - want).max() > 1e-4
+
+
+@pytest.mark.parametrize("shared", [False, True],
+                         ids=["per-head-keys", "with-a-shared-key-part"])
+@pytest.mark.parametrize("dk, dv", [(24, 16), (16, 24)],
+                         ids=["keys-wider", "values-wider"])
+def test_causal_kernel_takes_keys_and_values_of_two_widths(dk, dv, shared):
+    """192 / 128 scaled down to 24 / 16 (and the other way round), with
+    and without the 8-wide key part that every head shares."""
+    q, k, v, pair = causal_operands(12, 16, 48, shared, dk=dk, dv=dv)
+    got = run_causal(q, k, v, pair, 16, 8, 8)
+    assert got.shape == (1, 16, 2, dv)
+    assert np.abs(got - dense_causal(q, k, v, 16, pair)).max() < 1e-5
+    # and the shared part is in the logits: left out, the answer moves
+    if shared:
+        alone = run_causal(q, k, v, None, 16, 8, 8)
+        assert np.abs(alone - got).max() > 1e-2
+
+
+@pytest.mark.parametrize("case", [c for c in CAUSAL_CASES
+                                  if c != "off-the-block-grid"])
+def test_causal_kernel_reads_nothing_past_the_written_cache(case):
+    """Every slot from ``q_offset + L`` on holds NaN, in keys, values and
+    the shared key part: a block that was read and masked would still
+    put 0 x NaN into the accumulator, so a finite answer equal to the
+    clean one shows those blocks are not read."""
+    l, s, q_offset, block_q, block_kv = CAUSAL_CASES[case]
+    q, k, v, pair = causal_operands(13, l, s, shared=True)
+    end = q_offset + l
+    dirty = [x.at[:, end:].set(jnp.nan) for x in (k, v, pair[1])]
+    got = run_causal(q, dirty[0], dirty[1], (pair[0], dirty[2]), q_offset,
+                     block_q, block_kv)
+    assert np.isfinite(got).all()
+    assert np.abs(got - dense_causal(q, k, v, q_offset, pair)).max() < 1e-5
+
+
+@pytest.mark.parametrize("pos", [0, 8, 24, 56])
+def test_mla_prefill_reads_no_latent_past_its_chunk(params, pos):
+    """The layer itself: latents past ``pos + T`` are NaN and neither
+    the up-projection nor the kernel touches them; the chunk's output is
+    the one a clean cache gives, and the cache comes back with the
+    chunk's entries written and the NaN where it was."""
+    layer = params["layers"][CFG.mla_layers[0]]["attn"]
+    rng = np.random.RandomState(14)
+    x = jnp.asarray(rng.randn(1, 8, CFG.hidden_size), jnp.float32)
+    clean = jnp.asarray(rng.randn(1, 64, CFG.latent_width), jnp.float32)
+    clean = clean.at[:, pos:].set(0.0)
+    want, want_cache = ling.mla_prefill(layer, CFG, x, clean, pos)
+    dirty = clean.at[:, pos + 8:].set(jnp.nan)
+    got, cache = jax.jit(
+        lambda c, p: ling.mla_prefill(layer, CFG, x, c, p))(
+            dirty, jnp.int32(pos))
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < LAYER_TOL
+    assert np.abs(np.asarray(cache[:, :pos + 8])
+                  - np.asarray(want_cache[:, :pos + 8])).max() < LAYER_TOL
+    assert np.isnan(np.asarray(cache[:, pos + 8:])).all()
+
+
+@pytest.mark.parametrize("prompt, read, of", [
+    (8, 1, 8), (20, 6, 24), (64, 36, 64)],
+    ids=["one-chunk-12.5%", "three-chunks", "the-whole-context-56%"])
+def test_prefill_key_blocks_counts_what_the_bound_admits(prompt, read, of):
+    """Chunks of 8 against a capacity of 64 (one latent-attention layer,
+    a key block = a chunk): chunk i reads i + 1 of 8 blocks."""
+    assert ling.prefill_key_blocks(CFG, prompt, 8, 64) == (read, of)
 
 
 def test_the_layout_is_the_published_pattern():

@@ -6,6 +6,7 @@ import asyncio
 import base64
 import json
 
+import aiohttp
 import numpy as np
 import pytest
 
@@ -75,6 +76,40 @@ def test_token_logprobs_are_the_references(pipe):
         norm = np.log(np.exp(logits).sum(-1))
         want = logits[np.arange(7), new] - norm
         assert np.abs(want - np.asarray(seq["token_logprobs"])).max() < 1e-4
+
+
+def key_blocks_counted():
+    from chiaswarm_tpu.obs.metrics import REGISTRY
+
+    values = REGISTRY.snapshot()[
+        "chiaswarm_text_prefill_key_blocks_total"]["values"]
+    return np.array([values.get("yes", 0), values.get("no", 0)])
+
+
+def test_a_three_chunk_prompt_reads_6_of_24_key_blocks():
+    """20 tokens in chunks of 8 against a capacity of 8 chunks: the
+    causal kernel's bound admits 1 + 2 + 3 blocks, the other 18 lie past
+    the written cache; counted from what the host knows of the job."""
+    pipe = TextPipeline(TextComponents.random(seed=2), prefill_chunk=8,
+                        max_context=64)
+    before = key_blocks_counted()
+    out = pipe(words(np.random.RandomState(3).randint(0, 96, 20)), seed=1,
+               max_new_tokens=2)
+    assert out["prompt_tokens"] == 20
+    assert list(key_blocks_counted() - before) == [6, 18]
+
+
+@pytest.mark.parametrize("tokens, read, unread", [
+    (5, 1, 3), (8, 1, 3), (9, 3, 5), (32, 10, 6)],
+    ids=["part-of-a-chunk", "one-chunk", "into-the-second", "full"])
+def test_key_blocks_are_counted_per_chunk_of_the_prompt(pipe, tokens, read,
+                                                        unread):
+    """The count follows the prompt's chunks (8 tokens, capacity 32): a
+    padded last chunk counts like a whole one, for it reads as much."""
+    zero = {k: 0 for k in ling.empty_stats()}
+    before = key_blocks_counted()
+    pipe._count(tokens, 1, 16, zero, zero)
+    assert list(key_blocks_counted() - before) == [read, unread]
 
 
 def test_without_logprobs_the_artifact_has_text_alone(pipe):
@@ -165,6 +200,7 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
             "chiaswarm_moe_routed_pairs_total",
             "chiaswarm_moe_experts_hit_total",
             "chiaswarm_moe_layer_steps_total",
+            "chiaswarm_text_prefill_key_blocks_total",
             "chiaswarm_text_cache_bytes")}
 
     async def scenario():
@@ -174,7 +210,8 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
             settings=Settings(
                 hive_uri=uri, hive_token="t", worker_name="text",
                 install_signal_handlers=False, poll_busy_s=0.02,
-                poll_idle_s=0.02, drain_timeout_s=30.0),
+                poll_idle_s=0.02, drain_timeout_s=30.0,
+                health_bind_ephemeral=True),
             registry=registry, pool=ChipPool(n_slots=1))
         task = asyncio.create_task(worker.run())
         try:
@@ -184,14 +221,19 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
                          "num_return_sequences": 2, "logprobs": True,
                          "content_type": "application/json"})
             await hive.wait_for_results(1, timeout=300)
+            host, port = worker.health_address
+            async with aiohttp.ClientSession() as session:
+                async with session.get(
+                        f"http://{host}:{port}/metrics") as resp:
+                    served = await resp.text()
         finally:
             worker.request_stop()
             await asyncio.wait_for(task, timeout=60)
             await hive.stop()
-        return hive.results[0], hive.flights.get("hive-1")
+        return hive.results[0], hive.flights.get("hive-1"), served
 
     before = counters()
-    result, record = asyncio.run(scenario())
+    result, record, served = asyncio.run(scenario())
     after = counters()
     assert "error" not in result["pipeline_config"], result
     payload = decode_artifact(result)
@@ -217,6 +259,12 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
     hit = moved("chiaswarm_moe_experts_hit_total", "")
     assert 0 < hit <= moved(pairs, "decode,yes")
     assert moved("chiaswarm_moe_layer_steps_total", "") == 15 * layers
+    # 19 tokens in chunks of 8 against 32 slots: 1 + 2 + 3 of 3 x 4 blocks
+    blocks = "chiaswarm_text_prefill_key_blocks_total"
+    assert (moved(blocks, "yes"), moved(blocks, "no")) == (6, 6)
+    for family in after:
+        assert f"# TYPE {family} " in served
+    assert 'chiaswarm_text_prefill_key_blocks_total{read="yes"}' in served
     assert after["chiaswarm_text_cache_bytes"]["recurrent"] > 0
     assert after["chiaswarm_text_cache_bytes"]["latent"] \
         == (32 + 2 * 16) * ling.LING_TINY.latent_width * 4

@@ -14,7 +14,6 @@ WORKDIR /opt/swarm-tpu
 COPY pyproject.toml ./
 COPY chiaswarm_tpu ./chiaswarm_tpu
 COPY csrc ./csrc
-COPY bench.py ./
 
 # deps come from pyproject.toml; the [tpu] extra resolves libtpu for TPU
 # VMs (on other hosts the base jax wheel's CPU backend runs)

@@ -7,7 +7,7 @@ compiles to a wrong collective: an ``all-reduce`` over an
 already-complete product is invisible in Python and one grep away in the
 scheduled HLO. Reuses ``obs/hlocost.py``'s HLO walker — pure stdlib,
 text in, facts out, no jax import (callers that *build* programs, like
-``tools/shard_audit.py`` and ``benchmark.py``, bring their own).
+``tools/shard_audit.py``, bring their own).
 
 Three checks against a declared per-program **contract** (JSON):
 
@@ -28,7 +28,7 @@ Three checks against a declared per-program **contract** (JSON):
   promised to reuse quietly doubles peak HBM.
 
 Every absent contract key is record-only: :func:`census` always reports
-the observed facts so BENCH can stamp them per config, and CI pins only
+the observed facts, and CI pins only
 what is stable on the host it runs on (donation is not implemented on
 CPU backends, so the CPU contract pins collectives and dtype, and
 records donation).
@@ -122,8 +122,8 @@ def donated_param_indices(text: str) -> list[int]:
 
 
 def census(text: str) -> dict[str, Any]:
-    """All observed contract-relevant facts of one program — the BENCH
-    stamp and the record-only half of an audit."""
+    """All observed contract-relevant facts of one program — the
+    record-only half of an audit."""
     return {
         "collectives": collective_census(text),
         "matmul_dtypes": matmul_dtype_census(text),

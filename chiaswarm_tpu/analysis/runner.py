@@ -26,7 +26,7 @@ from chiaswarm_tpu.analysis.project import DEFAULT_CACHE_NAME, ProjectIndex
 #: the repo surfaces the lint gate covers — single source of truth for
 #: the CLI default paths, tests/test_lint.py, and the CI job
 DEFAULT_LINT_PATHS = ("chiaswarm_tpu", "tests", "tools",
-                      "bench.py", "__graft_entry__.py", "chip_smoke.py")
+                      "__graft_entry__.py", "chip_smoke.py")
 
 
 @dataclasses.dataclass

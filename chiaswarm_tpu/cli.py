@@ -7,7 +7,6 @@ SURVEY.md §1 L6):
     swarm-tpu init [--reset --silent --warm-compile]   configure + prefetch
     swarm-tpu worker                                   serve the swarm
     swarm-tpu smoke [--workflow X | --all]             hermetic smoke jobs
-    swarm-tpu bench                                    BASELINE.json configs
     swarm-tpu info                                     device/mesh report
 """
 
@@ -41,7 +40,6 @@ def main(argv: list[str] | None = None) -> int:
     sub.add_parser("init", add_help=False)
     sub.add_parser("worker")
     sub.add_parser("smoke", add_help=False)
-    sub.add_parser("bench")
     sub.add_parser("info")
 
     args, rest = parser.parse_known_args(argv)
@@ -61,11 +59,6 @@ def main(argv: list[str] | None = None) -> int:
         from chiaswarm_tpu.node.smoke import main as smoke_main
 
         return smoke_main(rest)
-    if args.command == "bench":
-        from chiaswarm_tpu.benchmark import main as bench_main
-
-        bench_main()
-        return 0
     if args.command == "info":
         return cmd_info(args)
     return 2

@@ -51,8 +51,9 @@ class MeshSlot:
     are pure jitted functions, so a second job can safely tokenize and
     dispatch its program while the first drains its device->host image
     transfer — XLA serializes execution on the chip's stream and the
-    overlap removes the chip-idle gap (bench.py measures it at ~+7%
-    steady-state throughput on SDXL-1024). Depth 2 captures the overlap;
+    overlap removes the chip-idle gap. What the overlap is worth on this
+    chip is not measured (no benchmark cell has two jobs in flight:
+    PERF.md section 7, question 0). Depth 2 captures the overlap;
     deeper only grows queue latency.
     """
 

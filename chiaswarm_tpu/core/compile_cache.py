@@ -23,8 +23,10 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import os
 import threading
 import time
+from pathlib import Path
 from typing import Any, Callable, Hashable
 
 from chiaswarm_tpu.obs.metrics import REGISTRY
@@ -102,10 +104,8 @@ def xla_compiler_options() -> dict[str, str] | None:
     Passed as ``compiler_options`` to the pipelines' TOP-LEVEL ``jax.jit``
     calls (nested jits reject them). The main production knob is
     ``xla_tpu_scoped_vmem_limit_kib`` — the default ~16 MiB scoped VMEM
-    caps the flash-attention block sweep and conv fusion buffer sizes
-    (BASELINE.md block-size table)."""
-    import os
-
+    caps the flash-attention block sweep and conv fusion buffer sizes.
+    No option name is verified on this chip (ROADMAP, Maintenance)."""
     raw = os.environ.get("CHIASWARM_XLA_OPTIONS", "").strip()
     if not raw:
         return None
@@ -138,8 +138,6 @@ def _trace_knobs() -> tuple:
     """The set-and-nonempty trace-affecting knobs as a sorted-by-table
     ((name, value), ...) vector — empty tuple in a default environment,
     so callers can fold it only-when-set."""
-    import os
-
     return tuple((name, os.environ[name].strip())
                  for name in _TRACE_ENV_KNOBS
                  if os.environ.get(name, "").strip())
@@ -201,12 +199,10 @@ def enable_persistent_compilation_cache() -> str:
       anchor ``native/`` uses for ``csrc/``) — never ``~``, a temp name,
       a pid or a time.
 
-    Every entry point (worker, benchmark, tests/conftest, chip_smoke)
-    calls this and nothing else touches the setting. Failures raise: a
+    The worker, tests/conftest and chip_smoke call this and nothing
+    else in the package touches the setting (``perfbench/run.py`` sets
+    the variable itself, to the same directory). Failures raise: a
     worker that cannot persist its compiles must say so at start-up."""
-    import os
-    from pathlib import Path
-
     import jax
 
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
@@ -216,6 +212,20 @@ def enable_persistent_compilation_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
     return cache_dir
+
+
+_ROOT_ENV_VARS = ("SWARM_TPU_ROOT", "SDAAS_ROOT")
+
+
+def settings_root() -> Path:
+    """Resolve the settings directory (reference: swarm/settings.py:53-64).
+    Here, beside the other placement this package makes, because the
+    residency ledger below ``node/`` persists under it too."""
+    for var in _ROOT_ENV_VARS:
+        root = os.environ.get(var)
+        if root:
+            return Path(root).expanduser()
+    return Path.home() / ".swarm-tpu"
 
 
 def static_cache_key(owner: int, tag: str, static: dict) -> tuple:
@@ -278,6 +288,27 @@ def bucket_batch(n: int) -> int:
         if n <= p:
             return p
     raise ValueError(f"batch {n} exceeds supported maximum {_POW2[-1]}")
+
+
+def single_chip_rows(kwargs: dict[str, Any]) -> int:
+    """How many batch rows ONE device is given for this job class: 4 up
+    to 512 x 512 px, else 1. Reached by the DIFFUSION workflows only
+    (the executor's burst key, the worker's drain and the lane's width
+    anchor). The rule was set on an installation that is gone; the
+    ledger's only reading of rows on this chip is PR 27's — a width-2
+    lane step cost 2.07x (1024 px) / 2.18x (512 px) a width-1 step — so
+    the 4 is unverified here (ROADMAP S1b). Size comes from the explicit
+    kwargs or, for img2img/inpaint jobs that take the image's own grid,
+    the fetched image shape; otherwise assumed large."""
+    try:
+        h, w = int(kwargs.get("height") or 0), int(kwargs.get("width") or 0)
+    except (TypeError, ValueError):
+        return 1
+    if not (h and w):
+        image = kwargs.get("image")
+        if image is not None and getattr(image, "ndim", 0) >= 2:
+            h, w = int(image.shape[0]), int(image.shape[1])
+    return 4 if 0 < h * w <= 512 * 512 else 1
 
 
 _STEP_BUCKETS = (16, 32, 64, 128)

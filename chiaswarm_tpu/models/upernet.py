@@ -258,7 +258,7 @@ class UperNetDetector:
 
     def __call__(self, image: np.ndarray) -> np.ndarray:
         """uint8 RGB -> uint8 RGB ADE-colored segmentation map."""
-        from chiaswarm_tpu.workloads.ade_palette import ADE20K_PALETTE
+        from chiaswarm_tpu.models.ade_palette import ADE20K_PALETTE
 
         classes = self.class_map(image)
         # class k -> palette row k, exactly the reference's mapping

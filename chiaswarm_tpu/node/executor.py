@@ -22,6 +22,7 @@ from typing import Any
 import numpy as np
 
 from chiaswarm_tpu import WORKER_VERSION
+from chiaswarm_tpu.core.compile_cache import single_chip_rows
 from chiaswarm_tpu.node.job_args import format_args
 from chiaswarm_tpu.node.output_processor import (
     encode_image,
@@ -396,27 +397,6 @@ def job_rows(job_or_kwargs: dict[str, Any]) -> int:
         return max(1, int(job_or_kwargs.get("num_images_per_prompt") or 1))
     except (TypeError, ValueError):
         return 1
-
-
-def single_chip_rows(kwargs: dict[str, Any]) -> int:
-    """How many batch rows ONE device profitably carries for this job
-    class. Measured (BASELINE.md r4) on the DIFFUSION workflows — the
-    only job class reaching this via _burst_key/coalescable, so the rule
-    cannot leak onto unbenched classes (ADVICE r4 #4): 512px-class
-    programs are not MXU-saturated at batch 1 — batch 4 reaches +20%
-    images/sec on one chip and the gain plateaus there; 1024px-class is
-    saturated at batch 1 (r1). Size comes from the explicit kwargs or,
-    for img2img/inpaint jobs that take the image's own grid, the fetched
-    image shape; otherwise assumed large."""
-    try:
-        h, w = int(kwargs.get("height") or 0), int(kwargs.get("width") or 0)
-    except (TypeError, ValueError):
-        return 1
-    if not (h and w):
-        image = kwargs.get("image")
-        if image is not None and getattr(image, "ndim", 0) >= 2:
-            h, w = int(image.shape[0]), int(image.shape[1])
-    return 4 if 0 < h * w <= 512 * 512 else 1
 
 
 def rows_cap(rows_max: int, data_width: int, per_device_rows: int = 1) -> int:

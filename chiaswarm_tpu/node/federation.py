@@ -244,6 +244,10 @@ class FederatedHive:
         self.journals: list[HiveJournal | None] = []
         self.shards: list[ShardHive] = []
         self.ports: list[int] = [0] * self.router.n_shards
+        # shards killed and not yet recovered: the dead OBJECT stays in
+        # ``shards`` for the harness to read, but its memory is garbage
+        # and its journal detached, so no peer may route through it
+        self.down: set[int] = set()
         for index in range(self.router.n_shards):
             journal = None
             if self.journal_root is not None:
@@ -294,6 +298,7 @@ class FederatedHive:
         shard.planner = getattr(self, "planner", None)
         if index < len(self.shards):
             self.shards[index] = shard
+        self.down.discard(int(index))
         return shard
 
     @property
@@ -360,6 +365,7 @@ class FederatedHive:
         every OTHER shard keeps serving — the blast radius this module
         exists to bound. Returns the port for :meth:`restart_shard`."""
         shard = self.shards[index]
+        self.down.add(int(index))
         port = await kill_hive(shard)
         self.ports[index] = port
         log.warning("shard %d killed on port %d (%d shard(s) still "
@@ -418,9 +424,13 @@ class FederatedHive:
             return []
         # a shard partitioned from this worker must not hand it work
         # through the back door — the lease would live on a hive the
-        # worker cannot heartbeat or upload to
+        # worker cannot heartbeat or upload to. A killed shard hands
+        # out nothing at all: a grant from its dead memory is in no
+        # journal, so the recovered shard would meet the job's upload
+        # as a digest of an attempt it never granted
         peers = [shard for shard in self.shards
                  if shard is not thief and shard.pending_jobs
+                 and shard.shard_index not in self.down
                  and worker_name not in shard.partitioned]
         if not peers:
             return []

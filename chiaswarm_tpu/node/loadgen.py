@@ -39,8 +39,7 @@ satellite): :func:`sweep_lane_gains` replays seeded traces through
 host simulation to score grow/shrink/patience gains, and
 :func:`sweep_prefetch_window` scores the residency
 :class:`~chiaswarm_tpu.serving.residency.ArrivalEwma` prefetch-ranking
-window the same way; ``benchmark.py`` stamps both sweeps (and a
-compact overload run) into BENCH json.
+window the same way.
 
 Like the chaos harness, this is product code: operators smoke a build's
 overload behavior with ``python -m chiaswarm_tpu.node.loadgen``
@@ -84,8 +83,8 @@ def _suggest_hang_budget() -> dict:
 
 def percentile(values: Sequence[float], q: float) -> float:
     """Nearest-rank percentile (q in [0, 1]) of an unsorted sequence;
-    0.0 for an empty one. Shared by the scorer and the BENCH config so
-    a p99 always means the same thing."""
+    0.0 for an empty one. One definition, so a p99 always means the
+    same thing."""
     if not values:
         return 0.0
     ordered = sorted(values)
@@ -151,9 +150,11 @@ DEFAULT_PROFILES: tuple[WorkloadProfile, ...] = (
 #: jitter (the PR-9 margin lesson, applied to the budget side)
 DEADLINE_MARGIN = 1.5
 
-#: relative denoise cost per model family (sd15 = 1.0; sdxl from the
-#: BASELINE.md step-time ratio at default sizes, tiny from the test
-#: family's measured share) — scales the synthetic service model the
+#: relative denoise cost per model family (sd15 = 1.0; sdxl's 3.2 is
+#: a step-time ratio of an installation that is gone — on this chip
+#: the width-1 lane steps read 121.85 / 19.956 ms = 6.1, ledger, PR 29
+#: — and tiny is the test family's share) — scales the synthetic
+#: service model, which times sleeps and never the chip, the
 #: same way the family scales the real denoise loop. ``sdxl_turbo``
 #: (ISSUE 12) is the few-step-distilled SDXL class: the per-step cost
 #: stays SDXL's 3.2 but 4 steps replace 30, so 3.2 x 4/30 ≈ 0.43 —
@@ -1069,8 +1070,7 @@ async def autoscale_comparison(schedule: Sequence[ScheduledJob], *,
                                seed: Any = "swarmplan",
                                shed_slack: float = 0.02,
                                **run_kwargs: Any) -> dict[str, Any]:
-    """THE swarmplan headline (ISSUE 19 gate + BENCH ``autoscaler``
-    config): drive the SAME seeded schedule once under the planner and
+    """THE swarmplan headline (ISSUE 19 gate): drive the SAME seeded schedule once under the planner and
     once per static roster size, then compare worker-hours among the
     rosters that actually served the traffic.
 
@@ -1269,8 +1269,7 @@ def score_run(hive: LoadHive, issued: Sequence[str], workers: Sequence[Any],
     def attribution_table(samples: dict[str, dict[str, list[float]]]
                           ) -> dict[str, dict]:
         """Per-family budget-attribution table: mean seconds + share
-        per phase, plus the argmax phase (ISSUE 13 — the table the
-        BENCH load_harness config stamps)."""
+        per phase, plus the argmax phase (ISSUE 13)."""
         table: dict[str, dict] = {}
         for family, phases in sorted(samples.items()):
             mean = {phase: round(sum(vals) / max(1, len(vals)), 4)
@@ -1482,8 +1481,7 @@ def sweep_lane_gains(seed: Any = "swarmload",
     """Score LaneWidthController gain triples over the harness's three
     canonical regimes (steady trickle, diurnal, spiky burst), each
     replayed over a ``panel`` of seed-derived traces so one lucky trace
-    cannot crown a winner. ``benchmark.py`` stamps the table into BENCH
-    json; the shipped defaults are asserted against the default-seed
+    cannot crown a winner. The shipped defaults are asserted against the default-seed
     winner in tests/test_loadgen.py so a default and the harness can
     never silently disagree."""
     if grid is None:
@@ -1601,8 +1599,8 @@ def sweep_prefetch_window(seed: Any = "swarmload",
                           panel: int = 6) -> dict[str, Any]:
     """Rank candidate ArrivalEwma windows for the residency prefetch
     ranking (ISSUE 9 satellite: tune prefetch aggressiveness from
-    harness sweeps), averaged over a ``panel`` of seed-derived streams;
-    stamped into BENCH json beside the gains table. The shipped value
+    harness sweeps), averaged over a ``panel`` of seed-derived streams.
+    The shipped value
     is ``serving.residency.PREFETCH_RANK_WINDOW_S`` — deliberately
     separate from the lane demand EWMA's short window (model reuse has
     minutes-scale locality, lane demand has seconds-scale)."""

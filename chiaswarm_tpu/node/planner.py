@@ -76,8 +76,7 @@ class PlannerConfig:
     """The autoscaler's knobs (README "Autoscaling" operator guide).
 
     ``capacity_jobs_s_per_worker`` is the PRIOR — the PR-9 capacity
-    model's jobs/s/worker under the expected mix (BENCH's
-    ``jobs_per_s_per_chip`` x chips/worker). The planner refines it
+    model's jobs/s/worker under the expected mix. The planner refines it
     online from observed settle throughput whenever the fleet is
     provably saturated (hive-side backlog > 0), so a wrong prior
     converges instead of oscillating."""
@@ -331,7 +330,7 @@ class FleetPlanner:
         desired = max(cfg.min_workers,
                       min(cfg.max_workers, raw_desired))
         # worker-hours accrue continuously (actual x wall time) — the
-        # cost ledger BENCH compares against static rosters
+        # cost the ISSUE-19 gate compares against static rosters
         if self._last_tick is not None and now > self._last_tick:
             self._m_hours.inc(actual * (now - self._last_tick) / 3600.0)
         self._last_tick = now

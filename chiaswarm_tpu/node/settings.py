@@ -17,6 +17,8 @@ import os
 from pathlib import Path
 from typing import Any
 
+from chiaswarm_tpu.core.compile_cache import settings_root
+
 # the hive protocol's adaptive poll cadence (the reference's constants,
 # swarm/worker.py). They live HERE — the pure-config module — so hive.py
 # (which needs aiohttp) can re-export them without config depending on an
@@ -39,8 +41,6 @@ _ENV_OVERRIDES = {
     "SWARM_TPU_FRONT_URI": "hive_front_uri",
     "SWARM_TPU_LOG_LEVEL": "log_level",
 }
-
-_ROOT_ENV_VARS = ("SWARM_TPU_ROOT", "SDAAS_ROOT")
 
 
 @dataclasses.dataclass
@@ -222,15 +222,6 @@ class Settings:
         data["sdaas_uri"] = data.pop("hive_uri")
         data["sdaas_token"] = data.pop("hive_token")
         return data
-
-
-def settings_root() -> Path:
-    """Resolve the settings directory (reference: swarm/settings.py:53-64)."""
-    for var in _ROOT_ENV_VARS:
-        root = os.environ.get(var)
-        if root:
-            return Path(root).expanduser()
-    return Path.home() / ".swarm-tpu"
 
 
 def settings_path() -> Path:

@@ -52,13 +52,13 @@ from chiaswarm_tpu.obs import profiling as obs_profiling
 from chiaswarm_tpu.obs import trace as obs_trace
 
 from chiaswarm_tpu.core.chip_pool import ChipPool
+from chiaswarm_tpu.core.compile_cache import single_chip_rows
 from chiaswarm_tpu.node.executor import (
     do_work,
     do_work_batch,
     error_result,
     job_rows,
     rows_cap,
-    single_chip_rows,
 )
 from chiaswarm_tpu.node.hive import BadWorkerError, HiveClient
 from chiaswarm_tpu.node.hivelog import HIVE_EPOCH_KEY, HIVE_SHARD_KEY
@@ -1641,10 +1641,10 @@ class Worker:
         # cross-job coalescing: a dp-sharded slot runs up to dp compatible
         # jobs as ONE batched program (executor groups them; incompatible
         # jobs in a burst just run serially). 512px-class jobs
-        # additionally batch up to single_chip_rows() per device — one
-        # chip is NOT saturated by them at batch 1 (+20% measured,
-        # BASELINE.md r4); 1024px-class stays at one row per device
-        # (saturated, r1). On multi-slot pools the drain loop below
+        # additionally batch up to single_chip_rows() per device and
+        # 1024px-class stays at one row per device (a rule this chip
+        # has not verified: core/compile_cache.py::single_chip_rows,
+        # ROADMAP S1b). On multi-slot pools the drain loop below
         # additionally leaves ``_hungry_slots`` jobs in the queue, so a
         # coalescing slot never strips work an idle neighbor is already
         # waiting for.

@@ -3,8 +3,8 @@
 Five layers, one vocabulary (ISSUE 4 + ISSUE 11):
 
 - ``metrics``   — Prometheus-style :class:`Registry` of counters /
-                  gauges / histograms; ``/metrics`` exposition, BENCH
-                  snapshots, and the ``/healthz`` read-through view.
+                  gauges / histograms; ``/metrics`` exposition and the
+                  ``/healthz`` read-through view.
 - ``trace``     — Dapper-style per-job span trees on ``perf_counter``
                   (poll -> execute -> encode/step/decode -> upload),
                   kept in a bounded ring and exported as
@@ -20,9 +20,11 @@ Five layers, one vocabulary (ISSUE 4 + ISSUE 11):
                   time), per-step per-shard summaries in a bounded
                   ring at ``/debug/numerics``, and the stream format
                   ``tools/divergence_bisect.py`` aligns.
-- ``hlocost``   — the static HLO cost model (conv/dot/flash FLOPs, HBM
-                  bytes, roofline attainment) shared by
-                  ``tools/op_roofline.py`` and the BENCH stamping.
+- ``hlocost``   — the static HLO parser (the walker
+                  ``analysis/hlocheck.py`` shares; conv/dot/flash FLOPs
+                  and HBM bytes per instruction) and ``ProgramCapture``
+                  for the audit tools and ``chip_smoke.py``; no clock
+                  and no peaks (those are the benchmark's).
 
 Like ``analysis/``, this package imports without jax, aiohttp, or any
 accelerator — host tools, the linter environment, and CI jobs can load

@@ -1,44 +1,29 @@
-"""Static HLO cost model + roofline attainment (swarmlens, ISSUE 11).
+"""Static HLO parsing and program capture (swarmlens, ISSUE 11).
 
-Extracted from ``tools/op_roofline.py`` (which is now a thin CLI over
-this module) so roofline attainment is an importable SIGNAL instead of
-a one-off script: ``benchmark.py`` stamps per-config attainment into
-BENCH json, tests cost canned HLO fixtures without a TPU, and the CLI
-keeps printing the per-fusion table.
-
-Three layers:
+Two layers, both with a caller outside this module:
 
 - **parsing/costing** — :func:`parse_hlo_text` statically costs every
   fusion / bare conv / dot / flash custom-call in a scheduled-HLO dump:
   conv FLOPs from window/dim_labels/feature_group_count, dot FLOPs from
   contracting dims, flash FLOPs from the folded (B*H, L, D) operands,
-  HBM bytes as operands+result touched once. Each entry also records
-  its enclosing computation, and :func:`while_body_computations` names
-  the computations executed once per loop trip — so a denoise scan's
-  per-step work can be folded N times into a whole-program bound.
-- **measured attainment** — :func:`collect_op_times` reads per-op
-  device durations from a profiler xplane dump (TPU only) and
-  :func:`attainment_rows` joins them against the static costs:
-  achieved TFLOP/s, both roofline components, percent-of-roofline per
-  fusion (``tools/op_roofline.py``'s table).
-- **static attainment** — :func:`static_program_report` needs no
-  profiler: the program's modeled FLOPs/bytes and its roofline lower
-  bound (sum over fusions of max(compute time, memory time)), compared
-  against a measured wall time. This is what BENCH stamps per config —
-  on CPU hosts the TPU peak numbers make the percentage notional, but
-  the schema and the modeled-work numbers are stable across rounds, so
-  the r06+ trajectory can track *where the chip time goes*.
+  HBM bytes as operands+result touched once (``chip_smoke.py`` counts
+  the flash custom calls of every lane step program with it).
+  :func:`iter_instruction_lines` is the one HLO walker; the contract
+  checker (``analysis/hlocheck.py``) walks HLO through it too.
+- **program capture** — :func:`compiled_hlo_text` and
+  :class:`ProgramCapture` (``tools/shard_audit.py``,
+  ``tools/key_audit.py``, ``chip_smoke.py``).
 
-Peaks default to TPU v5e (197 bf16 TFLOP/s, 819 GB/s), overridable via
-``CHIASWARM_PEAK_TFLOPS`` / ``CHIASWARM_PEAK_GBPS`` or keyword args.
-Pure stdlib at import (jax only inside :func:`collect_op_times` /
-:class:`ProgramCapture`), like the rest of ``obs/``.
+This module joins nothing to a clock and knows no peaks: roofline
+shares are the benchmark's (``perfbench/hlo.py`` with
+``perfbench/peaks.json``, by ``device_kind``).
+Pure stdlib at import (jax only inside :class:`ProgramCapture`), like
+the rest of ``obs/``.
 """
 
 from __future__ import annotations
 
 import math
-import os
 import re
 from typing import Any, Callable, Iterable
 
@@ -50,12 +35,6 @@ _DTYPE_BYTES = {
 _SHAPE_RE = re.compile(r"\b(pred|[su]\d+|bf16|f16|f32|f64)\[([\d,]*)\]")
 _NAME_RE = re.compile(r"%([\w.-]+)")
 _DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.-]+)\s*=\s*(.+)$")
-
-
-def default_peaks() -> tuple[float, float]:
-    """(peak TFLOP/s, peak GB/s) from env or the TPU v5e defaults."""
-    return (float(os.environ.get("CHIASWARM_PEAK_TFLOPS", "197")),
-            float(os.environ.get("CHIASWARM_PEAK_GBPS", "819")))
 
 
 def _shape_dims(dtype_dims: tuple[str, str]):
@@ -217,35 +196,10 @@ def iter_instruction_lines(text: str):
         yield (current or ""), line
 
 
-def called_computations(text: str) -> set[str]:
-    """Computation names referenced by ``calls=`` (fused computations).
-    Instructions INSIDE them also parse as bare conv/dot rows — fine for
-    the measured join (the profiler only emits fusion names) but a
-    double count for a static whole-program sum, which must skip them."""
-    return {m.group(1)
-            for m in re.finditer(r"calls=%?([\w.-]+)", text)}
-
-
-def while_body_computations(text: str) -> set[str]:
-    """Computation names executed once per while-loop trip (body AND
-    condition) — the denoise scan's per-step region. Instructions
-    costed inside these computations should be folded by the trip
-    count when modeling a whole program."""
-    bodies: set[str] = set()
-    for line in text.splitlines():
-        if re.search(r"\bwhile\(", line):
-            for field in ("body", "condition"):
-                m = re.search(field + r"=%?([\w.-]+)", line)
-                if m:
-                    bodies.add(m.group(1))
-    return bodies
-
-
 def parse_hlo_text(text: str) -> dict[str, dict]:
     """fusion/conv/dot name -> {flops, bytes, kind, computation} from
     scheduled HLO. ``computation`` is the enclosing computation name
-    ("" at module scope) — join against
-    :func:`while_body_computations` to find per-loop-trip work."""
+    ("" at module scope)."""
     shape_map = build_shape_map(text)
 
     # computation name -> [total conv+dot flops inside it, kind]
@@ -305,168 +259,7 @@ def parse_hlo_text(text: str) -> dict[str, dict]:
 
 
 # ---------------------------------------------------------------------------
-# measured attainment (profiler join — TPU hosts)
-# ---------------------------------------------------------------------------
-
-
-def collect_op_times(xplane_path: str) -> dict[str, dict]:
-    """op name -> {total_ps, count} from the TPU device plane."""
-    from jax.profiler import ProfileData
-
-    pd = ProfileData.from_file(xplane_path)
-    times: dict[str, dict] = {}
-    for plane in pd.planes:
-        if not plane.name.startswith("/device:TPU"):
-            continue
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            for event in line.events:
-                stats = dict(event.stats)
-                dur = stats.get("device_duration_ps")
-                if dur is None:
-                    continue
-                name = event.name.split(" = ")[0].lstrip("%")
-                entry = times.setdefault(
-                    name, {"total_ps": 0, "count": 0,
-                           "signature": event.name})
-                entry["total_ps"] += int(dur)
-                entry["count"] += 1
-    return times
-
-
-def is_container_op(name: str) -> bool:
-    """A while/conditional event SPANS its body ops, which also appear
-    on the same profiler line — counting both would double-book time."""
-    return name.split(".")[0] in ("while", "conditional", "call")
-
-
-def attainment_rows(times: dict[str, dict], costs: dict[str, dict], *,
-                    peak_tflops: float, peak_gbps: float) -> list[dict]:
-    """Join measured per-op durations against static costs: one row per
-    op with achieved TFLOP/s, the binding roofline side, and
-    percent-of-roofline, sorted heaviest-first."""
-    total_ps = sum(t["total_ps"] for name, t in times.items()
-                   if not is_container_op(name))
-    rows = []
-    for name, t in times.items():
-        if is_container_op(name):
-            continue
-        cost = costs.get(name) or {}
-        secs = t["total_ps"] * 1e-12
-        flops = cost.get("flops", 0.0) * t["count"]
-        bts = cost.get("bytes", 0) * t["count"]
-        t_compute = flops / (peak_tflops * 1e12)
-        t_bw = bts / (peak_gbps * 1e9)
-        t_roof = max(t_compute, t_bw)
-        kind = cost.get("kind", "other")
-        if kind == "other" and "flash" in name:
-            kind = "flash"
-        rows.append({
-            "name": name, "kind": kind, "count": t["count"],
-            "ms": secs * 1e3,
-            "gflop": flops / 1e9, "mb": bts / 1e6,
-            "tflops": (flops / secs / 1e12) if secs else 0.0,
-            "bound": "flops" if t_compute >= t_bw else "hbm",
-            "roof_pct": (100.0 * t_roof / secs) if secs else 0.0,
-            "share_pct": 100.0 * t["total_ps"] / max(total_ps, 1),
-        })
-    rows.sort(key=lambda r: -r["ms"])
-    return rows
-
-
-def conv_attainment_summary(rows: list[dict]) -> dict:
-    """Time-weighted conv-fusion roofline attainment over the SANELY
-    costed rows. A fusion whose static cost model exceeds its measured
-    time by >1.2x is MIS-COSTED (e.g. a multi-conv fusion
-    double-counted, or a rematerialized op the profiler books
-    elsewhere) — folding it into the average would report >100%
-    nonsense; it is counted separately instead."""
-    conv_rows = [r for r in rows if r["kind"] in ("conv", "mixed")]
-    conv_ms = sum(r["ms"] for r in conv_rows)
-    sane = [r for r in conv_rows if r["roof_pct"] <= 120.0]
-    sane_ms = sum(r["ms"] for r in sane)
-    weighted = (sum(r["roof_pct"] * r["ms"] for r in sane)
-                / max(sane_ms, 1e-9))
-    total_ms = sum(r["ms"] for r in rows)
-    return {
-        "total_ms": total_ms,
-        "conv_ms": conv_ms,
-        "conv_share_pct": 100.0 * conv_ms / max(total_ms, 1e-9),
-        "weighted_conv_roof_pct": weighted,
-        "sane_ms": sane_ms,
-        "miscosted_fusions": len(conv_rows) - len(sane),
-        "miscosted_ms": conv_ms - sane_ms,
-    }
-
-
-# ---------------------------------------------------------------------------
-# static attainment (no profiler — the BENCH stamping)
-# ---------------------------------------------------------------------------
-
-
-def static_program_report(hlo_text: str, *, steps: int = 1,
-                          peak_tflops: float | None = None,
-                          peak_gbps: float | None = None,
-                          achieved_s: float | None = None,
-                          top: int = 5) -> dict:
-    """Whole-program roofline model from HLO text alone.
-
-    ``steps`` folds instructions inside while-loop bodies (the denoise
-    scan executes its body once per step; static HLO prints it once).
-    ``achieved_s`` (a measured wall time for one program execution)
-    turns the modeled bound into an attainment percentage; without it
-    only the modeled quantities are reported."""
-    if peak_tflops is None or peak_gbps is None:
-        d_tflops, d_gbps = default_peaks()
-        peak_tflops = peak_tflops or d_tflops
-        peak_gbps = peak_gbps or d_gbps
-    costs = parse_hlo_text(hlo_text)
-    loop_comps = while_body_computations(hlo_text)
-    fused_comps = called_computations(hlo_text)
-    total_flops = total_bytes = 0.0
-    bound_s = compute_s = memory_s = 0.0
-    heaviest: list[dict] = []
-    for name, cost in costs.items():
-        if cost.get("computation") in fused_comps:
-            continue  # costed via the fusion row that calls it
-        count = steps if cost.get("computation") in loop_comps else 1
-        flops = cost["flops"] * count
-        bts = cost["bytes"] * count
-        t_c = flops / (peak_tflops * 1e12)
-        t_b = bts / (peak_gbps * 1e9)
-        total_flops += flops
-        total_bytes += bts
-        compute_s += t_c
-        memory_s += t_b
-        bound_s += max(t_c, t_b)
-        heaviest.append({
-            "name": name, "kind": cost["kind"], "count": count,
-            "gflop": round(flops / 1e9, 3), "mb": round(bts / 1e6, 3),
-            "bound_ms": round(max(t_c, t_b) * 1e3, 4),
-            "bound": "flops" if t_c >= t_b else "hbm",
-        })
-    heaviest.sort(key=lambda r: -r["bound_ms"])
-    report = {
-        "modeled_gflop": round(total_flops / 1e9, 3),
-        "modeled_gb": round(total_bytes / 1e9, 4),
-        "roofline_bound_s": round(bound_s, 9),
-        "bound": "flops" if compute_s >= memory_s else "hbm",
-        "steps_folded": int(steps),
-        "loop_computations": len(loop_comps),
-        "costed_ops": len(costs),
-        "heaviest": heaviest[:top],
-        "peaks": {"tflops": peak_tflops, "gbps": peak_gbps},
-    }
-    if achieved_s is not None and achieved_s > 0:
-        report["achieved_s"] = round(float(achieved_s), 6)
-        report["attainment_pct"] = round(
-            100.0 * bound_s / float(achieved_s), 2)
-    return report
-
-
-# ---------------------------------------------------------------------------
-# program capture (AOT-compile seam for benchmark.py / op_roofline.py)
+# program capture (the AOT-compile seam of the audit tools)
 # ---------------------------------------------------------------------------
 
 
@@ -474,7 +267,7 @@ def compiled_hlo_text(compiled: Any) -> str:
     """Post-optimization HLO of a jax Compiled object, across backends:
     CPU exposes ``as_text``; the TPU plugin's scheduled HLO comes from
     ``runtime_executable().get_hlo_text()`` (the exact text the chip
-    runs, which op_roofline joins against profiler op names)."""
+    runs)."""
     runtime = getattr(compiled, "runtime_executable", None)
     if callable(runtime):
         try:
@@ -506,7 +299,6 @@ class ProgramCapture:
             real_toplevel_jit = toplevel_jit
         self._real = real_toplevel_jit
         self.executables: list[Any] = []
-        self._mark = 0
 
     def capturing_toplevel_jit(self, fn, **kwargs):
         jitted = self._real(fn, **kwargs)
@@ -547,13 +339,6 @@ class ProgramCapture:
                     m.toplevel_jit = real
 
         return cm()
-
-    def mark(self) -> list[Any]:
-        """Executables captured since the previous mark (per-config
-        attribution in a multi-config bench run)."""
-        fresh = self.executables[self._mark:]
-        self._mark = len(self.executables)
-        return fresh
 
     def largest_hlo(self, executables: Iterable[Any] | None = None) -> str | None:
         """The longest HLO text among captured executables — in a

@@ -5,8 +5,7 @@ counters (PR 2), the stepper lane stats (PR 3), and the seed's bare
 ``/healthz`` dict. This module is the one vocabulary they all migrate
 onto: a :class:`Registry` of named metrics with label support, rendered
 in the Prometheus text exposition format at ``/metrics``
-(node/worker.py) and snapshot as JSON into BENCH runs (benchmark.py)
-and ``/healthz`` (which stays a read-through view for back-compat).
+(node/worker.py) and snapshot as JSON into ``/healthz`` (which stays a read-through view for back-compat).
 
 Design constraints, in order:
 
@@ -209,9 +208,8 @@ class Histogram(_Metric):
         Returns None for an empty series. Mass above the last finite
         bucket clamps to that bound (the estimate cannot exceed what
         the buckets resolve), so pick buckets that cover the tail you
-        care about. This is the primitive behind the BENCH
-        step-seconds percentiles and the measured hang-budget
-        suggestion (serving/guard.py, ISSUE 11)."""
+        care about. This is the primitive behind the measured
+        hang-budget suggestion (serving/guard.py, ISSUE 11)."""
         key = self._key(labels)
         with self._lock:
             # COPY under the lock: a concurrent observe() mutates the
@@ -362,8 +360,8 @@ class Registry:
         return "\n".join(lines) + "\n"
 
     def snapshot(self) -> dict[str, Any]:
-        """JSON-able view of every family — the BENCH ``metrics`` key
-        and the programmatic twin of ``render()``."""
+        """JSON-able view of every family — the programmatic twin of
+        ``render()``."""
         self.collect()
         return {m.name: m.snapshot() for m in self._sorted_metrics()}
 
@@ -620,8 +618,7 @@ def residency_bounces_counter(registry: Registry | None = None) -> Counter:
 def residency_load_seconds_histogram(
         registry: Registry | None = None) -> Histogram:
     """Wall time of one model load (convert/build + measure), by mode —
-    with ``swapped="1"`` when the load had to evict first. The swap
-    latency the ``model_churn`` bench config stamps into BENCH json."""
+    with ``swapped="1"`` when the load had to evict first."""
     return (registry or REGISTRY).histogram(
         "chiaswarm_residency_load_seconds",
         "model load wall time, by residency mode and whether the load "
@@ -908,8 +905,8 @@ def planner_worker_hours_counter(
         registry: Registry | None = None) -> Counter:
     """Accumulated worker-hours as the planner observes them (actual
     fleet size x tick interval). THE cost side of the autoscaler's
-    headline: BENCH compares this against every static roster in the
-    swept set."""
+    headline: the ISSUE-19 gate compares this against every static
+    roster in the swept set."""
     return (registry or REGISTRY).counter(
         "chiaswarm_planner_worker_hours_total",
         "worker-hours accumulated under the planner's watch")

@@ -2,9 +2,9 @@
 
 DeepCache feature reuse and few-step sampling trade compute for image
 fidelity, so they ship quality-GATED the way int8 weights shipped
-parity-gated (ISSUE 8): the bench and tests/test_fewstep.py compare the
+parity-gated (ISSUE 8): tests/test_fewstep.py compares the
 accelerated output against its full-compute reference with PSNR/SSIM
-and refuse the trick below threshold (PSNR >= 30 dB, SSIM >= 0.9).
+and refuses the trick below threshold (PSNR >= 30 dB, SSIM >= 0.9).
 
 Pure numpy on uint8/float host images — no jax, no scipy, no cv2, so
 the gate runs identically on any host. SSIM follows Wang et al. 2004
@@ -85,11 +85,11 @@ def quality_report(test: np.ndarray, reference: np.ndarray, *,
                    ssim_floor: float = 0.9) -> dict:
     """The step-collapse quality gate as one stampable dict: PSNR/SSIM
     of ``test`` against ``reference`` plus the pass verdicts at the
-    shipped floors (BENCH json stamps this; tests assert ``passed``)."""
+    shipped floors (tests assert ``passed``)."""
     p = psnr(test, reference)
     s = ssim(test, reference)
     return {
-        # bit-identical inputs: null, not inf — BENCH json must stay
+        # bit-identical inputs: null, not inf — the dict must stay
         # strict-JSON parseable (json.dumps prints inf as bare
         # 'Infinity', which jq/JSON.parse reject)
         "psnr_db": round(p, 2) if np.isfinite(p) else None,

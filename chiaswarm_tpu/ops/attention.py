@@ -68,8 +68,8 @@ def _ring_min_tokens() -> int:
 
 
 def _env_impl() -> str | None:
-    """CHIASWARM_ATTENTION: operator override of the ``auto`` pick (the
-    attainment-sweep knob — flip kinds without touching worker config).
+    """CHIASWARM_ATTENTION: operator override of the ``auto`` pick (a
+    sweep knob — flip kinds without touching worker config).
     Explicit ``impl=`` callers are never overridden."""
     import os
 
@@ -289,11 +289,11 @@ def attention(
     if impl == "flash":
         use_flash = True
     elif impl == "auto":
-        # Block-size sweep on v5e (SDXL 1024px, 30 steps, end-to-end):
-        # flash@256 blocks 6.98s < XLA fused 5.07s < flash@2048x1024
-        # blocks 3.98s per image. With the tuned blocks the Pallas kernel
-        # wins from 1024 tokens up; tiny KV (77-token text cross-attention)
-        # and small spatial grids stay on the einsum path.
+        # The Pallas kernel from 1024 tokens up; tiny KV (77-token text
+        # cross-attention) and small spatial grids stay on the einsum
+        # path. The boundary was swept on an installation that is gone
+        # and has not been swept on this chip (ROADMAP S3: head sizes
+        # under 128 at short KV may belong on the XLA path).
         use_flash = (
             jax.default_backend() == "tpu"
             and q.shape[1] >= 1024

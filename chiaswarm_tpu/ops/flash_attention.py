@@ -20,11 +20,11 @@ Head dims of SD UNets (40/80/160/64/128) are zero-padded up to the 128-lane
 tile; padded lanes contribute zero logits and zero values, so results are
 exact. Sequence lengths pad up to the block size with -inf-masked logits.
 
-Default blocks (2048 q x 1024 kv) come from an end-to-end sweep on v5e
-(SDXL 1024px, 30 steps): 256x256 ran 6.98 s/image, XLA's fused attention
-5.07 s, 2048x1024 3.98 s; 2048x2048 and 4096x1024 exceed the 16 MB VMEM
-scoped limit. Large q blocks amortize the running-softmax scratch traffic;
-the kernel clamps blocks to the (padded) sequence length for small inputs.
+Default blocks (2048 q x 1024 kv) come from an end-to-end sweep on an
+installation that is gone; on this chip they read 31.7% / 22.6% of the
+roofline at the stated head sizes (`flash_roofline.lat`, ledger, PR 29)
+and no other block size has been run. Large q blocks amortize the scratch
+traffic; the kernel clamps blocks to the (padded) sequence length.
 
 The same kernel runs in Pallas interpret mode on CPU, which is how the
 hermetic test suite validates it against the einsum reference
@@ -228,11 +228,11 @@ def flash_attention(
     # None = auto (divisibility-aware pick, unless an env sweep pins the
     # block); an explicit caller/env value is honored, clamped only to
     # the padded sequence length. (A per-shape measured-pair override
-    # was tried and REJECTED: tools/flash_sweep.py's isolated chain
-    # showed 1152x2304 beating 768x768 by ~21% at 2304 tokens, but the
-    # end-to-end portrait program measured ~equal-or-worse — in-program
-    # these ops already run at 97 TFLOP/s with XLA overlapping them,
-    # and the isolated ~40 TFLOP/s chain mispredicts that regime.)
+    # was tried and rejected on an installation that is gone: the
+    # kernel alone, tools/flash_sweep.py, preferred 1152x2304 at 2304
+    # tokens where the end-to-end program did not. The lesson stands,
+    # the numbers do not: judge a block size by the whole program, in
+    # a benchmark cell.)
     if block_q is None:
         block_q = (_clamp_block(l, _DEFAULT_BLOCK_Q) if _ENV_PINNED
                    else _pick_block(l, _DEFAULT_BLOCK_Q))

@@ -2,10 +2,9 @@
 
 `parallel/ring_attention.py` alternates phases — each hop runs the local
 partial softmax, THEN `lax.ppermute` rotates the KV shard — so the MXU
-idles during every rotation and the ICI idles during every compute. The
-BENCH r05 roofline puts the 2304-token flash levels at 49% attainment
-(9216 at 69%): attention is where the remaining chip time lives (ROADMAP
-item 2). This kernel closes the gap by issuing the NEXT hop's KV transfer
+idles during every rotation and the ICI idles during every compute.
+Neither ring has run on this chip (ROADMAP R1, D7): what the overlap is
+worth is not measured. This kernel issues the NEXT hop's KV transfer
 as an async remote DMA (`pltpu.make_async_remote_copy`) into a
 double-buffered VMEM slot while the blockwise flash inner loop — the
 online-softmax recurrence shared with `ops/flash_attention.py` via

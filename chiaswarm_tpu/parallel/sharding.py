@@ -21,10 +21,10 @@ needed — a 1x1-style channel split, not spatial): ``conv1`` is
 column-parallel on output channels (with ``time_emb_proj`` and ``norm2``
 sharded to match, group stats staying shard-local because tp divides the
 32 GroupNorm groups), ``conv2`` is row-parallel on input channels, and
-GSPMD emits one psum per resnet block on the residual. SD-class UNets are
-~65% conv FLOPs (BASELINE.md op profile), so leaving convs replicated made
-tp pay 44% over ideal (r3 dry run on the virtual CPU mesh; the record
-was deleted in PR 21); with the resnet pairs sharded the
+GSPMD emits one psum per resnet block on the residual. Most of an
+SD-class UNet's FLOPs are in its convs (the share on this chip is not
+measured: ROADMAP S7), so leaving them replicated leaves most of the
+work unsplit under tp; with the resnet pairs sharded the
 per-device FLOPs fraction drops to ~1/(dp*tp) + small residue (conv_in/
 out, shortcuts, up/downsamples — measured by dryrun_multichip).
 

@@ -949,10 +949,9 @@ class DiffusionPipeline:
         """Dispatch a request WITHOUT blocking on the device->host image
         transfer. JAX's async dispatch returns the uint8 result array as a
         future; ``PendingImages.wait()`` fetches it. Submitting job N+1
-        before waiting on job N overlaps N's ~0.2 s host transfer with
-        N+1's denoise compute. bench.py measures this steady-state number
-        directly; the serving loop gets the same overlap from depth-2
-        slots (core/chip_pool.py MeshSlot.depth + node/worker.py
+        before waiting on job N overlaps N's host transfer with N+1's
+        denoise compute; the serving loop gets the same overlap from
+        depth-2 slots (core/chip_pool.py MeshSlot.depth + node/worker.py
         _slot_worker), where two blocking jobs interleave across threads.
         No reference analog — torch blocks per pipeline call."""
         fam = self.c.family
@@ -1177,8 +1176,7 @@ class DiffusionPipeline:
                 args.append(jnp.asarray(tab))
             img = fn(*args)
         # step-collapse accounting (ISSUE 12): FULL UNet evals each image
-        # pays — the cost term BENCH's >=4x reduction gate reads — plus
-        # the live counter/histogram families
+        # pays, plus the live counter/histogram families
         full_evals = (steps - start_step) - len(schedule)
         _UNET_EVALS.inc(req.batch * full_evals, mode="full")
         if schedule:

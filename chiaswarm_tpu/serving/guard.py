@@ -207,7 +207,7 @@ def suggest_hang_budget(histogram: Any = None, *,
       compile time, only pathological-but-alive steps).
 
     Returns ``{"measured": False, "samples": n}`` until ``min_samples``
-    observations exist; /healthz, the loadgen report, and BENCH all
+    observations exist; /healthz and the loadgen report both
     stamp this payload, so a real TPU deployment reads its knobs off
     its own histogram.
     """

@@ -331,7 +331,7 @@ class ResidencyManager:
     @staticmethod
     def _default_persist_path() -> Path | None:
         try:
-            from chiaswarm_tpu.node.settings import settings_root
+            from chiaswarm_tpu.core.compile_cache import settings_root
 
             return settings_root() / "residency.json"
         except Exception:

@@ -368,8 +368,8 @@ class LaneWidthController:
       width ahead of the queue.
     - **shrink under trickle**: occupancy <= ``shrink_at`` for
       ``patience`` consecutive boundaries with nothing pending halves
-      the width — padding rows are batched UNet FLOPs burned, the
-      exact waste BENCH r05's 0.33 padding ratio measures.
+      the width — padding rows are batched UNet FLOPs burned
+      (``lane_fill_pct.lat`` in the benchmark).
     - **a share is only read where it is at least one row** (ISSUE
       27): ``shrink_at`` x width is half a row at width 2, so no
       occupancy a resident row can produce ever meets it, and
@@ -395,8 +395,7 @@ class LaneWidthController:
                  rate_window_s: float = 10.0) -> None:
         # defaults are the swarmload harness sweep winner (ISSUE 9:
         # node/loadgen.py::sweep_lane_gains, seed "swarmload" — grow
-        # earlier at 0.75 occupancy, hold width until 0.25): the table
-        # rides every BENCH json under configs.load_harness, and
+        # earlier at 0.75 occupancy, hold width until 0.25), and
         # tests/test_loadgen.py pins defaults == winner
         # (pre-sweep statics were grow_at=0.875, shrink_at=0.375)
         self.min_width = max(1, int(min_width))
@@ -1568,8 +1567,10 @@ class StepScheduler:
         if env:
             width_rows = int(env)
         else:
-            from chiaswarm_tpu.core.compile_cache import bucket_batch
-            from chiaswarm_tpu.node.executor import single_chip_rows
+            from chiaswarm_tpu.core.compile_cache import (
+                bucket_batch,
+                single_chip_rows,
+            )
 
             data_width = max(1, int(getattr(self.slot, "data_width", 1)))
             per_device = single_chip_rows({"height": height, "width": width})

@@ -277,7 +277,7 @@ def image_to_normal(image: Image.Image) -> Image.Image:
 
 # full ADE20K palette (the 150-class table + background row the reference
 # embeds at input_processor.py:118-272), shared with models/upernet.py
-from chiaswarm_tpu.workloads.ade_palette import (  # noqa: E402
+from chiaswarm_tpu.models.ade_palette import (  # noqa: E402
     ADE20K_PALETTE as _ADE_PALETTE,
 )
 

@@ -23,7 +23,8 @@ if "--xla_force_host_platform_device_count" not in flags:
 
 import jax  # noqa: E402
 
-jax.config.update("jax_default_matmul_precision", "float32")
+SUITE_MATMUL_PRECISION = "float32"
+jax.config.update("jax_default_matmul_precision", SUITE_MATMUL_PRECISION)
 
 # persistent XLA compile cache: the suite is compile-bound; warm reruns
 # skip most of that. Same placement rule as every entry point
@@ -40,6 +41,18 @@ enable_persistent_compilation_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
 
 import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _restore_matmul_precision():
+    """``Worker.run()`` pins bf16 matmuls for its process (node/worker.py),
+    and a test's worker shares this one: put the suite's precision back
+    after every test, so that what a later test of the same xdist worker
+    traces or computes does not depend on which file ran before it."""
+    yield
+    jax.config.update("jax_default_matmul_precision",
+                      SUITE_MATMUL_PRECISION)
+
 
 # ---- fast / slow tiers (VERDICT r3 weak #4) ---------------------------
 # Default `pytest -q` runs the fast tier; the ~10 compile-heaviest tests

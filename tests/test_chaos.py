@@ -42,18 +42,6 @@ def _tmp_root(tmp_path, monkeypatch):
     return tmp_path
 
 
-@pytest.fixture(autouse=True)
-def _restore_matmul_precision():
-    """Worker.startup() pins bf16 matmuls; restore the suite's precision
-    so chaos tests (early in collection order) don't skew later numeric
-    tests."""
-    import jax
-
-    before = jax.config.jax_default_matmul_precision
-    yield
-    jax.config.update("jax_default_matmul_precision", before)
-
-
 class StubSlot:
     """Executor-less slot: the ChaoticExecutor never touches the mesh.
     ``__call__`` mirrors the real slot contract (core/chip_pool.py) just

@@ -482,10 +482,10 @@ def test_coalesced_default_content_type_is_png(registry):
 @pytest.mark.slow
 def test_single_chip_slot_batches_small_jobs(registry):
     """A data_width=1 slot merges 512px-class jobs into one batched
-    program — one chip is not saturated by them at batch 1 (+20%
-    images/sec measured at batch 4 on the real chip, BASELINE.md r4).
-    1024px-class jobs stay one row per device (saturated at batch 1)."""
-    from chiaswarm_tpu.node.executor import single_chip_rows
+    program (four rows a device up to 512 x 512 px; 1024px-class jobs
+    stay one row per device). The rule is unverified on this chip:
+    core/compile_cache.py::single_chip_rows."""
+    from chiaswarm_tpu.core.compile_cache import single_chip_rows
 
     assert single_chip_rows({"height": 512, "width": 512}) == 4
     assert single_chip_rows({"height": 64, "width": 64}) == 4

@@ -57,15 +57,6 @@ def _tmp_root(tmp_path, monkeypatch):
     return tmp_path
 
 
-@pytest.fixture(autouse=True)
-def _restore_matmul_precision():
-    import jax
-
-    before = jax.config.jax_default_matmul_precision
-    yield
-    jax.config.update("jax_default_matmul_precision", before)
-
-
 class StubSlot:
     def __init__(self, depth: int = 2, data_width: int = 1,
                  name: str = "stub"):

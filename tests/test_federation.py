@@ -60,15 +60,6 @@ def _tmp_root(tmp_path, monkeypatch):
     return tmp_path
 
 
-@pytest.fixture(autouse=True)
-def _restore_matmul_precision():
-    import jax
-
-    before = jax.config.jax_default_matmul_precision
-    yield
-    jax.config.update("jax_default_matmul_precision", before)
-
-
 class StubSlot:
     """Executor-less slot (the test_chaos/test_durability stand-in)."""
 
@@ -420,6 +411,38 @@ def test_steal_grant_journaled_by_owner_replay_reconciles(tmp_path):
 
     fed, victim_id = asyncio.run(scenario())
     assert fed.uploaded_ids() == [victim_id]
+
+
+def test_no_steal_from_a_killed_shard(tmp_path):
+    """A killed shard's memory is garbage and its journal detached: an
+    empty poll on a live peer must not be granted the dead shard's
+    backlog (no journal would hold that grant, and the recovered shard
+    would file the job's upload as an orphan digest). Recovered, the
+    shard is a steal victim again and the grant is in ITS journal."""
+
+    async def scenario():
+        fed = FederatedHive(n_shards=2, journal_root=tmp_path / "hive",
+                            journal_fsync=False, lease_s=30.0,
+                            delay_s=0.0)
+        await fed.start()
+        try:
+            fed.submit(_job("fed-0"))  # shard 0 of 2 owns it
+            await fed.kill_shard(0)
+            assert fed.shards[1]._take_jobs("w1") == []
+            recovered = await fed.restart_shard(0)
+            [payload] = fed.shards[1]._take_jobs("w1")
+            assert payload["id"] == "fed-0"
+            assert payload[HIVE_SHARD_KEY] == 0
+            ack = recovered._record_result(
+                dict(_ok_result("fed-0", "w1", shard=0),
+                     span_digest={"attempt": payload["attempt"]}), "w1")
+            assert ack == {"status": "ok"}
+        finally:
+            await fed.stop()
+        return fed
+
+    fed = asyncio.run(scenario())
+    assert fed.verify_flights(["fed-0"]) == []
 
 
 # ---------------------------------------------------------------------------

@@ -60,15 +60,6 @@ def _tmp_root(tmp_path, monkeypatch):
     return tmp_path
 
 
-@pytest.fixture(autouse=True)
-def _restore_matmul_precision():
-    import jax
-
-    before = jax.config.jax_default_matmul_precision
-    yield
-    jax.config.update("jax_default_matmul_precision", before)
-
-
 def _job(job_id: str, chaos=None, model: str = "shared/tiny", **over):
     job = {"id": job_id, "model_name": model, "prompt": f"p {job_id}",
            "num_inference_steps": 2, "height": 64, "width": 64,

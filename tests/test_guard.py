@@ -34,6 +34,7 @@ from __future__ import annotations
 import asyncio
 import base64
 import io
+import threading
 import time
 
 import numpy as np
@@ -591,7 +592,6 @@ def test_fleet_gate_wedge_and_nan_settle_exactly_once(monkeypatch):
 
     from chiaswarm_tpu.core.chip_pool import ChipPool
     from chiaswarm_tpu.core.mesh import MeshSpec
-    from chiaswarm_tpu.node.loadgen import ContentionProbe
     from chiaswarm_tpu.node.minihive import MiniHive
     from chiaswarm_tpu.node.registry import ModelRegistry
     from chiaswarm_tpu.node.settings import Settings
@@ -605,8 +605,9 @@ def test_fleet_gate_wedge_and_nan_settle_exactly_once(monkeypatch):
     # compile under the tight watchdog
     monkeypatch.setenv("CHIASWARM_STEPPER_LANE_WIDTH", "2")
     # factor 25 over the ~0.1-0.2 s honest post-warm-up step keeps
-    # honest steps far under the budget, while the 15 s wedge (below)
-    # sails far over it even when GIL contention inflates the EWMA;
+    # honest steps far under the budget, while the wedge (below: twice
+    # the widest budget a scheduler holds when it is armed) sails
+    # over it however far GIL contention inflated the EWMA;
     # the ceiling stays at its (generous) default so any cold compile
     # — e.g. on a worker the warm-up poll race starved — never condemns
     monkeypatch.setenv(guard.ENV_HANG_FACTOR, "25")
@@ -649,15 +650,6 @@ def test_fleet_gate_wedge_and_nan_settle_exactly_once(monkeypatch):
                 pool=pool))
         tasks = [asyncio.create_task(w.run()) for w in workers]
         bodies = []
-        # contention probe (ISSUE 17 deflake, the PR-12 pattern): on a
-        # 1-core container the GIL-contended warm-up inflates each
-        # scheduler's honest-step EWMA, and the hang budget (EWMA x
-        # factor) inflates with it — a FIXED 15 s wedge can then land
-        # UNDER the budget and never condemn. Sampling host contention
-        # across the warm-up and scaling the wedge seconds by the
-        # measured factor keeps the wedge/budget margin the test was
-        # designed with; the settlement clauses below are untouched.
-        probe = ContentionProbe().start()
         try:
             # PHASE 1 (warm-up, chaos unarmed, generous cold budgets):
             # the same job SHAPES the gate jobs use (steps 4 lands in
@@ -672,12 +664,25 @@ def test_fleet_gate_wedge_and_nan_settle_exactly_once(monkeypatch):
                             strength=0.8))
             await hive.wait_for_results(3, timeout=600)
 
-            # PHASE 2: arm the wedge (15 s nominal, scaled by the
-            # measured contention factor; fired 5 post-arm steps in —
-            # its job has checkpoints by then) and the NaN poison
-            # (row 0, 2 post-arm steps in), then release the gate
-            # jobs: mixed workloads, two txt2img + one img2img
-            wedge_s = 15.0 * probe.stop()
+            # PHASE 2: arm the wedge (fired 5 post-arm steps in — its
+            # job has checkpoints by then) and the NaN poison (row 0, 2
+            # post-arm steps in), then release the gate jobs: mixed
+            # workloads, two txt2img + one img2img. The wedge has to
+            # outlast the budget the watchdog ARMS: factor x the step
+            # EWMA its scheduler holds, which three workers compiling
+            # under one GIL leave at 0.7-1.1 s on an idle host (budgets
+            # of 17-27 s) and higher on a busy one. A wedge under the
+            # budget never condemns — the old load-dependent failure
+            # of 15 s x a sleep-overshoot factor — so size it from what
+            # the watchdog will compare against. (A worker the warm-up
+            # starved holds no EWMA yet. The EWMA only falls over the
+            # five honest steps before the wedge fires, so twice the
+            # widest budget is room enough.)
+            ewmas = [slot._stepper.step_ewma() for worker in workers
+                     for slot in worker.pool
+                     if getattr(slot, "_stepper", None) is not None]
+            wedge_s = max(15.0, 2.0 * max(
+                guard.hang_budget_s(ewma) for ewma in ewmas if ewma > 0))
             monkeypatch.setenv(guard.ENV_CHAOS_WEDGE,
                                f"5:{wedge_s:.2f}")
             monkeypatch.setenv(guard.ENV_CHAOS_NAN, "2:0")
@@ -713,6 +718,13 @@ def test_fleet_gate_wedge_and_nan_settle_exactly_once(monkeypatch):
         return hive, workers, bodies
 
     hive, workers, bodies = asyncio.run(scenario())
+    # the condemned lane's driver is still asleep in its wedge; see it
+    # out, or the next file of this xdist worker meets a live
+    # ``stepper-lane-*`` thread (tests/test_chip_smoke.py counts them)
+    for thread in threading.enumerate():
+        if thread.name.startswith("stepper-lane-"):
+            thread.join(timeout=120.0)
+            assert not thread.is_alive(), thread.name
 
     # exactly-once settlement: completed / redispatched invalid_output
     uploaded = hive.uploaded_ids()

@@ -1,11 +1,8 @@
-"""Static HLO cost model (chiaswarm_tpu/obs/hlocost.py, extracted from
-tools/op_roofline.py in ISSUE 11): conv/dot/flash FLOPs and HBM byte
-estimates from scheduled-HLO text (operands printed as bare %names,
-shapes resolved through the definition map), while-body step folding,
-and the static whole-program roofline report BENCH stamps — all costed
-from canned fixtures, no TPU or jax.profiler needed."""
-
-import pytest
+"""Static HLO parsing (chiaswarm_tpu/obs/hlocost.py): conv/dot/flash
+FLOPs and HBM byte estimates from scheduled-HLO text (operands printed
+as bare %names, shapes resolved through the definition map) and
+``ProgramCapture`` — costed from canned fixtures, no TPU needed — and
+the compiled-side contract audits of ``analysis/hlocheck.py``."""
 
 from chiaswarm_tpu.obs import hlocost
 
@@ -37,37 +34,6 @@ ENTRY %main (a: bf16[2,64,64,320], w: bf16[3,3,320,640]) -> bf16[2,64,64,640] {
   ROOT %out = bf16[2,64,64,640]{3,2,1,0:T(8,128)(2,1)} fusion(%conv_fusion.1), kind=kLoop, calls=%fused_computation.7
 }
 """
-
-# a scheduled module with a while loop: the denoise-scan shape — the
-# body's fusion must fold by the step count in the static report, the
-# entry-scope fusion must not
-_HLO_WHILE = """\
-HloModule jit_loop, is_scheduled=true
-
-%body_dot (p0: f32[128,128], p1: f32[128,128]) -> f32[128,128] {
-  %p0 = f32[128,128]{1,0} parameter(0)
-  %p1 = f32[128,128]{1,0} parameter(1)
-  ROOT %dot.1 = f32[128,128]{1,0} dot(%p0, %p1), lhs_batch_dims={}, lhs_contracting_dims={1}, rhs_contracting_dims={0}
-}
-
-%while_body (arg: f32[128,128]) -> f32[128,128] {
-  %arg = f32[128,128]{1,0} parameter(0)
-  ROOT %step_fusion = f32[128,128]{1,0} fusion(%arg, %arg), kind=kOutput, calls=%body_dot
-}
-
-%while_cond (arg: f32[128,128]) -> pred[] {
-  %arg = f32[128,128]{1,0} parameter(0)
-  ROOT %lt = pred[] parameter(1)
-}
-
-ENTRY %main (x: f32[128,128], y: f32[128,128]) -> f32[128,128] {
-  %x = f32[128,128]{1,0} parameter(0)
-  %y = f32[128,128]{1,0} parameter(1)
-  %prologue_fusion = f32[128,128]{1,0} fusion(%x, %y), kind=kOutput, calls=%body_dot
-  ROOT %loop = f32[128,128]{1,0} while(%prologue_fusion), condition=%while_cond, body=%while_body
-}
-"""
-
 
 def test_conv_fusion_flops_and_bytes():
     costs = hlocost.parse_hlo_text(_HLO)
@@ -109,79 +75,6 @@ def test_operand_scan_stops_at_list_close():
     assert shapes == [("bf16", [4, 4]), ("f32", [2, 2])]
 
 
-def test_while_body_computations_detected():
-    assert hlocost.while_body_computations(_HLO_WHILE) == {
-        "while_body", "while_cond"}
-    assert hlocost.while_body_computations(_HLO) == set()
-
-
-def test_static_report_folds_while_body_by_steps():
-    """The denoise-scan shape: the body fusion counts ``steps`` times,
-    the prologue once — so a 30-step program's modeled work is
-    30x body + 1x prologue, not 2 fusions."""
-    dot_flops = 2 * 128 * 128 * 128
-    report = hlocost.static_program_report(
-        _HLO_WHILE, steps=30, peak_tflops=100.0, peak_gbps=800.0)
-    assert report["steps_folded"] == 30
-    expect_flops = dot_flops * (30 + 1)
-    # the report rounds to 3 decimals; compare at that resolution
-    assert report["modeled_gflop"] == pytest.approx(
-        expect_flops / 1e9, abs=5e-4)
-    by_name = {r["name"]: r for r in report["heaviest"]}
-    assert by_name["step_fusion"]["count"] == 30
-    assert by_name["prologue_fusion"]["count"] == 1
-    assert report["roofline_bound_s"] > 0
-    assert report["bound"] in ("flops", "hbm")
-
-    # achieved time turns the bound into attainment
-    measured = hlocost.static_program_report(
-        _HLO_WHILE, steps=30, peak_tflops=100.0, peak_gbps=800.0,
-        achieved_s=report["roofline_bound_s"] * 2)
-    assert measured["attainment_pct"] == pytest.approx(50.0, abs=0.1)
-
-
-def test_attainment_rows_join_and_container_exclusion():
-    """The measured join: profiler durations x static costs; while/call
-    container events are excluded so time is never double-booked."""
-    costs = hlocost.parse_hlo_text(_HLO)
-    times = {
-        "conv_fusion.1": {"total_ps": 2_000_000_000, "count": 2},  # 2 ms
-        "while.1": {"total_ps": 50_000_000_000, "count": 1},  # container
-        "unknown_op": {"total_ps": 1_000_000_000, "count": 1},  # 1 ms
-    }
-    rows = hlocost.attainment_rows(times, costs, peak_tflops=100.0,
-                                   peak_gbps=800.0)
-    names = [r["name"] for r in rows]
-    assert "while.1" not in names
-    conv = next(r for r in rows if r["name"] == "conv_fusion.1")
-    assert conv["count"] == 2 and conv["kind"] == "conv"
-    assert conv["gflop"] == pytest.approx(
-        2 * 2 * (2 * 64 * 64 * 640) * 9 * 320 / 1e9)
-    # share excludes the container's span
-    assert sum(r["share_pct"] for r in rows) == pytest.approx(100.0)
-
-    summary = hlocost.conv_attainment_summary(rows)
-    assert summary["conv_ms"] == pytest.approx(2.0)
-    assert summary["miscosted_fusions"] >= 0
-
-
-def test_op_roofline_cli_is_a_thin_shim():
-    """tools/op_roofline.py now imports the library instead of owning a
-    fork of the parser — the CLI module must expose the SAME objects."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "op_roofline",
-        os.path.join(os.path.dirname(__file__), "..", "tools",
-                     "op_roofline.py"))
-    roofline = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(roofline)
-    assert roofline.parse_hlo_text is hlocost.parse_hlo_text
-    assert roofline.collect_op_times is hlocost.collect_op_times
-    assert roofline.attainment_rows is hlocost.attainment_rows
-
-
 def test_program_capture_keys_by_signature():
     """ProgramCapture recompiles per input-shape signature (a lattice
     program reused across widths must not call a stale executable)."""
@@ -197,7 +90,6 @@ def test_program_capture_keys_by_signature():
     assert a.shape == b.shape == (2, 2) and c.shape == (4, 4)
     hlo = cap.largest_hlo()
     assert hlo and "HloModule" in hlo
-    assert len(cap.mark()) == 2 and cap.mark() == []
 
 
 # ------------------- swarmproof compiled-side contracts (ISSUE 15):

@@ -28,16 +28,6 @@ def _tmp_root(tmp_path, monkeypatch):
     return tmp_path
 
 
-@pytest.fixture(autouse=True)
-def _restore_matmul_precision():
-    """Worker.startup() pins bf16 matmuls; restore the suite default."""
-    import jax
-
-    before = jax.config.jax_default_matmul_precision
-    yield
-    jax.config.update("jax_default_matmul_precision", before)
-
-
 # ---------------------------------------------------------------------------
 # registry semantics
 # ---------------------------------------------------------------------------
@@ -1049,8 +1039,8 @@ def test_numerics_ring_records_are_json_and_dumpable(tmp_path):
 
 
 def test_histogram_percentile_interpolation():
-    """Bucket-interpolated quantiles: the primitive behind the BENCH
-    step-seconds percentiles and the measured hang-budget suggestion."""
+    """Bucket-interpolated quantiles: the primitive behind the measured
+    hang-budget suggestion."""
     from chiaswarm_tpu.obs.metrics import Histogram
 
     hist = Histogram("h", buckets=(1.0, 2.0, 4.0, 8.0))

@@ -69,7 +69,7 @@ def test_upernet_conversion_matches_torch():
 
 
 def test_detector_runs_and_colors_with_ade_palette():
-    from chiaswarm_tpu.workloads.ade_palette import ADE20K_PALETTE
+    from chiaswarm_tpu.models.ade_palette import ADE20K_PALETTE
 
     det = UperNetDetector.random(seed=0)
     img = (np.random.RandomState(0).rand(50, 70, 3) * 255).astype(np.uint8)
@@ -81,7 +81,7 @@ def test_detector_runs_and_colors_with_ade_palette():
 
 
 def test_ade_palette_matches_reference_table():
-    from chiaswarm_tpu.workloads.ade_palette import ADE20K_PALETTE
+    from chiaswarm_tpu.models.ade_palette import ADE20K_PALETTE
 
     assert ADE20K_PALETTE.shape == (151, 3)
     assert tuple(ADE20K_PALETTE[0]) == (0, 0, 0)

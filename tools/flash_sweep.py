@@ -1,14 +1,14 @@
 """Micro-benchmark the Pallas flash-attention kernel across block sizes.
 
 Times the kernel alone (no UNet) at a given (B, L, H, D) self-attention
-shape on the real chip, for a list of (block_q, block_kv) candidates.
-Used to tune `_pick_block` (ops/flash_attention.py) for non-power-of-two
+shape on the chip, for a list of (block_q, block_kv) candidates. For
+tuning `_pick_block` (ops/flash_attention.py) at non-power-of-two
 serving levels — e.g. the SVD portrait's 2304- and 9216-token spatial
-levels, where the roofline showed 49% / 69% attainment with the
-auto-picked blocks (tools/roofline_img2vid_r5_shortcut.txt).
+levels. A block size that wins here is a candidate, not a result: judge
+it by the whole program (ops/flash_attention.py, `flash_attention`).
 
     python tools/flash_sweep.py --batch 28 --seq 2304 --heads 10 \
-        --blocks 768x768,1152x1152,1152x2304,2304x1024
+        --blocks 768x768,1152x1152,1152x2304,2304x1152
 
 Prints one line per candidate: median ms over --iters and achieved
 TFLOP/s (4*B*H*L^2*D flops).

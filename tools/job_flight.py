@@ -9,7 +9,7 @@ with each attempt's worker span digest aligned onto the hive clock at
 its grant anchor (the residual against the settle anchor prints as
 ``clock_skew_s``). The heavy lifting lives in
 ``chiaswarm_tpu/obs/flight.py`` (stdlib-only; this tool runs without
-jax); this is the thin CLI, like tools/op_roofline.py.
+jax); this is the thin CLI.
 
 Formats:
 

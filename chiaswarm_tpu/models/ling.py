@@ -291,15 +291,30 @@ def kda_recurrent_step(q, k, v, g, beta, state):
     return jnp.einsum("bhk,bhkv->bhv", q, state, precision=HIGHEST), state
 
 
+def kda_block(chunk: int) -> int:
+    """Tokens a side of the sub-blocks ``kda_chunked`` cuts a chunk's two
+    matrices into: 16, or the whole chunk where 16 does not divide it."""
+    return chunk if chunk % 16 else 16
+
+
 def kda_chunked(q, k, v, g, beta, state, chunk: int):
     """The same recurrence over T tokens, ``chunk`` at a time (T a
     multiple of it). Inside a chunk, with G the running sum of g:
     ``(I + Diag(b) A) U = Diag(b) (V - (K*e^G) S0)`` with
     ``A_tj = sum_c k_tc k_jc e^(G_tc - G_jc)`` for j < t, then
     ``O = (Q*e^G) S0 + B U`` with B the same sum over q_t k_j, j <= t,
-    and ``S = e^(G_last) S0 + (K*e^(G_last - G))^T U``."""
+    and ``S = e^(G_last) S0 + (K*e^(G_last - G))^T U``.
+
+    A and B are built in row blocks of ``kda_block(chunk)`` tokens. On a
+    diagonal sub-block the decays are pairwise, ``e^(G_t - G_j)``. Left
+    of it, with r the block's first row, they are a product:
+    ``[k_t e^(G_t - G_r)] . [k_j e^(G_r - G_j)]``. G never rises, so for
+    j < r <= t both exponents are <= 0: nothing overflows, and a factor
+    that underflows stands for a weight under e^-87."""
     b_, t, h, dk = q.shape
     n = t // chunk
+    sub = kda_block(chunk)
+    nb = chunk // sub
 
     def split(z):  # (B, T, H, ...) -> (N, B, H, C, ...)
         z = z.reshape(b_, n, chunk, *z.shape[2:])
@@ -307,20 +322,45 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int):
 
     qs, ks, vs, gs = (split(z) for z in (q, k, v, g))
     bs = split(beta)
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
     strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    lower_sub = jnp.tril(jnp.ones((sub, sub), bool))
+    # keys before each row block's first row
+    earlier = jnp.arange(chunk)[None, :] < sub * jnp.arange(nb)[:, None]
+
+    def blocks(z):  # (B, H, C, D) -> (B, H, C/sub, sub, D)
+        return z.reshape(b_, h, nb, sub, dk)
+
+    def in_place(diag):  # (B, H, C/sub, sub, sub) -> (B, H, C/sub, sub, C)
+        return jnp.stack([
+            jnp.pad(diag[:, :, i], ((0, 0),) * 3
+                    + ((i * sub, chunk - (i + 1) * sub),))
+            for i in range(nb)], axis=2)
 
     def body(state, xs):
         qc, kc, vc, gc, bc = xs                    # (B, H, C, D) / (B, H, C)
         big_g = jnp.cumsum(gc, axis=2)
-        # pairwise decays, masked BEFORE the exponential: for j > t the
-        # difference is positive and can overflow
-        diff = big_g[:, :, :, None, :] - big_g[:, :, None, :, :]
-        decay = jnp.exp(jnp.where(lower[:, :, None], diff, -jnp.inf))
-        a_mat = jnp.sum(kc[:, :, :, None, :] * kc[:, :, None, :, :] * decay,
-                        axis=-1)
-        b_mat = jnp.sum(qc[:, :, :, None, :] * kc[:, :, None, :, :] * decay,
-                        axis=-1)
+        g_blk, q_blk, k_blk = blocks(big_g), blocks(qc), blocks(kc)
+        # diagonal sub-blocks: pairwise decays, masked BEFORE the
+        # exponential: for j > t the difference is positive and can
+        # overflow
+        diff = g_blk[:, :, :, :, None, :] - g_blk[:, :, :, None, :, :]
+        decay = jnp.exp(jnp.where(lower_sub[:, :, None], diff, -jnp.inf))
+        pair = k_blk[:, :, :, None, :, :] * decay
+        a_mat = in_place(jnp.sum(k_blk[:, :, :, :, None, :] * pair, axis=-1))
+        b_mat = in_place(jnp.sum(q_blk[:, :, :, :, None, :] * pair, axis=-1))
+        if nb > 1:
+            # left of them: rows (A's keys and B's queries in one product)
+            # and earlier keys rescaled to the row block's first row, the
+            # keys masked BEFORE the exponential too
+            first = g_blk[:, :, :, :1, :]
+            rows = jnp.stack([k_blk, q_blk], axis=2) \
+                * jnp.exp(g_blk - first)[:, :, None]
+            right = kc[:, :, None] * jnp.exp(jnp.where(
+                earlier[:, :, None], first - big_g[:, :, None], -jnp.inf))
+            left = jnp.einsum("bhxitd,bhijd->bhxitj", rows, right,
+                              precision=HIGHEST)
+            a_mat, b_mat = a_mat + left[:, :, 0], b_mat + left[:, :, 1]
+        a_mat, b_mat = (m.reshape(b_, h, chunk, chunk) for m in (a_mat, b_mat))
         e_g = jnp.exp(big_g)
         rhs = bc[..., None] * (vc - jnp.einsum(
             "bhck,bhkv->bhcv", kc * e_g, state, precision=HIGHEST))
@@ -340,6 +380,19 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int):
     state, o = jax.lax.scan(body, state, (qs, ks, vs, gs, bs))
     o = jnp.swapaxes(jnp.moveaxis(o, 0, 1), 2, 3)      # (B, N, C, H, Dv)
     return o.reshape(b_, t, h, -1), state
+
+
+def kda_blocks(cfg: LingConfig, prompt_tokens: int, chunk: int
+               ) -> tuple[int, int]:
+    """(sub-blocks of the in-chunk matrices ``kda_chunked`` builds from
+    pairwise decays, sub-blocks it builds as products), summed over the
+    KDA layers, a prompt's prefill chunks and their sub-chunks: host
+    integers, for the counter."""
+    sub_chunk = min(cfg.kda_chunk, chunk)
+    nb = sub_chunk // kda_block(sub_chunk)
+    n = len(cfg.kda_layers) * -(-prompt_tokens // chunk) \
+        * (chunk // sub_chunk)
+    return n * nb, n * nb * (nb - 1) // 2
 
 
 def _kda_out(p, cfg: LingConfig, x, o):

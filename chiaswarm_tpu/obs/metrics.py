@@ -957,6 +957,16 @@ TEXT_PREFILL_KEY_BLOCKS = REGISTRY.counter(
     "kernel's bound admits them or they lie past the written cache",
     labelnames=("read",))
 
+#: sub-blocks of the delta-rule prefill's in-chunk matrices, by how
+#: ``kda_chunked`` builds them: "pairwise" decays on the diagonal,
+#: "product" of rescaled factors left of it; per KDA layer, prefill chunk
+#: and sub-chunk, from what the host knows of a job (tokens, chunk sizes)
+TEXT_KDA_BLOCKS = REGISTRY.counter(
+    "chiaswarm_text_kda_blocks_total",
+    "sub-blocks of the delta-rule prefill's in-chunk matrices, by "
+    "whether they are built from pairwise decays or as a product",
+    labelnames=("form",))
+
 #: bytes of the two kinds of cache the last decode held
 TEXT_CACHE_BYTES = REGISTRY.gauge(
     "chiaswarm_text_cache_bytes",

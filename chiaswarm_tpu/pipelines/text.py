@@ -252,6 +252,10 @@ class TextPipeline:
             cfg, prompt_tokens, self.prefill_chunk, self.max_context)
         metrics.TEXT_PREFILL_KEY_BLOCKS.inc(read, read="yes")
         metrics.TEXT_PREFILL_KEY_BLOCKS.inc(total - read, read="no")
+        pairwise, product = ling.kda_blocks(cfg, prompt_tokens,
+                                            self.prefill_chunk)
+        metrics.TEXT_KDA_BLOCKS.inc(pairwise, form="pairwise")
+        metrics.TEXT_KDA_BLOCKS.inc(product, form="product")
         metrics.MOE_LAYER_STEPS.inc((new - 1) * sum(
             cfg.is_moe(i) for i in range(cfg.num_hidden_layers)))
         for kind, size in ling.cache_bytes(

@@ -43,9 +43,9 @@ def sizes():
     return ref.sizes_of(CFG)
 
 
-def kda_operands(seed, t, strong_decay=False):
+def kda_operands(seed, t, strong_decay=False, h=None, d=None):
     rng = np.random.RandomState(seed)
-    h, d = CFG.num_attention_heads, CFG.head_dim
+    h, d = h or CFG.num_attention_heads, d or CFG.head_dim
     q, k, v = (jnp.asarray(rng.randn(1, t, h, d), jnp.float32)
                for _ in range(3))
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
@@ -56,29 +56,92 @@ def kda_operands(seed, t, strong_decay=False):
     return q, k, v, g, b, state
 
 
+@pytest.mark.parametrize("chunk, t", [(4, 16), (32, 128), (64, 128)],
+                         ids=["chunk4", "chunk32", "chunk64"])
 @pytest.mark.parametrize("strong_decay", [False, True])
-def test_chunked_kda_is_the_recurrence(strong_decay):
-    """UT-form chunks (4 tokens each) against the token-by-token
-    recurrence of the reference AND of the program's own decode step;
-    with every gate near e^-5 a chunk's running decay reaches e^-20,
-    which a ratio of exponentials would lose and the pairwise form
-    keeps."""
-    q, k, v, g, b, state = kda_operands(5, 16, strong_decay)
-    o, s = ling.kda_chunked(q, k, v, g, b, state, chunk=4)
+def test_chunked_kda_is_the_recurrence(strong_decay, chunk, t):
+    """UT-form chunks against the token-by-token recurrence of the
+    reference AND of the program's own decode step. Chunks of 4 are one
+    pairwise sub-block each; chunks of 32 and 64 are 2 and 4 row blocks
+    of 16, whose sub-blocks left of the diagonal are products of factors
+    rescaled to the row block's first row. With every gate drawn down to
+    e^-5 a 64-token chunk's running decay reaches e^-320, which a ratio
+    against the chunk's first or last row would lose; a ratio against the
+    row block's first row is safe, because for a key before that row both
+    exponents are <= 0: a factor can underflow (its weight is then under
+    e^-87) and none can overflow. Every output is finite."""
+    q, k, v, g, b, state = kda_operands(5, t, strong_decay, h=2, d=8)
+    o, s = ling.kda_chunked(q, k, v, g, b, state, chunk=chunk)
+    assert np.isfinite(np.asarray(o)).all()
+    assert np.isfinite(np.asarray(s)).all()
     with jax.default_matmul_precision("highest"):
         want_o, want_s = ref.kda_recurrence(q[0], k[0], v[0], g[0], b[0],
                                             state[0])
     assert np.abs(np.asarray(o[0]) - want_o).max() < LAYER_TOL
     assert np.abs(np.asarray(s[0]) - want_s).max() < LAYER_TOL
+    step = jax.jit(ling.kda_recurrent_step)
     step_s, outs = state, []
-    for t in range(16):
-        out, step_s = ling.kda_recurrent_step(q[:, t], k[:, t], v[:, t],
-                                              g[:, t], b[:, t], step_s)
+    for i in range(t):
+        out, step_s = step(q[:, i], k[:, i], v[:, i], g[:, i], b[:, i],
+                           step_s)
         outs.append(out)
     assert np.abs(np.asarray(jnp.stack(outs, 1)) - want_o).max() < LAYER_TOL
     # bfloat16 operands miss the limit
-    o16, _ = ling.kda_chunked(*bf16((q, k, v)), g, b, state, chunk=4)
+    o16, _ = ling.kda_chunked(*bf16((q, k, v)), g, b, state, chunk=chunk)
     assert np.abs(np.asarray(o16[0]) - want_o).max() > 10 * LAYER_TOL
+
+
+def _float32_sizes(jaxpr):
+    """Element counts of every float32 value a jaxpr computes, its
+    sub-jaxprs (the scan's body) included."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            if getattr(var.aval, "dtype", None) == jnp.float32:
+                yield var.aval.size
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _float32_sizes(sub)
+
+
+@pytest.mark.parametrize("chunk, whole", [(64, False), (4, True)],
+                         ids=["chunk64-blocks-of-16", "chunk4-one-block"])
+def test_chunked_kda_holds_no_chunk_by_chunk_by_channel_tensor(chunk, whole):
+    """At chunk 64 the largest float32 intermediate is the diagonal
+    sub-blocks' (C/16, 16, 16, D) a head, a quarter of the (C, C, D)
+    that pairwise decays over the whole chunk took; at chunk 4 the one
+    sub-block IS the chunk, today's mathematics."""
+    h, d = 2, 8
+    q, k, v, g, b, state = kda_operands(5, 2 * chunk, h=h, d=d)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: ling.kda_chunked(*a, chunk=chunk))(q, k, v, g, b, state)
+    largest = max(_float32_sizes(jaxpr.jaxpr))
+    assert (largest >= h * chunk * chunk * d) == whole
+    if not whole:
+        assert largest == h * chunk * ling.kda_block(chunk) * d
+
+
+@pytest.mark.parametrize("chunk, block", [(4, 4), (16, 16), (24, 24),
+                                          (32, 16), (64, 16)])
+def test_kda_block_is_16_where_16_divides_the_chunk(chunk, block):
+    assert ling.kda_block(chunk) == block
+
+
+@pytest.mark.parametrize("cfg, prompt, chunk, pairwise, product", [
+    (CFG, 1, 8, 7 * 2, 0), (CFG, 20, 8, 7 * 3 * 2, 0),
+    (dataclasses.replace(CFG, kda_chunk=64), 1, 2048, 7 * 32 * 4,
+     7 * 32 * 6),
+    (dataclasses.replace(CFG, kda_chunk=64), 2048, 2048, 7 * 32 * 4,
+     7 * 32 * 6),
+    (dataclasses.replace(CFG, kda_chunk=64), 16384, 2048, 7168, 10752),
+    (dataclasses.replace(CFG, kda_chunk=64), 100, 32, 7 * 4 * 2, 7 * 4)],
+    ids=["chunk4-one-token", "chunk4-three-chunks", "one-token",
+         "2048-tokens", "16384-tokens-the-cell", "a-32-token-chunk"])
+def test_kda_blocks_counts_sub_blocks_by_form(cfg, prompt, chunk, pairwise,
+                                              product):
+    """Seven KDA layers; a padded prefill chunk counts like a whole one,
+    for it computes as much. Sub-chunks of 4 are one pairwise block each;
+    of 64, 4 diagonal blocks and 6 left of them; of 32 (the prefill
+    chunk under ``kda_chunk``), 2 and 1."""
+    assert ling.kda_blocks(cfg, prompt, chunk) == (pairwise, product)
 
 
 def prefill(params, cfg, ids, chunk, capacity=64):

@@ -4,6 +4,7 @@
 
 import asyncio
 import base64
+import dataclasses
 import json
 
 import aiohttp
@@ -78,12 +79,19 @@ def test_token_logprobs_are_the_references(pipe):
         assert np.abs(want - np.asarray(seq["token_logprobs"])).max() < 1e-4
 
 
-def key_blocks_counted():
+def counted(family, *labels):
     from chiaswarm_tpu.obs.metrics import REGISTRY
 
-    values = REGISTRY.snapshot()[
-        "chiaswarm_text_prefill_key_blocks_total"]["values"]
-    return np.array([values.get("yes", 0), values.get("no", 0)])
+    values = REGISTRY.snapshot()[family]["values"]
+    return np.array([values.get(label, 0) for label in labels])
+
+
+def key_blocks_counted():
+    return counted("chiaswarm_text_prefill_key_blocks_total", "yes", "no")
+
+
+def kda_blocks_counted():
+    return counted("chiaswarm_text_kda_blocks_total", "pairwise", "product")
 
 
 def test_a_three_chunk_prompt_reads_6_of_24_key_blocks():
@@ -110,6 +118,29 @@ def test_key_blocks_are_counted_per_chunk_of_the_prompt(pipe, tokens, read,
     before = key_blocks_counted()
     pipe._count(tokens, 1, 16, zero, zero)
     assert list(key_blocks_counted() - before) == [read, unread]
+
+
+@pytest.mark.parametrize("tokens, kda_chunk, chunk, pairwise, product", [
+    (5, 4, 8, 7 * 2, 0), (32, 4, 8, 7 * 4 * 2, 0),
+    (16384, 64, 2048, 7168, 10752)],
+    ids=["chunk4-part-of-a-chunk", "chunk4-full", "16384-tokens-the-cell"])
+def test_kda_blocks_are_counted_by_form(pipe, tokens, kda_chunk, chunk,
+                                        pairwise, product):
+    """The delta-rule prefill's sub-blocks by how they are built, from
+    host integers: at the tiny size (sub-chunks of 4) every block is
+    pairwise; at the cell's sizes (sub-chunks of 64 in prefill chunks of
+    2,048) a job of 16,384 tokens adds 7,168 and 10,752 (the other prompt
+    lengths: ``test_ling.py``). No program runs: the count needs the
+    configuration and the chunk alone."""
+    components = dataclasses.replace(
+        pipe.c, config=dataclasses.replace(pipe.c.config,
+                                           kda_chunk=kda_chunk))
+    counted = TextPipeline(components, prefill_chunk=chunk,
+                           max_context=8 * chunk)
+    zero = {k: 0 for k in ling.empty_stats()}
+    before = kda_blocks_counted()
+    counted._count(tokens, 1, 16, zero, zero)
+    assert list(kda_blocks_counted() - before) == [pairwise, product]
 
 
 def test_without_logprobs_the_artifact_has_text_alone(pipe):
@@ -201,6 +232,7 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
             "chiaswarm_moe_experts_hit_total",
             "chiaswarm_moe_layer_steps_total",
             "chiaswarm_text_prefill_key_blocks_total",
+            "chiaswarm_text_kda_blocks_total",
             "chiaswarm_text_cache_bytes")}
 
     async def scenario():
@@ -265,6 +297,12 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
     for family in after:
         assert f"# TYPE {family} " in served
     assert 'chiaswarm_text_prefill_key_blocks_total{read="yes"}' in served
+    # 19 tokens in chunks of 8, sub-chunks of 4: 7 layers x 3 x 2 blocks,
+    # each the whole sub-chunk, so none is a product
+    kda = "chiaswarm_text_kda_blocks_total"
+    assert (moved(kda, "pairwise"), moved(kda, "product")) == (42, 0)
+    assert 'chiaswarm_text_kda_blocks_total{form="pairwise"} ' in served
+    assert 'chiaswarm_text_kda_blocks_total{form="product"} ' in served
     assert after["chiaswarm_text_cache_bytes"]["recurrent"] > 0
     assert after["chiaswarm_text_cache_bytes"]["latent"] \
         == (32 + 2 * 16) * ling.LING_TINY.latent_width * 4

@@ -14,17 +14,14 @@ Three layer kinds the UNet families do not have:
   inside a chunk are applied pairwise (``exp(G_t - G_j)``, never a ratio
   of two exponentials), so any gate in (e^-5, 1) is exact.
 - **MLA** — latent attention with a compressed cache (512 latent + 64
-  rotary values a token) and two compute paths: prefill up-projects keys
-  and values and goes through ``ops.attention`` (causal), decode folds
-  the key up-projection into the query and the value up-projection
-  after the softmax (the absorbed form), against the latents directly.
-- **Experts** — the layer is told which experts it holds
-  (``experts_held``), routes over all of them, and computes its own
-  experts' part for the tokens routed to them (plus the shared expert);
-  what the absent experts would add is left out. Tokens are grouped by
-  expert into blocks and a loop with a dynamic trip count walks the
-  blocks in use, so a step reads the weights of the experts that were
-  hit and no others, and no token is ever dropped.
+  rotary values a token): the core is ``models/text_layers.py``'s
+  (up-projected causal prefill, absorbed decode), shared with
+  models/deepseek.py. This stack's own: a full-rank query, plain rotary
+  frequencies, ``(nope + rope)^-0.5`` as the softmax scale and one
+  sigmoid gate a head before W_o.
+- **Experts** — the held-experts layer of ``models/text_layers.py``
+  under this stack's router: sigmoid scores with a bias, groups ranked
+  by the sum of their top two, weights normalised over the chosen.
 
 Functional style (a dict pytree of arrays, plain functions): the expert
 weights are stacked (experts, in, out) and sliced by a traced index,
@@ -37,16 +34,20 @@ pass on a TPU otherwise).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import Any, ClassVar
 
 import jax
 import jax.numpy as jnp
 
-from chiaswarm_tpu.ops.attention import attention
-from chiaswarm_tpu.ops.causal_flash_attention import key_block
-
-HIGHEST = jax.lax.Precision.HIGHEST
-NEG_INF = -1e30
+from chiaswarm_tpu.models import text_layers
+from chiaswarm_tpu.models.text_layers import (  # noqa: F401
+    HIGHEST,
+    empty_stats,
+    param_bytes,
+    rms_norm,
+    swiglu,
+)
+from chiaswarm_tpu.models.text_layers import proj as _proj
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,15 +82,14 @@ class LingConfig:
     dtype: str = "bfloat16"
     kda_chunk: int = 64                 # tokens a UT-form chunk holds
 
+    #: the module that serves this configuration (models/text_stacks.py)
+    stack: ClassVar[str] = "ling"
+
     def is_mla(self, layer: int) -> bool:
         return (layer + 1) % self.layer_group_size == 0
 
     def is_moe(self, layer: int) -> bool:
         return layer >= self.first_k_dense_replace
-
-    @property
-    def n_held(self) -> int:
-        return self.experts_held[1] - self.experts_held[0]
 
     @property
     def kda_layers(self) -> list[int]:
@@ -114,6 +114,7 @@ LING_TINY = LingConfig(
     intermediate_size=96, moe_intermediate_size=32, num_experts=16,
     experts_held=(0, 4), num_experts_per_tok=4, n_group=4, topk_group=2,
     dtype="float32", kda_chunk=4)
+TINY = LING_TINY
 
 
 # ---- checkpoint layout ---------------------------------------------------
@@ -160,7 +161,7 @@ def param_shapes(cfg: LingConfig) -> dict[str, Any]:
             mlp = {"router": w(d, cfg.num_experts, dtype=f32),
                    "router_bias": w(cfg.num_experts, dtype=f32),
                    "experts": swiglu(cfg.moe_intermediate_size,
-                                     (cfg.n_held,)),
+                                     (text_layers.n_held(cfg),)),
                    "shared": swiglu(cfg.moe_intermediate_size)}
         else:
             mlp = swiglu(cfg.intermediate_size)
@@ -195,43 +196,6 @@ def random_params(cfg: LingConfig, seed: int = 0) -> dict[str, Any]:
         return jnp.asarray(value, spec.dtype)
 
     return jax.tree_util.tree_map_with_path(fill, param_shapes(cfg))
-
-
-def param_bytes(params) -> int:
-    return sum(x.nbytes for x in jax.tree.leaves(params))
-
-
-# ---- pieces shared by every layer ----------------------------------------
-
-
-def rms_norm(x, weight, eps: float):
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps)
-            * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def _proj(x, w):
-    """x @ w in the activations' dtype, accumulated in float32."""
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
-
-
-def swiglu(p, x):
-    return _proj(jax.nn.silu(_proj(x, p["gate"])) * _proj(x, p["up"]),
-                 p["down"])
-
-
-def rope(x, positions, theta: float):
-    """Rotate-half RoPE over the last axis of ``x`` (..., T, D) at
-    integer ``positions`` (T,), in float32."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = positions.astype(jnp.float32)[:, None] * freq[None]
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :half], x32[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1)
 
 
 # ---- KDA: gated delta-rule linear attention -----------------------------
@@ -430,24 +394,12 @@ def kda_empty_cache(cfg: LingConfig, batch: int):
     return (jnp.zeros((batch, h, dk, dk), jnp.float32), (tail,) * 3)
 
 
-# ---- MLA: latent attention ------------------------------------------------
+# ---- MLA: latent attention (the core is text_layers') ---------------------
 
 
-def _mla_query_and_latent(p, cfg: LingConfig, x, positions):
-    """q_nope (B, T, H, Dn), q_rope (B, T, H, Dr) and the cache entry
-    (B, T, latent + Dr): the normed latent and the rotated shared key."""
-    b_, t, _ = x.shape
-    h = cfg.num_attention_heads
-    q = _proj(x, p["wq"]).reshape(b_, t, h, -1)
-    q_n, q_r = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
-    q_r = rope(jnp.swapaxes(q_r, 1, 2), positions, cfg.rope_theta)
-    q_r = jnp.swapaxes(q_r, 1, 2).astype(x.dtype)
-    ckr = _proj(x, p["wdkv"])
-    c = rms_norm(ckr[..., :cfg.kv_lora_rank], p["kv_norm"],
-                 cfg.rms_norm_eps)
-    k_r = rope(ckr[..., cfg.kv_lora_rank:], positions,
-               cfg.rope_theta).astype(x.dtype)
-    return q_n, q_r, jnp.concatenate([c, k_r], axis=-1)
+def _rope_frequencies(cfg: LingConfig):
+    return text_layers.rope_frequencies(cfg.rope_theta,
+                                        cfg.qk_rope_head_dim)
 
 
 def _mla_out(p, cfg: LingConfig, x, o):
@@ -463,39 +415,11 @@ def mla_scale(cfg: LingConfig) -> float:
 
 
 def mla_prefill(p, cfg: LingConfig, x, cache, pos):
-    """x (B, T, d) at positions [pos, pos + T); ``cache`` (B, S, latent
-    + Dr) holds every earlier token's entry. Up-projects the latents to
-    keys and values block by block, as far as the cache is written and
-    no further, and attends causally (``ops.attention``) over the same
-    blocks; the rotary key goes in as it lies in the cache, one for all
-    heads."""
-    b_, t, _ = x.shape
-    h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
-    q_n, q_r, entry = _mla_query_and_latent(p, cfg, x,
-                                            pos + jnp.arange(t))
-    cache = jax.lax.dynamic_update_slice_in_dim(cache, entry, pos, axis=1)
-    s = cache.shape[1]
-    block = key_block(t, s)
-    wukv = p["wukv"].reshape(rank, h, -1)
-    w_uk, w_uv = (w.reshape(rank, -1) for w in (
-        wukv[..., :cfg.qk_nope_head_dim], wukv[..., cfg.qk_nope_head_dim:]))
-
-    def up_project(i, kv):
-        latents = jax.lax.dynamic_slice_in_dim(
-            cache, i * block, block, axis=1)[..., :rank]
-        return tuple(
-            jax.lax.dynamic_update_slice_in_dim(
-                whole, _proj(latents, w), i * block, axis=1)
-            for whole, w in zip(kv, (w_uk, w_uv)))
-
-    # blocks past the written length stay zero and are never read
-    k_n, v = jax.lax.fori_loop(
-        0, (pos + t + block - 1) // block, up_project,
-        tuple(jnp.zeros((b_, s, w.shape[1]), x.dtype)
-              for w in (w_uk, w_uv)))
-    o = attention(q_n, k_n.reshape(b_, s, h, -1), v.reshape(b_, s, h, -1),
-                  scale=mla_scale(cfg), causal=True, q_offset=pos,
-                  shared_key=(q_r, cache[..., rank:]))
+    """x (B, T, d) at positions [pos, pos + T) -> (y, cache): a
+    full-rank query, ``text_layers.latent_prefill``, the gate, W_o."""
+    o, cache = text_layers.latent_prefill(
+        p, cfg, x, _proj(x, p["wq"]), cache, pos,
+        inv_freq=_rope_frequencies(cfg), scale=mla_scale(cfg))
     return _mla_out(p, cfg, x, o), cache
 
 
@@ -504,53 +428,21 @@ def prefill_key_blocks(cfg: LingConfig, prompt_tokens: int, chunk: int,
     """(key blocks ``mla_prefill`` reads over a prompt's chunks, key
     blocks of the whole capacity over the same chunks), summed over the
     latent-attention layers: host integers, for the counter."""
-    block = key_block(chunk, capacity)
-    starts = range(0, prompt_tokens, chunk)
-    layers = len(cfg.mla_layers)
-    return (layers * sum(-(-(pos + chunk) // block) for pos in starts),
-            layers * len(starts) * -(-capacity // block))
+    return text_layers.prefill_key_blocks(len(cfg.mla_layers),
+                                          prompt_tokens, chunk, capacity)
 
 
 def mla_decode(p, cfg: LingConfig, x, prompt_cache, prompt_len, suffix,
                step):
-    """The absorbed form for one new token a row. x (R, 1, d);
-    ``prompt_cache`` (1, S, W) is shared by the rows (the first
-    ``prompt_len`` entries are valid), ``suffix`` (R, N, W) is each
-    row's own (entries [0, step] valid after this call's write)."""
-    r = x.shape[0]
-    h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
-    position = (prompt_len + step)[None]
-    q_n, q_r, entry = _mla_query_and_latent(p, cfg, x, position)
-    suffix = jax.lax.dynamic_update_slice_in_dim(suffix, entry, step, axis=1)
-    wukv = p["wukv"].reshape(rank, h, -1)
-    w_uk, w_uv = (wukv[..., :cfg.qk_nope_head_dim],
-                  wukv[..., cfg.qk_nope_head_dim:])
-    q_abs = jnp.einsum("rhd,chd->rhc", q_n[:, 0], w_uk,
-                       preferred_element_type=jnp.float32).astype(x.dtype)
-    q_all = jnp.concatenate([q_abs, q_r[:, 0]], axis=-1)      # (R, H, W)
-    shared = prompt_cache[0]
-    s_prompt = jnp.einsum("rhw,sw->rhs", q_all, shared,
-                          preferred_element_type=jnp.float32)
-    s_own = jnp.einsum("rhw,rnw->rhn", q_all, suffix,
-                       preferred_element_type=jnp.float32)
-    s_prompt = jnp.where(jnp.arange(shared.shape[0]) < prompt_len,
-                         s_prompt, NEG_INF)
-    s_own = jnp.where(jnp.arange(suffix.shape[1]) <= step, s_own, NEG_INF)
-    weights = jax.nn.softmax(
-        jnp.concatenate([s_prompt, s_own], -1) * mla_scale(cfg), axis=-1)
-    weights = weights.astype(x.dtype)
-    n_prompt = shared.shape[0]
-    o_lat = jnp.einsum("rhs,sc->rhc", weights[..., :n_prompt],
-                       shared[:, :rank],
-                       preferred_element_type=jnp.float32) \
-        + jnp.einsum("rhn,rnc->rhc", weights[..., n_prompt:],
-                     suffix[..., :rank], preferred_element_type=jnp.float32)
-    o = jnp.einsum("rhc,chd->rhd", o_lat.astype(x.dtype), w_uv,
-                   preferred_element_type=jnp.float32)
-    return _mla_out(p, cfg, x, o[:, None].astype(x.dtype)), suffix
+    """One new token a row, absorbed form (``text_layers.latent_decode``):
+    x (R, 1, d) -> (y, suffix)."""
+    o, suffix = text_layers.latent_decode(
+        p, cfg, x, _proj(x, p["wq"]), prompt_cache, prompt_len, suffix,
+        step, inv_freq=_rope_frequencies(cfg), scale=mla_scale(cfg))
+    return _mla_out(p, cfg, x, o), suffix
 
 
-# ---- experts ---------------------------------------------------------------
+# ---- experts (the layer is text_layers') -----------------------------------
 
 
 def route(p, cfg: LingConfig, x):
@@ -576,95 +468,12 @@ def route(p, cfg: LingConfig, x):
     return chosen.astype(jnp.int32), weight
 
 
-def held_experts_part(p, cfg: LingConfig, x, chosen, weight, valid):
-    """The weighted outputs of the HELD experts among the chosen. The
-    (token, expert) pairs that land on a held expert are sorted by
-    expert and laid out in blocks of ``block`` rows, each block one
-    expert's; a loop over the blocks in use (dynamic trip count) slices
-    that expert's weights and computes the block. Returns (y (T, d),
-    pairs held, distinct held experts hit)."""
-    t, k = chosen.shape
-    d = x.shape[-1]
-    n_held = cfg.n_held
-    block = 128 if t >= 1024 else 8
-    pairs = t * k
-    max_blocks = min(n_held, pairs) + pairs // block
-    local = chosen.reshape(-1) - cfg.experts_held[0]
-    held = (local >= 0) & (local < n_held) & jnp.repeat(valid, k)
-    local = jnp.where(held, local, n_held)          # the rest sort last
-    order = jnp.argsort(local, stable=True)
-    sorted_e = local[order]
-    counts = jnp.zeros((n_held + 1,), jnp.int32).at[local].add(1)[:n_held]
-    blocks_of = (counts + block - 1) // block
-    ends = jnp.cumsum(blocks_of)
-    first_block, n_blocks = ends - blocks_of, ends[-1]
-    first_pair = jnp.cumsum(counts) - counts
-    e_safe = jnp.minimum(sorted_e, n_held - 1)
-    row = first_block[e_safe] * block \
-        + (jnp.arange(pairs) - first_pair[e_safe])
-    row = jnp.where(sorted_e < n_held, row, max_blocks * block)
-    token_of_row = jnp.full((max_blocks * block,), t, jnp.int32).at[
-        row].set((order // k).astype(jnp.int32), mode="drop")
-    expert_of_block = jnp.searchsorted(
-        ends, jnp.arange(max_blocks), side="right").astype(jnp.int32)
-    x_pad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])
-    experts = p["experts"]
-
-    def body(i, out):
-        e = jnp.minimum(expert_of_block[i], n_held - 1)
-        rows = jax.lax.dynamic_slice_in_dim(token_of_row, i * block, block)
-        xb = x_pad[rows]
-        one = {name: jax.lax.dynamic_index_in_dim(w, e, keepdims=False)
-               for name, w in experts.items()}
-        return jax.lax.dynamic_update_slice_in_dim(
-            out, swiglu(one, xb), i * block, axis=0)
-
-    out = jax.lax.fori_loop(
-        0, n_blocks, body,
-        jnp.zeros((max_blocks * block + 1, d), x.dtype))
-    row_of_pair = jnp.zeros((pairs,), jnp.int32).at[order].set(
-        row.astype(jnp.int32))
-    gathered = out[row_of_pair].reshape(t, k, d).astype(jnp.float32)
-    w_held = jnp.where(held.reshape(t, k), weight, 0.0)
-    y = jnp.einsum("tkd,tk->td", gathered, w_held, precision=HIGHEST)
-    return y.astype(x.dtype), jnp.sum(held), jnp.sum(counts > 0)
-
-
 def moe(p, cfg: LingConfig, x, valid=None):
     """x (..., d) -> (shared expert + held experts' part, stats)."""
-    lead = x.shape[:-1]
-    flat = x.reshape(-1, x.shape[-1])
-    if valid is None:
-        valid = jnp.ones((flat.shape[0],), bool)
-    chosen, weight = route(p, cfg, flat)
-    y, held, hit = held_experts_part(p, cfg, flat, chosen, weight,
-                                     valid.reshape(-1))
-    y = y + swiglu(p["shared"], flat)
-    stats = {"pairs": jnp.sum(valid) * cfg.num_experts_per_tok,
-             "pairs_held": held, "experts_hit": hit}
-    return y.reshape(*lead, -1), stats
+    return text_layers.moe(p, cfg, x, route, valid)
 
 
 # ---- the stack -------------------------------------------------------------
-
-
-def empty_stats():
-    zero = jnp.zeros((), jnp.int32)
-    return {"pairs": zero, "pairs_held": zero, "experts_hit": zero}
-
-
-def _mlp(layer, cfg: LingConfig, i: int, x, stats, valid=None):
-    h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-    if not cfg.is_moe(i):
-        return x + swiglu(layer["mlp"], h), stats
-    y, s = moe(layer["mlp"], cfg, h, valid)
-    return x + y, {k: stats[k] + s[k].astype(jnp.int32) for k in stats}
-
-
-def logits_of(params, cfg: LingConfig, x):
-    """Hidden states (..., d) -> float32 logits over the slice held."""
-    h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
 
 
 def empty_prefill_caches(cfg: LingConfig, capacity: int):
@@ -672,9 +481,8 @@ def empty_prefill_caches(cfg: LingConfig, capacity: int):
     conv tails per KDA layer, a latent cache of ``capacity`` entries per
     MLA layer."""
     return {"kda": [kda_empty_cache(cfg, 1) for _ in cfg.kda_layers],
-            "mla": [jnp.zeros((1, capacity, cfg.latent_width),
-                              jnp.dtype(cfg.dtype))
-                    for _ in cfg.mla_layers]}
+            "mla": text_layers.empty_latent_caches(
+                cfg, len(cfg.mla_layers), capacity)}
 
 
 def prefill_chunk(params, cfg: LingConfig, ids, caches, pos, n_valid):
@@ -694,9 +502,11 @@ def prefill_chunk(params, cfg: LingConfig, ids, caches, pos, n_valid):
         else:
             j = cfg.kda_layers.index(i)
             y, kda[j] = kda_prefill(layer["attn"], cfg, h, kda[j], n_valid)
-        x, stats = _mlp(layer, cfg, i, x + y, stats, valid[None])
+        x, stats = text_layers.mlp_block(layer, cfg, x + y, stats, route,
+                                         valid[None])
     last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)[:, 0]
-    return logits_of(params, cfg, last), {"kda": kda, "mla": mla}, stats
+    return (text_layers.logits_of(params, cfg, last),
+            {"kda": kda, "mla": mla}, stats)
 
 
 def decode_caches(cfg: LingConfig, caches, rows: int, max_new: int):
@@ -709,8 +519,8 @@ def decode_caches(cfg: LingConfig, caches, rows: int, max_new: int):
 
     return {"kda": jax.tree.map(spread, caches["kda"]),
             "prompt": caches["mla"],
-            "suffix": [jnp.zeros((rows, max_new, cfg.latent_width),
-                                 c.dtype) for c in caches["mla"]]}
+            "suffix": text_layers.empty_suffixes(cfg, caches["mla"], rows,
+                                                 max_new)}
 
 
 def decode_step(params, cfg: LingConfig, tokens, caches, prompt_len, step):
@@ -729,9 +539,9 @@ def decode_step(params, cfg: LingConfig, tokens, caches, prompt_len, step):
         else:
             j = cfg.kda_layers.index(i)
             y, kda[j] = kda_decode(layer["attn"], cfg, h, kda[j])
-        x, stats = _mlp(layer, cfg, i, x + y, stats)
+        x, stats = text_layers.mlp_block(layer, cfg, x + y, stats, route)
     caches = {"kda": kda, "prompt": caches["prompt"], "suffix": suffix}
-    return logits_of(params, cfg, x[:, 0]), caches, stats
+    return text_layers.logits_of(params, cfg, x[:, 0]), caches, stats
 
 
 def cache_bytes(cfg: LingConfig, rows: int, capacity: int,
@@ -742,6 +552,23 @@ def cache_bytes(cfg: LingConfig, rows: int, capacity: int,
     recurrent = len(cfg.kda_layers) * rows * (
         h * dk * dk * 4
         + 3 * (cfg.short_conv_kernel_size - 1) * h * dk * item)
-    latent = len(cfg.mla_layers) * (capacity + rows * max_new) \
-        * cfg.latent_width * item
-    return {"recurrent": recurrent, "latent": latent}
+    return {"recurrent": recurrent,
+            "latent": text_layers.latent_cache_bytes(
+                cfg, len(cfg.mla_layers), rows, capacity, max_new)}
+
+
+def job_counts(cfg: LingConfig, prompt_tokens: int, rows: int, new: int,
+               chunk: int, capacity: int) -> dict[str, Any]:
+    """What the host knows of one job's two programs, for the counters
+    (``pipelines/text.py::TextPipeline._count``): key blocks the causal
+    kernel reads and leaves, query-key pairs a head scores by phase, the
+    delta-rule prefill's sub-blocks by form, the expert layers."""
+    layers = len(cfg.mla_layers)
+    return {
+        "key_blocks": prefill_key_blocks(cfg, prompt_tokens, chunk,
+                                         capacity),
+        "attention_pairs": text_layers.attention_pairs(
+            layers, prompt_tokens, rows, new),
+        "kda_blocks": kda_blocks(cfg, prompt_tokens, chunk),
+        "expert_layers": sum(cfg.is_moe(i)
+                             for i in range(cfg.num_hidden_layers))}

@@ -1,0 +1,342 @@
+"""Layers the text stacks share (models/ling.py, models/deepseek.py):
+the norm, the SwiGLU and the rotary pieces, latent attention (MLA) with
+its compressed cache, and the expert layer of which a chip holds a part.
+One implementation of each; a stack tells them what differs.
+
+- **Latent attention.** The cache holds 512 normed latent + 64 rotated
+  key values a token (``kv_lora_rank + qk_rope_head_dim``), one entry for
+  all heads. Two compute paths: prefill up-projects keys and values and
+  goes through ``ops.attention`` (causal), decode folds the key
+  up-projection into the query and the value up-projection after the
+  softmax (the absorbed form), against the latents directly: a prompt's
+  latents shared by the rows, each row's own suffix beside them. A stack
+  hands in its query projection (full rank, or through a bottleneck with
+  its own norm), its rotary frequencies (plain, or YaRN's), the softmax
+  scale, and does what follows the heads' read-out itself (a gate or
+  none, then W_o).
+- **Experts.** The layer is told which experts it holds
+  (``experts_held``), routes over all of them by the stack's router
+  (sigmoid with a bias and normalised weights, or softmax over groups'
+  maxima with plain weights), and computes its own experts' part for the
+  tokens routed to them (plus the shared expert); what the absent
+  experts would add is left out. Tokens are grouped by expert into
+  blocks and a loop with a dynamic trip count walks the blocks in use,
+  so a step reads the weights of the experts that were hit and no
+  others, and no token is ever dropped.
+
+A configuration is any object with the published key names these
+functions read (``num_attention_heads``, ``kv_lora_rank``,
+``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+``rms_norm_eps``, ``num_experts_per_tok``, ``experts_held``). Weights
+and activations in the configuration's dtype (bfloat16 served), the
+router in float32 with ``Precision.HIGHEST`` (a float32 product is one
+bfloat16 pass on a TPU otherwise).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from chiaswarm_tpu.ops.attention import attention
+from chiaswarm_tpu.ops.causal_flash_attention import key_block
+
+HIGHEST = jax.lax.Precision.HIGHEST
+NEG_INF = -1e30
+
+
+# ---- pieces every layer uses ---------------------------------------------
+
+
+def rms_norm(x, weight, eps: float):
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps)
+            * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def proj(x, w):
+    """x @ w in the activations' dtype, accumulated in float32."""
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def swiglu(p, x):
+    return proj(jax.nn.silu(proj(x, p["gate"])) * proj(x, p["up"]),
+                p["down"])
+
+
+def rope_frequencies(theta: float, dim: int):
+    """The ``dim // 2`` plain rotary frequencies ``theta^(-2i/dim)``."""
+    half = dim // 2
+    return theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+
+
+def rope(x, positions, inv_freq, amplitude: float = 1.0):
+    """Rotate-half RoPE over the last axis of ``x`` (..., T, D) at
+    integer ``positions`` (T,) with the D/2 frequencies ``inv_freq``, in
+    float32; cos and sin times ``amplitude`` (YaRN's ratio of mscales)."""
+    half = x.shape[-1] // 2
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
+
+
+def logits_of(params, cfg, x):
+    """Hidden states (..., d) -> float32 logits over the slice held."""
+    h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
+
+
+def param_bytes(params) -> int:
+    return sum(x.nbytes for x in jax.tree.leaves(params))
+
+
+# ---- latent attention ----------------------------------------------------
+
+
+def latent_width(cfg) -> int:
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def _query_and_entry(p, cfg, x, q, positions, inv_freq, amplitude):
+    """From the stack's query projection ``q`` (B, T, H * (Dn + Dr)):
+    q_nope (B, T, H, Dn), rotated q_rope (B, T, H, Dr), and the cache
+    entry (B, T, latent + Dr): the normed latent and the rotated shared
+    key of the normed input ``x``."""
+    b_, t, _ = x.shape
+    q = q.reshape(b_, t, cfg.num_attention_heads, -1)
+    q_n, q_r = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
+    q_r = rope(jnp.swapaxes(q_r, 1, 2), positions, inv_freq, amplitude)
+    q_r = jnp.swapaxes(q_r, 1, 2).astype(x.dtype)
+    ckr = proj(x, p["wdkv"])
+    c = rms_norm(ckr[..., :cfg.kv_lora_rank], p["kv_norm"],
+                 cfg.rms_norm_eps)
+    k_r = rope(ckr[..., cfg.kv_lora_rank:], positions, inv_freq,
+               amplitude).astype(x.dtype)
+    return q_n, q_r, jnp.concatenate([c, k_r], axis=-1)
+
+
+def _up_projections(p, cfg):
+    """W_uk (rank, H, Dn) and W_uv (rank, H, Dv) of the stacked W_ukv."""
+    wukv = p["wukv"].reshape(cfg.kv_lora_rank, cfg.num_attention_heads, -1)
+    return (wukv[..., :cfg.qk_nope_head_dim],
+            wukv[..., cfg.qk_nope_head_dim:])
+
+
+def latent_prefill(p, cfg, x, q, cache, pos, *, inv_freq, scale: float,
+                   rope_amplitude: float = 1.0):
+    """The heads' read-out (B, T, H, Dv) of x (B, T, d) at positions
+    [pos, pos + T), and the cache with their entries written; ``cache``
+    (B, S, latent + Dr) holds every earlier token's. Up-projects the
+    latents to keys and values block by block, as far as the cache is
+    written and no further, and attends causally (``ops.attention``)
+    over the same blocks; the rotary key goes in as it lies in the
+    cache, one for all heads."""
+    b_, t, _ = x.shape
+    h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+    q_n, q_r, entry = _query_and_entry(p, cfg, x, q, pos + jnp.arange(t),
+                                       inv_freq, rope_amplitude)
+    cache = jax.lax.dynamic_update_slice_in_dim(cache, entry, pos, axis=1)
+    s = cache.shape[1]
+    block = key_block(t, s)
+    w_uk, w_uv = (w.reshape(rank, -1) for w in _up_projections(p, cfg))
+
+    def up_project(i, kv):
+        latents = jax.lax.dynamic_slice_in_dim(
+            cache, i * block, block, axis=1)[..., :rank]
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                whole, proj(latents, w), i * block, axis=1)
+            for whole, w in zip(kv, (w_uk, w_uv)))
+
+    # blocks past the written length stay zero and are never read
+    k_n, v = jax.lax.fori_loop(
+        0, (pos + t + block - 1) // block, up_project,
+        tuple(jnp.zeros((b_, s, w.shape[1]), x.dtype)
+              for w in (w_uk, w_uv)))
+    o = attention(q_n, k_n.reshape(b_, s, h, -1), v.reshape(b_, s, h, -1),
+                  scale=scale, causal=True, q_offset=pos,
+                  shared_key=(q_r, cache[..., rank:]))
+    return o, cache
+
+
+def latent_decode(p, cfg, x, q, prompt_cache, prompt_len, suffix, step, *,
+                  inv_freq, scale: float, rope_amplitude: float = 1.0):
+    """The absorbed form for one new token a row: the heads' read-out
+    (R, 1, H, Dv) of x (R, 1, d) and the suffix with the rows' entries
+    written. ``prompt_cache`` (1, S, W) is shared by the rows (the first
+    ``prompt_len`` entries are valid), ``suffix`` (R, N, W) is each
+    row's own (entries [0, step] valid after this call's write)."""
+    rank = cfg.kv_lora_rank
+    position = (prompt_len + step)[None]
+    q_n, q_r, entry = _query_and_entry(p, cfg, x, q, position, inv_freq,
+                                       rope_amplitude)
+    suffix = jax.lax.dynamic_update_slice_in_dim(suffix, entry, step, axis=1)
+    w_uk, w_uv = _up_projections(p, cfg)
+    q_abs = jnp.einsum("rhd,chd->rhc", q_n[:, 0], w_uk,
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    q_all = jnp.concatenate([q_abs, q_r[:, 0]], axis=-1)      # (R, H, W)
+    shared = prompt_cache[0]
+    s_prompt = jnp.einsum("rhw,sw->rhs", q_all, shared,
+                          preferred_element_type=jnp.float32)
+    s_own = jnp.einsum("rhw,rnw->rhn", q_all, suffix,
+                       preferred_element_type=jnp.float32)
+    s_prompt = jnp.where(jnp.arange(shared.shape[0]) < prompt_len,
+                         s_prompt, NEG_INF)
+    s_own = jnp.where(jnp.arange(suffix.shape[1]) <= step, s_own, NEG_INF)
+    weights = jax.nn.softmax(
+        jnp.concatenate([s_prompt, s_own], -1) * scale, axis=-1)
+    weights = weights.astype(x.dtype)
+    n_prompt = shared.shape[0]
+    o_lat = jnp.einsum("rhs,sc->rhc", weights[..., :n_prompt],
+                       shared[:, :rank],
+                       preferred_element_type=jnp.float32) \
+        + jnp.einsum("rhn,rnc->rhc", weights[..., n_prompt:],
+                     suffix[..., :rank], preferred_element_type=jnp.float32)
+    o = jnp.einsum("rhc,chd->rhd", o_lat.astype(x.dtype), w_uv,
+                   preferred_element_type=jnp.float32)
+    return o[:, None].astype(x.dtype), suffix
+
+
+def empty_latent_caches(cfg, layers: int, capacity: int):
+    """One row's latent cache of ``capacity`` entries per layer."""
+    return [jnp.zeros((1, capacity, latent_width(cfg)),
+                      jnp.dtype(cfg.dtype)) for _ in range(layers)]
+
+
+def empty_suffixes(cfg, prompt_caches, rows: int, max_new: int):
+    """An empty suffix of ``max_new`` latents a row per layer."""
+    return [jnp.zeros((rows, max_new, latent_width(cfg)), c.dtype)
+            for c in prompt_caches]
+
+
+def latent_cache_bytes(cfg, layers: int, rows: int, capacity: int,
+                       max_new: int) -> int:
+    return layers * (capacity + rows * max_new) * latent_width(cfg) \
+        * jnp.dtype(cfg.dtype).itemsize
+
+
+# what the host knows of a job, for the counters (no callback in a jit)
+
+
+def prefill_key_blocks(layers: int, prompt_tokens: int, chunk: int,
+                       capacity: int) -> tuple[int, int]:
+    """(key blocks ``latent_prefill`` reads over a prompt's chunks, key
+    blocks of the whole capacity over the same chunks), summed over
+    ``layers`` latent-attention layers."""
+    block = key_block(chunk, capacity)
+    starts = range(0, prompt_tokens, chunk)
+    return (layers * sum(-(-(pos + chunk) // block) for pos in starts),
+            layers * len(starts) * -(-capacity // block))
+
+
+def attention_pairs(layers: int, prompt_tokens: int, rows: int,
+                    new: int) -> tuple[int, int]:
+    """(query-key pairs a head scores in a prompt's prefill, pairs it
+    scores in the decode of ``rows`` rows), summed over ``layers``
+    latent-attention layers: a prompt token sees the tokens up to
+    itself, a decode step (``new - 1`` of them) the prompt and the row's
+    suffix up to its own entry."""
+    prefill = prompt_tokens * (prompt_tokens + 1) // 2
+    steps = new - 1
+    decode = rows * (steps * (prompt_tokens + 1) + steps * (steps - 1) // 2)
+    return layers * prefill, layers * decode
+
+
+# ---- experts ---------------------------------------------------------------
+
+
+def n_held(cfg) -> int:
+    return cfg.experts_held[1] - cfg.experts_held[0]
+
+
+def held_experts_part(p, cfg, x, chosen, weight, valid):
+    """The weighted outputs of the HELD experts among the chosen. The
+    (token, expert) pairs that land on a held expert are sorted by
+    expert and laid out in blocks of ``block`` rows, each block one
+    expert's; a loop over the blocks in use (dynamic trip count) slices
+    that expert's weights and computes the block. Returns (y (T, d),
+    pairs held, distinct held experts hit)."""
+    t, k = chosen.shape
+    d = x.shape[-1]
+    held_n = n_held(cfg)
+    block = 128 if t >= 1024 else 8
+    pairs = t * k
+    max_blocks = min(held_n, pairs) + pairs // block
+    local = chosen.reshape(-1) - cfg.experts_held[0]
+    held = (local >= 0) & (local < held_n) & jnp.repeat(valid, k)
+    local = jnp.where(held, local, held_n)          # the rest sort last
+    order = jnp.argsort(local, stable=True)
+    sorted_e = local[order]
+    counts = jnp.zeros((held_n + 1,), jnp.int32).at[local].add(1)[:held_n]
+    blocks_of = (counts + block - 1) // block
+    ends = jnp.cumsum(blocks_of)
+    first_block, n_blocks = ends - blocks_of, ends[-1]
+    first_pair = jnp.cumsum(counts) - counts
+    e_safe = jnp.minimum(sorted_e, held_n - 1)
+    row = first_block[e_safe] * block \
+        + (jnp.arange(pairs) - first_pair[e_safe])
+    row = jnp.where(sorted_e < held_n, row, max_blocks * block)
+    token_of_row = jnp.full((max_blocks * block,), t, jnp.int32).at[
+        row].set((order // k).astype(jnp.int32), mode="drop")
+    expert_of_block = jnp.searchsorted(
+        ends, jnp.arange(max_blocks), side="right").astype(jnp.int32)
+    x_pad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])
+    experts = p["experts"]
+
+    def body(i, out):
+        e = jnp.minimum(expert_of_block[i], held_n - 1)
+        rows = jax.lax.dynamic_slice_in_dim(token_of_row, i * block, block)
+        xb = x_pad[rows]
+        one = {name: jax.lax.dynamic_index_in_dim(w, e, keepdims=False)
+               for name, w in experts.items()}
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, swiglu(one, xb), i * block, axis=0)
+
+    out = jax.lax.fori_loop(
+        0, n_blocks, body,
+        jnp.zeros((max_blocks * block + 1, d), x.dtype))
+    row_of_pair = jnp.zeros((pairs,), jnp.int32).at[order].set(
+        row.astype(jnp.int32))
+    gathered = out[row_of_pair].reshape(t, k, d).astype(jnp.float32)
+    w_held = jnp.where(held.reshape(t, k), weight, 0.0)
+    y = jnp.einsum("tkd,tk->td", gathered, w_held, precision=HIGHEST)
+    return y.astype(x.dtype), jnp.sum(held), jnp.sum(counts > 0)
+
+
+def moe(p, cfg, x, route, valid=None):
+    """x (..., d) -> (shared expert + held experts' part, stats).
+    ``route(p, cfg, x (T, d))`` is the stack's router: (chosen experts
+    (T, K) int32, their weights (T, K) float32) over ALL experts."""
+    lead = x.shape[:-1]
+    flat = x.reshape(-1, x.shape[-1])
+    if valid is None:
+        valid = jnp.ones((flat.shape[0],), bool)
+    chosen, weight = route(p, cfg, flat)
+    y, held, hit = held_experts_part(p, cfg, flat, chosen, weight,
+                                     valid.reshape(-1))
+    y = y + swiglu(p["shared"], flat)
+    stats = {"pairs": jnp.sum(valid) * cfg.num_experts_per_tok,
+             "pairs_held": held, "experts_hit": hit}
+    return y.reshape(*lead, -1), stats
+
+
+def empty_stats():
+    zero = jnp.zeros((), jnp.int32)
+    return {"pairs": zero, "pairs_held": zero, "experts_hit": zero}
+
+
+def mlp_block(layer, cfg, x, stats, route, valid=None):
+    """x + MLP(RMSNorm(x)): the dense SwiGLU where the layer has one
+    (no ``experts`` among its weights), the expert layer otherwise,
+    whose counts are added to ``stats``."""
+    h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+    if "experts" not in layer["mlp"]:
+        return x + swiglu(layer["mlp"], h), stats
+    y, s = moe(layer["mlp"], cfg, h, route, valid)
+    return x + y, {k: stats[k] + s[k].astype(jnp.int32) for k in stats}

@@ -550,13 +550,14 @@ class ModelRegistry:
         )
 
     def text_pipeline(self, model_name: str, mesh=None):
-        """Resident Ling-class text generator (models/ling.py +
-        pipelines/text.py): the first model that takes most of a chip
-        (10.7 GB at the benchmark's cut), so its entry is what the
-        ledger's budget is sized around. No checkpoint converter exists
-        yet: a node serves it from ``_load_text_components`` (seeded
-        weights in the benchmark) or, under ``allow_random``, at the
-        tiny preset."""
+        """Resident text generator (pipelines/text.py over one of the
+        stacks of models/text_stacks.py): models that take most of a
+        chip (10.3-10.7 GB at the benchmark's cuts), so their entries
+        are what the ledger's budget is sized around. No checkpoint
+        converter exists yet: a node serves one from
+        ``_load_text_components`` (seeded weights in the benchmark) or,
+        under ``allow_random``, at the tiny preset of the stack its
+        catalog entry names (``"stack"``; the default stack without)."""
         from chiaswarm_tpu.pipelines.text import TextPipeline
 
         self._check_quarantine(model_name)
@@ -591,9 +592,14 @@ class ModelRegistry:
         from chiaswarm_tpu.pipelines.text import TextComponents
 
         if self.allow_random:
+            from chiaswarm_tpu.models import text_stacks
+
+            stack = (self._catalog.get(model_name) or {}).get(
+                "stack", text_stacks.DEFAULT)
             log.warning("no checkpoint loader for text model %s; using "
-                        "random tiny weights", model_name)
-            return TextComponents.random(model_name=model_name)
+                        "random tiny %s weights", model_name, stack)
+            return TextComponents.random(text_stacks.get(stack).TINY,
+                                         model_name=model_name)
         raise ValueError(
             f"text model {model_name!r} is not available on this node "
             f"(no checkpoint at {model_dir(model_name)})")

@@ -72,6 +72,18 @@ SMOKE_JOBS: dict[str, dict[str, Any]] = {
         "logprobs": True,
         "content_type": "application/json",
     },
+    "txt2txt_deepseek": {
+        # the second text stack (models/deepseek.py), named by its
+        # catalog entry in ``run_smoke``
+        "id": "smoke-txt2txt-deepseek",
+        "workflow": "txt2txt",
+        "model_name": "random/deepseek_tiny",
+        "prompt": "ab cd ab ba",
+        "max_new_tokens": 4,
+        "num_return_sequences": 2,
+        "logprobs": True,
+        "content_type": "application/json",
+    },
     "tts": {
         # the reference's bark smoke job (swarm/test.py:45-51)
         "id": "smoke-tts",
@@ -148,7 +160,9 @@ def run_smoke(workflow: str, random_weights: bool = True) -> dict[str, Any]:
                          for i in range(3)]
 
     registry = ModelRegistry(
-        catalog=[{"name": "tiny", "family": "tiny"}],
+        catalog=[{"name": "tiny", "family": "tiny"},
+                 {"name": "random/deepseek_tiny", "stack": "deepseek",
+                  "prefill_chunk": 8, "max_context": 32}],
         allow_random=random_weights,
     )
     pool = ChipPool(n_slots=1)

@@ -957,6 +957,16 @@ TEXT_PREFILL_KEY_BLOCKS = REGISTRY.counter(
     "kernel's bound admits them or they lie past the written cache",
     labelnames=("read",))
 
+#: query-key pairs one head scores in the latent-attention layers: a
+#: prompt token against the tokens up to itself (prefill), a decode step
+#: against the prompt and the row's suffix up to its own entry; summed
+#: over those layers, chunks or steps, and rows, from host integers
+TEXT_ATTENTION_PAIRS = REGISTRY.counter(
+    "chiaswarm_text_attention_pairs_total",
+    "query-key pairs a head scores in the latent-attention layers, by "
+    "phase",
+    labelnames=("phase",))
+
 #: sub-blocks of the delta-rule prefill's in-chunk matrices, by how
 #: ``kda_chunked`` builds them: "pairwise" decays on the diagonal,
 #: "product" of rescaled factors left of it; per KDA layer, prefill chunk

@@ -1,20 +1,22 @@
 """Text generation pipeline (txt2txt): prefill in fixed chunks, then one
 scan that samples every row — two resident programs a model.
 
-The repo's first prefill/decode split, and the first carry with two
-kinds of cache in it (models/ling.py): a fixed-size float32 recurrent
-state plus a conv tail for each linear-attention layer, a growing
-latent cache for each latent-attention layer.
+The repo's first prefill/decode split. The model is a text stack
+(models/text_stacks.py: a module of plain functions named by the
+configuration it reads), and the caches are the stack's own carry: a
+fixed-size float32 recurrent state plus a conv tail for each
+linear-attention layer beside a growing latent cache for each
+latent-attention layer in one stack, latent caches alone in another.
 
 - ``text_prefill``: one chunk of ``prefill_chunk`` tokens of one row,
-  both caches carried in and out, called once a chunk with the chunk's
+  the caches carried in and out, called once a chunk with the chunk's
   position and its count of real tokens as traced operands: ONE program
   for any prompt up to ``max_context`` (the last chunk may be part
   padding, which leaves the caches as they were).
 - ``text_decode``: one ``lax.scan`` over ``max_new_tokens`` for
-  ``num_return_sequences`` rows. The prompt's recurrent state is
-  broadcast to the rows, the prompt's latents are shared by them (each
-  row appends to its own suffix), and every row samples on the device
+  ``num_return_sequences`` rows. A prompt's recurrent state is
+  broadcast to the rows, its latents are shared by them (each row
+  appends to its own suffix), and every row samples on the device
   from its own key (``core.rng.per_sample_keys``: row i of seed s draws
   what row 0 of seed s + i draws) — the static-shape, no-per-token
   dispatch design of models/gpt.py.
@@ -39,7 +41,7 @@ from chiaswarm_tpu.core.compile_cache import (
     toplevel_jit,
 )
 from chiaswarm_tpu.core.rng import per_sample_keys
-from chiaswarm_tpu.models import ling
+from chiaswarm_tpu.models import text_stacks
 from chiaswarm_tpu.models.tokenizer import WordPieceTokenizer
 from chiaswarm_tpu.obs import metrics
 from chiaswarm_tpu.obs.trace import span
@@ -68,23 +70,32 @@ def word_vocab(size: int) -> dict[str, int]:
 
 @dataclasses.dataclass
 class TextComponents:
-    config: ling.LingConfig
+    config: Any             # a stack's configuration; it names its stack
     model_name: str
     tokenizer: WordPieceTokenizer
     params: dict[str, Any]
 
     @classmethod
-    def random(cls, config: ling.LingConfig = ling.LING_TINY,
-               seed: int = 0,
+    def random(cls, config=None, seed: int = 0,
                model_name: str | None = None) -> "TextComponents":
+        """Random weights at ``config`` (a stack's configuration), or at
+        the default stack's tiny preset."""
+        if config is None:
+            config = text_stacks.get(text_stacks.DEFAULT).TINY
         return cls(config=config,
-                   model_name=model_name or "random/ling_tiny",
+                   model_name=model_name or f"random/{config.stack}_tiny",
                    tokenizer=WordPieceTokenizer(
                        word_vocab(config.vocab_size)),
-                   params=ling.random_params(config, seed))
+                   params=text_stacks.get(config.stack).random_params(
+                       config, seed))
+
+    @property
+    def stack(self):
+        """The module that reads ``config`` (models/text_stacks.py)."""
+        return text_stacks.get(self.config.stack)
 
     def param_bytes(self) -> int:
-        return ling.param_bytes(self.params)
+        return self.stack.param_bytes(self.params)
 
 
 def _bucket_new(n: int) -> int:
@@ -110,12 +121,12 @@ class TextPipeline:
     # ---- the two programs ----
 
     def _prefill_fn(self):
-        cfg = self.c.config
+        cfg, stack = self.c.config, self.c.stack
 
         def build():
             def text_prefill(params, ids, caches, pos, n_valid):
-                return ling.prefill_chunk(params, cfg, ids, caches, pos,
-                                          n_valid)
+                return stack.prefill_chunk(params, cfg, ids, caches, pos,
+                                           n_valid)
 
             return toplevel_jit(text_prefill)
 
@@ -125,7 +136,7 @@ class TextPipeline:
                               "context": self.max_context}), build)
 
     def _decode_fn(self, rows: int, max_new: int):
-        cfg = self.c.config
+        cfg, stack = self.c.config, self.c.stack
 
         def build():
             def sample(keys, logits, temperature):
@@ -139,20 +150,20 @@ class TextPipeline:
 
             def text_decode(params, logits, caches, prompt_len, keys,
                             temperature):
-                caches = ling.decode_caches(cfg, caches, rows, max_new)
+                caches = stack.decode_caches(cfg, caches, rows, max_new)
                 keys, first, first_lp = sample(
                     keys, jnp.broadcast_to(logits, (rows,) + logits.shape[1:]),
                     temperature)
 
                 def body(carry, step):
                     caches, token, keys, stats = carry
-                    logits, caches, s = ling.decode_step(
+                    logits, caches, s = stack.decode_step(
                         params, cfg, token, caches, prompt_len, step)
                     keys, nxt, logprob = sample(keys, logits, temperature)
                     stats = {k: stats[k] + s[k] for k in stats}
                     return (caches, nxt, keys, stats), (nxt, logprob)
 
-                carry = (caches, first, keys, ling.empty_stats())
+                carry = (caches, first, keys, stack.empty_stats())
                 carry, (tokens, logprobs) = jax.lax.scan(
                     body, carry, jnp.arange(max_new - 1, dtype=jnp.int32))
                 tokens = jnp.concatenate([first[None], tokens]).T
@@ -185,7 +196,7 @@ class TextPipeline:
         """-> (logits after the last token (1, V), caches, stats)."""
         cfg, chunk = self.c.config, self.prefill_chunk
         fn = self._prefill_fn()
-        caches = ling.empty_prefill_caches(cfg, self.max_context)
+        caches = self.c.stack.empty_prefill_caches(cfg, self.max_context)
         stats = None
         for pos in range(0, len(ids), chunk):
             part = ids[pos:pos + chunk]
@@ -209,12 +220,13 @@ class TextPipeline:
             raise ValueError("num_return_sequences must be at least 1")
         row_bucket, new_bucket = bucket_batch(rows), _bucket_new(new)
         t0 = time.perf_counter()
-        with span("text.tokenize"):
+        stack = self.c.config.stack
+        with span("text.tokenize", stack=stack):
             ids = self.tokenize(prompt)
-        with span("text.prefill", tokens=int(ids.size)):
+        with span("text.prefill", stack=stack, tokens=int(ids.size)):
             logits, caches, prefill_stats = self.prefill(ids)
             jax.block_until_ready(logits)
-        with span("text.decode", rows=rows, tokens=new):
+        with span("text.decode", stack=stack, rows=rows, tokens=new):
             tokens, token_logprobs, stats = self._decode_fn(
                 row_bucket, new_bucket)(
                     self.c.params, logits, caches, jnp.int32(ids.size),
@@ -222,7 +234,7 @@ class TextPipeline:
                     jnp.float32(temperature))
             tokens = np.asarray(tokens)[:rows, :new]
             token_logprobs = np.asarray(token_logprobs)[:rows, :new]
-        with span("text.detokenize"):
+        with span("text.detokenize", stack=stack):
             sequences = []
             for row, row_logprobs in zip(tokens, token_logprobs):
                 entry = {"text": self.c.tokenizer.decode(row)}
@@ -248,16 +260,20 @@ class TextPipeline:
                                          phase=phase, held="no")
         metrics.MOE_EXPERTS_HIT.inc(int(stats["experts_hit"]))
         cfg = self.c.config
-        read, total = ling.prefill_key_blocks(
-            cfg, prompt_tokens, self.prefill_chunk, self.max_context)
+        counts = self.c.stack.job_counts(cfg, prompt_tokens, rows, new,
+                                         self.prefill_chunk,
+                                         self.max_context)
+        read, total = counts["key_blocks"]
         metrics.TEXT_PREFILL_KEY_BLOCKS.inc(read, read="yes")
         metrics.TEXT_PREFILL_KEY_BLOCKS.inc(total - read, read="no")
-        pairwise, product = ling.kda_blocks(cfg, prompt_tokens,
-                                            self.prefill_chunk)
-        metrics.TEXT_KDA_BLOCKS.inc(pairwise, form="pairwise")
-        metrics.TEXT_KDA_BLOCKS.inc(product, form="product")
-        metrics.MOE_LAYER_STEPS.inc((new - 1) * sum(
-            cfg.is_moe(i) for i in range(cfg.num_hidden_layers)))
-        for kind, size in ling.cache_bytes(
-                self.c.config, rows, self.max_context, new).items():
+        for phase, pairs in zip(("prefill", "decode"),
+                                counts["attention_pairs"]):
+            metrics.TEXT_ATTENTION_PAIRS.inc(pairs, phase=phase)
+        if "kda_blocks" in counts:      # a stack with delta-rule layers
+            pairwise, product = counts["kda_blocks"]
+            metrics.TEXT_KDA_BLOCKS.inc(pairwise, form="pairwise")
+            metrics.TEXT_KDA_BLOCKS.inc(product, form="product")
+        metrics.MOE_LAYER_STEPS.inc((new - 1) * counts["expert_layers"])
+        for kind, size in self.c.stack.cache_bytes(
+                cfg, rows, self.max_context, new).items():
             metrics.TEXT_CACHE_BYTES.set(size, kind=kind)

@@ -348,12 +348,14 @@ def job_counts(cfg: DeepseekConfig, prompt_tokens: int, rows: int, new: int,
                chunk: int, capacity: int) -> dict[str, Any]:
     """What the host knows of one job's two programs, for the counters
     (``pipelines/text.py::TextPipeline._count``): key blocks the causal
-    kernel reads and leaves, query-key pairs a head scores by phase, the
-    expert layers."""
+    kernel reads and leaves in the prefill and in the decode, query-key
+    pairs a head scores by phase, the expert layers."""
     layers = cfg.num_hidden_layers
     return {
         "key_blocks": text_layers.prefill_key_blocks(
             layers, prompt_tokens, chunk, capacity),
+        "decode_key_blocks": text_layers.decode_key_blocks(
+            layers, prompt_tokens, new, capacity),
         "attention_pairs": text_layers.attention_pairs(
             layers, prompt_tokens, rows, new),
         "expert_layers": sum(cfg.is_moe(i) for i in range(layers))}

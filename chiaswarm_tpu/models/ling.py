@@ -561,12 +561,15 @@ def job_counts(cfg: LingConfig, prompt_tokens: int, rows: int, new: int,
                chunk: int, capacity: int) -> dict[str, Any]:
     """What the host knows of one job's two programs, for the counters
     (``pipelines/text.py::TextPipeline._count``): key blocks the causal
-    kernel reads and leaves, query-key pairs a head scores by phase, the
-    delta-rule prefill's sub-blocks by form, the expert layers."""
+    kernel reads and leaves in the prefill and in the decode, query-key
+    pairs a head scores by phase, the delta-rule prefill's sub-blocks by
+    form, the expert layers."""
     layers = len(cfg.mla_layers)
     return {
         "key_blocks": prefill_key_blocks(cfg, prompt_tokens, chunk,
                                          capacity),
+        "decode_key_blocks": text_layers.decode_key_blocks(
+            layers, prompt_tokens, new, capacity),
         "attention_pairs": text_layers.attention_pairs(
             layers, prompt_tokens, rows, new),
         "kda_blocks": kda_blocks(cfg, prompt_tokens, chunk),

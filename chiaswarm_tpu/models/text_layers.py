@@ -9,7 +9,9 @@ One implementation of each; a stack tells them what differs.
   goes through ``ops.attention`` (causal), decode folds the key
   up-projection into the query and the value up-projection after the
   softmax (the absorbed form), against the latents directly: a prompt's
-  latents shared by the rows, each row's own suffix beside them. A stack
+  latents shared by the rows (one key-blocked sweep on the chip,
+  ``ops.attention.shared_latent_attention``: the scores never reach
+  memory), each row's own suffix beside them, joined exactly. A stack
   hands in its query projection (full rank, or through a bottleneck with
   its own norm), its rotary frequencies (plain, or YaRN's), the softmax
   scale, and does what follows the heads' read-out itself (a gate or
@@ -38,8 +40,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from chiaswarm_tpu.ops.attention import attention
-from chiaswarm_tpu.ops.causal_flash_attention import key_block
+from chiaswarm_tpu.ops.attention import attention, shared_latent_attention
+from chiaswarm_tpu.ops.causal_flash_attention import (
+    key_block,
+    shared_key_block,
+)
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG_INF = -1e30
@@ -170,8 +175,16 @@ def latent_decode(p, cfg, x, q, prompt_cache, prompt_len, suffix, step, *,
     """The absorbed form for one new token a row: the heads' read-out
     (R, 1, H, Dv) of x (R, 1, d) and the suffix with the rows' entries
     written. ``prompt_cache`` (1, S, W) is shared by the rows (the first
-    ``prompt_len`` entries are valid), ``suffix`` (R, N, W) is each
-    row's own (entries [0, step] valid after this call's write)."""
+    ``prompt_len`` entries are valid, at least one), ``suffix`` (R, N, W)
+    is each row's own (entries [0, step] valid after this call's write).
+
+    The rows' heads are R x H queries against ONE key/value head, so the
+    prompt's part (scores, mask, softmax, read-out) is one key-blocked
+    sweep on the chip (``ops.attention.shared_latent_attention``): no
+    (R, H, S) array exists in memory, and key blocks past ``prompt_len``
+    are not read. The suffix's part, at most N keys a row, stays here;
+    the two partial softmaxes are joined by their log-sum-exps in
+    float32, which is the softmax over prompt + suffix."""
     rank = cfg.kv_lora_rank
     position = (prompt_len + step)[None]
     q_n, q_r, entry = _query_and_entry(p, cfg, x, q, position, inv_freq,
@@ -181,23 +194,21 @@ def latent_decode(p, cfg, x, q, prompt_cache, prompt_len, suffix, step, *,
     q_abs = jnp.einsum("rhd,chd->rhc", q_n[:, 0], w_uk,
                        preferred_element_type=jnp.float32).astype(x.dtype)
     q_all = jnp.concatenate([q_abs, q_r[:, 0]], axis=-1)      # (R, H, W)
-    shared = prompt_cache[0]
-    s_prompt = jnp.einsum("rhw,sw->rhs", q_all, shared,
-                          preferred_element_type=jnp.float32)
+    rows, h, width = q_all.shape
+    o_prompt, lse_prompt = shared_latent_attention(
+        q_all.reshape(rows * h, width), prompt_cache[0], prompt_len,
+        value_width=rank, scale=scale)
+    o_prompt = o_prompt.reshape(rows, h, rank)
+    lse_prompt = lse_prompt.reshape(rows, h)
     s_own = jnp.einsum("rhw,rnw->rhn", q_all, suffix,
                        preferred_element_type=jnp.float32)
-    s_prompt = jnp.where(jnp.arange(shared.shape[0]) < prompt_len,
-                         s_prompt, NEG_INF)
-    s_own = jnp.where(jnp.arange(suffix.shape[1]) <= step, s_own, NEG_INF)
-    weights = jax.nn.softmax(
-        jnp.concatenate([s_prompt, s_own], -1) * scale, axis=-1)
-    weights = weights.astype(x.dtype)
-    n_prompt = shared.shape[0]
-    o_lat = jnp.einsum("rhs,sc->rhc", weights[..., :n_prompt],
-                       shared[:, :rank],
-                       preferred_element_type=jnp.float32) \
-        + jnp.einsum("rhn,rnc->rhc", weights[..., n_prompt:],
-                     suffix[..., :rank], preferred_element_type=jnp.float32)
+    s_own = jnp.where(jnp.arange(suffix.shape[1]) <= step, s_own,
+                      NEG_INF) * scale
+    lse = jnp.logaddexp(lse_prompt, jax.nn.logsumexp(s_own, axis=-1))
+    w_own = jnp.exp(s_own - lse[..., None]).astype(x.dtype)
+    o_lat = o_prompt * jnp.exp(lse_prompt - lse)[..., None] \
+        + jnp.einsum("rhn,rnc->rhc", w_own, suffix[..., :rank],
+                     preferred_element_type=jnp.float32)
     o = jnp.einsum("rhc,chd->rhd", o_lat.astype(x.dtype), w_uv,
                    preferred_element_type=jnp.float32)
     return o[:, None].astype(x.dtype), suffix
@@ -233,6 +244,16 @@ def prefill_key_blocks(layers: int, prompt_tokens: int, chunk: int,
     starts = range(0, prompt_tokens, chunk)
     return (layers * sum(-(-(pos + chunk) // block) for pos in starts),
             layers * len(starts) * -(-capacity // block))
+
+
+def decode_key_blocks(layers: int, prompt_tokens: int, new: int,
+                      capacity: int) -> tuple[int, int]:
+    """(key blocks ``latent_decode``'s sweep reads over a job's ``new -
+    1`` decode steps, key blocks of the whole capacity over the same
+    steps), summed over ``layers`` latent-attention layers."""
+    block = shared_key_block(capacity)
+    steps = layers * (new - 1)
+    return steps * -(-prompt_tokens // block), steps * -(-capacity // block)
 
 
 def attention_pairs(layers: int, prompt_tokens: int, rows: int,

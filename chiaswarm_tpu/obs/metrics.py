@@ -957,6 +957,17 @@ TEXT_PREFILL_KEY_BLOCKS = REGISTRY.counter(
     "kernel's bound admits them or they lie past the written cache",
     labelnames=("read",))
 
+#: key blocks of the latent-attention decode's sweep over the prompt's
+#: shared latents: those up to the prompt's length, which the kernel
+#: reads ("yes"), against the rest of the capacity, which its clamped
+#: index map leaves ("no"); per latent-attention layer and decode step,
+#: from what the host knows of a job (prompt, steps, capacity, the block)
+TEXT_DECODE_KEY_BLOCKS = REGISTRY.counter(
+    "chiaswarm_text_decode_key_blocks_total",
+    "key blocks of the latent-attention decode, by whether they hold a "
+    "prompt token and are read or lie past the prompt and are not",
+    labelnames=("read",))
+
 #: query-key pairs one head scores in the latent-attention layers: a
 #: prompt token against the tokens up to itself (prefill), a decode step
 #: against the prompt and the row's suffix up to its own entry; summed

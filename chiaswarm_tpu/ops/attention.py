@@ -31,7 +31,11 @@ Five implementations behind one function:
 ``causal=True`` is a second entry, not a sixth kind: a chunk of a
 prefill against a partly written cache goes to the causal flash kernel
 (ops/causal_flash_attention.py) whatever the backend, and none of the
-calls above can reach it.
+calls above can reach it. ``shared_latent_attention`` below is that
+kernel's other entry, behind the same door: a decode step's rows, all at
+one position, against one shared key/value head, with each row's
+log-sum-exp beside the read-out; models/text_layers.py imports no kernel
+module for either.
 
 All take (B, L, H, D) query / (B, S, H, D) key-value tensors and return
 (B, L, H, D). Head-batched layouts keep the last dim = head_dim (128-lane
@@ -186,6 +190,26 @@ def _xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         preferred_element_type=jnp.float32)
     weights = jax.nn.softmax(logits * scale, axis=-1).astype(q.dtype)
     return jnp.einsum("bhls,bshd->blhd", weights, v)
+
+
+def shared_latent_attention(q: jnp.ndarray, keys: jnp.ndarray, n_keys, *,
+                            value_width: int, scale: float | None = None
+                            ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Rows q (N, W), all behind the first ``n_keys`` (traced, at least
+    1) of ``keys`` (S, W), one key/value head whose values are its first
+    ``value_width`` columns: latent attention's decode in the absorbed
+    form, every row's heads as rows. Returns (the softmax read-out over
+    those keys (N, value_width) float32, each row's log-sum-exp (N,)
+    float32, by which the caller joins further keys of its own). One
+    path, the causal kernel's sweep with all rows at one position
+    (ops/causal_flash_attention.py; Pallas interpret mode off the chip):
+    the scores stay in VMEM and key blocks past ``n_keys`` are not
+    read."""
+    from chiaswarm_tpu.ops.causal_flash_attention import (
+        shared_latent_attention as sweep,
+    )
+
+    return sweep(q, keys, n_keys, value_width=value_width, scale=scale)
 
 
 def attention(

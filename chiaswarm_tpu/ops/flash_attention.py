@@ -66,7 +66,8 @@ _LANES = 128
 
 def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
                                 scale: float, kv_len, col_offset,
-                                row_offset=None, shared=None):
+                                row_offset=None, shared=None,
+                                rows_per_position: int = 1):
     """One KV block of the running-softmax recurrence, shared by the
     local flash kernel below, the fused ring kernel
     (ops/ring_flash_attention.py) and the causal prefill kernel
@@ -84,7 +85,16 @@ def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
     block's first GLOBAL query row: column ``c`` is visible to row ``r``
     when ``c <= r``; it takes the padding mask's place (a causal caller's
     rows end before its keys do). With ``kv_len`` None too, the block is
-    not masked at all."""
+    not masked at all.
+
+    The shared-latent decode's one, off by default as well:
+    ``rows_per_position`` = g, that many consecutive rows sit at one
+    position: row ``r`` sees ``c <= r // g``, tested as ``c * g <= r``
+    (no vector division). The probabilities stay float32 against values
+    cast to float32 for every caller: on a v5e the decode read the same
+    numbers in the same time with them cast to bfloat16 instead (PERF.md,
+    PR 34: the MXU takes a float32 product at default precision in one
+    bfloat16 pass either way)."""
     logits = jax.lax.dot_general(
         q, k, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -104,6 +114,8 @@ def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
             # KV positions past the true sequence length (block padding)
             visible = col < kv_len
         else:
+            if rows_per_position != 1:
+                col = col * rows_per_position
             visible = col <= row_offset + jax.lax.broadcasted_iota(
                 jnp.int32, logits.shape, 0)
         logits = jnp.where(visible, logits, _NEG_INF)

@@ -263,9 +263,12 @@ class TextPipeline:
         counts = self.c.stack.job_counts(cfg, prompt_tokens, rows, new,
                                          self.prefill_chunk,
                                          self.max_context)
-        read, total = counts["key_blocks"]
-        metrics.TEXT_PREFILL_KEY_BLOCKS.inc(read, read="yes")
-        metrics.TEXT_PREFILL_KEY_BLOCKS.inc(total - read, read="no")
+        for family, name in (
+                (metrics.TEXT_PREFILL_KEY_BLOCKS, "key_blocks"),
+                (metrics.TEXT_DECODE_KEY_BLOCKS, "decode_key_blocks")):
+            read, total = counts[name]
+            family.inc(read, read="yes")
+            family.inc(total - read, read="no")
         for phase, pairs in zip(("prefill", "decode"),
                                 counts["attention_pairs"]):
             metrics.TEXT_ATTENTION_PAIRS.inc(pairs, phase=phase)

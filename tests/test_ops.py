@@ -223,6 +223,80 @@ def test_diffusion_attention_traces_to_the_parents_jaxpr(cell, call,
         == _PARENT_JAXPR_SHA256[cell, call]
 
 
+# ---- the decode's sweep is a second entry of the causal kernel's module:
+# the prefill's call is as it was (ISSUE 34) --------------------------------
+# sha256 of ``str(jax.make_jaxpr(...))`` of ``attention(causal=True)`` at
+# the two text cells' prefill shapes, taken on the commit BEFORE the
+# kernel body learned of rows that share a position (e2e2d2b), under this
+# suite's conftest: the kernel's jaxpr, grid and block mappings are in
+# that text. Traced as a TPU process traces it (the Mosaic call, not
+# the interpreter's), like the cross-lowering below, whose cached trace
+# it shares.
+
+_PARENT_PREFILL_JAXPR_SHA256 = {
+    32: "f53c4801f53280967ce368584d6ee3423b68e50b5c703712b3f302b244b764ad",
+    128: "68d95684f3253799aef34836c928e3bc30f8ef23b78673de610e6c0391726516",
+}
+
+
+def _bf16_spec(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("heads", list(_PARENT_PREFILL_JAXPR_SHA256),
+                         ids=["ling-32-heads", "deepseek-128-heads"])
+def test_the_prefills_causal_call_traces_to_the_parents_jaxpr(heads):
+    """One 2048-token chunk against 16,384 slots with the shared rotary
+    key: at one row a position the generalised kernel body is the
+    parent's program letter for letter (so bit for bit in its results)."""
+    import hashlib
+    from unittest import mock
+
+    def fn(q, k, v, q_offset, q_rotary, k_rotary):
+        return attention(q, k, v, scale=192 ** -0.5, causal=True,
+                         q_offset=q_offset, shared_key=(q_rotary, k_rotary))
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = str(jax.make_jaxpr(fn)(
+            _bf16_spec(1, 2048, heads, 128),
+            _bf16_spec(1, 16384, heads, 128),
+            _bf16_spec(1, 16384, heads, 128),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            _bf16_spec(1, 2048, heads, 64), _bf16_spec(1, 16384, 64)))
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _PARENT_PREFILL_JAXPR_SHA256[heads]
+
+
+@pytest.mark.parametrize("rows", [16 * 128, 32 * 32],
+                         ids=["deepseek-16-rows-of-128-heads",
+                              "ling-32-rows-of-32-heads"])
+def test_the_decodes_sweep_cross_lowers_for_tpu_at_the_text_cells_shapes(
+        rows):
+    """Every row's heads as query rows, 512 + 64 wide, against the
+    16,384 shared latents with a traced prompt length, through
+    ``ops.attention``: one Mosaic call named apart from the prefill's,
+    handed the cache as it lies, twice (no (16384, 512) copy of the
+    latents, no (16384, 128) padded copy of the rotary columns)."""
+    from unittest import mock
+
+    from chiaswarm_tpu.ops.attention import shared_latent_attention
+
+    def fn(q, cache, prompt_len):
+        return shared_latent_attention(q, cache, prompt_len,
+                                       value_width=512, scale=192 ** -0.5)
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = jax.jit(fn).trace(
+            _bf16_spec(rows, 576), _bf16_spec(16384, 576),
+            jax.ShapeDtypeStruct((), jnp.int32),
+        ).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "shared_latent_attention" in text
+    assert "x16384x576xbf16" in text
+    assert "16384x512x" not in text and "16384x128x" not in text
+
+
 def test_causal_flash_cross_lowers_for_tpu_at_the_text_cells_shapes():
     """One 2048-token chunk of 32 heads (128-wide keys and values, the
     64-wide rotary key shared) against 16,384 slots, through
@@ -232,11 +306,9 @@ def test_causal_flash_cross_lowers_for_tpu_at_the_text_cells_shapes():
         return attention(q, k, v, scale=192 ** -0.5, causal=True,
                          q_offset=q_offset, shared_key=(q_rotary, k_rotary))
 
-    def spec(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-
     from unittest import mock
 
+    spec = _bf16_spec
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         lowered = jax.jit(fn).trace(
             spec(1, 2048, 32, 128), spec(1, 16384, 32, 128),

@@ -232,6 +232,7 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
             "chiaswarm_moe_experts_hit_total",
             "chiaswarm_moe_layer_steps_total",
             "chiaswarm_text_prefill_key_blocks_total",
+            "chiaswarm_text_decode_key_blocks_total",
             "chiaswarm_text_kda_blocks_total",
             "chiaswarm_text_cache_bytes")}
 
@@ -297,6 +298,11 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
     for family in after:
         assert f"# TYPE {family} " in served
     assert 'chiaswarm_text_prefill_key_blocks_total{read="yes"}' in served
+    # the decode's sweep: 15 steps of one layer, the 32 slots one block
+    # that holds the prompt's 19 tokens
+    swept = "chiaswarm_text_decode_key_blocks_total"
+    assert (moved(swept, "yes"), moved(swept, "no")) == (15, 0)
+    assert 'chiaswarm_text_decode_key_blocks_total{read="yes"} ' in served
     # 19 tokens in chunks of 8, sub-chunks of 4: 7 layers x 3 x 2 blocks,
     # each the whole sub-chunk, so none is a product
     kda = "chiaswarm_text_kda_blocks_total"

@@ -11,7 +11,10 @@ suffixes) and the held-experts layer. This stack's own:
 - **the query** goes through a bottleneck: ``c_q = RMSNorm(W_dq x)``
   (``q_lora_rank`` wide), then ``W_uq c_q`` to the heads' nope and rope
   parts;
-- **YaRN**: of the rotary frequencies ``f_i = theta^(-2i/d)`` the fast
+- **YaRN** (``text_layers.yarn_band`` / ``yarn_frequencies`` /
+  ``yarn_mscale``, which the Laguna stack calls too; this module keeps
+  the readings of its own ``rope_scaling`` group): of the rotary
+  frequencies ``f_i = theta^(-2i/d)`` the fast
   ones (more than ``beta_fast`` turns over the original context) stay,
   the slow ones (fewer than ``beta_slow``) are divided by ``factor``,
   those between are blended linearly in i; the softmax scale is
@@ -35,7 +38,6 @@ values a token a layer, whatever the head count.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, ClassVar
 
 import jax
@@ -118,37 +120,27 @@ TINY = DeepseekConfig(
     dtype="float32")
 
 
-# ---- YaRN ------------------------------------------------------------------
+# ---- YaRN (models/text_layers.py computes it; these read the config) ------
+
+yarn_mscale = text_layers.yarn_mscale
 
 
-def yarn_mscale(factor: float, mscale: float) -> float:
-    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+def _yarn_keys(cfg: DeepseekConfig) -> tuple:
+    y = cfg.rope_scaling
+    return (cfg.qk_rope_head_dim, cfg.rope_theta,
+            y.original_max_position_embeddings, y.beta_fast, y.beta_slow)
 
 
 def yarn_band(cfg: DeepseekConfig) -> tuple[int, int]:
-    """(low, high): frequency pairs below ``low`` keep their frequency,
-    pairs from ``high`` on are interpolated, those between blended."""
-    y, dim = cfg.rope_scaling, cfg.qk_rope_head_dim
-
-    def pair_with(turns: float) -> float:
-        return dim * math.log(y.original_max_position_embeddings
-                              / (turns * 2 * math.pi)) \
-            / (2 * math.log(cfg.rope_theta))
-
-    return (max(math.floor(pair_with(y.beta_fast)), 0),
-            min(math.ceil(pair_with(y.beta_slow)), dim - 1))
+    """(low, high) over the ``qk_rope_head_dim`` rotated values."""
+    return text_layers.yarn_band(*_yarn_keys(cfg))
 
 
 def yarn_frequencies(cfg: DeepseekConfig) -> np.ndarray:
     """The ``qk_rope_head_dim // 2`` rotary frequencies, float32."""
-    dim = cfg.qk_rope_head_dim
-    low, high = yarn_band(cfg)
-    plain = cfg.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
-                   0.0, 1.0)
-    keep = 1.0 - ramp
-    return (plain / cfg.rope_scaling.factor * (1.0 - keep)
-            + plain * keep).astype(np.float32)
+    dim, theta, *rest = _yarn_keys(cfg)
+    return text_layers.yarn_frequencies(dim, theta, cfg.rope_scaling.factor,
+                                        *rest)
 
 
 def rope_amplitude(cfg: DeepseekConfig) -> float:
@@ -208,19 +200,7 @@ def param_shapes(cfg: DeepseekConfig) -> dict[str, Any]:
 def random_params(cfg: DeepseekConfig, seed: int = 0) -> dict[str, Any]:
     """Host-side random weights for tiny presets (tests, the registry's
     ``allow_random``): projections fan-in scaled, norm gains one."""
-    rng = np.random.RandomState(seed)
-
-    def fill(path, spec):
-        name = path[-1].key
-        if name.endswith("norm"):
-            value = np.ones(spec.shape, np.float32)
-        elif name == "embed":
-            value = rng.normal(0.0, 1.0, spec.shape)
-        else:  # (..., fan_in, fan_out)
-            value = rng.normal(0.0, spec.shape[-2] ** -0.5, spec.shape)
-        return jnp.asarray(value, spec.dtype)
-
-    return jax.tree_util.tree_map_with_path(fill, param_shapes(cfg))
+    return text_layers.random_fill(param_shapes(cfg), seed)
 
 
 # ---- the layers ------------------------------------------------------------

@@ -1,7 +1,9 @@
-"""Layers the text stacks share (models/ling.py, models/deepseek.py):
-the norm, the SwiGLU and the rotary pieces, latent attention (MLA) with
-its compressed cache, and the expert layer of which a chip holds a part.
-One implementation of each; a stack tells them what differs.
+"""Layers the text stacks share (models/ling.py, models/deepseek.py,
+models/laguna.py): the norm, the SwiGLU and the rotary pieces (YaRN
+among them), latent attention (MLA) with its compressed cache, attention
+over plain keys and values with its two kinds of cache, and the expert
+layer of which a chip holds a part. One implementation of each; a stack
+tells them what differs.
 
 - **Latent attention.** The cache holds 512 normed latent + 64 rotated
   key values a token (``kv_lora_rank + qk_rope_head_dim``), one entry for
@@ -16,6 +18,22 @@ One implementation of each; a stack tells them what differs.
   its own norm), its rotary frequencies (plain, or YaRN's), the softmax
   scale, and does what follows the heads' read-out itself (a gate or
   none, then W_o).
+- **Keys and values.** Grouped-query attention over a cache of each
+  token's keys and values (``kv_heads x head_dim`` of each). A stack
+  hands in the layer's head count, its rotary frequencies (as many as
+  half the values of a head that are rotated: all of them, or the first
+  part), their amplitude, the softmax scale and, for a sliding layer,
+  the window. A FULL layer's cache has the capacity's slots; its prefill
+  writes a chunk and attends causally over what is written, its decode
+  sweeps the prompt's keys and values once a key-value head for all
+  rows (``ops.attention.shared_prompt_attention``) and joins each row's
+  own suffix exactly, as the latent core does. A SLIDING layer's cache
+  holds the last ``window`` entries whatever the capacity: its prefill
+  lays them and the chunk's into one local buffer of ``window + chunk``
+  slots and attends under the window (``ops.attention(window=...)``),
+  its decode scores those ``window`` entries and the row's suffix in one
+  masked softmax (a few hundred keys: no kernel), and is right when the
+  suffix outgrows the window.
 - **Experts.** The layer is told which experts it holds
   (``experts_held``), routes over all of them by the stack's router
   (sigmoid with a bias and normalised weights, or softmax over groups'
@@ -37,13 +55,23 @@ bfloat16 pass on a TPU otherwise).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from chiaswarm_tpu.ops.attention import attention, shared_latent_attention
+from chiaswarm_tpu.ops.attention import (
+    attention,
+    shared_latent_attention,
+    shared_prompt_attention,
+)
 from chiaswarm_tpu.ops.causal_flash_attention import (
     key_block,
+    prompt_key_block,
     shared_key_block,
+    stepped_pairs,
+    window_key_block,
 )
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -91,6 +119,37 @@ def rope(x, positions, inv_freq, amplitude: float = 1.0):
                            axis=-1)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_band(dim: int, theta: float, original: int, beta_fast: float,
+              beta_slow: float) -> tuple[int, int]:
+    """(low, high) of YaRN over ``dim`` rotated values: frequency pairs
+    below ``low`` keep their frequency (more than ``beta_fast`` turns
+    over the ``original`` positions), pairs from ``high`` on are
+    interpolated (fewer than ``beta_slow``), those between blended."""
+    def pair_with(turns: float) -> float:
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    return (max(math.floor(pair_with(beta_fast)), 0),
+            min(math.ceil(pair_with(beta_slow)), dim - 1))
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float, original: int,
+                     beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's ``dim // 2`` rotary frequencies, float32: the plain ones
+    ``theta^(-2i/dim)`` below the band, divided by ``factor`` past it, a
+    linear ramp in i between."""
+    low, high = yarn_band(dim, theta, original, beta_fast, beta_slow)
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
+                   0.0, 1.0)
+    keep = 1.0 - ramp
+    return (plain / factor * (1.0 - keep) + plain * keep).astype(np.float32)
+
+
 def logits_of(params, cfg, x):
     """Hidden states (..., d) -> float32 logits over the slice held."""
     h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
@@ -99,6 +158,25 @@ def logits_of(params, cfg, x):
 
 def param_bytes(params) -> int:
     return sum(x.nbytes for x in jax.tree.leaves(params))
+
+
+def random_fill(shapes, seed: int):
+    """Host-side random weights in a checkpoint layout (a pytree of
+    ShapeDtypeStruct), for tiny presets: kernels (..., fan_in, fan_out)
+    fan-in scaled, the embedding unit normal, norm gains one."""
+    rng = np.random.RandomState(seed)
+
+    def fill(path, spec):
+        name = path[-1].key
+        if name.endswith("norm"):
+            value = np.ones(spec.shape, np.float32)
+        elif name == "embed":
+            value = rng.normal(0.0, 1.0, spec.shape)
+        else:
+            value = rng.normal(0.0, spec.shape[-2] ** -0.5, spec.shape)
+        return jnp.asarray(value, spec.dtype)
+
+    return jax.tree_util.tree_map_with_path(fill, shapes)
 
 
 # ---- latent attention ----------------------------------------------------
@@ -267,6 +345,212 @@ def attention_pairs(layers: int, prompt_tokens: int, rows: int,
     steps = new - 1
     decode = rows * (steps * (prompt_tokens + 1) + steps * (steps - 1) // 2)
     return layers * prefill, layers * decode
+
+
+# ---- keys and values: grouped-query attention, full or windowed -------------
+
+
+def _rotated(x, positions, inv_freq, amplitude: float):
+    """x (B, T, H, D) with the first ``2 x len(inv_freq)`` values of
+    every head rotated (all of them, or a leading part: the rest passes
+    as it is, unscaled)."""
+    width = 2 * inv_freq.shape[0]
+    part = rope(jnp.swapaxes(x[..., :width], 1, 2), positions, inv_freq,
+                amplitude)
+    part = jnp.swapaxes(part, 1, 2).astype(x.dtype)
+    if width == x.shape[-1]:
+        return part
+    return jnp.concatenate([part, x[..., width:]], axis=-1)
+
+
+def _queries_keys_values(p, x, positions, heads: int, kv_heads: int,
+                         inv_freq, amplitude: float):
+    """q (B, T, H, D) and k, v (B, T, Hk, D) of the normed input x
+    (B, T, d), q and k rotated at ``positions`` (T,)."""
+    b_, t, _ = x.shape
+    q = proj(x, p["wq"]).reshape(b_, t, heads, -1)
+    k = proj(x, p["wk"]).reshape(b_, t, kv_heads, -1)
+    v = proj(x, p["wv"]).reshape(b_, t, kv_heads, -1)
+    return (_rotated(q, positions, inv_freq, amplitude),
+            _rotated(k, positions, inv_freq, amplitude), v)
+
+
+def _written(cache, k, v, at):
+    return {"k": jax.lax.dynamic_update_slice_in_dim(cache["k"], k, at,
+                                                     axis=1),
+            "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], v, at,
+                                                     axis=1)}
+
+
+def kv_prefill(p, x, cache, pos, n_valid, *, heads: int, inv_freq,
+               scale: float, rope_amplitude: float = 1.0,
+               window: int | None = None):
+    """The heads' read-out (1, T, H, D) of the normed x (1, T, d) at
+    positions [pos, pos + T), of which the first ``n_valid`` are tokens,
+    and the cache with their entries written. ``cache`` = {"k", "v"}
+    (1, S, Hk, D).
+
+    A full layer (``window`` None): S is the capacity, slot c holds
+    position c, the chunk is written at ``pos`` and attends causally
+    over what is written. A sliding layer: S = ``window``, the cache
+    holds the ``window`` entries before ``pos`` (slot c holds position
+    ``max(pos - window, 0) + c``; slots past ``pos`` hold nothing while
+    the sequence is shorter than the window). They and the chunk's are
+    laid into one local buffer of ``window + T`` slots, over which a
+    query sees the ``window`` keys up to its own; the ``window`` entries
+    before ``pos + n_valid`` are what the next call (or the decode)
+    gets."""
+    kv_heads = cache["k"].shape[2]
+    t = x.shape[1]
+    q, k, v = _queries_keys_values(p, x, pos + jnp.arange(t), heads,
+                                   kv_heads, inv_freq, rope_amplitude)
+    if window is None:
+        cache = _written(cache, k, v, pos)
+        o = attention(q, cache["k"], cache["v"], scale=scale, causal=True,
+                      q_offset=pos)
+        return o, cache
+    first = jnp.maximum(pos - window, 0)        # position of slot 0
+    local = _written(
+        {name: jnp.concatenate([cache[name], jnp.zeros_like(new)], axis=1)
+         for name, new in (("k", k), ("v", v))}, k, v, pos - first)
+    o = attention(q, local["k"], local["v"], scale=scale, causal=True,
+                  q_offset=pos - first, window=window)
+    keep = jnp.maximum(pos + n_valid - window, 0) - first
+    return o, {name: jax.lax.dynamic_slice_in_dim(whole, keep, window,
+                                                  axis=1)
+               for name, whole in local.items()}
+
+
+def kv_decode(p, x, prompt, prompt_len, suffix, step, *, heads: int,
+              inv_freq, scale: float, rope_amplitude: float = 1.0,
+              window: int | None = None):
+    """One new token a row: the heads' read-out (R, 1, H, D) of the
+    normed x (R, 1, d) at position ``prompt_len + step`` and the suffix
+    with the rows' entries written. ``prompt`` = {"k", "v"} (1, S, Hk, D)
+    is what ``kv_prefill`` left, shared by the rows; ``suffix`` = {"k",
+    "v"} (R, N, Hk, D) is each row's own (slot n holds position
+    ``prompt_len + n``; [0, step] valid after this call's write).
+
+    A full layer: the prompt's part is one key-blocked sweep on the chip
+    (``ops.attention.shared_prompt_attention``: a key-value head's keys
+    and values read once for all rows and its G query heads, no
+    (R, H, S) array in memory, blocks past ``prompt_len`` not read); the
+    suffix's part stays here, and the two partial softmaxes are joined
+    by their log-sum-exps in float32. A sliding layer: the prompt's last
+    ``window`` entries and the suffix in one masked softmax over
+    ``window + N`` keys; a key is seen while it lies less than ``window``
+    behind the query, so the prompt's entries leave one by one and, once
+    ``step >= window``, the suffix's oldest too."""
+    kv_heads = prompt["k"].shape[2]
+    q, k, v = _queries_keys_values(p, x, (prompt_len + step)[None], heads,
+                                   kv_heads, inv_freq, rope_amplitude)
+    suffix = _written(suffix, k, v, step)
+    rows, n = suffix["k"].shape[:2]
+    q = q[:, 0].reshape(rows, kv_heads, heads // kv_heads, -1)
+    slot = jnp.arange(n)
+    own = slot <= step
+    if window is not None:
+        own &= slot > step - window
+    s_own = jnp.einsum("rkgd,rnkd->rkgn", q, suffix["k"],
+                       preferred_element_type=jnp.float32)
+    s_own = jnp.where(own, s_own, NEG_INF) * scale
+    if window is None:
+        o_prompt, lse_prompt = shared_prompt_attention(
+            q.reshape(rows, heads, -1), prompt["k"][0], prompt["v"][0],
+            prompt_len, scale=scale)
+        o_prompt = o_prompt.reshape(q.shape)
+        lse_prompt = lse_prompt.reshape(q.shape[:3])
+        lse = jnp.logaddexp(lse_prompt, jax.nn.logsumexp(s_own, axis=-1))
+        w_own = jnp.exp(s_own - lse[..., None]).astype(x.dtype)
+        o = o_prompt * jnp.exp(lse_prompt - lse)[..., None] \
+            + jnp.einsum("rkgn,rnkd->rkgd", w_own, suffix["v"],
+                         preferred_element_type=jnp.float32)
+    else:
+        position = jnp.maximum(prompt_len - window, 0) + jnp.arange(window)
+        seen = (position < prompt_len) \
+            & (position > prompt_len + step - window)
+        s_prompt = jnp.einsum("rkgd,ckd->rkgc", q, prompt["k"][0],
+                              preferred_element_type=jnp.float32)
+        s_prompt = jnp.where(seen, s_prompt, NEG_INF) * scale
+        w = jax.nn.softmax(jnp.concatenate([s_prompt, s_own], axis=-1),
+                           axis=-1).astype(x.dtype)
+        o = jnp.einsum("rkgc,ckd->rkgd", w[..., :window], prompt["v"][0],
+                       preferred_element_type=jnp.float32) \
+            + jnp.einsum("rkgn,rnkd->rkgd", w[..., window:], suffix["v"],
+                         preferred_element_type=jnp.float32)
+    return o.reshape(rows, 1, heads, -1).astype(x.dtype), suffix
+
+
+def empty_kv_cache(cfg, slots: int, rows: int = 1):
+    """{"k", "v"} of ``slots`` empty entries a row."""
+    shape = (rows, slots, cfg.num_key_value_heads, cfg.head_dim)
+    return {name: jnp.zeros(shape, jnp.dtype(cfg.dtype))
+            for name in ("k", "v")}
+
+
+def kv_cache_bytes(cfg, layers: int, rows: int, slots: int,
+                   max_new: int) -> int:
+    """Bytes of ``layers`` layers' keys and values in a decode of
+    ``rows`` rows: the prompt's ``slots`` entries once, ``max_new`` a
+    row."""
+    return layers * (slots + rows * max_new) * 2 \
+        * cfg.num_key_value_heads * cfg.head_dim \
+        * jnp.dtype(cfg.dtype).itemsize
+
+
+# what the host knows of a job's grouped-query layers, for the counters
+
+
+def kv_key_blocks(layers: int, prompt_tokens: int, new: int, chunk: int,
+                  capacity: int) -> tuple[tuple[int, int], tuple[int, int]]:
+    """Of ``layers`` FULL layers: ((key blocks the prefill reads over a
+    prompt's chunks, those of the whole capacity over the same chunks),
+    (key blocks the decode's sweep reads over ``new - 1`` steps, those of
+    the whole capacity))."""
+    prefill = prefill_key_blocks(layers, prompt_tokens, chunk, capacity)
+    block = prompt_key_block(capacity)
+    steps = layers * (new - 1)
+    return prefill, (steps * -(-prompt_tokens // block),
+                     steps * -(-capacity // block))
+
+
+def window_key_blocks(layers: int, prompt_tokens: int, chunk: int,
+                      window: int) -> tuple[int, int]:
+    """Of ``layers`` SLIDING layers: (key blocks of the local buffer up
+    to a chunk's end, summed over a prompt's chunks; blocks of the whole
+    buffer over the same chunks)."""
+    block = window_key_block(window + chunk)
+    starts = range(0, prompt_tokens, chunk)
+    return (layers * sum(-(-(min(pos, window) + chunk) // block)
+                         for pos in starts),
+            layers * len(starts) * -(-(window + chunk) // block))
+
+
+def window_pairs(heads: int, kv_heads: int, prompt_tokens: int, rows: int,
+                 new: int, chunk: int, window: int
+                 ) -> dict[str, tuple[int, int]]:
+    """Query-key pairs of ONE sliding layer a head, (prefill, decode):
+    ``visible`` = inside the window (a query at position p sees ``min(p
+    + 1, window)`` keys); ``scored`` = what is computed for them, masked
+    or not: in the prefill every key of every block the kernel steps
+    (``stepped_pairs``, of the G heads' rows of a key-value head, so
+    over G), in the decode the ``window + new`` slots (the prompt's
+    window and the whole suffix) a row's step scores."""
+    g = heads // kv_heads
+    steps = new - 1
+
+    def seen(first: int, count: int) -> int:
+        """sum of min(p + 1, window) over p in [first, first + count)."""
+        rising = max(min(first + count, window) - first, 0)
+        return rising * (2 * first + rising + 1) // 2 \
+            + (count - rising) * window
+
+    scored = sum(stepped_pairs(chunk * g, g, min(pos, window),
+                               window + chunk, window)
+                 for pos in range(0, prompt_tokens, chunk)) // g
+    return {"visible": (seen(0, prompt_tokens),
+                        rows * seen(prompt_tokens, steps)),
+            "scored": (scored, rows * steps * (window + new))}
 
 
 # ---- experts ---------------------------------------------------------------

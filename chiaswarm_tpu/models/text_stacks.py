@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import importlib
 
-NAMES = ("ling", "deepseek")
+NAMES = ("ling", "deepseek", "laguna")
 DEFAULT = "ling"
 
 

@@ -84,6 +84,18 @@ SMOKE_JOBS: dict[str, dict[str, Any]] = {
         "logprobs": True,
         "content_type": "application/json",
     },
+    "txt2txt_laguna": {
+        # the third text stack (models/laguna.py): window and full
+        # attention over plain keys and values
+        "id": "smoke-txt2txt-laguna",
+        "workflow": "txt2txt",
+        "model_name": "random/laguna_tiny",
+        "prompt": "ab cd ab ba",
+        "max_new_tokens": 4,
+        "num_return_sequences": 2,
+        "logprobs": True,
+        "content_type": "application/json",
+    },
     "tts": {
         # the reference's bark smoke job (swarm/test.py:45-51)
         "id": "smoke-tts",
@@ -162,7 +174,9 @@ def run_smoke(workflow: str, random_weights: bool = True) -> dict[str, Any]:
     registry = ModelRegistry(
         catalog=[{"name": "tiny", "family": "tiny"},
                  {"name": "random/deepseek_tiny", "stack": "deepseek",
-                  "prefill_chunk": 8, "max_context": 32}],
+                  "prefill_chunk": 8, "max_context": 32},
+                 {"name": "random/laguna_tiny", "stack": "laguna",
+                  "prefill_chunk": 16, "max_context": 64}],
         allow_random=random_weights,
     )
     pool = ChipPool(n_slots=1)

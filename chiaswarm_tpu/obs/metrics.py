@@ -947,36 +947,53 @@ MOE_LAYER_STEPS = REGISTRY.counter(
     "chiaswarm_moe_layer_steps_total",
     "decode steps x expert layers the experts-hit count was summed over")
 
-#: key blocks of the latent-attention prefill: those the causal kernel's
+#: key blocks of the attention layers' prefill: those the causal kernel's
 #: bound admits at each chunk's position ("yes") against the rest of the
-#: cache's capacity ("no"), per latent-attention layer; from what the
-#: host knows of a job (positions, chunk, capacity, the kernel's block)
+#: cache's capacity ("no"; of a sliding layer's local buffer), per
+#: attention layer; from what the host knows of a job (positions, chunk,
+#: capacity, the kernel's block)
 TEXT_PREFILL_KEY_BLOCKS = REGISTRY.counter(
     "chiaswarm_text_prefill_key_blocks_total",
-    "key blocks of the latent-attention prefill, by whether the causal "
+    "key blocks of the attention layers' prefill, by whether the causal "
     "kernel's bound admits them or they lie past the written cache",
     labelnames=("read",))
 
-#: key blocks of the latent-attention decode's sweep over the prompt's
-#: shared latents: those up to the prompt's length, which the kernel
-#: reads ("yes"), against the rest of the capacity, which its clamped
-#: index map leaves ("no"); per latent-attention layer and decode step,
-#: from what the host knows of a job (prompt, steps, capacity, the block)
+#: key blocks of the decode's sweep over the prompt's shared entries
+#: (latents, or a full layer's keys and values): those up to the prompt's
+#: length, which the kernel reads ("yes"), against the rest of the
+#: capacity, which its clamped index map leaves ("no"); per such layer
+#: and decode step, from what the host knows of a job (prompt, steps,
+#: capacity, the block)
 TEXT_DECODE_KEY_BLOCKS = REGISTRY.counter(
     "chiaswarm_text_decode_key_blocks_total",
-    "key blocks of the latent-attention decode, by whether they hold a "
-    "prompt token and are read or lie past the prompt and are not",
+    "key blocks of the decode's sweep over the shared prompt, by whether "
+    "they hold a prompt token and are read or lie past the prompt and "
+    "are not",
     labelnames=("read",))
 
-#: query-key pairs one head scores in the latent-attention layers: a
+#: query-key pairs one head scores in the softmax-attention layers: a
 #: prompt token against the tokens up to itself (prefill), a decode step
-#: against the prompt and the row's suffix up to its own entry; summed
-#: over those layers, chunks or steps, and rows, from host integers
+#: against the prompt and the row's suffix up to its own entry, in a
+#: sliding layer only those inside the window; summed over those layers,
+#: chunks or steps, and rows, from host integers
 TEXT_ATTENTION_PAIRS = REGISTRY.counter(
     "chiaswarm_text_attention_pairs_total",
-    "query-key pairs a head scores in the latent-attention layers, by "
-    "phase",
+    "query-key pairs a head scores in the softmax-attention layers "
+    "(under the window in a sliding layer), by phase",
     labelnames=("phase",))
+
+#: query-key pairs of the sliding-window layers, a head: "visible" =
+#: inside the window (what ``attention_pairs`` counts for those layers),
+#: "scored" = what is computed for them, masked or not: every key of
+#: every block the windowed kernel steps in the prefill, the window's
+#: and the suffix's slots a decode step scores; summed over sliding
+#: layers, chunks or steps, and rows, from host integers. scored /
+#: visible = 1 is a sweep that steps only what the window shows
+TEXT_WINDOW_PAIRS = REGISTRY.counter(
+    "chiaswarm_text_window_pairs_total",
+    "query-key pairs of the sliding-window layers a head, by whether "
+    "they are inside the window (visible) or computed for it (scored)",
+    labelnames=("kind",))
 
 #: sub-blocks of the delta-rule prefill's in-chunk matrices, by how
 #: ``kda_chunked`` builds them: "pairwise" decays on the diagonal,
@@ -988,10 +1005,11 @@ TEXT_KDA_BLOCKS = REGISTRY.counter(
     "whether they are built from pairwise decays or as a product",
     labelnames=("form",))
 
-#: bytes of the two kinds of cache the last decode held
+#: bytes of each kind of cache the last decode held
 TEXT_CACHE_BYTES = REGISTRY.gauge(
     "chiaswarm_text_cache_bytes",
-    "cache bytes of the last text decode, by kind (latent / recurrent)",
+    "cache bytes of the last text decode, by kind (latent / recurrent / "
+    "full / window)",
     labelnames=("kind",))
 
 

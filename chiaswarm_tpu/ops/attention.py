@@ -212,6 +212,26 @@ def shared_latent_attention(q: jnp.ndarray, keys: jnp.ndarray, n_keys, *,
     return sweep(q, keys, n_keys, value_width=value_width, scale=scale)
 
 
+def shared_prompt_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                            n_keys, *, scale: float | None = None
+                            ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Rows q (R, H, D), all behind the first ``n_keys`` (traced, at
+    least 1) of k (S, Hk, D) / v (S, Hk, Dv), H a multiple of Hk:
+    grouped-query attention's decode over a prompt the rows share.
+    Returns (the softmax read-out over those keys (R, H, Dv) float32,
+    each row's log-sum-exp (R, H) float32, by which the caller joins
+    further keys of its own). One path, the causal kernel's sweep with a
+    key-value head's rows at one position
+    (ops/causal_flash_attention.py; Pallas interpret mode off the chip):
+    a head's keys and values are read once for all rows, the scores stay
+    in VMEM and key blocks past ``n_keys`` are not read."""
+    from chiaswarm_tpu.ops.causal_flash_attention import (
+        shared_prompt_attention as sweep,
+    )
+
+    return sweep(q, k, v, n_keys, scale=scale)
+
+
 def attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -222,6 +242,7 @@ def attention(
     causal: bool = False,
     q_offset=0,
     shared_key: tuple[jnp.ndarray, jnp.ndarray] | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Multi-head scaled dot-product attention, (B, L, H, D) layout.
 
@@ -236,22 +257,32 @@ def attention(
     (``CHIASWARM_ATTENTION`` does not apply). Keys and values may differ
     in head size there, and ``shared_key`` = (q_shared (B, L, H, R),
     k_shared (B, S, R)) adds a key part that every head shares to the
-    logits (``scale`` then defaults to ``(D + R) ** -0.5``)."""
+    logits (``scale`` then defaults to ``(D + R) ** -0.5``). Keys and
+    values may also have fewer heads than the queries (grouped-query
+    attention: query head j reads key-value head j // (H / Hk), whose
+    blocks are fetched once for the group), and ``window`` = w hides the
+    keys more than w - 1 behind a query (the same kernel body under its
+    own operation name, ``window_flash_attention``: a key block wholly
+    outside every window of a query block is neither read nor
+    scored)."""
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError(f"expected (B, L, H, D) tensors, got {q.shape}")
     if causal:
         if impl not in ("auto", "flash"):
             raise ValueError(f"attention impl {impl!r} has no causal mask; "
                              "causal=True takes 'auto' or 'flash'")
-        from chiaswarm_tpu.ops.causal_flash_attention import (
-            causal_flash_attention,
-        )
+        from chiaswarm_tpu.ops import causal_flash_attention as kernel
 
-        return causal_flash_attention(q, k, v, q_offset, shared_key,
-                                      scale=scale)
-    if shared_key is not None:
-        raise ValueError("shared_key is the causal kernel's operand; "
-                         "pass causal=True with it")
+        if window is not None:
+            if shared_key is not None:
+                raise ValueError("a window takes no shared key part")
+            return kernel.window_flash_attention(q, k, v, q_offset,
+                                                 window=window, scale=scale)
+        return kernel.causal_flash_attention(q, k, v, q_offset, shared_key,
+                                             scale=scale)
+    if shared_key is not None or window is not None:
+        raise ValueError("shared_key and window are the causal kernel's "
+                         "operands; pass causal=True with them")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     env_forced = False

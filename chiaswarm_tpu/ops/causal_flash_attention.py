@@ -1,13 +1,21 @@
-"""Pallas TPU causal flash attention over a partly written cache, in two
+"""Pallas TPU causal flash attention over a partly written cache, in four
 entries that share one kernel body, one set of index maps and one
 ``pallas_call``:
 
 - ``causal_flash_attention``, a chunked prefill: one chunk of queries at
   absolute positions ``[q_offset, q_offset + L)`` against a cache of ``S``
-  key slots of which only ``[0, q_offset + L)`` are written;
+  key slots of which only ``[0, q_offset + L)`` are written; with fewer
+  key-value heads than query heads (grouped-query attention) the G
+  heads of a key-value head go through as G rows of one position;
+- ``window_flash_attention``, the same under a sliding window: a query
+  sees the ``window`` keys up to its own, and a key block wholly outside
+  the window of every query of a block is neither fetched nor stepped;
 - ``shared_latent_attention``, latent attention's decode in the absorbed
   form: every row's heads as query rows, ALL at the one position behind
-  the prompt, against the prompt's latents as one key/value head.
+  the prompt, against the prompt's latents as one key/value head;
+- ``shared_prompt_attention``, grouped-query attention's decode over a
+  prompt the rows share: a key-value head's rows x G query rows at one
+  position against its separate keys and values, with the log-sum-exp.
 
 The local flash kernel (ops/flash_attention.py) sweeps every key block of
 every call and masks block padding only. This one is its causal twin; it
@@ -130,7 +138,8 @@ def _at_group(x, g: int):
 def _causal_kernel(offset_ref, *refs, scale: float, block_q: int,
                    block_kv: int, has_shared: bool, g: int = 1,
                    values_are_keys: bool = False, with_stats: bool = False,
-                   shared_lanes: int | None = None):
+                   shared_lanes: int | None = None,
+                   window: int | None = None, n_rows: int | None = None):
     refs = list(refs)
     q_ref = refs.pop(0)
     qs_ref = refs.pop(0) if has_shared else None
@@ -164,7 +173,13 @@ def _causal_kernel(offset_ref, *refs, scale: float, block_q: int,
             # keys end where its rows do: ``key_block``)
             col = first_key + jax.lax.broadcasted_iota(
                 jnp.int32, (block_kv, 1), 0)
-            v = jnp.where(col * g <= last_row, v, jnp.zeros_like(v))
+            last = last_row
+            if n_rows is not None:
+                # a call's last query block is padded with rows past its
+                # ``n_rows``, which would "see" slots nobody wrote
+                last = jnp.minimum(
+                    last, _at_group(offset_ref[0], g) + n_rows - 1)
+            v = jnp.where(col * g <= last, v, jnp.zeros_like(v))
         return v
 
     def shared_part():
@@ -184,18 +199,31 @@ def _causal_kernel(offset_ref, *refs, scale: float, block_q: int,
             scale=scale, kv_len=None, col_offset=first_key,
             row_offset=first_row if masked else None,
             shared=shared_part() if has_shared else None,
-            rows_per_position=g,
+            rows_per_position=g, window=window,
         )
         acc_scr[:] = acc_next
         m_scr[:] = jnp.broadcast_to(m_next, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_next, l_scr.shape)
 
-    # wholly below the diagonal: every key of the block is visible
-    pl.when(last_col <= first_row)(functools.partial(update, False))
-    # on the diagonal; a block wholly above it (first_col > last_row)
-    # is skipped, and the index map has not fetched it either
-    pl.when((last_col > first_row) & (first_col <= last_row))(
-        functools.partial(update, True))
+    if window is None:
+        # wholly below the diagonal: every key of the block is visible
+        pl.when(last_col <= first_row)(functools.partial(update, False))
+        # on the diagonal; a block wholly above it (first_col > last_row)
+        # is skipped, and the index map has not fetched it either
+        pl.when((last_col > first_row) & (first_col <= last_row))(
+            functools.partial(update, True))
+    else:
+        # a row sees the ``window`` keys up to its own: a block is whole
+        # when it lies below the diagonal AND inside every row's window,
+        # skipped when wholly above the diagonal or wholly below every
+        # row's window (the index map has fetched neither), masked
+        # otherwise
+        span = _at_group(window, g)
+        whole = (last_col <= first_row) & (first_col + span > last_row)
+        reached = (first_col <= last_row) & (last_col + span > first_row)
+        pl.when(whole)(functools.partial(update, False))
+        pl.when(reached & jnp.logical_not(whole))(
+            functools.partial(update, True))
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
@@ -205,23 +233,39 @@ def _causal_kernel(offset_ref, *refs, scale: float, block_q: int,
 
 
 def _index_maps(block_q: int, block_kv: int, g: int = 1,
-                shared_tile: int = 0):
+                shared_tile: int = 0, window: int | None = None,
+                n_kv: int | None = None):
     """(queries' and output's, per-head keys' and values', the shared key
     part's) block index maps over the grid (batch, head, query block, key
     block) with the scalar-prefetched offset: a key block's index is
     clamped to the last block that holds a key visible to the query
-    block, so a block past it is neither fetched nor scored. The shared
-    part is column block ``shared_tile`` of its operand."""
+    block, so a block past it is neither fetched nor scored; under a
+    ``window`` also to the first block that holds one, so that a block
+    wholly below the window of every row is not fetched either. The
+    shared part is column block ``shared_tile`` of its operand. ``n_kv``
+    (the grouped entries give it) keeps the index inside the operand
+    where padded rows sit past the last key."""
     def q_index(bi, hi, i, j, offset):
         return (bi, i, hi)
 
     def last_block(i, offset):
         """The last key block with a key visible to query block ``i``."""
         last_row = _at_group(offset[0], g) + (i + 1) * block_q - 1
-        return (last_row if g == 1 else last_row // g) // block_kv
+        last = (last_row if g == 1 else last_row // g) // block_kv
+        return last if n_kv is None else jnp.minimum(last, n_kv - 1)
+
+    def first_block(i, offset):
+        """The first key block with a key inside the window of query
+        block ``i``'s first row."""
+        first_row = _at_group(offset[0], g) + i * block_q
+        first = first_row if g == 1 else first_row // g
+        return jnp.maximum(first - window + 1, 0) // block_kv
 
     def kv_index(bi, hi, i, j, offset):
-        return (bi, jnp.minimum(j, last_block(i, offset)), hi)
+        j = jnp.minimum(j, last_block(i, offset))
+        if window is not None:
+            j = jnp.maximum(j, first_block(i, offset))
+        return (bi, j, hi)
 
     def shared_index(bi, hi, i, j, offset):
         return (bi, jnp.minimum(j, last_block(i, offset)), shared_tile)
@@ -315,6 +359,12 @@ def causal_flash_attention(
             ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if k.shape[2] != h:
+        if has_shared:
+            raise ValueError("grouped query heads take no shared key part")
+        return _grouped_prefill(q, k, v, q_offset, scale=scale,
+                                block_q=block_q, block_kv=block_kv,
+                                interpret=interpret)
     block_q = _clamp_block(l, _BLOCK_Q if block_q is None else block_q)
     block_kv = key_block(l, s) if block_kv is None \
         else _clamp_block(s, block_kv)
@@ -339,6 +389,195 @@ def causal_flash_attention(
         out_dtype=q.dtype, interpret=interpret, scale=scale,
         has_shared=has_shared)
     return of[:, :l].reshape(b, l, h, dvp)[..., :dv]
+
+
+# ---- grouped query heads: G of them read one key-value head --------------
+
+# Key rows a block of the windowed sweep holds: half a 1024-row query
+# block's reach (1024 rows of 8 heads are 128 positions, whose windows of
+# 512 keys span 639), so that a query block steps two or three blocks of
+# the local buffer and not the 1024-key blocks' two (2,048 keys).
+_WINDOW_BLOCK_KV = 512
+
+
+def window_key_block(keys: int) -> int:
+    """Key rows a block of ``window_flash_attention`` holds."""
+    return _clamp_block(keys, _WINDOW_BLOCK_KV)
+
+
+def stepped_pairs(rows: int, g: int, q_offset: int, keys: int,
+                  window: int) -> int:
+    """(query row, key) pairs of the key blocks ``window_flash_attention``
+    steps, masked or not, summed over the query blocks of ``rows`` rows
+    (``g`` a position, the first at ``q_offset``) against ``keys``
+    slots: the kernel's own two tests (``_causal_kernel``) on host
+    integers, for the counters."""
+    block_q = _clamp_block(rows, _BLOCK_Q)
+    block_kv = window_key_block(keys)
+    stepped = 0
+    for first_row in range(q_offset * g, q_offset * g + rows, block_q):
+        last_row = first_row + block_q - 1
+        for first_key in range(0, keys, block_kv):
+            last_key = first_key + block_kv - 1
+            stepped += first_key * g <= last_row \
+                and (last_key + window) * g > first_row
+    return stepped * block_q * block_kv
+
+
+def _by_key_value_head(x: jnp.ndarray, hk: int) -> jnp.ndarray:
+    """(B, L, H, D) -> (B, L * G, Hk * Dp): the G = H / Hk query heads
+    of one key-value head as G consecutive rows of one position, D
+    zero-padded to the lane tile."""
+    x = _pad_to(x, 3, _LANES)
+    b, l, h, dp = x.shape
+    return jnp.swapaxes(x.reshape(b, l, hk, h // hk, dp), 2, 3).reshape(
+        b, l * (h // hk), hk * dp)
+
+
+def _grouped_prefill(q, k, v, q_offset, *, scale, block_q, block_kv,
+                     interpret, window: int | None = None,
+                     name: str | None = None):
+    """``causal_flash_attention`` for q (B, L, H, D) over k (B, S, Hk, D)
+    / v (B, S, Hk, Dv) with H = G x Hk: query head j reads key-value
+    head j // G. The G heads of a key-value head go through the sweep as
+    G rows of one position (the kernel's ``g``), so its key and value
+    blocks are fetched once for the whole group; under ``window`` a
+    query sees only the ``window`` keys up to its own."""
+    b, l, h, _ = q.shape
+    s, hk, dv = k.shape[1], k.shape[2], v.shape[3]
+    g = h // hk
+    if g * hk != h:
+        raise ValueError(f"{h} query heads over {hk} key-value heads")
+    rows = l * g
+    block_q = _clamp_block(rows, _BLOCK_Q if block_q is None else block_q)
+    if block_kv is not None:
+        block_kv = _clamp_block(s, block_kv)
+    elif window is None:
+        block_kv = key_block(l, s)
+    else:
+        block_kv = window_key_block(s)
+    n_kv = -(-s // block_kv)
+    q_index, kv_index, _ = _index_maps(block_q, block_kv, g, window=window,
+                                       n_kv=n_kv)
+    qf, kf, vf = _by_key_value_head(q, hk), _fold(k), _fold(v)
+    dvp = vf.shape[2] // hk
+    operands = [(qf, (1, block_q, qf.shape[2] // hk), q_index),
+                (kf, (1, block_kv, kf.shape[2] // hk), kv_index),
+                (vf, (1, block_kv, dvp), kv_index)]
+    of = _sweep(q_offset, operands,
+                grid=(b, hk, -(-rows // block_q), n_kv), out_width=dvp,
+                out_dtype=q.dtype, interpret=interpret, name=name,
+                scale=scale, has_shared=False, g=g, n_rows=rows,
+                window=window)
+    of = of[:, :rows].reshape(b, l, g, hk, dvp)
+    return jnp.swapaxes(of, 2, 3).reshape(b, l, h, dvp)[..., :dv]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("window", "scale", "block_q", "block_kv", "interpret"),
+)
+def window_flash_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    q_offset,
+    *,
+    window: int,
+    scale: float | None = None,
+    block_q: int | None = None,
+    block_kv: int | None = None,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Sliding-window causal attention of q (B, L, H, D) at positions
+    ``q_offset + l`` over k (B, S, Hk, D) / v (B, S, Hk, Dv), H a
+    multiple of Hk: query ``l`` sees keys ``q_offset + l - window < s <=
+    q_offset + l``; ``q_offset + L <= S``. A key block wholly below the
+    window of every query of a block is neither fetched nor stepped, the
+    block on the window's edge is masked. Its own operation name in a
+    device trace. ``scale`` defaults to ``D ** -0.5``."""
+    if scale is None:
+        scale = float(q.shape[3]) ** -0.5
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _grouped_prefill(q, k, v, q_offset, scale=scale, block_q=block_q,
+                            block_kv=block_kv, interpret=interpret,
+                            window=int(window),
+                            name="window_flash_attention")
+
+
+# The full layers' decode (``shared_prompt_attention``): a job's rows x the
+# G heads of one key-value head are a few hundred query rows (32 x 6), so
+# a grid step is bound by its two key and value blocks' copies, 512 KB
+# each at 2048 keys; fewer, larger steps.
+_PROMPT_BLOCK_KV = 2048
+
+
+def prompt_key_block(capacity: int) -> int:
+    """Key rows a block of ``shared_prompt_attention`` holds against
+    ``capacity`` slots."""
+    return _clamp_block(capacity, _PROMPT_BLOCK_KV)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("scale", "block_q", "block_kv", "interpret"),
+)
+def shared_prompt_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    n_keys,
+    *,
+    scale: float | None = None,
+    block_q: int | None = None,
+    block_kv: int | None = None,
+    interpret: bool | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Rows q (R, H, D) that all sit behind the first ``n_keys`` (traced,
+    at least 1) of k (S, Hk, D) / v (S, Hk, Dv), H = G x Hk: grouped-query
+    attention's decode over a prompt that the rows share. A key-value
+    head's R x G query rows go through the sweep at ONE position, so its
+    keys and values are read once a call for all rows. Returns (the
+    softmax read-out over those keys (R, H, Dv) in float32, the
+    log-sum-exp of each row's scaled logits (R, H) float32), by which a
+    caller joins further keys' partial softmax to this one exactly. Key
+    blocks past ``n_keys`` are not read. ``scale`` defaults to ``D **
+    -0.5``."""
+    r, h, d = q.shape
+    s, hk, dv = v.shape
+    g_heads = h // hk
+    if g_heads * hk != h:
+        raise ValueError(f"{h} query heads over {hk} key-value heads")
+    if scale is None:
+        scale = float(d) ** -0.5
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    n = r * g_heads
+    block_q = _clamp_block(n, _DECODE_BLOCK_Q if block_q is None
+                           else block_q)
+    block_kv = prompt_key_block(s) if block_kv is None \
+        else _clamp_block(s, block_kv)
+    rows = -(-n // block_q) * block_q
+    n_kv = -(-s // block_kv)
+    # every row, padding included, at the one position n_keys - 1
+    q_index, kv_index, _ = _index_maps(block_q, block_kv, rows, n_kv=n_kv)
+    qf = _by_key_value_head(q[None], hk)
+    kf, vf = _fold(k[None]), _fold(v[None])
+    dvp = vf.shape[2] // hk
+    operands = [(qf, (1, block_q, qf.shape[2] // hk), q_index),
+                (kf, (1, block_kv, kf.shape[2] // hk), kv_index),
+                (vf, (1, block_kv, dvp), kv_index)]
+    o, stats = _sweep(
+        jnp.asarray(n_keys, jnp.int32) - 1, operands,
+        grid=(1, hk, rows // block_q, n_kv), out_width=dvp,
+        out_dtype=jnp.float32, interpret=interpret,
+        name="shared_prompt_attention", scale=scale, has_shared=False,
+        g=rows, with_stats=True)
+    o = o[0, :n].reshape(r, g_heads, hk, dvp)
+    stats = stats[0, :n].reshape(r, g_heads, hk, _LANES)[..., 0]
+    return (jnp.swapaxes(o, 1, 2).reshape(r, h, dvp)[..., :dv],
+            jnp.swapaxes(stats, 1, 2).reshape(r, h))
 
 
 @functools.partial(

@@ -67,7 +67,8 @@ _LANES = 128
 def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
                                 scale: float, kv_len, col_offset,
                                 row_offset=None, shared=None,
-                                rows_per_position: int = 1):
+                                rows_per_position: int = 1,
+                                window: int | None = None):
     """One KV block of the running-softmax recurrence, shared by the
     local flash kernel below, the fused ring kernel
     (ops/ring_flash_attention.py) and the causal prefill kernel
@@ -94,7 +95,11 @@ def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
     cast to float32 for every caller: on a v5e the decode read the same
     numbers in the same time with them cast to bfloat16 instead (PERF.md,
     PR 34: the MXU takes a float32 product at default precision in one
-    bfloat16 pass either way)."""
+    bfloat16 pass either way).
+
+    The sliding layers' one, off by default too: ``window`` = w, row
+    ``r`` at position ``r // g`` sees only the w keys up to its own,
+    ``r // g - w < c``, tested as ``(c + w) * g > r``."""
     logits = jax.lax.dot_general(
         q, k, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -116,8 +121,11 @@ def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
         else:
             if rows_per_position != 1:
                 col = col * rows_per_position
-            visible = col <= row_offset + jax.lax.broadcasted_iota(
+            row = row_offset + jax.lax.broadcasted_iota(
                 jnp.int32, logits.shape, 0)
+            visible = col <= row
+            if window is not None:
+                visible &= col + window * rows_per_position > row
         logits = jnp.where(visible, logits, _NEG_INF)
 
     m_cur = jnp.max(logits, axis=-1, keepdims=True)

@@ -6,7 +6,9 @@ The repo's first prefill/decode split. The model is a text stack
 configuration it reads), and the caches are the stack's own carry: a
 fixed-size float32 recurrent state plus a conv tail for each
 linear-attention layer beside a growing latent cache for each
-latent-attention layer in one stack, latent caches alone in another.
+latent-attention layer in one stack, latent caches alone in another,
+full layers' keys and values beside sliding layers' last window of them
+in a third.
 
 - ``text_prefill``: one chunk of ``prefill_chunk`` tokens of one row,
   the caches carried in and out, called once a chunk with the chunk's
@@ -276,6 +278,9 @@ class TextPipeline:
             pairwise, product = counts["kda_blocks"]
             metrics.TEXT_KDA_BLOCKS.inc(pairwise, form="pairwise")
             metrics.TEXT_KDA_BLOCKS.inc(product, form="product")
+        # a stack with sliding-window layers
+        for kind, pairs in counts.get("window_pairs", {}).items():
+            metrics.TEXT_WINDOW_PAIRS.inc(pairs, kind=kind)
         metrics.MOE_LAYER_STEPS.inc((new - 1) * counts["expert_layers"])
         for kind, size in self.c.stack.cache_bytes(
                 cfg, rows, self.max_context, new).items():

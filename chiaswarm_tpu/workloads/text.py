@@ -11,9 +11,10 @@ log-probability of each sampled token, at temperature 1, when
 ``logprobs`` is set). Errors are swallowed into an error artifact, by
 img2txt's convention (workloads/caption.py).
 
-The model is a Ling-3.0-flash-class decoder (models/ling.py) served
-resident through the registry, two compiled programs a model
-(pipelines/text.py); row i of a job samples from the key of seed + i.
+The model is one of the text stacks (models/text_stacks.py), named by
+its catalog entry and served resident through the registry, two compiled
+programs a model (pipelines/text.py); row i of a job samples from the
+key of seed + i.
 """
 
 from __future__ import annotations

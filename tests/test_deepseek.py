@@ -318,7 +318,7 @@ def test_both_stacks_are_found_by_the_name_their_configuration_gives():
 
     assert text_stacks.get(CFG.stack) is deepseek
     assert text_stacks.get(ling.LING_TINY.stack) is ling
-    assert set(text_stacks.NAMES) == {"ling", "deepseek"}
+    assert set(text_stacks.NAMES) == {"ling", "deepseek", "laguna"}
     for name in text_stacks.NAMES:
         stack = text_stacks.get(name)
         assert stack.TINY.stack == name

@@ -316,3 +316,50 @@ def test_causal_flash_cross_lowers_for_tpu_at_the_text_cells_shapes():
             spec(1, 2048, 32, 64), spec(1, 16384, 64),
         ).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
+
+
+# ---- the grouped-query entries of the causal kernel's module (ISSUE 35) ----
+
+
+@pytest.mark.parametrize("entry", ["causal_flash_attention",
+                                   "window_flash_attention",
+                                   "shared_prompt_attention"])
+def test_the_grouped_entries_cross_lower_for_tpu_at_the_laguna_cells_shapes(
+        entry):
+    """A full layer's chunk (48 heads over 8 key-value heads against
+    16,384 slots), a sliding layer's (64 heads against its 512 + 2,048
+    local buffer, window 512) and a full layer's decode (32 rows x 48
+    heads behind a traced prompt length), through ``ops.attention``: one
+    Mosaic call each, under its own operation name, with the keys and
+    values handed over as they lie (no copy repeated to the query
+    heads)."""
+    from unittest import mock
+
+    from chiaswarm_tpu.ops.attention import shared_prompt_attention
+
+    spec, scalar = _bf16_spec, jax.ShapeDtypeStruct((), jnp.int32)
+    if entry == "shared_prompt_attention":
+        def fn(q, k, v, n):
+            return shared_prompt_attention(q, k, v, n, scale=128 ** -0.5)
+
+        shapes = (spec(32, 48, 128), spec(16384, 8, 128),
+                  spec(16384, 8, 128), scalar)
+    else:
+        windowed = entry == "window_flash_attention"
+
+        def fn(q, k, v, q_offset):
+            return attention(q, k, v, scale=128 ** -0.5, causal=True,
+                             q_offset=q_offset,
+                             window=512 if windowed else None)
+
+        slots, heads = (2560, 64) if windowed else (16384, 48)
+        shapes = (spec(1, 2048, heads, 128), spec(1, slots, 8, 128),
+                  spec(1, slots, 8, 128), scalar)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(fn).trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert entry in text
+    keys = shapes[1].shape[-3]
+    assert f"x{keys}x1024xbf16" in text          # 8 heads of 128, as stored
+    assert f"x{keys}x6144x" not in text and f"x{keys}x8192x" not in text

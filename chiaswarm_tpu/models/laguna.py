@@ -339,9 +339,9 @@ def job_counts(cfg: LagunaConfig, prompt_tokens: int, rows: int, new: int,
                chunk: int, capacity: int) -> dict[str, Any]:
     """What the host knows of one job's two programs, for the counters
     (``pipelines/text.py::TextPipeline._count``): key blocks the kernels
-    read and leave, query-key pairs a head scores by phase (under the
-    window in a sliding layer), the window's pairs visible and scored,
-    the expert layers."""
+    read and leave, their grid steps by kind, query-key pairs a head
+    scores by phase (under the window in a sliding layer), the window's
+    pairs visible and scored, the expert layers."""
     full, sliding = cfg.layers_of(FULL), cfg.layers_of(SLIDING)
     window = cfg.sliding_window
     key_blocks, decode_key_blocks = text_layers.kv_key_blocks(
@@ -355,7 +355,14 @@ def job_counts(cfg: LagunaConfig, prompt_tokens: int, rows: int, new: int,
     # (prefill, decode) inside the window, summed over the sliding layers
     seen = [sum(layer["visible"][phase] for layer in of_sliding)
             for phase in (0, 1)]
+    kv_heads = cfg.num_key_value_heads
+    block_steps = [text_layers.prefill_block_steps(
+        1, kv_heads, cfg.num_attention_heads_per_layer[i] // kv_heads,
+        prompt_tokens, chunk, capacity, window if i in sliding else None)
+        for i in (*full, *sliding)]
     return {
+        "block_steps": {kind: sum(layer[kind] for layer in block_steps)
+                        for kind in block_steps[0]},
         "key_blocks": tuple(a + b for a, b in zip(key_blocks, windowed)),
         "decode_key_blocks": decode_key_blocks,
         "attention_pairs": tuple(a + b for a, b in zip(of_full, seen)),

@@ -562,12 +562,16 @@ def job_counts(cfg: LingConfig, prompt_tokens: int, rows: int, new: int,
     """What the host knows of one job's two programs, for the counters
     (``pipelines/text.py::TextPipeline._count``): key blocks the causal
     kernel reads and leaves in the prefill and in the decode, query-key
-    pairs a head scores by phase, the delta-rule prefill's sub-blocks by
-    form, the expert layers."""
+    pairs a head scores by phase, the prefill kernel's grid steps by
+    kind, the delta-rule prefill's sub-blocks by form, the expert
+    layers."""
     layers = len(cfg.mla_layers)
     return {
         "key_blocks": prefill_key_blocks(cfg, prompt_tokens, chunk,
                                          capacity),
+        "block_steps": text_layers.prefill_block_steps(
+            layers, cfg.num_attention_heads, 1, prompt_tokens, chunk,
+            capacity),
         "decode_key_blocks": text_layers.decode_key_blocks(
             layers, prompt_tokens, new, capacity),
         "attention_pairs": text_layers.attention_pairs(

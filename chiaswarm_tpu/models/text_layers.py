@@ -67,6 +67,7 @@ from chiaswarm_tpu.ops.attention import (
     shared_prompt_attention,
 )
 from chiaswarm_tpu.ops.causal_flash_attention import (
+    block_steps,
     key_block,
     prompt_key_block,
     shared_key_block,
@@ -322,6 +323,27 @@ def prefill_key_blocks(layers: int, prompt_tokens: int, chunk: int,
     starts = range(0, prompt_tokens, chunk)
     return (layers * sum(-(-(pos + chunk) // block) for pos in starts),
             layers * len(starts) * -(-capacity // block))
+
+
+def prefill_block_steps(layers: int, grid_heads: int, g: int,
+                        prompt_tokens: int, chunk: int, capacity: int,
+                        window: int | None = None) -> dict[str, int]:
+    """Grid steps of ``layers`` like attention layers' prefill kernel
+    over a prompt's chunks by kind (``whole`` / ``diagonal`` / ``dead``),
+    summed over a layer's ``grid_heads`` heads of the grid (the query
+    heads of a latent layer; the key-value heads of a grouped one, ``g``
+    query heads' rows each). Sliding layers (``window``) sweep their
+    local buffer of ``window + chunk`` slots."""
+    total = {"whole": 0, "diagonal": 0, "dead": 0}
+    for pos in range(0, prompt_tokens, chunk):
+        if window is None:
+            steps = block_steps(chunk, g, pos, capacity)
+        else:
+            steps = block_steps(chunk, g, min(pos, window), window + chunk,
+                                window)
+        for kind in total:
+            total[kind] += layers * grid_heads * steps[kind]
+    return total
 
 
 def decode_key_blocks(layers: int, prompt_tokens: int, new: int,

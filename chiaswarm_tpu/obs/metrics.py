@@ -951,12 +951,30 @@ MOE_LAYER_STEPS = REGISTRY.counter(
 #: bound admits at each chunk's position ("yes") against the rest of the
 #: cache's capacity ("no"; of a sliding layer's local buffer), per
 #: attention layer; from what the host knows of a job (positions, chunk,
-#: capacity, the kernel's block)
+#: capacity, the kernel's block). It counts memory traffic only: what a
+#: block that is not read costs in grid steps is the next family's
 TEXT_PREFILL_KEY_BLOCKS = REGISTRY.counter(
     "chiaswarm_text_prefill_key_blocks_total",
     "key blocks of the attention layers' prefill, by whether the causal "
-    "kernel's bound admits them or they lie past the written cache",
+    "kernel's bound admits them and they are fetched or they lie past "
+    "the written cache and are not (a fetch saved, not a grid step: see "
+    "chiaswarm_text_prefill_block_steps_total)",
     labelnames=("read",))
+
+#: grid steps of the attention layers' prefill kernels by what the kernel
+#: does in them, summed over the grid's heads, query blocks, chunks and
+#: layers (``ops/causal_flash_attention.py::block_steps``, the kernel's
+#: own tests on host integers): "whole" = a block pair scored without a
+#: mask; "diagonal" = the sub-tiles scored in the pairs the diagonal or a
+#: window's edge crosses (a pair masked whole counts as one); "dead" = a
+#: step entered and left with nothing to do, above the diagonal or below
+#: every window (0.25 us each on a v5e: PERF.md, PR 36)
+TEXT_PREFILL_BLOCK_STEPS = REGISTRY.counter(
+    "chiaswarm_text_prefill_block_steps_total",
+    "grid steps of the attention layers' prefill kernels: block pairs "
+    "scored whole, sub-tiles scored where the diagonal crosses a pair, "
+    "and steps that hold no work",
+    labelnames=("kind",))
 
 #: key blocks of the decode's sweep over the prompt's shared entries
 #: (latents, or a full layer's keys and values): those up to the prompt's

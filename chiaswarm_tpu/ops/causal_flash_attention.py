@@ -18,9 +18,10 @@ entries that share one kernel body, one set of index maps and one
   position against its separate keys and values, with the log-sum-exp.
 
 The local flash kernel (ops/flash_attention.py) sweeps every key block of
-every call and masks block padding only. This one is its causal twin; it
-shares ``online_softmax_block_update`` with it and nothing that picks
-shapes:
+every call and masks block padding only. This one is its causal twin; its
+two decode entries share ``online_softmax_block_update`` with it, its two
+prefill entries have an update of their own (``_update_by_tiles``), and
+nothing that picks shapes is shared:
 
 - grid = (batch, heads, Q blocks, KV blocks), KV innermost, the running
   max / denominator / accumulator in float32 VMEM scratch, as there.
@@ -29,19 +30,45 @@ shapes:
   prompt length of a decode). The key blocks' index map clamps the block
   index to the last block that holds a key visible to this query block,
   so a block past it is never fetched (the pipeline does not copy a
-  block index it already holds) and its grid step is skipped. What lies
-  past the last visible key's block is therefore not read at all: it may
-  hold anything.
+  block index it already holds). What lies past the last visible key's
+  block is therefore not read at all: it may hold anything.
+- **the prefill's grid ends where the written cache does** (PR 36): the
+  key axis of the two prefill entries is ``ceil((q_offset + L) /
+  block_kv)`` steps long, a grid dimension traced with the offset
+  (``_written_blocks``), not the capacity's. A step past the last
+  visible block fetched nothing but was entered and left, 0.25 us each on
+  a v5e: 120 of a head's 256 steps over a 16,384-token job's chunks.
 - inside that bound, a key block wholly above the diagonal of a query
-  block is skipped, one on the diagonal is masked, one wholly below it
-  is not masked at all.
+  block is skipped, one wholly below it is not masked at all, and one
+  the diagonal crosses is, in the prefill at one row a position, **cut
+  to the diagonal**: its rows go in tiles of a quarter of the block,
+  each against the keys up to its own last row and no further, so that
+  10 of a 2048-row chunk's 16 sub-tiles of 512 x 512 are scored and 4
+  of them masked (by a constant; ``_update_by_tiles``). Where the
+  diagonal does not enter a block pair at a multiple of the smaller
+  block (an offset off the grid; a job's chunks make none), with rows
+  that share a position and under a window the pair is masked whole by
+  the traced positions, as every crossed block was before.
+- **the 128-head sweep** (PR 36; a v5e, one 2048-token chunk of 128
+  heads at each of a job's eight positions against 16,384 slots, ms a
+  layer a job, the kernel's call timed alone): the parent's 1024 x 1024
+  blocks 135.2; the grid's bound alone 131.3; with the update by tiles
+  120.0; then by (query x key) block: 2048 x 1024 **115.0**, 1024 x 2048
+  117.7, 2048 x 2048 120.3, 512 x 2048 122.3, 512 x 1024 129.8, 2048 x
+  512 135.2, 1024 x 512 142.4, 512 x 512 157.9. The compiler's own
+  schedule of the kernel (its final bundles, dumped off the chip:
+  PERF.md, PR 36) says why a step stands where it does: the four MXUs'
+  slots are full in nine tenths of a step's bundles (one 16-row push
+  every 12 bundles each), so what is left is the MXU's own work, three
+  128-deep passes a pair for the 320 lanes that are counted.
 - **rows that share a position** (``g``): query row ``r`` sits at
   position ``q_offset + r // g`` and sees key ``c`` when ``c <= q_offset
   + r // g``, tested as ``c * g <= q_offset * g + r`` (no division on the
-  vector unit). ``g`` = 1 is the prefill, whose program is letter for
-  letter what it was before ``g`` came (tests/test_ops.py holds its
-  jaxpr's digest); ``g`` = all rows with ``q_offset = prompt_len - 1`` is
-  the decode (``g`` = heads would be a multi-query prefill; no caller).
+  vector unit). ``g`` = 1 is the latent prefill; ``g`` = all rows with
+  ``q_offset = prompt_len - 1`` is the decode, whose two programs are
+  letter for letter what they were before the prefill's schedule changed
+  (tests/test_ops.py holds their jaxprs' digests; ``g`` = heads would
+  be a multi-query prefill; no caller).
   With ``g`` > 1 the rows of a block end inside a key block, so the
   block that holds the last visible key also holds slots nobody wrote:
   there the values past it are zeroed (a masked key's probability is 0,
@@ -89,11 +116,18 @@ from chiaswarm_tpu.ops.flash_attention import (
     online_softmax_block_update,
 )
 
-# 1024 x 1024 from a sweep of the kernel alone on a v5e at the text
-# cell's sizes (2048 queries x 32 heads against 16,384 slots, eight chunks:
-# PERF.md, PR 30): 26.6 ms a job; 2048-row or 2048-key blocks the same
-# within 3%, 512-key blocks 75% slower, 512-row blocks 14% slower.
-_BLOCK_Q = 1024
+# The prefill's blocks. 2048 x 1024 from the sweep at 128 heads on a v5e
+# (PERF.md, PR 36; the module's docstring has every pair): a chunk of
+# 2,048 tokens at one row a position is ONE query block, so with the
+# grid's traced bound no step past the diagonal is entered at all, and
+# its keys come in the 1024 rows that ``latent_prefill`` up-projects at a
+# time. The grouped entries at the Laguna cell's shapes, ms a layer a
+# job with 2048 / 1024 / 512 query rows a block: the full layers' 28.30 /
+# 30.21 / 33.91 (2048-key blocks 31.18), the windowed 7.40 / 8.09 / 9.79.
+# PR 30's sweep at 32 heads (26.6 ms a job at 1024 x 1024, 2048-row or
+# 2048-key blocks the same within 3%, 512-key blocks 75% slower) was of
+# the update that PR 36 replaced.
+_BLOCK_Q = 2048
 _BLOCK_KV = 1024
 _VMEM_MB = 48  # the kernel-scoped cap it was swept under (a guard only)
 
@@ -139,7 +173,8 @@ def _causal_kernel(offset_ref, *refs, scale: float, block_q: int,
                    block_kv: int, has_shared: bool, g: int = 1,
                    values_are_keys: bool = False, with_stats: bool = False,
                    shared_lanes: int | None = None,
-                   window: int | None = None, n_rows: int | None = None):
+                   window: int | None = None, n_rows: int | None = None,
+                   by_tiles: bool = False):
     refs = list(refs)
     q_ref = refs.pop(0)
     qs_ref = refs.pop(0) if has_shared else None
@@ -199,37 +234,150 @@ def _causal_kernel(offset_ref, *refs, scale: float, block_q: int,
             scale=scale, kv_len=None, col_offset=first_key,
             row_offset=first_row if masked else None,
             shared=shared_part() if has_shared else None,
-            rows_per_position=g, window=window,
+            rows_per_position=g,
         )
         acc_scr[:] = acc_next
         m_scr[:] = jnp.broadcast_to(m_next, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_next, l_scr.shape)
 
-    if window is None:
-        # wholly below the diagonal: every key of the block is visible
+    if by_tiles:
+        # the prefill's schedule: a block is whole when it lies below the
+        # diagonal AND inside every row's window, skipped when wholly
+        # above the diagonal or wholly below every row's window (the
+        # index map has fetched neither), crossed otherwise
+        whole, reached = last_col <= first_row, first_col <= last_row
+        if window is not None:
+            span = _at_group(window, g)
+            whole &= first_col + span > last_row
+            reached &= last_col + span > first_row
+        crossed = reached & jnp.logical_not(whole)
+        by_cut = functools.partial(
+            _update_by_tiles, q_ref, qs_ref, k_ref, ks_ref, v_ref,
+            (m_scr, l_scr, acc_scr), scale=scale, g=g, window=window,
+            first_row=first_row, first_key=first_key, values=values)
+        pl.when(whole)(functools.partial(by_cut, None))
+        # one row a position and no window: chunks and blocks of whole
+        # multiples let the diagonal enter a pair at one of few keys,
+        # and such a pair is cut to it; at any other offset, as with
+        # rows that share a position or a window, it is masked whole
+        entries = _diagonal_entries(block_q, block_kv) \
+            if g == 1 and window is None else ()
+        for cut in entries:
+            pl.when(crossed & (first_key - first_row == cut))(
+                functools.partial(by_cut, cut))
+            crossed &= first_key - first_row != cut
+        pl.when(crossed)(functools.partial(by_cut, True))
+    else:
+        # the decode's: one query block at one position. Wholly below
+        # the diagonal: every key of the block is visible
         pl.when(last_col <= first_row)(functools.partial(update, False))
         # on the diagonal; a block wholly above it (first_col > last_row)
         # is skipped, and the index map has not fetched it either
         pl.when((last_col > first_row) & (first_col <= last_row))(
             functools.partial(update, True))
-    else:
-        # a row sees the ``window`` keys up to its own: a block is whole
-        # when it lies below the diagonal AND inside every row's window,
-        # skipped when wholly above the diagonal or wholly below every
-        # row's window (the index map has fetched neither), masked
-        # otherwise
-        span = _at_group(window, g)
-        whole = (last_col <= first_row) & (first_col + span > last_row)
-        reached = (first_col <= last_row) & (last_col + span > first_row)
-        pl.when(whole)(functools.partial(update, False))
-        pl.when(reached & jnp.logical_not(whole))(
-            functools.partial(update, True))
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
+        if by_tiles:    # the denominator's lanes are summed here, once
+            o_ref[0] = (acc_scr[:] / jnp.sum(l_scr[:], axis=-1, keepdims=True)
+                        ).astype(o_ref.dtype)
+            return
         o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
         if with_stats:
             stats_ref[0] = m_scr[:] + jnp.log(l_scr[:])
+
+
+def _diagonal_tile(block_q: int) -> int:
+    """Query rows a tile holds where a block pair is cut to the
+    diagonal: a quarter of the block (512 of 2048 rows), the block itself
+    where a quarter is no whole number of sublane tiles."""
+    return block_q // 4 if block_q % 32 == 0 else block_q
+
+
+def _diagonal_entries(block_q: int, block_kv: int) -> range:
+    """``first_key - first_row`` of the block pairs the diagonal crosses
+    when chunks, offsets and blocks are whole multiples of the smaller
+    block: the only ones a job's chunks make."""
+    step = min(block_q, block_kv)
+    return range(step - block_kv, block_q, step)
+
+
+def _update_by_tiles(q_ref, qs_ref, k_ref, ks_ref, v_ref, scratch, cut, *,
+                     scale: float, g: int, window, first_row, first_key,
+                     values):
+    """One block pair of the prefill's running softmax. ``cut``: None,
+    every pair of it is visible: one update over the whole pair; an
+    integer, the diagonal enters it at key ``first_row + cut`` (static):
+    the rows go in tiles of ``_diagonal_tile``, each against the keys up
+    to its last row's and no further, masked by a constant where the
+    diagonal crosses; True, masked by the traced positions (any offset,
+    rows that share a position, a window).
+
+    What it does otherwise than ``online_softmax_block_update``, each
+    judged on a v5e at the DeepSeek cell's shape (PERF.md, PR 36; ms a
+    layer's eight chunks, 119.99 with all four): the two products are ONE
+    contraction over the concatenated (128 + 128-padded) keys, so that
+    the partial products meet as they leave the MXU and the first is not
+    stored (+6.7 apart); the probabilities go to the MXU as the values'
+    dtype, which the MXU's float32 pass rounded them to anyway (+1.9);
+    the running max is kept of the UNSCALED logits and ``scale x
+    log2(e)`` is one multiply inside ``exp2`` (+3.2); the denominator is
+    kept a lane apart and summed over lanes at the end (+5.3). Key
+    strips inside a pair are slower (512 keys +7.6, 256 keys +70: the
+    compiler does not overlap a strip's products with the last one's
+    softmax), so a pair's keys go in one piece."""
+    m_scr, l_scr, acc_scr = scratch
+    block_q, block_kv = q_ref.shape[1], k_ref.shape[1]
+    traced = cut is True
+    diagonal = cut is not None and not traced
+    tile = _diagonal_tile(block_q) if diagonal else block_q
+    factor = scale * 1.4426950408889634             # exp(x) = exp2(x log2 e)
+    for top in range(0, block_q, tile):
+        rows = slice(top, top + tile)
+        # local row r sees local key c when c + cut <= r
+        end = min(max(top + tile - cut, 0), block_kv) if diagonal \
+            else block_kv
+        if end == 0:
+            continue                # the tile lies wholly above the diagonal
+        q, k = q_ref[0, rows, :], k_ref[0, :end, :]
+        if qs_ref is not None:
+            q = jnp.concatenate([q, qs_ref[0, rows, :]], axis=1)
+            k = jnp.concatenate([k, ks_ref[0, :end, :]], axis=1)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        visible = None
+        if traced:
+            col = _at_group(
+                first_key + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1),
+                g)
+            row = first_row + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            visible = col <= row
+            if window is not None:
+                visible &= col + _at_group(window, g) > row
+        elif diagonal and end - 1 + cut > top:
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            visible = col + (cut - top) <= row
+        if visible is not None:
+            s = jnp.where(visible, s, _NEG_INF)
+        m_prev = m_scr[rows, :1]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp2((m_prev - m_next) * factor)
+        p = jnp.exp2((s - m_next) * factor)             # (tile, end) fp32
+        if end % _LANES:        # small shapes: the whole sum in lane 0
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+            lanes = jnp.where(lane == 0,
+                              jnp.sum(p, axis=-1, keepdims=True), 0.0)
+        else:
+            lanes = p[:, :_LANES]
+            for t in range(_LANES, end, _LANES):
+                lanes = lanes + p[:, t:t + _LANES]
+        l_scr[rows, :] = alpha * l_scr[rows, :] + lanes
+        v = values(True) if traced and g > 1 else v_ref[0, :end, :]
+        acc_scr[rows, :] = acc_scr[rows, :] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[rows, :] = jnp.broadcast_to(m_next, (tile, _LANES))
 
 
 def _index_maps(block_q: int, block_kv: int, g: int = 1,
@@ -324,6 +472,18 @@ def _sweep(q_offset, operands, *, grid, out_width: int, out_dtype,
       *(x for x, _, _ in operands))
 
 
+def _written_blocks(q_offset, positions: int, block_kv: int, n_kv: int):
+    """The key axis of a prefill's grid: the blocks that hold a slot
+    written by the end of this call, ``ceil((q_offset + positions) /
+    block_kv)`` of the capacity's ``n_kv``; traced with the offset, so
+    that one program serves every chunk and no step is entered past
+    them (a step that only clamps its index cost 0.25 us on a v5e, 120 of
+    a head's 256 a DeepSeek job: PERF.md, PR 36)."""
+    written = (jnp.asarray(q_offset, jnp.int32) + positions + block_kv - 1) \
+        // block_kv
+    return jnp.clip(written, 1, n_kv)
+
+
 def _fold(x: jnp.ndarray) -> jnp.ndarray:
     """(B, N, H, D) -> (B, N, H * Dp), D zero-padded to the lane tile."""
     x = _pad_to(x, 3, _LANES)
@@ -385,18 +545,20 @@ def causal_flash_attention(
     dvp = operands[-1][1][2]
     of = _sweep(
         q_offset, operands,
-        grid=(b, h, -(-l // block_q), -(-s // block_kv)), out_width=dvp,
-        out_dtype=q.dtype, interpret=interpret, scale=scale,
-        has_shared=has_shared)
+        grid=(b, h, -(-l // block_q),
+              _written_blocks(q_offset, l, block_kv, -(-s // block_kv))),
+        out_width=dvp, out_dtype=q.dtype, interpret=interpret, scale=scale,
+        has_shared=has_shared, by_tiles=True)
     return of[:, :l].reshape(b, l, h, dvp)[..., :dv]
 
 
 # ---- grouped query heads: G of them read one key-value head --------------
 
-# Key rows a block of the windowed sweep holds: half a 1024-row query
-# block's reach (1024 rows of 8 heads are 128 positions, whose windows of
-# 512 keys span 639), so that a query block steps two or three blocks of
-# the local buffer and not the 1024-key blocks' two (2,048 keys).
+# Key rows a block of the windowed sweep holds: a 2048-row query block of
+# 8 heads is 256 positions, whose windows of 512 keys span 767, so that
+# it steps two or three blocks of the local buffer and not the 1024-key
+# blocks' two (2,048 keys); 256-key blocks were slower (11.11 ms a layer a
+# job against 8.09 at 1024 query rows: PERF.md, PR 36).
 _WINDOW_BLOCK_KV = 512
 
 
@@ -405,23 +567,74 @@ def window_key_block(keys: int) -> int:
     return _clamp_block(keys, _WINDOW_BLOCK_KV)
 
 
+def _prefill_blocks(positions: int, g: int, keys: int,
+                    window: int | None) -> tuple[int, int]:
+    """(query rows, keys) a block of a prefill entry holds for a call of
+    ``positions`` positions, ``g`` rows each, against ``keys`` slots: the
+    entries' own pick."""
+    return (_clamp_block(positions * g, _BLOCK_Q),
+            key_block(positions, keys) if window is None
+            else window_key_block(keys))
+
+
+def block_steps(positions: int, g: int, q_offset: int, keys: int,
+                window: int | None = None) -> dict[str, int]:
+    """One prefill call's grid steps a head of the grid (a key-value
+    head), by what the kernel does in them; the kernel's own tests
+    (``_causal_kernel``) and its grid's bound (``_written_blocks``) on
+    host integers, for the counters:
+
+    - ``whole``: every pair of the block pair is visible, none masked;
+    - ``diagonal``: the sub-tiles scored in the pairs the diagonal (or a
+      window's edge) crosses: ``_diagonal_tile`` rows x as many keys
+      where the schedule cuts the pair to the diagonal, the pair as one
+      where it is masked whole (rows that share a position, a window, an
+      offset off the block grid);
+    - ``dead``: steps entered and left with nothing to do, above the
+      diagonal or below every row's window;
+    - ``pairs``: the (query row, key) pairs all of those score, masked
+      or not."""
+    block_q, block_kv = _prefill_blocks(positions, g, keys, window)
+    rows = positions * g
+    n_kv = -(-keys // block_kv)
+    steps = min(max(-(-(q_offset + positions) // block_kv), 1), n_kv)
+    tile = _diagonal_tile(block_q)
+    cut_to_diagonal = g == 1 and window is None
+    aligned = _diagonal_entries(block_q, block_kv)
+    count = {"whole": 0, "diagonal": 0, "dead": 0, "pairs": 0}
+    for first_row in range(q_offset * g, q_offset * g + rows, block_q):
+        last_row = first_row + block_q - 1
+        for first_key in range(0, steps * block_kv, block_kv):
+            first_col, last_col = first_key * g, (first_key + block_kv - 1) * g
+            below = last_col <= first_row
+            reached = first_col <= last_row
+            if window is not None:
+                below &= first_col + window * g > last_row
+                reached &= last_col + window * g > first_row
+            if below:
+                count["whole"] += 1
+                count["pairs"] += block_q * block_kv
+            elif not reached:
+                count["dead"] += 1
+            elif cut_to_diagonal and first_key - first_row in aligned:
+                cut = first_key - first_row
+                for top in range(0, block_q, tile):
+                    end = min(max(top + tile - cut, 0), block_kv)
+                    count["diagonal"] += -(-end // tile)
+                    count["pairs"] += tile * end
+            else:
+                count["diagonal"] += 1
+                count["pairs"] += block_q * block_kv
+    return count
+
+
 def stepped_pairs(rows: int, g: int, q_offset: int, keys: int,
                   window: int) -> int:
     """(query row, key) pairs of the key blocks ``window_flash_attention``
     steps, masked or not, summed over the query blocks of ``rows`` rows
     (``g`` a position, the first at ``q_offset``) against ``keys``
-    slots: the kernel's own two tests (``_causal_kernel``) on host
-    integers, for the counters."""
-    block_q = _clamp_block(rows, _BLOCK_Q)
-    block_kv = window_key_block(keys)
-    stepped = 0
-    for first_row in range(q_offset * g, q_offset * g + rows, block_q):
-        last_row = first_row + block_q - 1
-        for first_key in range(0, keys, block_kv):
-            last_key = first_key + block_kv - 1
-            stepped += first_key * g <= last_row \
-                and (last_key + window) * g > first_row
-    return stepped * block_q * block_kv
+    slots, for the counters."""
+    return block_steps(rows // g, g, q_offset, keys, window)["pairs"]
 
 
 def _by_key_value_head(x: jnp.ndarray, hk: int) -> jnp.ndarray:
@@ -465,10 +678,11 @@ def _grouped_prefill(q, k, v, q_offset, *, scale, block_q, block_kv,
                 (kf, (1, block_kv, kf.shape[2] // hk), kv_index),
                 (vf, (1, block_kv, dvp), kv_index)]
     of = _sweep(q_offset, operands,
-                grid=(b, hk, -(-rows // block_q), n_kv), out_width=dvp,
-                out_dtype=q.dtype, interpret=interpret, name=name,
-                scale=scale, has_shared=False, g=g, n_rows=rows,
-                window=window)
+                grid=(b, hk, -(-rows // block_q),
+                      _written_blocks(q_offset, l, block_kv, n_kv)),
+                out_width=dvp, out_dtype=q.dtype, interpret=interpret,
+                name=name, scale=scale, has_shared=False, g=g, n_rows=rows,
+                window=window, by_tiles=True)
     of = of[:, :rows].reshape(b, l, g, hk, dvp)
     return jnp.swapaxes(of, 2, 3).reshape(b, l, h, dvp)[..., :dv]
 

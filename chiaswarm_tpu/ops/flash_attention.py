@@ -67,11 +67,10 @@ _LANES = 128
 def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
                                 scale: float, kv_len, col_offset,
                                 row_offset=None, shared=None,
-                                rows_per_position: int = 1,
-                                window: int | None = None):
+                                rows_per_position: int = 1):
     """One KV block of the running-softmax recurrence, shared by the
     local flash kernel below, the fused ring kernel
-    (ops/ring_flash_attention.py) and the causal prefill kernel
+    (ops/ring_flash_attention.py) and the causal kernel's decode entries
     (ops/causal_flash_attention.py). All operands are plain arrays (the
     callers own the scratch refs): q (bq, d), k/v (bkv, d), m/l (bq, 1)
     running max/denominator, acc (bq, d) fp32 accumulator. ``col_offset``
@@ -97,9 +96,10 @@ def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
     PR 34: the MXU takes a float32 product at default precision in one
     bfloat16 pass either way).
 
-    The sliding layers' one, off by default too: ``window`` = w, row
-    ``r`` at position ``r // g`` sees only the w keys up to its own,
-    ``r // g - w < c``, tested as ``(c + w) * g > r``."""
+    The causal PREFILL no longer comes here (PR 36: its update, by
+    tiles, is ``ops/causal_flash_attention.py::_update_by_tiles``, and
+    the sliding layers' window went with it); the decode's two sweeps
+    do."""
     logits = jax.lax.dot_general(
         q, k, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -124,8 +124,6 @@ def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
             row = row_offset + jax.lax.broadcasted_iota(
                 jnp.int32, logits.shape, 0)
             visible = col <= row
-            if window is not None:
-                visible &= col + window * rows_per_position > row
         logits = jnp.where(visible, logits, _NEG_INF)
 
     m_cur = jnp.max(logits, axis=-1, keepdims=True)

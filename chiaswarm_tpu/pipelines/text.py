@@ -271,6 +271,8 @@ class TextPipeline:
             read, total = counts[name]
             family.inc(read, read="yes")
             family.inc(total - read, read="no")
+        for kind, steps in counts["block_steps"].items():
+            metrics.TEXT_PREFILL_BLOCK_STEPS.inc(steps, kind=kind)
         for phase, pairs in zip(("prefill", "decode"),
                                 counts["attention_pairs"]):
             metrics.TEXT_ATTENTION_PAIRS.inc(pairs, phase=phase)

@@ -695,6 +695,7 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_of_the_third_stack():
             "chiaswarm_moe_experts_hit_total",
             "chiaswarm_moe_layer_steps_total",
             "chiaswarm_text_prefill_key_blocks_total",
+            "chiaswarm_text_prefill_block_steps_total",
             "chiaswarm_text_decode_key_blocks_total",
             "chiaswarm_text_attention_pairs_total",
             "chiaswarm_text_window_pairs_total",
@@ -764,6 +765,12 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_of_the_third_stack():
         >= moved(window, "visible")
     blocks = "chiaswarm_text_prefill_key_blocks_total"
     assert moved(blocks, "yes") == want["key_blocks"][0]
+    # the grouped and the windowed sweep's grid steps: with rows that
+    # share a position every crossed pair is masked whole, one a pair
+    steps = "chiaswarm_text_prefill_block_steps_total"
+    assert {kind: moved(steps, kind) for kind in want["block_steps"]} \
+        == want["block_steps"]
+    assert want["block_steps"]["diagonal"] > 0
     blocks = "chiaswarm_text_decode_key_blocks_total"
     assert moved(blocks, "yes") == 2 * 15
     kda = "chiaswarm_text_kda_blocks_total"

@@ -350,3 +350,99 @@ def test_count_feeds_the_decode_key_blocks_family(stack, tokens, share):
     layers = len(getattr(cfg, "mla_layers", range(cfg.num_hidden_layers)))
     assert yes + no == layers * 63 * 16384 // shared_key_block(16384)
     assert yes == share * (yes + no)
+
+
+# ---- the prefill kernel's grid steps, from host integers (ISSUE 36) --------
+
+
+def test_the_cells_jobs_enter_no_step_past_the_written_cache():
+    """The DeepSeek job (five latent layers of 128 heads, eight chunks of
+    2,048 against 16,384 slots): the parent's grid of 1024 x 1024 pairs
+    entered 76,800 steps with all their pairs visible, 10,240 on the
+    diagonal and 76,800 with nothing to do (120 a head a layer: above
+    the diagonal or past the written cache). One 2,048-row query block
+    against the keys written so far enters none of the last; each of a
+    chunk's two diagonal pairs is cut in 512-row tiles, 10 of whose 16
+    512 x 512 sub-tiles hold a visible pair."""
+    big = deepseek.DeepseekConfig(num_hidden_layers=5)
+    steps = deepseek.job_counts(big, 16384, 16, 64, 2048, 16384)[
+        "block_steps"]
+    grid_heads = 5 * 128
+    assert steps["dead"] == 0
+    assert steps["whole"] == grid_heads * sum(2 * c for c in range(8))
+    assert steps["diagonal"] == grid_heads * 8 * 10
+    # the pairs those steps score: the keys before a chunk whole, the
+    # chunk's own triangle at 10 / 16 of its square (8 / 16 are visible)
+    from chiaswarm_tpu.ops.causal_flash_attention import block_steps
+
+    scored = sum(block_steps(2048, 1, pos, 16384)["pairs"]
+                 for pos in range(0, 16384, 2048))
+    assert scored == sum(2048 * pos + 10 * 512 * 512
+                         for pos in range(0, 16384, 2048))
+    # a quarter-length prompt: two chunks
+    short = deepseek.job_counts(big, 4096, 16, 64, 2048, 16384)[
+        "block_steps"]
+    assert short == {"whole": grid_heads * 2, "diagonal": grid_heads * 20,
+                     "dead": 0}
+    # the Ling job: one latent layer of 32 heads, the same eight chunks
+    cell = ling.LingConfig(num_hidden_layers=8)
+    assert len(cell.mla_layers) == 1 and cell.num_attention_heads == 32
+    assert ling.job_counts(cell, 16384, 32, 128, 2048, 16384)[
+        "block_steps"] == {"whole": 32 * 56, "diagonal": 32 * 80, "dead": 0}
+
+
+@pytest.mark.parametrize("offset", [0, 64, 37, 192],
+                         ids=["first", "second", "odd", "last"])
+def test_block_steps_are_what_a_brute_force_count_of_the_grid_finds(offset):
+    """64 positions against 256 slots in 64 x 32 blocks (the entry's pick
+    for a 32-position chunk is asked of ``block_steps`` itself): every
+    (query block, key block) pair up to the written cache's last block
+    is whole, crossed or above the diagonal by its corner pairs."""
+    from chiaswarm_tpu.ops.causal_flash_attention import (
+        _prefill_blocks,
+        block_steps,
+    )
+
+    positions, keys = 64, 256
+    block_q, block_kv = _prefill_blocks(positions, 1, keys, None)
+    got = block_steps(positions, 1, offset, keys)
+    whole = crossed = dead = 0
+    for i in range(0, positions, block_q):
+        rows = np.arange(offset + i, offset + i + block_q)
+        for j in range(0, -(-(offset + positions) // block_kv) * block_kv,
+                       block_kv):
+            visible = np.arange(j, j + block_kv)[None, :] <= rows[:, None]
+            whole += visible.all()
+            dead += not visible.any()
+            crossed += visible.any() and not visible.all()
+    assert (got["whole"], got["dead"]) == (whole, dead)
+    # a crossed pair counts its scored sub-tiles when it is cut to the
+    # diagonal (an offset on the blocks' grid), itself when masked whole
+    assert got["diagonal"] >= crossed
+    if offset % min(block_q, block_kv):
+        assert got["diagonal"] == crossed
+
+
+@pytest.mark.parametrize("stack", STACKS)
+def test_count_feeds_the_block_steps_family(stack):
+    """``TextPipeline._count`` moves the three kinds by the stack's
+    ``job_counts``, for both stacks."""
+    from chiaswarm_tpu.obs.metrics import REGISTRY
+    from chiaswarm_tpu.pipelines.text import TextComponents, TextPipeline
+
+    module, cfg, _ = STACKS[stack]
+    pipe = TextPipeline(TextComponents.random(cfg, seed=1),
+                        prefill_chunk=2048, max_context=16384)
+    kinds = ("whole", "diagonal", "dead")
+
+    def counted():
+        values = REGISTRY.snapshot()[
+            "chiaswarm_text_prefill_block_steps_total"]["values"]
+        return np.array([values.get(k, 0) for k in kinds])
+
+    zero = {k: 0 for k in module.empty_stats()}
+    before = counted()
+    pipe._count(16384, 16, 64, zero, zero)
+    want = module.job_counts(cfg, 16384, 16, 64, 2048, 16384)["block_steps"]
+    assert list(counted() - before) == [want[k] for k in kinds]
+    assert want["whole"] > 0 and want["diagonal"] > 0

@@ -223,19 +223,21 @@ def test_diffusion_attention_traces_to_the_parents_jaxpr(cell, call,
         == _PARENT_JAXPR_SHA256[cell, call]
 
 
-# ---- the decode's sweep is a second entry of the causal kernel's module:
-# the prefill's call is as it was (ISSUE 34) --------------------------------
-# sha256 of ``str(jax.make_jaxpr(...))`` of ``attention(causal=True)`` at
-# the two text cells' prefill shapes, taken on the commit BEFORE the
-# kernel body learned of rows that share a position (e2e2d2b), under this
-# suite's conftest: the kernel's jaxpr, grid and block mappings are in
-# that text. Traced as a TPU process traces it (the Mosaic call, not
-# the interpreter's), like the cross-lowering below, whose cached trace
-# it shares.
+# ---- the prefill's schedule (ISSUE 36): the decode entries are as they
+# were, the prefill is held to the dense masked einsum ---------------------
+# sha256 of ``str(jax.make_jaxpr(...))`` of the two decode entries at the
+# three text cells' shapes, taken on the commit BEFORE the prefill's
+# schedule changed (b828833), under this suite's conftest: the kernel's
+# jaxpr, grid and block mappings are in that text. Traced as a TPU
+# process traces them (the Mosaic call, not the interpreter's).
 
-_PARENT_PREFILL_JAXPR_SHA256 = {
-    32: "f53c4801f53280967ce368584d6ee3423b68e50b5c703712b3f302b244b764ad",
-    128: "68d95684f3253799aef34836c928e3bc30f8ef23b78673de610e6c0391726516",
+_PARENT_DECODE_JAXPR_SHA256 = {
+    "deepseek-16-rows-of-128-heads":
+        "33f3cf12e726c7eea410160f7873ab6518330bae18c02d66564d15cd69ec6d82",
+    "ling-32-rows-of-32-heads":
+        "650125356758d09f7effcbb2dbe79e0de8232eb69a7f127ba5449842099e53f0",
+    "laguna-32-rows-of-48-heads-over-8":
+        "5b2adb44fb94cd27511a6a6bb69fef83ddc6d4b3b959d31426997f5a84e174fe",
 }
 
 
@@ -243,28 +245,139 @@ def _bf16_spec(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
 
 
-@pytest.mark.parametrize("heads", list(_PARENT_PREFILL_JAXPR_SHA256),
-                         ids=["ling-32-heads", "deepseek-128-heads"])
-def test_the_prefills_causal_call_traces_to_the_parents_jaxpr(heads):
-    """One 2048-token chunk against 16,384 slots with the shared rotary
-    key: at one row a position the generalised kernel body is the
-    parent's program letter for letter (so bit for bit in its results)."""
+@pytest.mark.parametrize("cell", list(_PARENT_DECODE_JAXPR_SHA256))
+def test_the_decodes_sweeps_trace_to_the_parents_jaxpr(cell):
+    """``shared_latent_attention`` (every row's heads against the 16,384
+    shared latents) and ``shared_prompt_attention`` (a key-value head's
+    rows against its 16,384 keys and values): the kernel body, the index
+    maps and the one ``pallas_call`` they share with the prefill are,
+    for them, the parent's program letter for letter."""
     import hashlib
     from unittest import mock
 
-    def fn(q, k, v, q_offset, q_rotary, k_rotary):
-        return attention(q, k, v, scale=192 ** -0.5, causal=True,
-                         q_offset=q_offset, shared_key=(q_rotary, k_rotary))
+    from chiaswarm_tpu.ops.attention import (
+        shared_latent_attention,
+        shared_prompt_attention,
+    )
 
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    if cell.startswith("laguna"):
+        def fn(q, k, v, n):
+            return shared_prompt_attention(q, k, v, n, scale=128 ** -0.5)
+
+        shapes = (_bf16_spec(32, 48, 128), _bf16_spec(16384, 8, 128),
+                  _bf16_spec(16384, 8, 128), scalar)
+    else:
+        def fn(q, cache, n):
+            return shared_latent_attention(q, cache, n, value_width=512,
+                                           scale=192 ** -0.5)
+
+        rows = 16 * 128 if cell.startswith("deepseek") else 32 * 32
+        shapes = (_bf16_spec(rows, 576), _bf16_spec(16384, 576), scalar)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        text = str(jax.make_jaxpr(fn)(
-            _bf16_spec(1, 2048, heads, 128),
-            _bf16_spec(1, 16384, heads, 128),
-            _bf16_spec(1, 16384, heads, 128),
-            jax.ShapeDtypeStruct((), jnp.int32),
-            _bf16_spec(1, 2048, heads, 64), _bf16_spec(1, 16384, 64)))
+        text = str(jax.make_jaxpr(fn)(*shapes))
+    assert "pallas_call" in text
     assert hashlib.sha256(text.encode()).hexdigest() \
-        == _PARENT_PREFILL_JAXPR_SHA256[heads]
+        == _PARENT_DECODE_JAXPR_SHA256[cell]
+
+
+def _dense_prefill(q, k, v, q_rotary, k_rotary, q_offset, scale):
+    """softmax over every slot in float64, masked to ``s <= q_offset +
+    l``; NaN in a masked slot's value counts as 0."""
+    q, k, v, q_rotary, k_rotary = (
+        np.asarray(x, np.float64) for x in (q, k, v, q_rotary, k_rotary))
+    logits = (np.einsum("blhd,bshd->bhls", q, np.nan_to_num(k))
+              + np.einsum("blhr,bsr->bhls", q_rotary,
+                          np.nan_to_num(k_rotary))) * scale
+    visible = np.arange(k.shape[1])[None, :] \
+        <= q_offset + np.arange(q.shape[1])[:, None]
+    logits = np.where(visible, logits, -np.inf)
+    p = np.exp(logits - logits.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bhls,bshd->blhd", p, np.nan_to_num(v))
+
+
+#: (queries, capacity, q_offset, block_q, block_kv); blocks None = the
+#: entry's own pick through ``ops.attention``. The cell's blocks are 2048
+#: x 1024 with the diagonal cut in 512-row tiles: 64 x 32 and 16 here.
+_PREFILL_SCHEDULE_CASES = {
+    "first-chunk": (64, 256, 0, 64, 32),
+    "one-block": (32, 32, 0, None, None),
+    "second-chunk": (64, 256, 64, 64, 32),
+    "an-odd-offset": (64, 256, 37, 64, 32),
+    "off-the-key-blocks-by-a-tile": (64, 256, 80, 64, 32),
+    "last-chunk": (64, 256, 192, 64, 32),
+    "last-chunk-own-pick": (64, 256, 192, None, None),
+    "square-blocks": (64, 256, 128, 32, 32),
+    "wide-key-blocks": (64, 256, 128, 32, 64),
+    "a-ragged-chunk": (40, 256, 64, 32, 32),
+}
+
+
+@pytest.mark.parametrize("case", list(_PREFILL_SCHEDULE_CASES))
+def test_the_prefills_schedule_is_the_dense_masked_einsum(case):
+    """The latent-attention prefill at the DeepSeek cell's widths (128 /
+    64 shared / 128, three heads), float32 operands: the sweep that ends
+    at the written cache, cuts the diagonal's block pairs into tiles and
+    hands the MXU one 256-deep contraction is the masked softmax over
+    every slot; what lies past the written cache's last block is NaN, in
+    keys, values and the shared key part, and is not read."""
+    from chiaswarm_tpu.ops.causal_flash_attention import (
+        causal_flash_attention,
+    )
+
+    l, s, q_offset, block_q, block_kv = _PREFILL_SCHEDULE_CASES[case]
+    rng = np.random.RandomState(36)
+    q, q_rotary = rng.randn(1, l, 3, 128), rng.randn(1, l, 3, 64)
+    k, v, k_rotary = (rng.randn(1, s, 3, 128), rng.randn(1, s, 3, 128),
+                      rng.randn(1, s, 64))
+    scale = 192 ** -0.5
+    want = _dense_prefill(q, k, v, q_rotary, k_rotary, q_offset, scale)
+    kv = block_kv or min(l, s)
+    end = -(-(q_offset + l) // kv) * kv
+    for x in (k, v, k_rotary):
+        x[:, end:] = np.nan
+    f32 = [jnp.asarray(x, jnp.float32) for x in (q, k, v, q_rotary, k_rotary)]
+    if block_q is None:
+        got = attention(f32[0], f32[1], f32[2], scale=scale, causal=True,
+                        q_offset=jnp.int32(q_offset),
+                        shared_key=(f32[3], f32[4]))
+    else:
+        got = causal_flash_attention(
+            f32[0], f32[1], f32[2], jnp.int32(q_offset), (f32[3], f32[4]),
+            scale=scale, block_q=block_q, block_kv=block_kv, interpret=True)
+    got = np.asarray(got)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() < 1e-5
+
+
+def test_the_prefills_schedule_in_bfloat16_is_as_close_as_the_parents():
+    """bfloat16 operands at the cell's widths, the last chunk: the
+    largest error against the float64 masked softmax is what rounding
+    the operands and the probabilities to bfloat16 gives (the parent's
+    kernel, whose MXU pass rounded the float32 probabilities itself,
+    read 0.0073 at the chunk's first rows on the chip and 0.0002 at its
+    last: CHANGES.md, PR 36), and far from what a wrong mask gives."""
+    from chiaswarm_tpu.ops.causal_flash_attention import (
+        causal_flash_attention,
+    )
+
+    rng = np.random.RandomState(37)
+    l, s, q_offset = 64, 256, 192
+    bf16 = [jnp.asarray(x, jnp.bfloat16) for x in (
+        rng.randn(1, l, 3, 128), rng.randn(1, s, 3, 128),
+        rng.randn(1, s, 3, 128), rng.randn(1, l, 3, 64),
+        rng.randn(1, s, 64))]
+    want = _dense_prefill(*[np.asarray(x, np.float32) for x in bf16],
+                          q_offset, 192 ** -0.5)
+    got = np.asarray(causal_flash_attention(
+        bf16[0], bf16[1], bf16[2], jnp.int32(q_offset), (bf16[3], bf16[4]),
+        scale=192 ** -0.5, block_q=64, block_kv=32, interpret=True),
+        np.float32)
+    assert np.abs(got - want).max() < 0.01
+    off_by_one = _dense_prefill(*[np.asarray(x, np.float32) for x in bf16],
+                                q_offset - 1, 192 ** -0.5)
+    assert np.abs(off_by_one - want).max() > 0.05
 
 
 @pytest.mark.parametrize("rows", [16 * 128, 32 * 32],
@@ -297,11 +410,16 @@ def test_the_decodes_sweep_cross_lowers_for_tpu_at_the_text_cells_shapes(
     assert "16384x512x" not in text and "16384x128x" not in text
 
 
-def test_causal_flash_cross_lowers_for_tpu_at_the_text_cells_shapes():
-    """One 2048-token chunk of 32 heads (128-wide keys and values, the
-    64-wide rotary key shared) against 16,384 slots, through
-    ``attention(causal=True)`` with a traced offset: the scalar-prefetch
-    grid spec and its clamped index maps trace and lower for Mosaic."""
+@pytest.mark.parametrize("heads", [32, 128],
+                         ids=["ling-32-heads", "deepseek-128-heads"])
+def test_causal_flash_cross_lowers_for_tpu_at_the_text_cells_shapes(heads):
+    """One 2048-token chunk (128-wide keys and values, the 64-wide rotary
+    key shared) against 16,384 slots, through ``attention(causal=True)``
+    with a traced offset: the scalar-prefetch grid spec, its clamped
+    index maps and the key axis whose bound is traced with the offset
+    (2048-row query blocks against 1024-key blocks, as far as the cache
+    is written) trace and lower for Mosaic, as one program for every
+    chunk."""
     def fn(q, k, v, q_offset, q_rotary, k_rotary):
         return attention(q, k, v, scale=192 ** -0.5, causal=True,
                          q_offset=q_offset, shared_key=(q_rotary, k_rotary))
@@ -310,12 +428,17 @@ def test_causal_flash_cross_lowers_for_tpu_at_the_text_cells_shapes():
 
     spec = _bf16_spec
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        lowered = jax.jit(fn).trace(
-            spec(1, 2048, 32, 128), spec(1, 16384, 32, 128),
-            spec(1, 16384, 32, 128), jax.ShapeDtypeStruct((), jnp.int32),
-            spec(1, 2048, 32, 64), spec(1, 16384, 64),
-        ).lower(lowering_platforms=("tpu",))
-    assert "tpu_custom_call" in lowered.as_text()
+        traced = jax.jit(fn).trace(
+            spec(1, 2048, heads, 128), spec(1, 16384, heads, 128),
+            spec(1, 16384, heads, 128), jax.ShapeDtypeStruct((), jnp.int32),
+            spec(1, 2048, heads, 64), spec(1, 16384, 64))
+        text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "causal_flash_attention" in text
+    program = str(traced.jaxpr)
+    assert f"grid=(1, {heads}, 1, DynamicGridDim)" in program
+    assert "Blocked(block_size=2048)" in program
+    assert "Blocked(block_size=1024)" in program
 
 
 # ---- the grouped-query entries of the causal kernel's module (ISSUE 35) ----

@@ -232,6 +232,7 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
             "chiaswarm_moe_experts_hit_total",
             "chiaswarm_moe_layer_steps_total",
             "chiaswarm_text_prefill_key_blocks_total",
+            "chiaswarm_text_prefill_block_steps_total",
             "chiaswarm_text_decode_key_blocks_total",
             "chiaswarm_text_kda_blocks_total",
             "chiaswarm_text_cache_bytes")}
@@ -298,6 +299,13 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
     for family in after:
         assert f"# TYPE {family} " in served
     assert 'chiaswarm_text_prefill_key_blocks_total{read="yes"}' in served
+    # the same three chunks as grid steps of the two heads' kernel: 0 + 1
+    # + 2 block pairs a head below the diagonal, three on it, and no
+    # step past the written cache
+    steps = "chiaswarm_text_prefill_block_steps_total"
+    assert (moved(steps, "whole"), moved(steps, "diagonal"),
+            moved(steps, "dead")) == (2 * 3, 2 * 3, 0)
+    assert 'chiaswarm_text_prefill_block_steps_total{kind="whole"} ' in served
     # the decode's sweep: 15 steps of one layer, the 32 slots one block
     # that holds the prompt's 19 tokens
     swept = "chiaswarm_text_decode_key_blocks_total"

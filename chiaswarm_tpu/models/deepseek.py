@@ -332,11 +332,15 @@ def job_counts(cfg: DeepseekConfig, prompt_tokens: int, rows: int, new: int,
     """What the host knows of one job's two programs, for the counters
     (``pipelines/text.py::TextPipeline._count``): key blocks the decode's
     sweep reads and leaves, query-key pairs a head scores by phase, the
-    prefill kernel's grid steps by kind, the expert layers."""
+    prefill kernel's grid steps by kind, the latents'
+    up-projections by where they are made, the expert layers."""
     layers = cfg.num_hidden_layers
     return {
         "block_steps": text_layers.prefill_block_steps(
             layers, cfg.num_attention_heads, 1, prompt_tokens, chunk,
+            capacity),
+        "up_projections": text_layers.prefill_up_projections(
+            layers, cfg.num_attention_heads, prompt_tokens, chunk,
             capacity),
         "decode_key_blocks": text_layers.decode_key_blocks(
             layers, prompt_tokens, new, capacity),

@@ -7,8 +7,10 @@ tells them what differs.
 
 - **Latent attention.** The cache holds 512 normed latent + 64 rotated
   key values a token (``kv_lora_rank + qk_rope_head_dim``), one entry for
-  all heads. Two compute paths: prefill up-projects keys and values and
-  goes through ``ops.attention`` (causal), decode folds the key
+  all heads. Two compute paths: prefill hands the latents and W_ukv to
+  the causal sweep, which up-projects each head's keys and values block
+  by block where it reads them
+  (``ops.attention.latent_prefill_attention``), decode folds the key
   up-projection into the query and the value up-projection after the
   softmax (the absorbed form), against the latents directly: a prompt's
   latents shared by the rows (one key-blocked sweep on the chip,
@@ -63,16 +65,17 @@ import numpy as np
 
 from chiaswarm_tpu.ops.attention import (
     attention,
+    latent_prefill_attention,
     shared_latent_attention,
     shared_prompt_attention,
 )
 from chiaswarm_tpu.obs.trace import scope
 from chiaswarm_tpu.ops.causal_flash_attention import (
     block_steps,
-    key_block,
     prompt_key_block,
     shared_key_block,
     stepped_pairs,
+    up_projected_blocks,
 )
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -217,36 +220,21 @@ def latent_prefill(p, cfg, x, q, cache, pos, *, inv_freq, scale: float,
                    rope_amplitude: float = 1.0):
     """The heads' read-out (B, T, H, Dv) of x (B, T, d) at positions
     [pos, pos + T), and the cache with their entries written; ``cache``
-    (B, S, latent + Dr) holds every earlier token's. Up-projects the
-    latents to keys and values block by block, as far as the cache is
-    written and no further, and attends causally (``ops.attention``)
-    over the same blocks; the rotary key goes in as it lies in the
-    cache, one for all heads."""
-    b_, t, _ = x.shape
+    (B, S, latent + Dr) holds every earlier token's. Attends causally
+    over the written cache (``ops.attention.latent_prefill_attention``):
+    the sweep up-projects a head's keys and values of each key block it
+    reads from the latents and W_ukv itself, so that no (B, S, H x D)
+    keys or values exist in memory, and blocks past the written length
+    are neither read nor up-projected; the rotary key goes in as it lies
+    in the cache, one for all heads."""
+    t = x.shape[1]
     h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
     q_n, q_r, entry = _query_and_entry(p, cfg, x, q, pos + jnp.arange(t),
                                        inv_freq, rope_amplitude)
     cache = jax.lax.dynamic_update_slice_in_dim(cache, entry, pos, axis=1)
-    s = cache.shape[1]
-    block = key_block(t, s)
-    w_uk, w_uv = (w.reshape(rank, -1) for w in _up_projections(p, cfg))
-
-    def up_project(i, kv):
-        latents = jax.lax.dynamic_slice_in_dim(
-            cache, i * block, block, axis=1)[..., :rank]
-        return tuple(
-            jax.lax.dynamic_update_slice_in_dim(
-                whole, proj(latents, w), i * block, axis=1)
-            for whole, w in zip(kv, (w_uk, w_uv)))
-
-    # blocks past the written length stay zero and are never read
-    k_n, v = jax.lax.fori_loop(
-        0, (pos + t + block - 1) // block, up_project,
-        tuple(jnp.zeros((b_, s, w.shape[1]), x.dtype)
-              for w in (w_uk, w_uv)))
-    o = attention(q_n, k_n.reshape(b_, s, h, -1), v.reshape(b_, s, h, -1),
-                  scale=scale, causal=True, q_offset=pos,
-                  shared_key=(q_r, cache[..., rank:]))
+    o = latent_prefill_attention(q_n, cache, p["wukv"].reshape(rank, h, -1),
+                                 pos, shared_key=(q_r, cache[..., rank:]),
+                                 scale=scale)
     return o, cache
 
 
@@ -334,6 +322,20 @@ def prefill_block_steps(layers: int, grid_heads: int, g: int,
         for kind in total:
             total[kind] += layers * grid_heads * steps[kind]
     return total
+
+
+def prefill_up_projections(layers: int, heads: int, prompt_tokens: int,
+                           chunk: int, capacity: int) -> dict[str, int]:
+    """(head, key block) up-projections of ``layers`` latent-attention
+    layers' prefill over a prompt's chunks, by where they are made:
+    ``kernel`` = inside the causal sweep, one a step that holds work
+    (``up_projected_blocks``); ``memory`` = keys and values written to
+    memory ahead of the sweep, the form before the sweep took latents,
+    which no layer takes."""
+    return {"kernel": layers * heads * sum(
+                up_projected_blocks(chunk, pos, capacity)
+                for pos in range(0, prompt_tokens, chunk)),
+            "memory": 0}
 
 
 def decode_key_blocks(layers: int, prompt_tokens: int, new: int,

@@ -962,6 +962,19 @@ TEXT_PREFILL_BLOCK_STEPS = REGISTRY.counter(
     "and steps that hold no work",
     labelnames=("kind",))
 
+#: (head, key block) up-projections of latents to keys and values in the
+#: latent-attention layers' prefill, summed over heads, chunks and layers
+#: (``ops/causal_flash_attention.py::up_projected_blocks``, from host
+#: integers): "kernel" = made inside the causal sweep at the step that
+#: reads them, "memory" = written to memory ahead of the sweep (the form
+#: before the sweep took latents; no layer takes it)
+TEXT_PREFILL_UP_PROJECTIONS = REGISTRY.counter(
+    "chiaswarm_text_prefill_up_projections_total",
+    "(head, key block) up-projections of latents to keys and values in "
+    "the latent-attention prefill, by whether the causal sweep makes them "
+    "or memory holds them",
+    labelnames=("where",))
+
 #: key blocks of the decode's sweep over the prompt's shared entries
 #: (latents, or a full layer's keys and values): those up to the prompt's
 #: length, which the kernel reads ("yes"), against the rest of the

@@ -31,11 +31,12 @@ Five implementations behind one function:
 ``causal=True`` is a second entry, not a sixth kind: a chunk of a
 prefill against a partly written cache goes to the causal flash kernel
 (ops/causal_flash_attention.py) whatever the backend, and none of the
-calls above can reach it. ``shared_latent_attention`` below is that
-kernel's other entry, behind the same door: a decode step's rows, all at
-one position, against one shared key/value head, with each row's
-log-sum-exp beside the read-out; models/text_layers.py imports no kernel
-module for either.
+calls above can reach it. ``latent_prefill_attention`` below is the same
+entry handed latents and up-projection weights in place of keys and
+values; ``shared_latent_attention`` is that kernel's decode entry, behind
+the same door: a decode step's rows, all at one position, against one
+shared key/value head, with each row's log-sum-exp beside the read-out;
+models/text_layers.py imports no kernel module for any of them.
 
 All take (B, L, H, D) query / (B, S, H, D) key-value tensors and return
 (B, L, H, D). Head-batched layouts keep the last dim = head_dim (128-lane
@@ -210,6 +211,27 @@ def shared_latent_attention(q: jnp.ndarray, keys: jnp.ndarray, n_keys, *,
     )
 
     return sweep(q, keys, n_keys, value_width=value_width, scale=scale)
+
+
+def latent_prefill_attention(q: jnp.ndarray, latents: jnp.ndarray,
+                             w_ukv: jnp.ndarray, q_offset, *,
+                             shared_key: tuple[jnp.ndarray, jnp.ndarray],
+                             scale: float) -> jnp.ndarray:
+    """``attention(causal=True)`` of q (B, L, H, D) over keys and values
+    that the sweep up-projects itself: the first C columns of
+    ``latents`` (B, S, W), the written cache, times each head's columns
+    of ``w_ukv`` (C, H, D + Dv), keys' then values'. Latent attention's
+    prefill, one path: the causal kernel's prefill entry given latents
+    (ops/causal_flash_attention.py; Pallas interpret mode off the chip),
+    which makes a head's keys and values of a key block at the step that
+    reads them, so that neither reaches memory; blocks past the written
+    cache are neither read nor up-projected."""
+    from chiaswarm_tpu.ops.causal_flash_attention import (
+        causal_flash_attention,
+    )
+
+    return causal_flash_attention(q, latents, w_ukv, q_offset, shared_key,
+                                  scale=scale)
 
 
 def shared_prompt_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

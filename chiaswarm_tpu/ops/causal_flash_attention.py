@@ -6,7 +6,10 @@ entries that share one kernel body, one set of index maps and one
   absolute positions ``[q_offset, q_offset + L)`` against a cache of ``S``
   key slots of which only ``[0, q_offset + L)`` are written; with fewer
   key-value heads than query heads (grouped-query attention) the G
-  heads of a key-value head go through as G rows of one position;
+  heads of a key-value head go through as G rows of one position; handed
+  latent attention's latents and W_ukv in place of keys and values, it
+  up-projects a head's keys and values of a key block at the step that
+  reads them (below);
 - ``window_flash_attention``, the same under a sliding window: a query
   sees the ``window`` keys up to its own, and a key block wholly outside
   the window of every query of a block is neither fetched nor stepped;
@@ -80,6 +83,18 @@ nothing that picks shapes is shared:
   shared part is never broadcast to the heads in memory. Values may
   differ from keys in head size, or BE the keys (the decode: a latent is
   both, one block fetched for the two).
+- **the latent prefill**: the key operand is the cache's latents
+  (a (block_kv, 512) window of the (S, 576) cache as it lies, no head
+  axis, under the key blocks' clamp) and the value operand W_ukv as
+  stored, a head's (512, 128 + 128) column block, fetched once for its
+  run of steps. A step that holds work makes the head's keys and values
+  of its key block in one product into VMEM scratch
+  (``_up_project``), from which the update reads them as it reads
+  fetched blocks: no (S, H x 128) keys or values exist in memory, and a
+  key block is up-projected once a chunk, since a chunk is one query
+  block. Before, ``latent_prefill`` up-projected every written latent
+  again each chunk in a loop over two zero-filled (16,384, 16,384)
+  arrays a layer: ~0.13 s of a 1.70-s DeepSeek job on a v5e.
 - **a second output** (the decode's): each row's log-sum-exp of its
   visible logits, float32, by which the caller joins the softmax over
   further keys of its own to this one exactly; the read-out then leaves
@@ -120,8 +135,8 @@ from chiaswarm_tpu.ops.flash_attention import (
 # (PERF.md, PR 36; the module's docstring has every pair): a chunk of
 # 2,048 tokens at one row a position is ONE query block, so with the
 # grid's traced bound no step past the diagonal is entered at all, and
-# its keys come in the 1024 rows that ``latent_prefill`` up-projects at a
-# time. The grouped entries at the Laguna cell's shapes, ms a layer a
+# in the latent form each key block of 1024 latents is up-projected once
+# a chunk. The grouped entries at the Laguna cell's shapes, ms a layer a
 # job with 2048 / 1024 / 512 query rows a block: the full layers' 28.30 /
 # 30.21 / 33.91 (2048-key blocks 31.18), the windowed 7.40 / 8.09 / 9.79.
 # PR 30's sweep at 32 heads (26.6 ms a job at 1024 x 1024, 2048-row or
@@ -174,7 +189,7 @@ def _causal_kernel(offset_ref, *refs, scale: float, block_q: int,
                    values_are_keys: bool = False, with_stats: bool = False,
                    shared_lanes: int | None = None,
                    window: int | None = None, n_rows: int | None = None,
-                   by_tiles: bool = False):
+                   by_tiles: bool = False, up_projected: bool = False):
     refs = list(refs)
     q_ref = refs.pop(0)
     qs_ref = refs.pop(0) if has_shared else None
@@ -183,7 +198,7 @@ def _causal_kernel(offset_ref, *refs, scale: float, block_q: int,
     v_ref = k_ref if values_are_keys else refs.pop(0)
     o_ref = refs.pop(0)
     stats_ref = refs.pop(0) if with_stats else None
-    m_scr, l_scr, acc_scr = refs
+    m_scr, l_scr, acc_scr, *up_scr = refs
     i, j = pl.program_id(2), pl.program_id(3)
     # rows and columns on one scale: row r sits at position r // g, so
     # column c is visible to it when c * g <= r
@@ -198,6 +213,16 @@ def _causal_kernel(offset_ref, *refs, scale: float, block_q: int,
         m_scr[:] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    if up_projected:
+        # the latent prefill: the key operand is the latents, the value
+        # operand this head's columns of W_ukv; the keys and values that
+        # the update reads are made here, once a step that holds work
+        # (one row a position and no window: every step the grid enters
+        # below the diagonal or on it)
+        pl.when(first_col <= last_row)(
+            functools.partial(_up_project, k_ref, v_ref, *up_scr))
+        k_ref, v_ref = up_scr
 
     def values(masked: bool):
         v = v_ref[0]
@@ -300,6 +325,19 @@ def _diagonal_entries(block_q: int, block_kv: int) -> range:
     block: the only ones a job's chunks make."""
     step = min(block_q, block_kv)
     return range(step - block_kv, block_q, step)
+
+
+def _up_project(latent_ref, w_ref, k_scr, v_scr):
+    """One head's keys and values of one key block: the block's latents
+    (block_kv, C) times the head's key and value columns of W_ukv
+    (C, D + Dv) in one product, accumulated in float32 and rounded to the
+    latents' dtype (what the up-projection in XLA gave), into VMEM."""
+    kv = jax.lax.dot_general(latent_ref[0], w_ref[...],
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    d = k_scr.shape[2]
+    k_scr[0] = kv[:, :d].astype(k_scr.dtype)
+    v_scr[0] = kv[:, d:].astype(v_scr.dtype)
 
 
 def _update_by_tiles(q_ref, qs_ref, k_ref, ks_ref, v_ref, scratch, cut, *,
@@ -422,14 +460,17 @@ def _index_maps(block_q: int, block_kv: int, g: int = 1,
 
 
 def _sweep(q_offset, operands, *, grid, out_width: int, out_dtype,
-           interpret: bool, name: str | None = None, **kernel_options):
-    """The one ``pallas_call`` of both entries. ``operands``: (array,
+           interpret: bool, name: str | None = None, scratch=(),
+           **kernel_options):
+    """The one ``pallas_call`` of every entry. ``operands``: (array,
     block, index map) in the kernel's order, queries first, the shared
     key part or the values last, each padded here to whole blocks of
-    tokens; ``kernel_options`` go to ``_causal_kernel``. Returns the
-    read-out (B, Lp, H * out_width) and, ``with_stats``, the log-sum-exp
-    of each row's visible logits, broadcast over a lane tile
-    (B, Lp, H * 128)."""
+    tokens; ``scratch``: VMEM beyond the running softmax's;
+    ``kernel_options`` go to ``_causal_kernel`` (``block_kv`` where the
+    last operand's block is not a key block). Returns the read-out (B,
+    Lp, H * out_width) and, ``with_stats``, the log-sum-exp of each row's
+    visible logits, broadcast over a lane tile (B, Lp, H * 128)."""
+    kernel_options.setdefault("block_kv", operands[-1][1][1])
     operands = [(_pad_to(x, 1, block[1]), block, index)
                 for x, block, index in operands]
     b, lp = operands[0][0].shape[:2]
@@ -451,8 +492,7 @@ def _sweep(q_offset, operands, *, grid, out_width: int, out_dtype,
     if name is not None:
         params["name"] = name
     return pl.pallas_call(
-        functools.partial(_causal_kernel, block_q=block_q,
-                          block_kv=operands[-1][1][1], **kernel_options),
+        functools.partial(_causal_kernel, block_q=block_q, **kernel_options),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -463,6 +503,7 @@ def _sweep(q_offset, operands, *, grid, out_width: int, out_dtype,
                 pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
                 pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom
                 pltpu.VMEM((block_q, out_width), jnp.float32),  # accumulator
+                *scratch,
             ],
         ),
         out_shape=out_shape if with_stats else out_shape[0],
@@ -510,16 +551,24 @@ def causal_flash_attention(
     over k (B, S, H, D) / v (B, S, H, Dv): query ``l`` sees keys ``s <=
     q_offset + l``; ``q_offset + L <= S``. ``shared_key`` = (q_shared
     (B, L, H, R), k_shared (B, S, R)) adds ``q_shared . k_shared`` to
-    every head's logits. ``scale`` defaults to ``(D + R) ** -0.5``."""
+    every head's logits. ``scale`` defaults to ``(D + R) ** -0.5``.
+
+    Latent attention's prefill hands its latents in place of the keys
+    and values: k = the cache (B, S, W), a token's latent in its first C
+    columns, and v = W_ukv (C, H, D + Dv), a head's key columns and then
+    its value columns. A step up-projects its head's keys and values of
+    its key block from the latents, in VMEM (``_up_project``), so that
+    neither ever reaches memory."""
     b, l, h, d = q.shape
-    s, dv = k.shape[1], v.shape[3]
+    latent = k.ndim == 3
+    s, dv = k.shape[1], v.shape[2] - d if latent else v.shape[3]
     has_shared = shared_key is not None
     if scale is None:
         scale = float(d + (shared_key[0].shape[3] if has_shared else 0)) \
             ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if k.shape[2] != h:
+    if not latent and k.shape[2] != h:
         if has_shared:
             raise ValueError("grouped query heads take no shared key part")
         return _grouped_prefill(q, k, v, q_offset, scale=scale,
@@ -534,22 +583,54 @@ def causal_flash_attention(
         """(operand, its block: a column strip of one head, index map)."""
         return x, (1, rows, x.shape[2] // h), index
 
-    operands = [per_head(_fold(q), block_q, q_index),
-                per_head(_fold(k), block_kv, kv_index),
-                per_head(_fold(v), block_kv, kv_index)]
+    if latent:
+        latents, w_ukv, widths = _latent_operands(k, v, d)
+        dvp = widths[1]
+        operands = [
+            per_head(_fold(q), block_q, q_index),
+            # the latents have no head axis: their first column block,
+            # the key blocks' clamp (the shared part's map)
+            (latents, (1, block_kv, w_ukv.shape[0]), shared_index),
+            (w_ukv, (w_ukv.shape[0], w_ukv.shape[1] // h),
+             lambda bi, hi, i, j, offset: (0, hi))]
+        up = {"up_projected": True, "block_kv": block_kv,
+              "scratch": [pltpu.VMEM((1, block_kv, width), k.dtype)
+                          for width in widths]}
+    else:
+        operands = [per_head(_fold(q), block_q, q_index),
+                    per_head(_fold(k), block_kv, kv_index),
+                    per_head(_fold(v), block_kv, kv_index)]
+        dvp, up = operands[-1][1][2], {}
     if has_shared:
         k_shared = _pad_to(shared_key[1], 2, _LANES)
         operands.insert(1, per_head(_fold(shared_key[0]), block_q, q_index))
         operands.insert(3, (k_shared, (1, block_kv, k_shared.shape[2]),
                             shared_index))
-    dvp = operands[-1][1][2]
     of = _sweep(
         q_offset, operands,
         grid=(b, h, -(-l // block_q),
               _written_blocks(q_offset, l, block_kv, -(-s // block_kv))),
         out_width=dvp, out_dtype=q.dtype, interpret=interpret, scale=scale,
-        has_shared=has_shared, by_tiles=True)
+        has_shared=has_shared, by_tiles=True, **up)
     return of[:, :l].reshape(b, l, h, dvp)[..., :dv]
+
+
+def _latent_operands(cache, w_ukv, d: int):
+    """(latents (B, S, Cp), W_ukv (Cp, H * (Dp + Dvp)), (Dp, Dvp)) as the
+    latent prefill's sweep reads them: the cache as it lies and the
+    weights as stored where the latent fills whole lane tiles and so do
+    a head's key and value columns (DeepSeek's and Ling's 512 and 128 +
+    128: no copy of either), zero-padded copies otherwise (exact: zero
+    lanes and rows add nothing)."""
+    c, _, width = w_ukv.shape
+    if c % _LANES or d % _LANES or (width - d) % _LANES:
+        cache = _pad_to(cache[..., :c], 2, _LANES)
+        w_ukv = _pad_to(jnp.concatenate(
+            [_pad_to(w_ukv[..., :d], 2, _LANES),
+             _pad_to(w_ukv[..., d:], 2, _LANES)], axis=2), 0, _LANES)
+    dp = _LANES * -(-d // _LANES)
+    return (cache, w_ukv.reshape(w_ukv.shape[0], -1),
+            (dp, w_ukv.shape[2] - dp))
 
 
 # ---- grouped query heads: G of them read one key-value head --------------
@@ -626,6 +707,20 @@ def block_steps(positions: int, g: int, q_offset: int, keys: int,
                 count["diagonal"] += 1
                 count["pairs"] += block_q * block_kv
     return count
+
+
+def up_projected_blocks(positions: int, q_offset: int, keys: int) -> int:
+    """Key blocks one call of the latent prefill (one row a position, no
+    window) up-projects a head: one a step that holds work, whole or
+    crossed, each query block's up to the last key block it reaches
+    inside the grid's bound (the kernel's test and ``_written_blocks`` on
+    host integers, for the counters)."""
+    block_q, block_kv = _prefill_blocks(positions, 1, keys, None)
+    steps = min(max(-(-(q_offset + positions) // block_kv), 1),
+                -(-keys // block_kv))
+    return sum(min(steps, (first_row + block_q - 1) // block_kv + 1)
+               for first_row in range(q_offset, q_offset + positions,
+                                      block_q))
 
 
 def stepped_pairs(rows: int, g: int, q_offset: int, keys: int,

@@ -271,6 +271,9 @@ class TextPipeline:
         metrics.TEXT_DECODE_KEY_BLOCKS.inc(total - read, read="no")
         for kind, steps in counts["block_steps"].items():
             metrics.TEXT_PREFILL_BLOCK_STEPS.inc(steps, kind=kind)
+        # a stack with latent-attention layers
+        for where, made in counts.get("up_projections", {}).items():
+            metrics.TEXT_PREFILL_UP_PROJECTIONS.inc(made, where=where)
         for phase, pairs in zip(("prefill", "decode"),
                                 counts["attention_pairs"]):
             metrics.TEXT_ATTENTION_PAIRS.inc(pairs, phase=phase)

@@ -11,7 +11,12 @@ rows' own suffixes by the log-sum-exps.
   reference) for both stacks' tiny configurations at the first, a middle
   and the last step;
 - no (rows, heads, capacity) array in the decode's program;
-- the counter from host integers.
+- the counter from host integers;
+- the prefill's latent form: ``latent_prefill`` against the
+  parent's unfused form (kept here) for both stacks, in float32 and in
+  bfloat16; no (1, S, H x D) keys or values and no loop in its program at
+  the DeepSeek cell's widths; its up-projections counted from host
+  integers against a brute-force count of the grid.
 """
 
 from __future__ import annotations
@@ -446,3 +451,228 @@ def test_count_feeds_the_block_steps_family(stack):
     want = module.job_counts(cfg, 16384, 16, 64, 2048, 16384)["block_steps"]
     assert list(counted() - before) == [want[k] for k in kinds]
     assert want["whole"] > 0 and want["diagonal"] > 0
+
+
+# ---- the latent prefill: the sweep up-projects the latents ----------------
+
+
+def unfused_latent_prefill(p, cfg, x, q, cache, pos, *, inv_freq, scale,
+                           rope_amplitude=1.0):
+    """``text_layers.latent_prefill`` in its unfused form: the
+    written latents up-projected to (B, S, H x D) keys and values in
+    memory, a key block at a time in a loop over two zero-filled arrays,
+    and the sweep over those."""
+    from chiaswarm_tpu.ops.attention import attention
+    from chiaswarm_tpu.ops.causal_flash_attention import key_block
+
+    b_, t, _ = x.shape
+    h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+    q_n, q_r, entry = text_layers._query_and_entry(
+        p, cfg, x, q, pos + jnp.arange(t), inv_freq, rope_amplitude)
+    cache = jax.lax.dynamic_update_slice_in_dim(cache, entry, pos, axis=1)
+    s = cache.shape[1]
+    block = key_block(t, s)
+    w_uk, w_uv = (w.reshape(rank, -1)
+                  for w in text_layers._up_projections(p, cfg))
+
+    def up_project(i, kv):
+        latents = jax.lax.dynamic_slice_in_dim(
+            cache, i * block, block, axis=1)[..., :rank]
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                whole, text_layers.proj(latents, w), i * block, axis=1)
+            for whole, w in zip(kv, (w_uk, w_uv)))
+
+    k_n, v = jax.lax.fori_loop(
+        0, (pos + t + block - 1) // block, up_project,
+        tuple(jnp.zeros((b_, s, w.shape[1]), x.dtype)
+              for w in (w_uk, w_uv)))
+    o = attention(q_n, k_n.reshape(b_, s, h, -1), v.reshape(b_, s, h, -1),
+                  scale=scale, causal=True, q_offset=pos,
+                  shared_key=(q_r, cache[..., rank:]))
+    return o, cache
+
+
+CHUNK = 16
+
+
+def prefill_forms(monkeypatch, stack, pos, dtype=jnp.float32):
+    """(the layer's output, the cache) of one chunk at ``pos`` of a
+    stack's tiny latent-attention layer, through the served form and the
+    parent's unfused one; the cache holds random latents before ``pos``
+    and zeros after."""
+    module, cfg, layer, _, cache, _ = layer_operands(stack, 33, pos, dtype)
+    x = jnp.asarray(np.random.RandomState(34).randn(1, CHUNK,
+                                                    cfg.hidden_size), dtype)
+    fn = jax.jit(lambda c, at: module.mla_prefill(layer, cfg, x, c, at))
+    got = fn(cache, jnp.int32(pos))
+    with monkeypatch.context() as patch:
+        patch.setattr(text_layers, "latent_prefill", unfused_latent_prefill)
+        want = jax.jit(
+            lambda c, at: module.mla_prefill(layer, cfg, x, c, at))(
+                cache, jnp.int32(pos))
+    return [tuple(np.asarray(a, np.float32) for a in pair)
+            for pair in (got, want)]
+
+
+@pytest.mark.parametrize("pos", [0, 16, CAPACITY - CHUNK],
+                         ids=["first-chunk", "a-middle-chunk", "last-chunk"])
+@pytest.mark.parametrize("stack", STACKS)
+def test_latent_prefill_is_the_parents_unfused_form(monkeypatch, stack, pos):
+    """A chunk of 16 tokens of each stack's tiny layer (a latent of 24,
+    read through a padded copy), float32: the sweep that up-projects its
+    latents gives the output that keys and values up-projected in memory
+    gave, and writes the same cache."""
+    (y, cache), (want, want_cache) = prefill_forms(monkeypatch, stack, pos)
+    assert np.abs(y - want).max() < 1e-5 * max(1.0, np.abs(want).max())
+    assert np.array_equal(cache, want_cache)
+
+
+@pytest.mark.parametrize("stack", STACKS)
+def test_latent_prefill_in_bfloat16_stays_as_close_as_the_parent(
+        monkeypatch, stack):
+    """Served dtype, the last chunk: both forms round the keys and values
+    to bfloat16 from a float32 sum, so against the float32 layer the
+    latent form misses by no more than 1.5 times what the parent does."""
+    pos = CAPACITY - CHUNK
+    _, (exact, _) = prefill_forms(monkeypatch, stack, pos)
+    (y, _), (want, _) = prefill_forms(monkeypatch, stack, pos, jnp.bfloat16)
+    assert np.abs(y - exact).max() < 1.5 * np.abs(want - exact).max()
+
+
+def _primitives(jaxpr):
+    """Names of every primitive a jaxpr runs, sub-jaxprs included, a
+    Pallas kernel's body left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+def test_the_latent_prefill_holds_no_keys_or_values_and_no_loop(
+        monkeypatch):
+    """One DeepSeek layer at the cell's widths (128 heads, a 512 + 64
+    latent, a 2,048-token chunk against 16,384 slots), traced as a TPU
+    process traces it: no (1, 16384, 128 x 128) keys or values and no
+    loop outside the kernel; the parent's form, traced the same way,
+    holds both."""
+    from unittest import mock
+
+    cfg = deepseek.DeepseekConfig(num_hidden_layers=1)
+    layer = deepseek.param_shapes(cfg)["layers"][0]["attn"]
+    bf = jnp.bfloat16
+
+    def traced():
+        def chunk(p, x, cache, pos):    # a new function: no cached trace
+            return deepseek.mla_prefill(p, cfg, x, cache, pos)
+
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            return jax.make_jaxpr(chunk)(
+                layer, jax.ShapeDtypeStruct((1, 2048, 5120), bf),
+                jax.ShapeDtypeStruct((1, 16384, 576), bf),
+                jax.ShapeDtypeStruct((), jnp.int32)).jaxpr
+
+    keys_sized = (1, 16384, 128 * 128)
+    program = traced()
+    assert "pallas_call" in set(_primitives(program))
+    assert "while" not in set(_primitives(program))
+    assert keys_sized not in set(_shapes(program))
+    monkeypatch.setattr(text_layers, "latent_prefill", unfused_latent_prefill)
+    parents = traced()
+    assert "while" in set(_primitives(parents))
+    assert keys_sized in set(_shapes(parents))
+
+
+# ---- the up-projections counted from host integers --------------------------
+
+
+def brute_force_up_projections(positions, q_offset, keys):
+    """The latent prefill's (query block, key block) steps a head that
+    hold a visible pair, up to the written cache's last key block, found
+    by looking at every pair of every block of the grid."""
+    from chiaswarm_tpu.ops.causal_flash_attention import _prefill_blocks
+
+    block_q, block_kv = _prefill_blocks(positions, 1, keys, None)
+    written = min(-(-(q_offset + positions) // block_kv), -(-keys // block_kv))
+    count = 0
+    for i in range(0, positions, block_q):
+        rows = np.arange(q_offset + i, q_offset + i + block_q)
+        for j in range(0, written * block_kv, block_kv):
+            count += (np.arange(j, j + block_kv)[None, :]
+                      <= rows[:, None]).any()
+    return count
+
+
+@pytest.mark.parametrize("prompt, chunk, capacity", [
+    (16384, 2048, 16384), (4096, 2048, 16384), (4097, 2048, 16384),
+    (20, 8, 64), (64, 16, 64), (40, 32, 256), (7, 16, 64),
+    (8192, 4096, 16384)],
+    ids=["the-cells-job", "a-quarter", "a-token-more", "three-chunks",
+         "the-whole-context", "a-ragged-chunk", "part-of-a-chunk",
+         "two-query-blocks-a-chunk"])
+def test_up_projections_are_what_a_brute_force_count_of_the_grid_finds(
+        prompt, chunk, capacity):
+    """Every latent layer's heads up-project, for each chunk, the key
+    blocks that hold a pair some query of a block sees, as far as the
+    cache is written; none is made in memory. A chunk of two query blocks
+    up-projects a block once for each that reaches it."""
+    want = sum(brute_force_up_projections(chunk, pos, capacity)
+               for pos in range(0, prompt, chunk))
+    for module, cfg in ((deepseek, deepseek.DeepseekConfig(
+            num_hidden_layers=5)), (ling, ling.LingConfig(
+                num_hidden_layers=8))):
+        layers = len(getattr(cfg, "mla_layers", range(5)))
+        assert module.job_counts(cfg, prompt, 16, 64, chunk, capacity)[
+            "up_projections"] == {
+                "kernel": layers * cfg.num_attention_heads * want,
+                "memory": 0}
+
+
+def test_the_cells_jobs_up_project_each_written_block_once_a_chunk():
+    """The DeepSeek job: five layers of 128 heads, chunk c (of eight)
+    up-projects its 2c key blocks of 1,024 latents, 46,080 in all; the
+    Ling job's one layer of 32 heads, 2,304; the Laguna job has no latent
+    layer."""
+    from chiaswarm_tpu.models import laguna
+
+    cell = deepseek.DeepseekConfig(num_hidden_layers=5)
+    assert deepseek.job_counts(cell, 16384, 16, 64, 2048, 16384)[
+        "up_projections"] == {"kernel": 46080, "memory": 0}
+    assert 5 * 128 * sum(2 * c for c in range(1, 9)) == 46080
+    assert ling.job_counts(ling.LingConfig(num_hidden_layers=8), 16384, 32,
+                           128, 2048, 16384)["up_projections"] \
+        == {"kernel": 2304, "memory": 0}
+    assert "up_projections" not in laguna.job_counts(
+        laguna.LagunaConfig(), 16384, 32, 128, 2048, 16384)
+
+
+@pytest.mark.parametrize("stack", ["ling", "deepseek", "laguna"])
+def test_count_feeds_the_up_projections_family(stack):
+    """``TextPipeline._count`` moves ``where="kernel"`` by the stack's
+    ``job_counts`` and ``where="memory"`` by nothing, for all three
+    stacks (Laguna's, with no latent layer, moves neither)."""
+    from chiaswarm_tpu.models import laguna
+    from chiaswarm_tpu.obs.metrics import REGISTRY
+    from chiaswarm_tpu.pipelines.text import TextComponents, TextPipeline
+
+    module, cfg = {"ling": (ling, ling.LING_TINY),
+                   "deepseek": (deepseek, deepseek.TINY),
+                   "laguna": (laguna, laguna.TINY)}[stack]
+    pipe = TextPipeline(TextComponents.random(cfg, seed=1),
+                        prefill_chunk=2048, max_context=16384)
+
+    def counted():
+        values = REGISTRY.snapshot()[
+            "chiaswarm_text_prefill_up_projections_total"]["values"]
+        return np.array([values.get(k, 0) for k in ("kernel", "memory")])
+
+    zero = {k: 0 for k in module.empty_stats()}
+    before = counted()
+    pipe._count(16384, 16, 64, zero, zero)
+    kernel, memory = counted() - before
+    want = module.job_counts(cfg, 16384, 16, 64, 2048, 16384).get(
+        "up_projections", {"kernel": 0, "memory": 0})
+    assert (kernel, memory) == (want["kernel"], want["memory"])
+    assert memory == 0 and (kernel > 0) == (stack != "laguna")

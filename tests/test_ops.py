@@ -380,6 +380,112 @@ def test_the_prefills_schedule_in_bfloat16_is_as_close_as_the_parents():
     assert np.abs(off_by_one - want).max() > 0.05
 
 
+# ---- the latent form of the prefill: the sweep up-projects the latents
+# itself --------------------------------------------------------------------
+
+
+def _latent_case(seed, heads, l, s, rank=128):
+    """Queries (1, l, H, 128) and their rotary part (1, l, H, 64), the
+    cache (1, s, rank + 64) and W_ukv (rank, H, 128 + 128), scaled so that
+    keys, values and logits are of unit size."""
+    rng = np.random.RandomState(seed)
+    return (rng.randn(1, l, heads, 128), rng.randn(1, l, heads, 64),
+            rng.randn(1, s, rank + 64), rng.randn(rank, heads, 256) / 16.0)
+
+
+def _up_projected(cache, w_ukv, rank=128):
+    """Keys and values (1, s, H, 128) of the cache's latents, float64."""
+    kv = np.einsum("bsc,chd->bshd", np.asarray(cache, np.float64)[..., :rank],
+                   np.asarray(w_ukv, np.float64))
+    return kv[..., :128], kv[..., 128:]
+
+
+def _run_latent(q, q_rotary, cache, w_ukv, q_offset, blocks, rank=128):
+    from chiaswarm_tpu.ops.causal_flash_attention import (
+        causal_flash_attention,
+    )
+
+    return np.asarray(causal_flash_attention(
+        q, cache, w_ukv, jnp.int32(q_offset), (q_rotary, cache[..., rank:]),
+        scale=192 ** -0.5, block_q=blocks[0], block_kv=blocks[1],
+        interpret=True), np.float32)
+
+
+@pytest.mark.parametrize("q_offset", [0, 128, 192],
+                         ids=["first-chunk", "a-middle-chunk", "last-chunk"])
+@pytest.mark.parametrize("heads", [128, 32],
+                         ids=["deepseek-128-heads", "ling-32-heads"])
+def test_the_latent_prefill_is_the_dense_softmax_of_up_projected_keys(
+        heads, q_offset):
+    """Latents and W_ukv in place of keys and values (a 128-wide latent
+    read from the cache as it lies, the rotary key beside it), 64 queries
+    against 256 slots in 64 x 32 blocks, float32: the masked softmax over
+    every slot of the keys and values up-projected in float64. A wrong
+    head's columns (the next head's) miss by far more."""
+    q, q_rotary, cache, w_ukv = _latent_case(38, heads, 64, 256)
+    k, v = _up_projected(cache, w_ukv)
+    want = _dense_prefill(q, k, v, q_rotary, cache[..., 128:], q_offset,
+                          192 ** -0.5)
+    f32 = [jnp.asarray(x, jnp.float32) for x in (q, q_rotary, cache, w_ukv)]
+    got = _run_latent(*f32, q_offset, (64, 32))
+    assert got.shape == (1, 64, heads, 128)
+    assert np.abs(got - want).max() < 1e-5
+    shifted = jnp.roll(f32[3], 1, axis=1)
+    assert np.abs(_run_latent(*f32[:3], shifted, q_offset, (64, 32))
+                  - want).max() > 0.05
+
+
+def test_the_latent_prefill_in_bfloat16_is_as_close_as_the_unfused_form():
+    """bfloat16 operands, the last chunk: the parent's form (keys and
+    values up-projected in XLA, rounded to bfloat16, then the sweep over
+    them) and the latent form both stay within the parent's own bfloat16
+    limit of the float64 softmax, and within it of each other: the
+    kernel's up-projection rounds what XLA's did."""
+    from chiaswarm_tpu.models.text_layers import proj
+    from chiaswarm_tpu.ops.causal_flash_attention import (
+        causal_flash_attention,
+    )
+
+    bf16 = [jnp.asarray(x, jnp.bfloat16)
+            for x in _latent_case(39, 4, 64, 256)]
+    q, q_rotary, cache, w_ukv = bf16
+    k, v = _up_projected(*[np.asarray(x, np.float32) for x in (cache, w_ukv)])
+    want = _dense_prefill(np.asarray(q, np.float32), k, v,
+                          np.asarray(q_rotary, np.float32),
+                          np.asarray(cache, np.float32)[..., 128:], 192,
+                          192 ** -0.5)
+    got = _run_latent(*bf16, 192, (64, 32))
+    kv = proj(cache[..., :128], w_ukv.reshape(128, -1)).reshape(1, 256, 4,
+                                                                 256)
+    unfused = np.asarray(causal_flash_attention(
+        q, kv[..., :128], kv[..., 128:], jnp.int32(192),
+        (q_rotary, cache[..., 128:]), scale=192 ** -0.5, block_q=64,
+        block_kv=32, interpret=True), np.float32)
+    assert np.abs(unfused - want).max() < 0.01
+    assert np.abs(got - want).max() < 0.01
+    assert np.abs(got - unfused).max() < 0.01
+
+
+@pytest.mark.parametrize("q_offset, blocks", [
+    (0, (64, 32)), (128, (64, 32)), (192, (64, 32)), (128, (32, 64))],
+    ids=["first-chunk", "a-middle-chunk", "last-chunk", "wide-key-blocks"])
+def test_the_latent_prefill_reads_no_latent_past_the_written_cache(q_offset,
+                                                                    blocks):
+    """Every cache row from ``q_offset + L`` on is NaN, latent and rotary
+    key alike: a block that was read would up-project NaN keys and
+    values, and 0 x NaN would reach the accumulator, so the clean answer
+    shows that those blocks are neither fetched nor up-projected."""
+    q, q_rotary, cache, w_ukv = _latent_case(40, 4, 64, 256)
+    k, v = _up_projected(cache, w_ukv)
+    want = _dense_prefill(q, k, v, q_rotary, cache[..., 128:], q_offset,
+                          192 ** -0.5)
+    cache[:, q_offset + 64:] = np.nan
+    f32 = [jnp.asarray(x, jnp.float32) for x in (q, q_rotary, cache, w_ukv)]
+    got = _run_latent(*f32, q_offset, blocks)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() < 1e-5
+
+
 @pytest.mark.parametrize("rows", [16 * 128, 32 * 32],
                          ids=["deepseek-16-rows-of-128-heads",
                               "ling-32-rows-of-32-heads"])
@@ -441,6 +547,44 @@ def test_causal_flash_cross_lowers_for_tpu_at_the_text_cells_shapes(heads):
     assert "Blocked(block_size=1024)" in program
 
 
+@pytest.mark.parametrize("heads", [32, 128],
+                         ids=["ling-32-heads", "deepseek-128-heads"])
+def test_the_latent_prefill_cross_lowers_for_tpu_at_the_text_cells_shapes(
+        heads):
+    """One 2048-token chunk of the latent form through
+    ``ops.attention.latent_prefill_attention`` with a traced offset: one
+    Mosaic call under the prefill's operation name, handed the (16384,
+    576) cache as it lies and W_ukv as stored, and no (16384, H x 128)
+    keys or values anywhere in the program; a head's (512, 128 + 128)
+    block of W_ukv and a key block's (1024, 512) latents."""
+    from unittest import mock
+
+    from chiaswarm_tpu.ops.attention import latent_prefill_attention
+
+    def fn(q, cache, wukv, q_offset, q_rotary):
+        return latent_prefill_attention(
+            q, cache, wukv.reshape(512, heads, 256), q_offset,
+            shared_key=(q_rotary, cache[..., 512:]), scale=192 ** -0.5)
+
+    spec = _bf16_spec
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        traced = jax.jit(fn).trace(
+            spec(1, 2048, heads, 128), spec(1, 16384, 576),
+            spec(512, heads * 256), jax.ShapeDtypeStruct((), jnp.int32),
+            spec(1, 2048, heads, 64))
+        text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "causal_flash_attention" in text
+    assert "x16384x576xbf16" in text and f"512x{heads * 256}xbf16" in text
+    assert f"16384x{heads * 128}x" not in text
+    program = str(traced.jaxpr)
+    assert f"grid=(1, {heads}, 1, DynamicGridDim)" in program
+    assert "block_shape=(Blocked(block_size=512), Blocked(block_size=256))" \
+        in program
+    assert ("block_shape=(Blocked(block_size=1), Blocked(block_size=1024), "
+            "Blocked(block_size=512))") in program
+
+
 # ---- the grouped-query entries of the causal kernel's module (ISSUE 35) ----
 
 
@@ -486,3 +630,41 @@ def test_the_grouped_entries_cross_lower_for_tpu_at_the_laguna_cells_shapes(
     keys = shapes[1].shape[-3]
     assert f"x{keys}x1024xbf16" in text          # 8 heads of 128, as stored
     assert f"x{keys}x6144x" not in text and f"x{keys}x8192x" not in text
+
+
+# sha256 of ``str(jax.make_jaxpr(...))`` of the grouped prefill entries at
+# the Laguna cell's shapes, taken on a04798d (before the latent form of
+# the prefill came into the kernel) under this suite's conftest, traced as
+# a TPU process traces them; the decode entries are held above.
+_PARENT_GROUPED_JAXPR_SHA256 = {
+    "causal_flash_attention-48-heads-over-8":
+        "85169fd83591ae426c90516aa5f0d02015a5758a099f3ad044ea61dfc66d94d4",
+    "window_flash_attention-64-heads-over-8":
+        "a4b1a8cf30ee73257a768ae0be570b8530ec09dd43ddc9b3b97bb66253fcbe45",
+}
+
+
+@pytest.mark.parametrize("entry", list(_PARENT_GROUPED_JAXPR_SHA256))
+def test_the_grouped_prefills_trace_to_the_parents_jaxpr(entry):
+    """A full layer's chunk of 48 heads over 8 key-value heads against
+    16,384 slots and a sliding layer's of 64 against its 2,560-slot local
+    buffer: the kernel body, index maps and ``pallas_call`` they share
+    with the latent form are, for them, the parent's letter for letter."""
+    import hashlib
+    from unittest import mock
+
+    windowed = entry.startswith("window")
+    slots, heads = (2560, 64) if windowed else (16384, 48)
+
+    def fn(q, k, v, q_offset):
+        return attention(q, k, v, scale=128 ** -0.5, causal=True,
+                         q_offset=q_offset, window=512 if windowed else None)
+
+    shapes = (_bf16_spec(1, 2048, heads, 128), _bf16_spec(1, slots, 8, 128),
+              _bf16_spec(1, slots, 8, 128),
+              jax.ShapeDtypeStruct((), jnp.int32))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = str(jax.make_jaxpr(fn)(*shapes))
+    assert "pallas_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _PARENT_GROUPED_JAXPR_SHA256[entry]

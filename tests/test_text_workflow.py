@@ -237,6 +237,7 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
             "chiaswarm_moe_experts_hit_total",
             "chiaswarm_moe_layer_steps_total",
             "chiaswarm_text_prefill_block_steps_total",
+            "chiaswarm_text_prefill_up_projections_total",
             "chiaswarm_text_decode_key_blocks_total",
             "chiaswarm_text_kda_blocks_total",
             "chiaswarm_text_cache_bytes")}
@@ -307,6 +308,12 @@ def test_an_unmodified_worker_settles_a_txt2txt_job_from_minihive():
     assert (moved(steps, "whole"), moved(steps, "diagonal"),
             moved(steps, "dead")) == (2 * 3, 2 * 3, 0)
     assert 'chiaswarm_text_prefill_block_steps_total{kind="whole"} ' in served
+    # each of those steps up-projects its key block inside the sweep: 1 +
+    # 2 + 3 blocks a head, none in memory
+    made = "chiaswarm_text_prefill_up_projections_total"
+    assert (moved(made, "kernel"), moved(made, "memory")) == (2 * 6, 0)
+    assert 'chiaswarm_text_prefill_up_projections_total{where="kernel"} ' \
+        in served
     # the decode's sweep: 15 steps of one layer, the 32 slots one block
     # that holds the prompt's 19 tokens
     swept = "chiaswarm_text_decode_key_blocks_total"

@@ -1,9 +1,26 @@
 """Layers the text stacks share (models/ling.py, models/deepseek.py,
-models/laguna.py): the norm, the SwiGLU and the rotary pieces (YaRN
-among them), latent attention (MLA) with its compressed cache, attention
-over plain keys and values with its two kinds of cache, and the expert
-layer of which a chip holds a part. One implementation of each; a stack
-tells them what differs.
+models/laguna.py, models/solar.py): the norm, the SwiGLU and the rotary
+pieces (YaRN among them), the delta rule with its recurrent state, latent
+attention (MLA) with its compressed cache, attention over plain keys and
+values with its two kinds of cache, and the expert layer of which a chip
+holds a part. One implementation of each; a stack tells them what
+differs.
+
+- **The delta rule.** Gated delta-rule linear attention with a decay
+  per channel (KDA). Per head a float32 state ``S`` (d_k x d_v):
+  ``S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T``,
+  ``o_t = S_t^T q_t``, with q, k, v through a short causal conv and SiLU,
+  q and k l2-normed, the read-out RMS-normed a head and gated. Prefill
+  computes it chunkwise (the UT form: one unit-lower-triangular solve a
+  chunk, the state carried between chunks), decode is the recurrence
+  itself. A stack's configuration gives ``DeltaRule`` its two options:
+  the write strength ``b = b_max sigmoid(W_b x)`` with ``b_max`` 1, or 2
+  where the transition may have negative eigenvalues (in (-1, 1]); and
+  the decay's form, a gate bounded below (``log a = lower sigmoid(e^A
+  (W_a x + dt_bias))``) or the softplus form (``log a = -e^A
+  softplus(W_a x + dt_bias)``), either a non-positive log-decay. Each
+  gate projection is one matrix or a low-rank pair, as the stack's
+  weights hold it (``wa`` / ``wa_down`` and ``wa_up``; ``wg`` likewise).
 
 - **Latent attention.** The cache holds 512 normed latent + 64 rotated
   key values a token (``kv_lora_rank + qk_rope_head_dim``), one entry for
@@ -24,7 +41,8 @@ tells them what differs.
   token's keys and values (``kv_heads x head_dim`` of each). A stack
   hands in the layer's head count, its rotary frequencies (as many as
   half the values of a head that are rotated: all of them, or the first
-  part), their amplitude, the softmax scale and, for a sliding layer,
+  part; None where the model encodes no position in this layer), their
+  amplitude, the softmax scale and, for a sliding layer,
   the window. A FULL layer's cache has the capacity's slots; its prefill
   writes a chunk and attends causally over what is written, its decode
   sweeps the prompt's keys and values once a key-value head for all
@@ -57,6 +75,7 @@ bfloat16 pass on a TPU otherwise).
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
@@ -182,6 +201,259 @@ def random_fill(shapes, seed: int):
         return jnp.asarray(value, spec.dtype)
 
     return jax.tree_util.tree_map_with_path(fill, shapes)
+
+
+# ---- the delta rule: gated delta-rule linear attention --------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class DeltaRule:
+    """What a stack's configuration tells the delta-rule core."""
+
+    heads: int
+    head_dim: int                       # d_k = d_v
+    conv: int                           # the short conv's kernel size
+    chunk: int                          # tokens a UT-form chunk holds
+    eps: float                          # the read-out's RMSNorm
+    #: the bounded gate's floor (``log a = lower_bound x sigmoid(...)``);
+    #: None: the softplus form ``log a = -e^A softplus(...)``
+    lower_bound: float | None = -5.0
+    #: b up to 2 (the transition's eigenvalues in (-1, 1]) where True,
+    #: up to 1 otherwise
+    allow_neg_eigval: bool = False
+
+
+def short_conv(pre, tail, weight, n_valid):
+    """Depthwise causal conv over time then SiLU. ``pre`` (B, T, C) new
+    pre-conv values, ``tail`` (B, K-1, C) the last ones before them.
+    Returns (out (B, T, C), the tail after ``n_valid`` new tokens)."""
+    k = weight.shape[0]
+    t = pre.shape[1]
+    seq = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
+    out = sum(seq[:, i:i + t].astype(jnp.float32)
+              * weight[i].astype(jnp.float32) for i in range(k))
+    new_tail = jax.lax.dynamic_slice_in_dim(seq, n_valid, k - 1, axis=1)
+    return jax.nn.silu(out).astype(pre.dtype), new_tail
+
+
+def gate_proj(p, name: str, x):
+    """x @ W for the gate ``name``: one matrix (``p[name]``) or a
+    low-rank pair (``p[name + "_down"]`` then ``p[name + "_up"]``)."""
+    if name in p:
+        return proj(x, p[name])
+    return proj(proj(x, p[f"{name}_down"]), p[f"{name}_up"])
+
+
+def kda_inputs(p, rule: DeltaRule, x, tails, n_valid):
+    """The recurrence's operands from the normed input ``x`` (B, T, d):
+    q, k, v (B, T, H, D) float32, log-decay g (B, T, H, D) and write
+    strength b (B, T, H), both float32 and neutral (0) past ``n_valid``;
+    plus the conv tails after the valid tokens."""
+    b_, t, _ = x.shape
+    h, dk = rule.heads, rule.head_dim
+    outs, new_tails = [], []
+    for name, tail in zip("qkv", tails):
+        y, nt = short_conv(proj(x, p[f"w{name}"]), tail, p[f"conv_{name}"],
+                           n_valid)
+        outs.append(y.astype(jnp.float32).reshape(b_, t, h, dk))
+        new_tails.append(nt)
+    q, k, v = outs
+
+    def l2(z):
+        return z * jax.lax.rsqrt(jnp.sum(z * z, -1, keepdims=True) + 1e-6)
+
+    q = l2(q) * dk ** -0.5
+    k = l2(k)
+    gate_in = gate_proj(p, "wa", x).astype(jnp.float32) + p["dt_bias"]
+    gate_in = gate_in.reshape(b_, t, h, dk)
+    rate = jnp.exp(p["a_log"])[None, None, :, None]
+    if rule.lower_bound is None:
+        g = -rate * jax.nn.softplus(gate_in)
+    else:
+        g = rule.lower_bound * jax.nn.sigmoid(gate_in * rate)
+    beta = jax.nn.sigmoid(proj(x, p["wb"]).astype(jnp.float32))
+    if rule.allow_neg_eigval:
+        beta = 2.0 * beta
+    valid = (jnp.arange(t) < n_valid)[None, :]
+    g = jnp.where(valid[..., None, None], g, 0.0)
+    beta = jnp.where(valid[..., None], beta, 0.0)
+    return q, k, v, g, beta, tuple(new_tails)
+
+
+def kda_recurrent_step(q, k, v, g, beta, state):
+    """One token of the recurrence. q, k, v, g (B, H, D), beta (B, H),
+    state (B, H, Dk, Dv) float32 -> (o (B, H, Dv), state)."""
+    state = state * jnp.exp(g)[..., None]
+    pred = jnp.einsum("bhk,bhkv->bhv", k, state, precision=HIGHEST)
+    u = beta[..., None] * (v - pred)
+    state = state + k[..., None] * u[..., None, :]
+    return jnp.einsum("bhk,bhkv->bhv", q, state, precision=HIGHEST), state
+
+
+def kda_block(chunk: int) -> int:
+    """Tokens a side of the sub-blocks ``kda_chunked`` cuts a chunk's two
+    matrices into: 16, or the whole chunk where 16 does not divide it."""
+    return chunk if chunk % 16 else 16
+
+
+def kda_chunked(q, k, v, g, beta, state, chunk: int):
+    """The same recurrence over T tokens, ``chunk`` at a time (T a
+    multiple of it). Inside a chunk, with G the running sum of g:
+    ``(I + Diag(b) A) U = Diag(b) (V - (K*e^G) S0)`` with
+    ``A_tj = sum_c k_tc k_jc e^(G_tc - G_jc)`` for j < t, then
+    ``O = (Q*e^G) S0 + B U`` with B the same sum over q_t k_j, j <= t,
+    and ``S = e^(G_last) S0 + (K*e^(G_last - G))^T U``.
+
+    A and B are built in row blocks of ``kda_block(chunk)`` tokens. On a
+    diagonal sub-block the decays are pairwise, ``e^(G_t - G_j)``. Left
+    of it, with r the block's first row, they are a product:
+    ``[k_t e^(G_t - G_r)] . [k_j e^(G_r - G_j)]``. G never rises (any
+    log-decay <= 0: the bounded gate's and the softplus form's alike), so
+    for j < r <= t both exponents are <= 0: nothing overflows, and a
+    factor that underflows stands for a weight under e^-87. ``b`` only
+    scales the strictly lower rows of the system, which stays unit
+    lower-triangular whatever b is (up to 2 where the transition has
+    negative eigenvalues): U is each token's ``b_t (v_t - S_{t-1}^T
+    k_t)``, bounded as the state is."""
+    b_, t, h, dk = q.shape
+    n = t // chunk
+    sub = kda_block(chunk)
+    nb = chunk // sub
+
+    def split(z):  # (B, T, H, ...) -> (N, B, H, C, ...)
+        z = z.reshape(b_, n, chunk, *z.shape[2:])
+        return jnp.moveaxis(jnp.swapaxes(z, 2, 3), 1, 0)
+
+    qs, ks, vs, gs = (split(z) for z in (q, k, v, g))
+    bs = split(beta)
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    lower_sub = jnp.tril(jnp.ones((sub, sub), bool))
+    # keys before each row block's first row
+    earlier = jnp.arange(chunk)[None, :] < sub * jnp.arange(nb)[:, None]
+
+    def blocks(z):  # (B, H, C, D) -> (B, H, C/sub, sub, D)
+        return z.reshape(b_, h, nb, sub, dk)
+
+    def in_place(diag):  # (B, H, C/sub, sub, sub) -> (B, H, C/sub, sub, C)
+        return jnp.stack([
+            jnp.pad(diag[:, :, i], ((0, 0),) * 3
+                    + ((i * sub, chunk - (i + 1) * sub),))
+            for i in range(nb)], axis=2)
+
+    def body(state, xs):
+        qc, kc, vc, gc, bc = xs                    # (B, H, C, D) / (B, H, C)
+        big_g = jnp.cumsum(gc, axis=2)
+        g_blk, q_blk, k_blk = blocks(big_g), blocks(qc), blocks(kc)
+        # diagonal sub-blocks: pairwise decays, masked BEFORE the
+        # exponential: for j > t the difference is positive and can
+        # overflow
+        diff = g_blk[:, :, :, :, None, :] - g_blk[:, :, :, None, :, :]
+        decay = jnp.exp(jnp.where(lower_sub[:, :, None], diff, -jnp.inf))
+        pair = k_blk[:, :, :, None, :, :] * decay
+        a_mat = in_place(jnp.sum(k_blk[:, :, :, :, None, :] * pair, axis=-1))
+        b_mat = in_place(jnp.sum(q_blk[:, :, :, :, None, :] * pair, axis=-1))
+        if nb > 1:
+            # left of them: rows (A's keys and B's queries in one product)
+            # and earlier keys rescaled to the row block's first row, the
+            # keys masked BEFORE the exponential too
+            first = g_blk[:, :, :, :1, :]
+            rows = jnp.stack([k_blk, q_blk], axis=2) \
+                * jnp.exp(g_blk - first)[:, :, None]
+            right = kc[:, :, None] * jnp.exp(jnp.where(
+                earlier[:, :, None], first - big_g[:, :, None], -jnp.inf))
+            left = jnp.einsum("bhxitd,bhijd->bhxitj", rows, right,
+                              precision=HIGHEST)
+            a_mat, b_mat = a_mat + left[:, :, 0], b_mat + left[:, :, 1]
+        a_mat, b_mat = (m.reshape(b_, h, chunk, chunk) for m in (a_mat, b_mat))
+        e_g = jnp.exp(big_g)
+        rhs = bc[..., None] * (vc - jnp.einsum(
+            "bhck,bhkv->bhcv", kc * e_g, state, precision=HIGHEST))
+        system = jnp.where(strict, bc[..., None] * a_mat, 0.0) \
+            + jnp.eye(chunk, dtype=jnp.float32)
+        u = jax.lax.linalg.triangular_solve(
+            system, rhs, left_side=True, lower=True, unit_diagonal=True)
+        o = jnp.einsum("bhck,bhkv->bhcv", qc * e_g, state,
+                       precision=HIGHEST) \
+            + jnp.einsum("bhcj,bhjv->bhcv", b_mat, u, precision=HIGHEST)
+        last = big_g[:, :, -1:, :]
+        state = state * jnp.exp(last)[:, :, 0, :, None] + jnp.einsum(
+            "bhck,bhcv->bhkv", kc * jnp.exp(last - big_g), u,
+            precision=HIGHEST)
+        return state, o
+
+    state, o = jax.lax.scan(body, state, (qs, ks, vs, gs, bs))
+    o = jnp.swapaxes(jnp.moveaxis(o, 0, 1), 2, 3)      # (B, N, C, H, Dv)
+    return o.reshape(b_, t, h, -1), state
+
+
+def kda_out(p, rule: DeltaRule, x, o):
+    """Per-head RMSNorm of the read-out, the sigmoid output gate, W_o."""
+    b_, t = x.shape[:2]
+    o = rms_norm(o, p["o_norm"], rule.eps)
+    gate = jax.nn.sigmoid(gate_proj(p, "wg", x).astype(jnp.float32))
+    o = (o * gate.reshape(o.shape)).astype(x.dtype)
+    return proj(o.reshape(b_, t, -1), p["wo"])
+
+
+@scope("recurrence")
+def kda_prefill(p, rule: DeltaRule, x, cache, n_valid):
+    """x (B, T, d) -> (y, cache): cache = (state, (tail_q, tail_k,
+    tail_v))."""
+    state, tails = cache
+    q, k, v, g, beta, tails = kda_inputs(p, rule, x, tails, n_valid)
+    o, state = kda_chunked(q, k, v, g, beta, state,
+                           min(rule.chunk, x.shape[1]))
+    return kda_out(p, rule, x, o), (state, tails)
+
+
+@scope("recurrence")
+def kda_decode(p, rule: DeltaRule, x, cache):
+    """x (B, 1, d): the recurrence itself."""
+    state, tails = cache
+    q, k, v, g, beta, tails = kda_inputs(p, rule, x, tails, 1)
+    o, state = kda_recurrent_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                  beta[:, 0], state)
+    return kda_out(p, rule, x, o[:, None]), (state, tails)
+
+
+def kda_empty_cache(rule: DeltaRule, batch: int, dtype):
+    """(state (B, H, D, D) float32, three conv tails (B, K-1, H x D))."""
+    h, dk = rule.heads, rule.head_dim
+    tail = jnp.zeros((batch, rule.conv - 1, h * dk), jnp.dtype(dtype))
+    return (jnp.zeros((batch, h, dk, dk), jnp.float32), (tail,) * 3)
+
+
+def kda_state_bytes(rule: DeltaRule, item: int) -> int:
+    """Bytes of one row's recurrent state and conv tails in one layer."""
+    h, dk = rule.heads, rule.head_dim
+    return h * dk * dk * 4 + 3 * (rule.conv - 1) * h * dk * item
+
+
+# what the host knows of a job's delta-rule layers, for the counters
+
+
+def kda_blocks(rule: DeltaRule, layers: int, prompt_tokens: int,
+               chunk: int) -> tuple[int, int]:
+    """(sub-blocks of the in-chunk matrices ``kda_chunked`` builds from
+    pairwise decays, sub-blocks it builds as products), summed over
+    ``layers`` delta-rule layers, a prompt's prefill chunks and their
+    sub-chunks: host integers, for the counter."""
+    sub_chunk = min(rule.chunk, chunk)
+    nb = sub_chunk // kda_block(sub_chunk)
+    n = layers * -(-prompt_tokens // chunk) * (chunk // sub_chunk)
+    return n * nb, n * nb * (nb - 1) // 2
+
+
+def recurrent_state_bytes(rule: DeltaRule, layers: int, item: int,
+                          prompt_tokens: int, rows: int, new: int,
+                          chunk: int) -> dict[str, int]:
+    """Bytes of recurrent state (the float32 state and the conv tails)
+    that ``layers`` delta-rule layers read and write in a job, by phase:
+    each prefill chunk reads one row's once and writes them once, each
+    of the decode's ``new - 1`` steps every row's."""
+    one = kda_state_bytes(rule, item)
+    return {"prefill": layers * -(-prompt_tokens // chunk) * 2 * one,
+            "decode": layers * (new - 1) * rows * 2 * one}
 
 
 # ---- latent attention ----------------------------------------------------
@@ -380,11 +652,14 @@ def _rotated(x, positions, inv_freq, amplitude: float):
 def _queries_keys_values(p, x, positions, heads: int, kv_heads: int,
                          inv_freq, amplitude: float):
     """q (B, T, H, D) and k, v (B, T, Hk, D) of the normed input x
-    (B, T, d), q and k rotated at ``positions`` (T,)."""
+    (B, T, d), q and k rotated at ``positions`` (T,); neither rotated
+    where ``inv_freq`` is None (no position encoding)."""
     b_, t, _ = x.shape
     q = proj(x, p["wq"]).reshape(b_, t, heads, -1)
     k = proj(x, p["wk"]).reshape(b_, t, kv_heads, -1)
     v = proj(x, p["wv"]).reshape(b_, t, kv_heads, -1)
+    if inv_freq is None:
+        return q, k, v
     return (_rotated(q, positions, inv_freq, amplitude),
             _rotated(k, positions, inv_freq, amplitude), v)
 
@@ -613,6 +888,30 @@ def held_experts_part(p, cfg, x, chosen, weight, valid):
     w_held = jnp.where(held.reshape(t, k), weight, 0.0)
     y = jnp.einsum("tkd,tk->td", gathered, w_held, precision=HIGHEST)
     return y.astype(x.dtype), jnp.sum(held), jnp.sum(counts > 0)
+
+
+def sigmoid_route(p, cfg, x):
+    """x (T, d) -> (chosen experts (T, K) int32, their weights (T, K)
+    float32): sigmoid scores over ALL experts; chosen by score + bias,
+    the best ``topk_group`` of ``n_group`` groups by the sum of their top
+    two (one group: no choice of groups), then the best K inside them;
+    weights are the scores of the chosen, summing to one, times the
+    scaling factor."""
+    t = x.shape[0]
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["router"],
+                                    precision=HIGHEST))
+    choose = scores + p["router_bias"]
+    groups = choose.reshape(t, cfg.n_group, -1)
+    group_score = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(group_score, cfg.topk_group)
+    keep = jnp.zeros((t, cfg.n_group), bool).at[
+        jnp.arange(t)[:, None], best].set(True)
+    masked = jnp.where(keep[..., None], groups, -jnp.inf).reshape(t, -1)
+    _, chosen = jax.lax.top_k(masked, cfg.num_experts_per_tok)
+    weight = jnp.take_along_axis(scores, chosen, axis=-1)
+    weight = weight / jnp.sum(weight, -1, keepdims=True) \
+        * cfg.routed_scaling_factor
+    return chosen.astype(jnp.int32), weight
 
 
 def moe(p, cfg, x, route, valid=None):

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import importlib
 
-NAMES = ("ling", "deepseek", "laguna")
+NAMES = ("ling", "deepseek", "laguna", "solar")
 DEFAULT = "ling"
 
 

@@ -96,6 +96,18 @@ SMOKE_JOBS: dict[str, dict[str, Any]] = {
         "logprobs": True,
         "content_type": "application/json",
     },
+    "txt2txt_solar": {
+        # the fourth text stack (models/solar.py): delta-rule layers beside
+        # softmax attention over plain keys and values
+        "id": "smoke-txt2txt-solar",
+        "workflow": "txt2txt",
+        "model_name": "random/solar_tiny",
+        "prompt": "ab cd ab ba",
+        "max_new_tokens": 4,
+        "num_return_sequences": 2,
+        "logprobs": True,
+        "content_type": "application/json",
+    },
     "tts": {
         # the reference's bark smoke job (swarm/test.py:45-51)
         "id": "smoke-tts",
@@ -176,6 +188,8 @@ def run_smoke(workflow: str, random_weights: bool = True) -> dict[str, Any]:
                  {"name": "random/deepseek_tiny", "stack": "deepseek",
                   "prefill_chunk": 8, "max_context": 32},
                  {"name": "random/laguna_tiny", "stack": "laguna",
+                  "prefill_chunk": 16, "max_context": 64},
+                 {"name": "random/solar_tiny", "stack": "solar",
                   "prefill_chunk": 16, "max_context": 64}],
         allow_random=random_weights,
     )

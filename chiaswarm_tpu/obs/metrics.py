@@ -1022,6 +1022,16 @@ TEXT_KDA_BLOCKS = REGISTRY.counter(
     "whether they are built from pairwise decays or as a product",
     labelnames=("form",))
 
+#: bytes of recurrent state (the float32 state and the conv tails) the
+#: delta-rule layers read and write, by phase: a prefill chunk reads and
+#: writes one row's once, a decode step every row's (host integers of
+#: ``text_layers.recurrent_state_bytes``; both delta-rule stacks)
+TEXT_RECURRENT_STATE_BYTES = REGISTRY.counter(
+    "chiaswarm_text_recurrent_state_bytes_total",
+    "bytes of recurrent state the delta-rule layers read and write, by "
+    "phase",
+    labelnames=("phase",))
+
 #: bytes of each kind of cache the last decode held
 TEXT_CACHE_BYTES = REGISTRY.gauge(
     "chiaswarm_text_cache_bytes",

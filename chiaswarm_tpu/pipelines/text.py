@@ -281,6 +281,8 @@ class TextPipeline:
             pairwise, product = counts["kda_blocks"]
             metrics.TEXT_KDA_BLOCKS.inc(pairwise, form="pairwise")
             metrics.TEXT_KDA_BLOCKS.inc(product, form="product")
+            for phase, size in counts["recurrent_state_bytes"].items():
+                metrics.TEXT_RECURRENT_STATE_BYTES.inc(size, phase=phase)
         # a stack with sliding-window layers
         for kind, pairs in counts.get("window_pairs", {}).items():
             metrics.TEXT_WINDOW_PAIRS.inc(pairs, kind=kind)

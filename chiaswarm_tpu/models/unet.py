@@ -356,6 +356,37 @@ def mid_trunk(cfg: UNetConfig, dtype: jnp.dtype, x: jnp.ndarray,
     return ResnetBlock(mid_ch, dtype, name="mid_resnets_1")(x, temb)
 
 
+def self_attention_sites(cfg: UNetConfig, height: int, width: int, *,
+                         part: str = "full") -> dict[tuple[int, int, int],
+                                                     int]:
+    """The spatial self-attention calls of one pass over a (height,
+    width) latent, by (tokens, heads, head size): host integers of the
+    blocks ``down_trunk``, ``mid_trunk`` and the up path build. ``part``:
+    ``"full"``, the whole network; ``"shallow"``, the DeepCache replay
+    (level 0's down and up blocks); ``"trunk"``, the down path and the
+    mid block (ControlNet's share)."""
+    channels = list(cfg.block_out_channels)
+    up_blocks = 0 if part == "trunk" else cfg.layers_per_block + 1
+    sites: dict[tuple[int, int, int], int] = {}
+
+    def add(tokens: int, ch: int, level: int, calls: int) -> None:
+        key = (tokens, *cfg.heads_for(ch, level))
+        sites[key] = sites.get(key, 0) + calls
+
+    h, w = height, width
+    for level, ch in enumerate(channels):
+        depth = cfg.transformer_depth[level]
+        if depth and (level == 0 or part != "shallow"):
+            add(h * w, ch, level,
+                depth * (cfg.layers_per_block + up_blocks))
+        if level < len(channels) - 1:
+            h, w = -(-h // 2), -(-w // 2)      # 3x3, stride 2, padding 1
+    if part != "shallow":
+        add(h * w, channels[-1], len(channels) - 1,
+            max(cfg.transformer_depth) or 1)
+    return sites
+
+
 class UNet(nn.Module):
     """Returns the model prediction (epsilon/v per family) for NHWC latents.
 

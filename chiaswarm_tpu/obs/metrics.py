@@ -1032,6 +1032,18 @@ TEXT_RECURRENT_STATE_BYTES = REGISTRY.counter(
     "phase",
     labelnames=("phase",))
 
+#: grid steps of the diffusion flash kernel (``ops/flash_attention.py``)
+#: by the update it runs in them, summed over the UNet's self-attention
+#: calls that take the kernel, once a lane step (host integers of
+#: ``DiffusionPipeline.stepper_flash_block_steps``, fixed when the lane's
+#: step program is built): "whole" = a key block scored without a mask,
+#: "masked" = a padded last key block
+FLASH_BLOCK_STEPS = REGISTRY.counter(
+    "chiaswarm_flash_block_steps_total",
+    "grid steps of the diffusion flash kernel a lane step runs, by "
+    "whether the key block is scored whole or masked for padding",
+    labelnames=("kind",))
+
 #: bytes of each kind of cache the last decode held
 TEXT_CACHE_BYTES = REGISTRY.gauge(
     "chiaswarm_text_cache_bytes",

@@ -362,21 +362,31 @@ def attention(
         # correctness first, fall through to the local paths
         impl = "auto"
 
-    use_flash = False
-    if impl == "flash":
-        use_flash = True
-    elif impl == "auto":
-        # The Pallas kernel from 1024 tokens up; tiny KV (77-token text
-        # cross-attention) and small spatial grids stay on the einsum
-        # path. The boundary was swept on an installation that is gone
-        # and has not been swept on this chip (ROADMAP S3: head sizes
-        # under 128 at short KV may belong on the XLA path).
-        use_flash = (
-            jax.default_backend() == "tpu"
-            and q.shape[1] >= 1024
-            and k.shape[1] >= 1024
-        )
-
-    if use_flash:
+    if _local_flash(impl, q.shape[1], k.shape[1]):
         return _out_tap(_flash(q, k, v, scale))
     return _out_tap(_xla_attention(q, k, v, scale))
+
+
+def _local_flash(impl: str, l: int, s: int) -> bool:
+    """Whether a call that stays on the local paths takes the flash
+    kernel: ``"flash"``, or ``"auto"`` on a TPU from 1024 tokens up; tiny
+    KV (77-token text cross-attention) and small spatial grids stay on
+    the einsum path. The boundary was swept on an installation that is
+    gone and has not been swept on this chip (ROADMAP S3: head sizes
+    under 128 at short KV may belong on the XLA path)."""
+    if impl == "flash":
+        return True
+    return (impl == "auto" and jax.default_backend() == "tpu"
+            and l >= 1024 and s >= 1024)
+
+
+def takes_flash(l: int, s: int, impl: AttentionImpl = "auto") -> bool:
+    """Whether ``attention(impl=impl)`` of ``l`` queries against ``s``
+    keys, with no sequence-parallel mesh, runs the local flash kernel
+    (``CHIASWARM_ATTENTION`` applied to ``"auto"`` as there): for the
+    counters, which price a program's calls from its shapes."""
+    if impl == "auto":
+        impl = _env_impl() or impl
+    if impl in ("ring", "ring_flash"):
+        impl = "auto"   # no seq mesh: the local paths, as attention() falls
+    return _local_flash(impl, l, s)

@@ -22,9 +22,10 @@ entries that share one kernel body, one set of index maps and one
 
 The local flash kernel (ops/flash_attention.py) sweeps every key block of
 every call and masks block padding only. This one is its causal twin; its
-two decode entries share ``online_softmax_block_update`` with it, its two
-prefill entries have an update of their own (``_update_by_tiles``), and
-nothing that picks shapes is shared:
+two decode entries share ``online_softmax_block_update`` with the ring
+kernel, its two prefill entries cut block pairs into tiles of their own
+(``_update_by_tiles``) and update each tile by the local kernel's body
+(``update_tile``), and nothing that picks shapes is shared:
 
 - grid = (batch, heads, Q blocks, KV blocks), KV innermost, the running
   max / denominator / accumulator in float32 VMEM scratch, as there.
@@ -129,6 +130,7 @@ from chiaswarm_tpu.ops.flash_attention import (
     _clamp_block,
     _pad_to,
     online_softmax_block_update,
+    update_tile,
 )
 
 # The prefill's blocks. 2048 x 1024 from the sweep at 128 heads on a v5e
@@ -356,20 +358,16 @@ def _update_by_tiles(q_ref, qs_ref, k_ref, ks_ref, v_ref, scratch, cut, *,
     layer's eight chunks, 119.99 with all four): the two products are ONE
     contraction over the concatenated (128 + 128-padded) keys, so that
     the partial products meet as they leave the MXU and the first is not
-    stored (+6.7 apart); the probabilities go to the MXU as the values'
-    dtype, which the MXU's float32 pass rounded them to anyway (+1.9);
-    the running max is kept of the UNSCALED logits and ``scale x
-    log2(e)`` is one multiply inside ``exp2`` (+3.2); the denominator is
-    kept a lane apart and summed over lanes at the end (+5.3). Key
-    strips inside a pair are slower (512 keys +7.6, 256 keys +70: the
-    compiler does not overlap a strip's products with the last one's
-    softmax), so a pair's keys go in one piece."""
-    m_scr, l_scr, acc_scr = scratch
+    stored (+6.7 apart); then a tile's update is the diffusion kernel's,
+    ``ops/flash_attention.py::update_tile`` (bfloat16 probabilities +1.9,
+    the scale inside ``exp2`` +3.2, the denominator summed over lanes at
+    the end +5.3). Key strips inside a pair are slower (512 keys +7.6,
+    256 keys +70: the compiler does not overlap a strip's products with
+    the last one's softmax), so a pair's keys go in one piece."""
     block_q, block_kv = q_ref.shape[1], k_ref.shape[1]
     traced = cut is True
     diagonal = cut is not None and not traced
     tile = _diagonal_tile(block_q) if diagonal else block_q
-    factor = scale * 1.4426950408889634             # exp(x) = exp2(x log2 e)
     for top in range(0, block_q, tile):
         rows = slice(top, top + tile)
         # local row r sees local key c when c + cut <= r
@@ -398,24 +396,9 @@ def _update_by_tiles(q_ref, qs_ref, k_ref, ks_ref, v_ref, scratch, cut, *,
             visible = col + (cut - top) <= row
         if visible is not None:
             s = jnp.where(visible, s, _NEG_INF)
-        m_prev = m_scr[rows, :1]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp2((m_prev - m_next) * factor)
-        p = jnp.exp2((s - m_next) * factor)             # (tile, end) fp32
-        if end % _LANES:        # small shapes: the whole sum in lane 0
-            lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
-            lanes = jnp.where(lane == 0,
-                              jnp.sum(p, axis=-1, keepdims=True), 0.0)
-        else:
-            lanes = p[:, :_LANES]
-            for t in range(_LANES, end, _LANES):
-                lanes = lanes + p[:, t:t + _LANES]
-        l_scr[rows, :] = alpha * l_scr[rows, :] + lanes
-        v = values(True) if traced and g > 1 else v_ref[0, :end, :]
-        acc_scr[rows, :] = acc_scr[rows, :] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[rows, :] = jnp.broadcast_to(m_next, (tile, _LANES))
+        update_tile(s, functools.partial(values, True) if traced and g > 1
+                    else lambda: v_ref[0, :end, :], scratch, rows,
+                    scale=scale)
 
 
 def _index_maps(block_q: int, block_kv: int, g: int = 1,

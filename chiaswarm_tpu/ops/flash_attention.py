@@ -20,11 +20,24 @@ Head dims of SD UNets (40/80/160/64/128) are zero-padded up to the 128-lane
 tile; padded lanes contribute zero logits and zero values, so results are
 exact. Sequence lengths pad up to the block size with -inf-masked logits.
 
-Default blocks (2048 q x 1024 kv) come from an end-to-end sweep on an
-installation that is gone; on this chip they read 31.7% / 22.6% of the
-roofline at the stated head sizes (`flash_roofline.lat`, ledger, PR 29)
-and no other block size has been run. Large q blocks amortize the scratch
-traffic; the kernel clamps blocks to the (padded) sequence length.
+The block sweep: a key block is scored by ``update_tile``, the causal
+prefill's tile update (the max of the unscaled logits, the scale inside
+``exp2``, the denominator kept per lane and summed once at the end,
+bfloat16 probabilities into the PV product). A block is masked only where
+it holds padding: where the key length fills whole key blocks (every SD /
+SDXL UNet self-attention) no mask is traced at all, else only the last key
+block of a query block is masked (``block_steps`` counts both kinds).
+
+On a v5e this update reads 39.7% / 26.4% of the roofline at the stated
+head sizes of the SDXL / SD1.5 steps (`flash_roofline.lat`; 31.8% / 22.6%
+with the update before it): a call at 4096 tokens 1.257 -> 1.088 ms
+(SDXL, 20 programs of 2048 x 1024 blocks), at 1024 tokens 0.190 -> 0.138
+ms (PERF.md). Default blocks (2048 q x 1024 kv) come from an end-to-end
+sweep on an installation that is gone, and hold on this chip: at 4096
+tokens 2048 x 2048 blocks are 2.7% and 1024 x 2048 4.4% slower a call,
+though the compiler's bundle counts rank both ahead. Large q blocks
+amortize the scratch traffic; the kernel clamps blocks to the (padded)
+sequence length.
 
 The same kernel runs in Pallas interpret mode on CPU, which is how the
 hermetic test suite validates it against the einsum reference
@@ -69,9 +82,11 @@ def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
                                 row_offset=None, shared=None,
                                 rows_per_position: int = 1):
     """One KV block of the running-softmax recurrence, shared by the
-    local flash kernel below, the fused ring kernel
-    (ops/ring_flash_attention.py) and the causal kernel's decode entries
-    (ops/causal_flash_attention.py). All operands are plain arrays (the
+    fused ring kernel (ops/ring_flash_attention.py) and the causal
+    kernel's decode entries (ops/causal_flash_attention.py), which are
+    bound by bandwidth; the sweeps that score tiles (the local flash
+    kernel below, the causal prefill) run ``update_tile``. All operands
+    are plain arrays (the
     callers own the scratch refs): q (bq, d), k/v (bkv, d), m/l (bq, 1)
     running max/denominator, acc (bq, d) fp32 accumulator. ``col_offset``
     is the block's first GLOBAL kv column (masks padding past
@@ -139,9 +154,55 @@ def online_softmax_block_update(q, k, v, m_prev, l_prev, acc_prev, *,
     return m_next, l_next, acc_next
 
 
+def update_tile(s, values, scratch, rows, *, scale: float):
+    """One score tile of the running softmax, as every sweep that scores
+    by tiles runs it: the diffusion kernel below and the causal prefill's
+    (``ops/causal_flash_attention.py::_update_by_tiles``). ``s`` (tile,
+    keys) float32 holds the tile's logits UNSCALED, hidden ones already
+    set to ``_NEG_INF``; ``values()`` reads the keys' (keys, Dv) values
+    (called where the product needs them); ``scratch`` = the (m, l, acc)
+    VMEM refs, of which the tile owns ``rows``.
+
+    - the running max is kept of the unscaled logits, and ``scale x
+      log2(e)`` is one multiply inside ``exp2``;
+    - the denominator is kept a lane apart in ``l`` (rows, 128): the
+      caller's finalize sums its lanes, once;
+    - the probabilities go to the MXU as the values' dtype, which the
+      MXU's float32 pass rounded them to anyway.
+
+    Each of the three was judged on a v5e in the causal prefill (PERF.md:
+    +3.2, +5.3 and +1.9 ms of ~120); together, with no padding mask, they
+    take a 2048 x 1024 step of the diffusion kernel from 12,092 to 10,648
+    bundles in the compiler's schedule and from ~7.9 to ~6.8 us."""
+    m_scr, l_scr, acc_scr = scratch
+    tile, end = s.shape
+    factor = scale * 1.4426950408889634             # exp(x) = exp2(x log2 e)
+    m_prev = m_scr[rows, :1]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp2((m_prev - m_next) * factor)
+    p = jnp.exp2((s - m_next) * factor)             # (tile, end) fp32
+    if end % _LANES:            # small shapes: the whole sum in lane 0
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+        lanes = jnp.where(lane == 0,
+                          jnp.sum(p, axis=-1, keepdims=True), 0.0)
+    else:
+        lanes = p[:, :_LANES]
+        for t in range(_LANES, end, _LANES):
+            lanes = lanes + p[:, t:t + _LANES]
+    l_scr[rows, :] = alpha * l_scr[rows, :] + lanes
+    v = values()
+    acc_scr[rows, :] = acc_scr[rows, :] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[rows, :] = jnp.broadcast_to(m_next, (tile, _LANES))
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                   scale: float, kv_len: int, block_kv: int):
-    """One (q-block, kv-block) tile of the running-softmax recurrence."""
+    """One (q-block, kv-block) tile of the running-softmax recurrence.
+    A key block is masked only where it holds padding: none where
+    ``kv_len`` fills whole key blocks (the masked body is not traced),
+    else the last one alone."""
     j = pl.program_id(2)
     n_kv = pl.num_programs(2)
 
@@ -151,18 +212,27 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    m_next, l_next, acc_next = online_softmax_block_update(
-        q_ref[0], k_ref[0], v_ref[0],
-        m_scr[:, :1], l_scr[:, :1], acc_scr[:],
-        scale=scale, kv_len=kv_len, col_offset=j * block_kv,
-    )
-    acc_scr[:] = acc_next
-    m_scr[:] = jnp.broadcast_to(m_next, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_next, l_scr.shape)
+    def update(masked: bool):
+        s = jax.lax.dot_general(q_ref[0], k_ref[0],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if masked:              # key columns past kv_len: block padding
+            col = j * block_kv + jax.lax.broadcasted_iota(jnp.int32,
+                                                          s.shape, 1)
+            s = jnp.where(col < kv_len, s, _NEG_INF)
+        update_tile(s, lambda: v_ref[0], (m_scr, l_scr, acc_scr),
+                    slice(None), scale=scale)
+
+    if kv_len % block_kv == 0:
+        update(False)
+    else:
+        pl.when(j < n_kv - 1)(functools.partial(update, False))
+        pl.when(j == n_kv - 1)(functools.partial(update, True))
 
     @pl.when(j == n_kv - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+    def _finalize():            # the denominator's lanes are summed here
+        o_ref[0] = (acc_scr[:] / jnp.sum(l_scr[:], axis=-1, keepdims=True)
+                    ).astype(o_ref.dtype)
 
 
 def _clamp_block(length: int, block: int) -> int:
@@ -201,6 +271,45 @@ def _pick_block(length: int, default: int) -> int:
         if key < best_key:
             best_key, best = key, cand
     return best
+
+
+def _blocks(l: int, s: int, block_q: int | None,
+            block_kv: int | None) -> tuple[int, int]:
+    """(query rows, keys) a block of ``flash_attention`` holds for ``l``
+    queries against ``s`` keys. None = auto (divisibility-aware pick,
+    unless an env sweep pins the block); an explicit caller/env value is
+    honored, clamped only to the padded sequence length. (A per-shape
+    measured-pair override was tried and rejected on an installation
+    that is gone: the kernel alone, tools/flash_sweep.py, preferred
+    1152x2304 at 2304 tokens where the end-to-end program did not. The
+    lesson stands, the numbers do not: judge a block size by the whole
+    program, in a benchmark cell.)"""
+    if block_q is None:
+        block_q = (_clamp_block(l, _DEFAULT_BLOCK_Q) if _ENV_PINNED
+                   else _pick_block(l, _DEFAULT_BLOCK_Q))
+    else:
+        block_q = _clamp_block(l, block_q)
+    if block_kv is None:
+        block_kv = (_clamp_block(s, _DEFAULT_BLOCK_KV) if _ENV_PINNED
+                    else _pick_block(s, _DEFAULT_BLOCK_KV))
+    else:
+        block_kv = _clamp_block(s, block_kv)
+    return block_q, block_kv
+
+
+def block_steps(bh: int, l: int, s: int, block_q: int | None = None,
+                block_kv: int | None = None) -> dict[str, int]:
+    """Grid steps of one ``flash_attention`` call over ``bh`` = batch x
+    heads programs, ``l`` queries against ``s`` keys, by the update the
+    kernel runs in them (its own test on host integers, for the
+    counters): ``whole`` = a key block scored without a mask, ``masked``
+    = the last key block of a query block where ``s`` leaves it padded.
+    Blocks None = the call's own pick."""
+    block_q, block_kv = _blocks(l, s, block_q, block_kv)
+    query_blocks = bh * -(-l // block_q)
+    masked = query_blocks if s % block_kv else 0
+    return {"whole": query_blocks * -(-s // block_kv) - masked,
+            "masked": masked}
 
 
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
@@ -243,24 +352,7 @@ def flash_attention(
 
     qf, kf, vf = fold(q), fold(k), fold(v)
 
-    # None = auto (divisibility-aware pick, unless an env sweep pins the
-    # block); an explicit caller/env value is honored, clamped only to
-    # the padded sequence length. (A per-shape measured-pair override
-    # was tried and rejected on an installation that is gone: the
-    # kernel alone, tools/flash_sweep.py, preferred 1152x2304 at 2304
-    # tokens where the end-to-end program did not. The lesson stands,
-    # the numbers do not: judge a block size by the whole program, in
-    # a benchmark cell.)
-    if block_q is None:
-        block_q = (_clamp_block(l, _DEFAULT_BLOCK_Q) if _ENV_PINNED
-                   else _pick_block(l, _DEFAULT_BLOCK_Q))
-    else:
-        block_q = _clamp_block(l, block_q)
-    if block_kv is None:
-        block_kv = (_clamp_block(s, _DEFAULT_BLOCK_KV) if _ENV_PINNED
-                    else _pick_block(s, _DEFAULT_BLOCK_KV))
-    else:
-        block_kv = _clamp_block(s, block_kv)
+    block_q, block_kv = _blocks(l, s, block_q, block_kv)
     qf = _pad_to(qf, 1, block_q)
     kf = _pad_to(kf, 1, block_kv)
     vf = _pad_to(vf, 1, block_kv)

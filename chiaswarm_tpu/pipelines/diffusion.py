@@ -894,6 +894,36 @@ class DiffusionPipeline:
         return GLOBAL_CACHE.cached_executable(
             static_cache_key(id(self.c), "stepper_step", statics), build)
 
+    def stepper_flash_block_steps(self, *, batch: int, height: int,
+                                  width: int, has_control: bool = False,
+                                  shallow: bool = False) -> dict[str, int]:
+        """Grid steps of the flash kernel one ``stepper_step_fn`` step
+        runs, by kind (``ops/flash_attention.py::block_steps``): over the
+        UNet's spatial self-attentions at the lane's shape that take the
+        kernel, the uncond and cond halves of ``batch`` rows each (and
+        ControlNet's trunk with ``has_control``; only level 0's blocks on
+        a DeepCache replay, ``shallow``). Host integers, for the counter
+        ``chiaswarm_flash_block_steps_total``."""
+        from chiaswarm_tpu.models.unet import self_attention_sites
+        from chiaswarm_tpu.ops.attention import takes_flash
+        from chiaswarm_tpu.ops.flash_attention import block_steps
+
+        cfg = self.c.unet.config
+        lh, lw = self._latent_hw(height, width)
+        parts = ["shallow" if shallow else "full"]
+        if has_control:
+            parts.append("trunk")
+        total = {"whole": 0, "masked": 0}
+        for part in parts:
+            sites = self_attention_sites(cfg, lh, lw, part=part)
+            for (tokens, heads, _head), calls in sites.items():
+                if not takes_flash(tokens, tokens, cfg.attn_impl):
+                    continue
+                steps = block_steps(2 * batch * heads, tokens, tokens)
+                for kind, n in steps.items():
+                    total[kind] += calls * n
+        return total
+
     def stepper_control_embed_fn(self, *, height: int, width: int):
         """(embed_params, cond (1, H, W, 3) in [0, 1]) -> (1, lh, lw, C0)
         hint embedding — the admission-time ControlNet prep. The embedder

@@ -109,6 +109,7 @@ import numpy as np
 
 from chiaswarm_tpu.obs import numerics as _numerics
 from chiaswarm_tpu.obs.metrics import (
+    FLASH_BLOCK_STEPS,
     REGISTRY,
     STEPPER_UNET_EVAL_MODES,
     arrival_rate_gauge,
@@ -221,6 +222,8 @@ for _workload in WORKLOADS:
 for _mode in STEPPER_UNET_EVAL_MODES:
     _UNET_EVALS.inc(0, mode=_mode)
 _STEPS_SKIPPED.inc(0)
+for _kind in ("whole", "masked"):
+    FLASH_BLOCK_STEPS.inc(0, kind=_kind)
 
 
 # ---- resume-state packing ------------------------------------------------
@@ -527,6 +530,9 @@ class Lane:
         self._h_reuse = np.zeros((self.width, self.steps_cap), bool)
         self._h_cache_ok = np.zeros(self.width, bool)
         self._h_skipped = np.zeros(self.width, np.int64)
+        # the flash kernel's grid steps a step runs, by (width, replay):
+        # host integers fixed with the step program, for the counter
+        self._flash_steps: dict[tuple[int, bool], dict[str, int]] = {}
         self._dev = None  # device state dict, allocated at first admission
         self._mesh = None
         self._deferred_counts: list[dict] = []
@@ -1194,6 +1200,8 @@ class Lane:
                 # a full step refreshed every active row's cache
                 self._h_cache_ok[self._h_active] = True
             _UNET_EVALS.inc(active, mode="full")
+        for kind, n in self._flash_block_steps(reuse_now).items():
+            FLASH_BLOCK_STEPS.inc(n, kind=kind)
         self._h_idx[self._h_active] += 1
         self.steps_executed += 1
         self._sched._count(steps_executed=1, row_steps_active=active,
@@ -1228,6 +1236,16 @@ class Lane:
                 device_guard.note_slow_step(devices)
             else:
                 device_guard.note_ok(devices)
+
+    def _flash_block_steps(self, shallow: bool) -> dict[str, int]:
+        """The flash kernel's grid steps of one step at the lane's
+        current width (a DeepCache replay runs level 0's blocks alone)."""
+        key = (self.width, shallow)
+        if key not in self._flash_steps:
+            self._flash_steps[key] = self.pipe.stepper_flash_block_steps(
+                batch=self.width, height=self.height, width=self.width_px,
+                has_control=self.ctrl is not None, shallow=shallow)
+        return self._flash_steps[key]
 
     def _retire_rows(self) -> None:
         """Retire finished rows (decode dispatched async — it overlaps the

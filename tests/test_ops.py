@@ -10,25 +10,49 @@ from chiaswarm_tpu.ops.attention import _xla_attention, attention
 from chiaswarm_tpu.ops.flash_attention import flash_attention
 
 
+#: (b, l, s, h, d) in 64 x 64 blocks: key lengths that fill whole key
+#: blocks (no mask is traced) and lengths that leave the last one padded
+#: (masked there alone), at the UNets' head sizes 40 / 64 / 80 / 160 and
+#: the lane width
+_FLASH_CASES = [
+    (2, 64, 64, 4, 40),    # SD1.5-style self-attention head_dim 40
+    (1, 100, 77, 2, 64),   # cross-attention: text KV of 77 tokens
+    (1, 300, 300, 2, 80),  # non-multiple-of-block lengths
+    (2, 128, 128, 1, 128), # exact lane-width head dim
+    (1, 96, 256, 2, 64),   # four whole key blocks
+    (2, 130, 192, 2, 40),  # three whole key blocks, a padded query block
+    (1, 128, 200, 2, 160), # a padded last key block, head dim 160
+    (1, 64, 128, 3, 80),   # two whole key blocks, head dim 80
+    (1, 64, 136, 2, 160),  # a last key block of 8 keys, head dim 160
+]
+
+
+def _flash_case(case, dtype: str):
+    """The float32 cases keep the ids they had before bfloat16 joined."""
+    name = "-".join(map(str, case))
+    return pytest.param(*case, dtype, id=name if dtype == "float32"
+                        else f"{name}-{dtype}")
+
+
 @pytest.mark.parametrize(
-    "b,l,s,h,d",
-    [
-        (2, 64, 64, 4, 40),    # SD1.5-style self-attention head_dim 40
-        (1, 100, 77, 2, 64),   # cross-attention: text KV of 77 tokens
-        (1, 300, 300, 2, 80),  # non-multiple-of-block lengths
-        (2, 128, 128, 1, 128), # exact lane-width head dim
-    ],
+    "b,l,s,h,d,dtype",
+    [_flash_case(case, dtype) for dtype in ("float32", "bfloat16")
+     for case in _FLASH_CASES],
 )
-def test_flash_matches_einsum(b, l, s, h, d):
+def test_flash_matches_einsum(b, l, s, h, d, dtype):
+    """The kernel against the einsum at the tolerances of each I/O dtype:
+    float32 2e-4; bfloat16 3e-2 against the float32 einsum of the same
+    bfloat16 inputs (the probabilities reach the MXU as bfloat16)."""
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(kq, (b, l, h, d), jnp.float32)
-    k = jax.random.normal(kk, (b, s, h, d), jnp.float32)
-    v = jax.random.normal(kv, (b, s, h, d), jnp.float32)
+    q, k, v = (jax.random.normal(key, (b, n, h, d), jnp.dtype(dtype))
+               for key, n in ((kq, l), (kk, s), (kv, s)))
     scale = d ** -0.5
-    ref = _xla_attention(q, k, v, scale)
+    ref = _xla_attention(*(x.astype(jnp.float32) for x in (q, k, v)), scale)
     got = flash_attention(q, k, v, block_q=64, block_kv=64, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
+    assert got.dtype == q.dtype
+    tol = 2e-4 if dtype == "float32" else 3e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(ref),
+                               rtol=tol, atol=tol)
 
 
 def test_flash_bf16_io():
@@ -109,6 +133,52 @@ def test_flash_cross_lowers_for_tpu(shape):
     assert "tpu_custom_call" in lowered.as_text()
 
 
+@pytest.mark.parametrize("shape", _TPU_SELF_ATTENTION_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_no_key_block_is_masked_at_the_served_shapes(shape):
+    """Every served self-attention length fills whole key blocks of the
+    picked size: ``block_steps`` counts no masked step, every step of the
+    grid whole, and the kernel's jaxpr holds no mask at all (no iota, no
+    comparison with the key length)."""
+    from chiaswarm_tpu.ops.flash_attention import _blocks, block_steps
+
+    b, n, h, _ = shape
+    block_q, block_kv = _blocks(n, n, None, None)
+    steps = block_steps(b * h, n, n)
+    assert steps == {"whole": b * h * -(-n // block_q) * (n // block_kv),
+                     "masked": 0}
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    text = str(jax.make_jaxpr(
+        lambda q, k, v: flash_attention(q, k, v, interpret=True))(x, x, x))
+    assert "pallas_call" in text and "iota" not in text
+
+
+#: (batch x heads, queries, keys, block_q, block_kv) whose keys leave the
+#: last key block padded, and two that do not
+_BLOCK_STEP_CASES = [(3, 300, 77, 64, 64), (2, 100, 300, 64, 64),
+                     (4, 64, 136, 32, 64), (1, 2304, 2304, 2048, 1024),
+                     (5, 9216, 9216, None, None),
+                     (2, 256, 256, 64, 64)]
+
+
+@pytest.mark.parametrize("bh, l, s, block_q, block_kv", _BLOCK_STEP_CASES)
+def test_block_steps_counts_the_kernels_grid(bh, l, s, block_q, block_kv):
+    """``block_steps`` against the grid walked step by step: a step is
+    masked when its key block reaches past ``s``, which is the last key
+    block of each (batch x head, query block) and only where ``s`` is no
+    multiple of the key block."""
+    from chiaswarm_tpu.ops.flash_attention import _blocks, block_steps
+
+    bq, bkv = _blocks(l, s, block_q, block_kv)
+    want = {"whole": 0, "masked": 0}
+    for _ in range(bh):
+        for _ in range(-(-l // bq)):
+            for j in range(-(-s // bkv)):
+                want["masked" if (j + 1) * bkv > s else "whole"] += 1
+    assert block_steps(bh, l, s, block_q, block_kv) == want
+    assert want["masked"] == (bh * -(-l // bq) if s % bkv else 0)
+
+
 def test_ring_flash_fused_cross_lowers_for_tpu():
     """The fused ring kernel (remote DMA + semaphores) on a 4-device
     virtual seq mesh: the scalar-prefetch grid spec, the index maps and
@@ -163,31 +233,33 @@ def test_flash_under_a_dp_tp_mesh_is_shard_mapped(monkeypatch):
         assert "tpu_custom_call" in lower().as_text()
 
 
-# ---- the causal kernel is a second entry: the diffusion calls are as they
-# were (ISSUE 30) ---------------------------------------------------------
-# sha256 of ``str(jax.make_jaxpr(...))`` taken on the commit BEFORE the
-# causal kernel came (97c0079), under this suite's conftest (its matmul
-# precision is in the text), at the two benchmark cells' self-attention
-# shapes: the Pallas kernel's own jaxpr, its grid and its block mappings
-# are in that text, so a change to ``online_softmax_block_update`` or to
-# ``_pick_block`` that reaches the diffusion call moves the digest.
+# ---- the causal kernel is a second entry: the diffusion calls are held to
+# a digest ----------------------------------------------------------------
+# sha256 of ``str(jax.make_jaxpr(...))`` under this suite's conftest (its
+# matmul precision is in the text), at the two benchmark cells'
+# self-attention shapes: the Pallas kernel's own jaxpr, its grid and its
+# block mappings are in that text, so a change to ``update_tile``, to the
+# kernel's masking or to ``_pick_block`` that reaches the diffusion call
+# moves the digest. The einsum's ("attention") are those of the commit
+# before the causal kernel came (97c0079); the kernel's are of its update
+# by ``update_tile`` with no padding mask at these shapes.
 
 _DIFFUSION_SHAPES = {"sd15-512": (1, 4096, 8, 40),
                      "sdxl-1024": (1, 4096, 10, 64)}
 
 _PARENT_JAXPR_SHA256 = {
     ("sd15-512", "flash_attention"):
-        "2c11e34cc5a7634da316ba983d6ec7ae8c7febbde7f0511245414e5db48f6145",
+        "868f870fb1e61e12fc75e59670d8c1a8bf5f361df0463b111c9559fb8ee057a3",
     ("sd15-512", "attention"):
         "ba05137c3bc6f5271cd0ccb294e9b905eaf18e778205aa9f433ece46d6c46c73",
     ("sd15-512", "attention-as-on-the-chip"):
-        "c4abcd9777bbbb560f27d2c3208eb4458c0c70104512f892abdb071c08cfbb50",
+        "cf5b18e0e28b55f863614cf97cca004882354737d712cd87c775ab1ed0a0a0c0",
     ("sdxl-1024", "flash_attention"):
-        "9f0e60eaddac2025392493b05784393b63599ce56fae9c26bfbf811cff1b613d",
+        "8df29ac82b6d087dc67dec803fa543a26fcc80e40b1d6e1d42a09f2c453a4b53",
     ("sdxl-1024", "attention"):
         "ea40404ee3fd39feccf7956809f40ebbffb9d33c38afa61b2edb56bb7958f320",
     ("sdxl-1024", "attention-as-on-the-chip"):
-        "16a853df3429ee1dab1893964b9f56be521b7ef4aeaa2e230b0bcb162ad1f2f9",
+        "cc0ee3c6289ea90a39a0f4d8b695ac589cd3db6870a3787c596c7fcd5487e95c",
 }
 
 
@@ -197,7 +269,7 @@ def test_diffusion_attention_traces_to_the_parents_jaxpr(cell, call,
                                                          monkeypatch):
     """``flash_attention()`` in interpret mode, ``attention()`` as the
     CPU picks (the einsum) and as a TPU process picks (the Mosaic
-    kernel): textually the parent's program, and none of them reaches
+    kernel): textually the pinned program, and none of them reaches
     the causal entry."""
     import hashlib
 

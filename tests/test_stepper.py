@@ -1394,3 +1394,68 @@ def test_admission_cap_throttles_rows_per_boundary(tiny_pipe, monkeypatch):
     assert capped[0] != capped[1], capped      # one row per boundary
     uncapped = run_pair(None)
     assert uncapped[0] == uncapped[1], uncapped  # both splice together
+
+
+# ---- the flash kernel's grid steps a lane step runs ----------------------
+
+
+@pytest.mark.parametrize("family, px, sites, whole", [
+    ("sdxl", 1024, {4096: 10, 1024: 60}, 10 * 160 + 60 * 40),
+    ("sd15", 512, {4096: 5, 1024: 5}, 5 * 128 + 5 * 16),
+])
+def test_flash_block_steps_at_the_cells_shapes(family, px, sites, whole,
+                                               monkeypatch):
+    """A width-1 lane of the two benchmark cells' families, as a TPU
+    process dispatches its attention: the UNet's self-attentions from
+    1024 tokens up take the flash kernel (SDXL 10 calls at 4096 tokens,
+    60 at 1024; SD1.5 5 and 5), each over the uncond and cond halves'
+    heads in 2048 x 1024 blocks (1024 x 1024 at 1024 tokens): every
+    grid step whole, none masked. A DeepCache replay runs level 0's."""
+    import jax
+
+    from chiaswarm_tpu.models.configs import FAMILIES
+    from chiaswarm_tpu.models.unet import UNet, self_attention_sites
+
+    fam = FAMILIES[family]
+    lh = px // fam.vae.downscale
+    calls = {}
+    for (tokens, _, _), n in self_attention_sites(fam.unet, lh, lh).items():
+        if tokens >= 1024:
+            calls[tokens] = calls.get(tokens, 0) + n
+    assert calls == sites
+
+    pipe = object.__new__(DiffusionPipeline)   # shapes only, no weights
+    pipe.c = type("C", (), {"unet": UNet(fam.unet), "family": fam})()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    steps = pipe.stepper_flash_block_steps(batch=1, height=px, width=px)
+    assert steps == {"whole": whole, "masked": 0}
+    assert pipe.stepper_flash_block_steps(
+        batch=2, height=px, width=px)["whole"] == 2 * whole
+    shallow = pipe.stepper_flash_block_steps(batch=1, height=px, width=px,
+                                             shallow=True)
+    assert shallow["whole"] == (5 * 128 if family == "sd15" else 0)
+
+
+def test_a_lane_step_feeds_the_flash_block_steps_counter():
+    """chiaswarm_flash_block_steps_total on a lane whose UNet takes the
+    flash kernel (interpret mode here): each step adds what the kernel's
+    grid holds over the UNet's self-attentions at the lane's shape, 3 + 3
+    + 1 calls of 2 halves x 4 heads in one whole block each, none
+    masked."""
+    from chiaswarm_tpu.obs.metrics import FLASH_BLOCK_STEPS
+
+    pipe = DiffusionPipeline(Components.random("tiny", seed=0),
+                             attn_impl="flash")
+    sched = StepScheduler()
+    before = {k: FLASH_BLOCK_STEPS.value(kind=k) for k in ("whole", "masked")}
+    base = sched.stats().get("steps_executed", 0)
+    fut = sched.submit_request(pipe, prompt="counted", steps=2,
+                               guidance_scale=7.5, height=64, width=64,
+                               rows=1, seed=71)
+    fut.result(timeout=300)[0].wait()
+    steps = sched.stats()["steps_executed"] - base
+    sched.shutdown()
+    assert steps >= 2
+    assert FLASH_BLOCK_STEPS.value(kind="whole") - before["whole"] \
+        == 56 * steps
+    assert FLASH_BLOCK_STEPS.value(kind="masked") == before["masked"]

@@ -377,7 +377,8 @@ def job_counts(cfg: LingConfig, prompt_tokens: int, rows: int, new: int,
             layers, prompt_tokens, rows, new),
         "kda_blocks": kda_blocks(cfg, prompt_tokens, chunk),
         "recurrent_state_bytes": text_layers.recurrent_state_bytes(
-            cfg.delta_rule, len(cfg.kda_layers),
-            jnp.dtype(cfg.dtype).itemsize, prompt_tokens, rows, new, chunk),
+            text_layers.kda_state_bytes(cfg.delta_rule,
+                                        jnp.dtype(cfg.dtype).itemsize),
+            len(cfg.kda_layers), prompt_tokens, rows, new, chunk),
         "expert_layers": sum(cfg.is_moe(i)
                              for i in range(cfg.num_hidden_layers))}

@@ -380,7 +380,8 @@ def job_counts(cfg: SolarConfig, prompt_tokens: int, rows: int, new: int,
         "kda_blocks": text_layers.kda_blocks(
             cfg.delta_rule, len(kda), prompt_tokens, chunk),
         "recurrent_state_bytes": text_layers.recurrent_state_bytes(
-            cfg.delta_rule, len(kda), jnp.dtype(cfg.dtype).itemsize,
-            prompt_tokens, rows, new, chunk),
+            text_layers.kda_state_bytes(cfg.delta_rule,
+                                        jnp.dtype(cfg.dtype).itemsize),
+            len(kda), prompt_tokens, rows, new, chunk),
         "expert_layers": sum(cfg.is_moe(i)
                              for i in range(cfg.num_hidden_layers))}

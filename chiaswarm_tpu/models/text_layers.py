@@ -1,6 +1,7 @@
 """Layers the text stacks share (models/ling.py, models/deepseek.py,
-models/laguna.py, models/solar.py): the norm, the SwiGLU and the rotary
-pieces (YaRN among them), the delta rule with its recurrent state, latent
+models/laguna.py, models/solar.py, models/lfm2.py): the norm, the SwiGLU
+and the rotary pieces (YaRN among them), the delta rule with its
+recurrent state, the gated short convolution with its conv tail, latent
 attention (MLA) with its compressed cache, attention over plain keys and
 values with its two kinds of cache, and the expert layer of which a chip
 holds a part. One implementation of each; a stack tells them what
@@ -21,6 +22,12 @@ differs.
   softplus(W_a x + dt_bias)``), either a non-positive log-decay. Each
   gate projection is one matrix or a low-rank pair, as the stack's
   weights hold it (``wa`` / ``wa_down`` and ``wa_up``; ``wg`` likewise).
+- **The gated short convolution** (LFM2's mixer): ``[B | C | u] = x
+  W_in``, ``y = (C * conv(B * u)) W_out`` with a depthwise causal conv of
+  a few taps and no activation. Its only state is the last taps - 1
+  values of ``B * u`` a channel (the conv tail), carried across a
+  prefill's chunks and rolled by each decode step: the delta rule's short
+  conv with the activation left out.
 
 - **Latent attention.** The cache holds 512 normed latent + 64 rotated
   key values a token (``kv_lora_rank + qk_rope_head_dim``), one entry for
@@ -42,8 +49,10 @@ differs.
   hands in the layer's head count, its rotary frequencies (as many as
   half the values of a head that are rotated: all of them, or the first
   part; None where the model encodes no position in this layer), their
-  amplitude, the softmax scale and, for a sliding layer,
-  the window. A FULL layer's cache has the capacity's slots; its prefill
+  amplitude, the softmax scale, where the model norms each head's query
+  and key before the rotary the norm's eps (the gains ``q_norm`` /
+  ``k_norm`` among the layer's weights) and, for a sliding layer, the
+  window. A FULL layer's cache has the capacity's slots; its prefill
   writes a chunk and attends causally over what is written, its decode
   sweeps the prompt's keys and values once a key-value head for all
   rows (``ops.attention.shared_prompt_attention``) and joins each row's
@@ -58,9 +67,10 @@ differs.
   (``experts_held``), routes over all of them by the stack's router
   (sigmoid with a bias and normalised weights, or softmax over groups'
   maxima with plain weights), and computes its own experts' part for the
-  tokens routed to them (plus the shared expert); what the absent
-  experts would add is left out. Tokens are grouped by expert into
-  blocks and a loop with a dynamic trip count walks the blocks in use,
+  tokens routed to them (plus the shared expert, where the layer has
+  one); what the absent experts would add is left out. Tokens are
+  grouped by expert into blocks and a loop with a dynamic trip count
+  walks the blocks in use,
   so a step reads the weights of the experts that were hit and no
   others, and no token is ever dropped.
 
@@ -175,9 +185,14 @@ def yarn_frequencies(dim: int, theta: float, factor: float, original: int,
 
 @scope("head")
 def logits_of(params, cfg, x):
-    """Hidden states (..., d) -> float32 logits over the slice held."""
+    """Hidden states (..., d) -> float32 logits over the slice held: by
+    the head, or where the weights hold none (a head tied to the
+    embedding) by the embedding's (V, d) rows, read where they lie."""
     h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
+    if "head" in params:
+        return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
+    return jnp.einsum("...d,vd->...v", h, params["embed"],
+                      preferred_element_type=jnp.float32)
 
 
 def param_bytes(params) -> int:
@@ -223,17 +238,21 @@ class DeltaRule:
     allow_neg_eigval: bool = False
 
 
-def short_conv(pre, tail, weight, n_valid):
-    """Depthwise causal conv over time then SiLU. ``pre`` (B, T, C) new
-    pre-conv values, ``tail`` (B, K-1, C) the last ones before them.
-    Returns (out (B, T, C), the tail after ``n_valid`` new tokens)."""
+def short_conv(pre, tail, weight, n_valid, activation=jax.nn.silu):
+    """Depthwise causal conv over time, then ``activation`` (None: none).
+    ``pre`` (B, T, C) new pre-conv values, ``tail`` (B, K-1, C) the last
+    ones before them; tap i of ``weight`` (K, C) multiplies the value
+    K-1-i tokens back. Returns (out (B, T, C), the tail after ``n_valid``
+    new tokens)."""
     k = weight.shape[0]
     t = pre.shape[1]
     seq = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
     out = sum(seq[:, i:i + t].astype(jnp.float32)
               * weight[i].astype(jnp.float32) for i in range(k))
     new_tail = jax.lax.dynamic_slice_in_dim(seq, n_valid, k - 1, axis=1)
-    return jax.nn.silu(out).astype(pre.dtype), new_tail
+    if activation is not None:
+        out = activation(out)
+    return out.astype(pre.dtype), new_tail
 
 
 def gate_proj(p, name: str, x):
@@ -444,16 +463,32 @@ def kda_blocks(rule: DeltaRule, layers: int, prompt_tokens: int,
     return n * nb, n * nb * (nb - 1) // 2
 
 
-def recurrent_state_bytes(rule: DeltaRule, layers: int, item: int,
-                          prompt_tokens: int, rows: int, new: int,
-                          chunk: int) -> dict[str, int]:
-    """Bytes of recurrent state (the float32 state and the conv tails)
-    that ``layers`` delta-rule layers read and write in a job, by phase:
-    each prefill chunk reads one row's once and writes them once, each
-    of the decode's ``new - 1`` steps every row's."""
-    one = kda_state_bytes(rule, item)
+def recurrent_state_bytes(one: int, layers: int, prompt_tokens: int,
+                          rows: int, new: int, chunk: int) -> dict[str, int]:
+    """Bytes of fixed-size state (``one``: one row's in one layer: the
+    delta rule's float32 state and conv tails, or a gated conv's tail)
+    that ``layers`` such layers read and write in a job, by phase: each
+    prefill chunk reads one row's once and writes it once, each of the
+    decode's ``new - 1`` steps every row's."""
     return {"prefill": layers * -(-prompt_tokens // chunk) * 2 * one,
             "decode": layers * (new - 1) * rows * 2 * one}
+
+
+# ---- the gated short convolution ------------------------------------------
+
+
+@scope("recurrence")
+def gated_conv(p, x, tail, n_valid):
+    """x (B, T, d) -> (y, tail). ``[B | C | u] = x W_in`` (``in_proj``, d
+    -> 3d, split in that order), ``z = B * u``, ``v`` = z through the
+    depthwise causal conv ``conv`` (K, d) with no activation, ``y = (C *
+    v) W_out``. ``tail`` (B, K-1, d) holds the last K-1 values of z
+    before x (zeros at a sequence's start); the returned tail is the last
+    K-1 after ``n_valid`` tokens. One function for a prefill chunk and a
+    decode step (T = 1: the tail rolls by one)."""
+    b, c, u = jnp.split(proj(x, p["in_proj"]), 3, axis=-1)
+    z, tail = short_conv(b * u, tail, p["conv"], n_valid, activation=None)
+    return proj(c * z, p["out_proj"]), tail
 
 
 # ---- latent attention ----------------------------------------------------
@@ -650,14 +685,20 @@ def _rotated(x, positions, inv_freq, amplitude: float):
 
 
 def _queries_keys_values(p, x, positions, heads: int, kv_heads: int,
-                         inv_freq, amplitude: float):
+                         inv_freq, amplitude: float,
+                         qk_norm_eps: float | None = None):
     """q (B, T, H, D) and k, v (B, T, Hk, D) of the normed input x
-    (B, T, d), q and k rotated at ``positions`` (T,); neither rotated
-    where ``inv_freq`` is None (no position encoding)."""
+    (B, T, d), q and k each RMS-normed a head (gains ``q_norm`` /
+    ``k_norm``, eps ``qk_norm_eps``; not where it is None) and then
+    rotated at ``positions`` (T,); neither rotated where ``inv_freq`` is
+    None (no position encoding)."""
     b_, t, _ = x.shape
     q = proj(x, p["wq"]).reshape(b_, t, heads, -1)
     k = proj(x, p["wk"]).reshape(b_, t, kv_heads, -1)
     v = proj(x, p["wv"]).reshape(b_, t, kv_heads, -1)
+    if qk_norm_eps is not None:
+        q = rms_norm(q, p["q_norm"], qk_norm_eps)
+        k = rms_norm(k, p["k_norm"], qk_norm_eps)
     if inv_freq is None:
         return q, k, v
     return (_rotated(q, positions, inv_freq, amplitude),
@@ -673,6 +714,7 @@ def _written(cache, k, v, at):
 
 def kv_prefill(p, x, cache, pos, n_valid, *, heads: int, inv_freq,
                scale: float, rope_amplitude: float = 1.0,
+               qk_norm_eps: float | None = None,
                window: int | None = None):
     """The heads' read-out (1, T, H, D) of the normed x (1, T, d) at
     positions [pos, pos + T), of which the first ``n_valid`` are tokens,
@@ -692,7 +734,8 @@ def kv_prefill(p, x, cache, pos, n_valid, *, heads: int, inv_freq,
     kv_heads = cache["k"].shape[2]
     t = x.shape[1]
     q, k, v = _queries_keys_values(p, x, pos + jnp.arange(t), heads,
-                                   kv_heads, inv_freq, rope_amplitude)
+                                   kv_heads, inv_freq, rope_amplitude,
+                                   qk_norm_eps)
     if window is None:
         cache = _written(cache, k, v, pos)
         o = attention(q, cache["k"], cache["v"], scale=scale, causal=True,
@@ -712,6 +755,7 @@ def kv_prefill(p, x, cache, pos, n_valid, *, heads: int, inv_freq,
 
 def kv_decode(p, x, prompt, prompt_len, suffix, step, *, heads: int,
               inv_freq, scale: float, rope_amplitude: float = 1.0,
+              qk_norm_eps: float | None = None,
               window: int | None = None):
     """One new token a row: the heads' read-out (R, 1, H, D) of the
     normed x (R, 1, d) at position ``prompt_len + step`` and the suffix
@@ -732,7 +776,8 @@ def kv_decode(p, x, prompt, prompt_len, suffix, step, *, heads: int,
     ``step >= window``, the suffix's oldest too."""
     kv_heads = prompt["k"].shape[2]
     q, k, v = _queries_keys_values(p, x, (prompt_len + step)[None], heads,
-                                   kv_heads, inv_freq, rope_amplitude)
+                                   kv_heads, inv_freq, rope_amplitude,
+                                   qk_norm_eps)
     suffix = _written(suffix, k, v, step)
     rows, n = suffix["k"].shape[:2]
     q = q[:, 0].reshape(rows, kv_heads, heads // kv_heads, -1)
@@ -895,7 +940,8 @@ def sigmoid_route(p, cfg, x):
     float32): sigmoid scores over ALL experts; chosen by score + bias,
     the best ``topk_group`` of ``n_group`` groups by the sum of their top
     two (one group: no choice of groups), then the best K inside them;
-    weights are the scores of the chosen, summing to one, times the
+    weights are the scores of the chosen over their sum (plus the
+    configuration's ``norm_topk_eps`` where it has one), times the
     scaling factor."""
     t = x.shape[0]
     scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["router"],
@@ -909,15 +955,19 @@ def sigmoid_route(p, cfg, x):
     masked = jnp.where(keep[..., None], groups, -jnp.inf).reshape(t, -1)
     _, chosen = jax.lax.top_k(masked, cfg.num_experts_per_tok)
     weight = jnp.take_along_axis(scores, chosen, axis=-1)
-    weight = weight / jnp.sum(weight, -1, keepdims=True) \
-        * cfg.routed_scaling_factor
+    total = jnp.sum(weight, -1, keepdims=True)
+    eps = getattr(cfg, "norm_topk_eps", 0.0)
+    if eps:
+        total = total + eps
+    weight = weight / total * cfg.routed_scaling_factor
     return chosen.astype(jnp.int32), weight
 
 
 def moe(p, cfg, x, route, valid=None):
-    """x (..., d) -> (shared expert + held experts' part, stats).
-    ``route(p, cfg, x (T, d))`` is the stack's router: (chosen experts
-    (T, K) int32, their weights (T, K) float32) over ALL experts."""
+    """x (..., d) -> (shared expert, where the layer has one, + held
+    experts' part, stats). ``route(p, cfg, x (T, d))`` is the stack's
+    router: (chosen experts (T, K) int32, their weights (T, K) float32)
+    over ALL experts."""
     lead = x.shape[:-1]
     flat = x.reshape(-1, x.shape[-1])
     if valid is None:
@@ -926,8 +976,9 @@ def moe(p, cfg, x, route, valid=None):
         chosen, weight = route(p, cfg, flat)
     y, held, hit = held_experts_part(p, cfg, flat, chosen, weight,
                                      valid.reshape(-1))
-    with scope("shared_expert"):
-        y = y + swiglu(p["shared"], flat)
+    if "shared" in p:
+        with scope("shared_expert"):
+            y = y + swiglu(p["shared"], flat)
     stats = {"pairs": jnp.sum(valid) * cfg.num_experts_per_tok,
              "pairs_held": held, "experts_hit": hit}
     return y.reshape(*lead, -1), stats

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import importlib
 
-NAMES = ("ling", "deepseek", "laguna", "solar")
+NAMES = ("ling", "deepseek", "laguna", "solar", "lfm2")
 DEFAULT = "ling"
 
 

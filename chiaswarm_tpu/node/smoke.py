@@ -108,6 +108,18 @@ SMOKE_JOBS: dict[str, dict[str, Any]] = {
         "logprobs": True,
         "content_type": "application/json",
     },
+    "txt2txt_lfm2": {
+        # the fifth text stack (models/lfm2.py): gated short convs beside
+        # QK-normed rotary attention, experts with no shared one
+        "id": "smoke-txt2txt-lfm2",
+        "workflow": "txt2txt",
+        "model_name": "random/lfm2_tiny",
+        "prompt": "ab cd ab ba",
+        "max_new_tokens": 4,
+        "num_return_sequences": 2,
+        "logprobs": True,
+        "content_type": "application/json",
+    },
     "tts": {
         # the reference's bark smoke job (swarm/test.py:45-51)
         "id": "smoke-tts",
@@ -190,6 +202,8 @@ def run_smoke(workflow: str, random_weights: bool = True) -> dict[str, Any]:
                  {"name": "random/laguna_tiny", "stack": "laguna",
                   "prefill_chunk": 16, "max_context": 64},
                  {"name": "random/solar_tiny", "stack": "solar",
+                  "prefill_chunk": 16, "max_context": 64},
+                 {"name": "random/lfm2_tiny", "stack": "lfm2",
                   "prefill_chunk": 16, "max_context": 64}],
         allow_random=random_weights,
     )

@@ -1022,14 +1022,15 @@ TEXT_KDA_BLOCKS = REGISTRY.counter(
     "whether they are built from pairwise decays or as a product",
     labelnames=("form",))
 
-#: bytes of recurrent state (the float32 state and the conv tails) the
-#: delta-rule layers read and write, by phase: a prefill chunk reads and
-#: writes one row's once, a decode step every row's (host integers of
-#: ``text_layers.recurrent_state_bytes``; both delta-rule stacks)
+#: bytes of recurrent state (the delta rule's float32 state and conv
+#: tails, a gated conv's tail) the fixed-state layers read and write, by
+#: phase: a prefill chunk reads and writes one row's once, a decode step
+#: every row's (host integers of ``text_layers.recurrent_state_bytes``;
+#: both delta-rule stacks and the gated-conv one)
 TEXT_RECURRENT_STATE_BYTES = REGISTRY.counter(
     "chiaswarm_text_recurrent_state_bytes_total",
-    "bytes of recurrent state the delta-rule layers read and write, by "
-    "phase",
+    "bytes of recurrent state the fixed-state (delta-rule or gated-conv) "
+    "layers read and write, by phase",
     labelnames=("phase",))
 
 #: grid steps of the diffusion flash kernel (``ops/flash_attention.py``)

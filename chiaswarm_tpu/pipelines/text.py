@@ -8,7 +8,8 @@ fixed-size float32 recurrent state plus a conv tail for each
 linear-attention layer beside a growing latent cache for each
 latent-attention layer in one stack, latent caches alone in another,
 full layers' keys and values beside sliding layers' last window of them
-in a third.
+in a third, a gated conv's tail beside full layers' keys and values in
+another.
 
 - ``text_prefill``: one chunk of ``prefill_chunk`` tokens of one row,
   the caches carried in and out, called once a chunk with the chunk's
@@ -281,8 +282,9 @@ class TextPipeline:
             pairwise, product = counts["kda_blocks"]
             metrics.TEXT_KDA_BLOCKS.inc(pairwise, form="pairwise")
             metrics.TEXT_KDA_BLOCKS.inc(product, form="product")
-            for phase, size in counts["recurrent_state_bytes"].items():
-                metrics.TEXT_RECURRENT_STATE_BYTES.inc(size, phase=phase)
+        # a stack with fixed-size state (delta-rule or gated-conv layers)
+        for phase, size in counts.get("recurrent_state_bytes", {}).items():
+            metrics.TEXT_RECURRENT_STATE_BYTES.inc(size, phase=phase)
         # a stack with sliding-window layers
         for kind, pairs in counts.get("window_pairs", {}).items():
             metrics.TEXT_WINDOW_PAIRS.inc(pairs, kind=kind)

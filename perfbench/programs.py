@@ -43,23 +43,3 @@ def load(directory: str, window_s: float) -> dict:
 def program_name(event_name: str) -> str:
     """``jit_stepper_step(4316745985256595699)`` -> ``jit_stepper_step``."""
     return event_name.split("(")[0].strip()
-
-
-def totals(form: dict) -> dict[str, dict]:
-    """program -> {"seconds", "count"} over the traced window."""
-    out: dict[str, dict] = {}
-    for name, _start, dur in form["modules"]:
-        entry = out.setdefault(program_name(name),
-                               {"seconds": 0.0, "count": 0})
-        entry["seconds"] += dur * 1e-9
-        entry["count"] += 1
-    return out
-
-
-def mean_ms(form: dict, program: str) -> float | None:
-    """Mean device milliseconds of one execution of ``program``; None if
-    it never ran in the window."""
-    entry = totals(form).get(program)
-    if not entry or entry["count"] <= 0:
-        return None
-    return 1e3 * entry["seconds"] / entry["count"]

@@ -23,23 +23,11 @@ a kind without the functions, or where the trace holds no whole run.
 """
 from perfbench import programs
 from perfbench import spans as digests
-from perfbench.readers import counter_ratio
+from perfbench.readers import counter_ratio, program_whole
 from perfbench.readers.prefill_attention_roofline import (
     kernel_seconds,
     whole_runs,
 )
-
-
-def whole_executions(modules: list, program: str) -> list[tuple]:
-    """[start_ns, end_ns) of each execution of ``program`` that the
-    trace cut at neither end (``program_whole``'s rule)."""
-    if not modules:
-        return []
-    first = min(start for _, start, _ in modules)
-    last = max(start + dur for _, start, dur in modules)
-    return [(start, start + dur) for name, start, dur in modules
-            if programs.program_name(name) == program
-            and start > first and start + dur < last]
 
 
 def read(context, program, kernel, executions, flops, bytes, counter):
@@ -62,7 +50,7 @@ def read(context, program, kernel, executions, flops, bytes, counter):
         return None
     jobs = [context.ran["sent"][s["id"]]["job"] for s in context.good]
     if per_job is None:
-        runs = whole_executions(modules, program)
+        runs = program_whole.whole_executions(modules, program)
     else:
         lengths = {per_job(context.config, job) for job in jobs}
         if len(lengths) != 1:

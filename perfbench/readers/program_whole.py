@@ -13,17 +13,24 @@ from perfbench import programs
 from perfbench import spans as digests
 
 
-def whole_ms(form: dict, program: str) -> list[float]:
-    """Milliseconds of each execution of ``program`` that the trace cut
-    at neither end."""
-    modules = form["modules"]
+def whole_executions(modules: list, program: str) -> list[tuple]:
+    """[start_ns, end_ns) of each execution of ``program`` that the trace
+    cut at neither end: the benchmark's one rule for which executions
+    of a program count."""
     if not modules:
         return []
     first = min(start for _, start, _ in modules)
     last = max(start + dur for _, start, dur in modules)
-    return [dur * 1e-6 for name, start, dur in modules
+    return [(start, start + dur) for name, start, dur in modules
             if programs.program_name(name) == program
             and start > first and start + dur < last]
+
+
+def whole_ms(form: dict, program: str) -> list[float]:
+    """Milliseconds of each execution of ``program`` that the trace cut
+    at neither end."""
+    return [(end - start) * 1e-6
+            for start, end in whole_executions(form["modules"], program)]
 
 
 def read(context, program):

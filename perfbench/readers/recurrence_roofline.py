@@ -25,7 +25,7 @@ import bisect
 import statistics
 
 from perfbench import hlo, scopes, trace
-from perfbench.readers import counter_ratio, scope_device
+from perfbench.readers import counter_ratio, program_whole, scope_device
 
 ITEM = 2                                                # bfloat16
 
@@ -65,7 +65,7 @@ def recurrence_ms(context, program, scope) -> float | None:
     device = sorted(device, key=lambda event: event[1])
     starts = [start for _, start, _ in device]
     found = []
-    for lo, hi in scopes.whole_executions(modules, program):
+    for lo, hi in program_whole.whole_executions(modules, program):
         ms = 0.0
         for name, start, dur in device[bisect.bisect_left(starts, lo):
                                        bisect.bisect_left(starts, hi)]:

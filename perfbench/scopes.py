@@ -89,21 +89,6 @@ def carries(maps: dict, program: str, scope: str) -> bool:
                for scopes in by_shape.values())
 
 
-def whole_executions(modules: list, program: str) -> list[tuple]:
-    """[start_ns, end_ns) of each execution of ``program`` that the
-    trace cut at neither end: ``program_whole.whole_ms``'s rule, asked of
-    each event between two empty marks at the line's first and last
-    nanosecond."""
-    if not modules:
-        return []
-    marks = [["", min(start for _, start, _ in modules), 0],
-             ["", max(start + dur for _, start, dur in modules), 0]]
-    return [(start, start + dur) for name, start, dur in modules
-            if program_whole.whole_ms({"modules": marks + [[name, start,
-                                                            dur]]},
-                                      program)]
-
-
 def split(events: list, ops: dict) -> dict:
     """Device ms of ``events`` by scope (an event under two scopes counts
     in each), plus ``"all"`` (every operation, containers out) and
@@ -131,7 +116,7 @@ def splits(device: list, maps: dict, modules: list, program: str,
     starts = [start for _, start, _ in device]
     intervals = (
         prefill_attention_roofline.whole_runs(modules, program, run) if run
-        else whole_executions(modules, program))
+        else program_whole.whole_executions(modules, program))
     return [split([event for event in device[bisect.bisect_left(starts, lo):
                                              bisect.bisect_left(starts, hi)]
                    if event[1] + event[2] <= hi], maps.get(program, {}))

@@ -50,7 +50,6 @@ TINY_BENCH = {
 
 def test_the_cell_is_what_the_issue_names():
     cell_entry = next(w for w in BENCH["workloads"] if w["name"] == CELL)
-    assert cell_entry == BENCH["workloads"][-1]
     assert cell_entry["chips"] == 1 and cell_entry["traffic"] == "sample16"
     assert cell_entry["config"] == "deepseek-v2"
     mix = traffic.load_mix("sample16")
@@ -63,26 +62,35 @@ def test_the_cell_is_what_the_issue_names():
     assert serving["prefill_chunk"] == 2048 and serving["dtype"] == "bfloat16"
     assert serving["max_context"] == 16384
     assert serving["residency_budget_fraction"] == 0.75
+    # the cell reports these (never only these: a later PR lists its own
+    # metrics in the cell too)
     mine = [m["name"] for m in BENCH["per_layer"] if CELL in m["workloads"]]
-    assert sorted(mine) == sorted(SHARED + NEW)
-    # the three of PR 29 that average cut events, and the kernels' share,
-    # do not list this cell (PERF.md 5b; ROADMAP R3d)
-    for name in ("prefill_device_ms.lat", "decode_device_ms.lat",
-                 "decode_hbm_roofline.lat", "flash_roofline.lat"):
+    assert set(SHARED + NEW) <= set(mine)
+    # the Ling kind's bytes' share and the diffusion kernels' share do
+    # not list this cell
+    for name in ("decode_hbm_roofline.lat", "flash_roofline.lat"):
         assert name not in mine
-    # the new entries are the last of per_layer; the two that read whole
-    # executions of a text program read the Ling cell's too (one sound
-    # reader on both cells; its three older metrics await a benchmark
-    # PR), the two that need this kind's counts are this cell's alone
-    assert [m["name"] for m in BENCH["per_layer"][-4:]] == NEW
-    for m in BENCH["per_layer"][-4:]:
-        whole = m["name"].endswith("_whole_ms.lat")
-        assert m["workloads"] == ([LING_CELL, CELL] if whole else [CELL])
+    # the two that read whole executions of a text program list the Ling
+    # cell too, the two priced by the kind's counts of work do not; each
+    # is read from the trace by its own reader
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    readers_of = {"prefill_whole_ms.lat": "program_whole",
+                  "decode_whole_ms.lat": "program_whole",
+                  "decode_roofline.lat": "decode_roofline",
+                  "causal_flash_attention_roofline.lat":
+                      "prefill_attention_roofline"}
+    for name in NEW:
+        m = by_name[name]
+        whole = name.endswith("_whole_ms.lat")
+        assert (LING_CELL in m["workloads"]) == whole, name
         assert m["moves"] == "job_p50_s" and m["source"] == "device_trace"
-        assert m["layer"] == ("kernels" if m["name"].startswith("causal")
+        assert m["layer"] == ("kernels" if name.startswith("causal")
                               else "text programs")
+        spec = json.loads((ROOT / "perfbench" / "metrics"
+                           / f"{name}.json").read_text())
+        assert spec["reader"] == readers_of[name], name
     p50 = next(m for m in BENCH["end_to_end"] if m["name"] == "job_p50_s")
-    assert p50["workloads"][-1] == CELL and p50["bound"] == 0.02
+    assert CELL in p50["workloads"] and p50["bound"] == 0.02
 
 
 def test_the_configuration_keeps_every_published_width():
@@ -481,10 +489,11 @@ def test_program_whole_leaves_out_the_events_the_trace_cut(monkeypatch):
         == [150.0, 152.0, 190.0]
     assert program_whole.whole_ms(CUT_FORM, "jit_stepper_step") == []
     assert program_whole.whole_ms({"modules": []}, "jit_text_decode") == []
-    # the mean over every event, which the older reader takes, reads a
-    # third lower on the same window
-    assert programs.mean_ms(CUT_FORM, "jit_text_decode") \
-        == pytest.approx(2420.0 / 3)
+    # the window without its cut decode reads the same whole decodes (its
+    # first event, a prefill chunk, is left out in its place)
+    assert program_whole.whole_ms(
+        {"modules": CUT_FORM["modules"][1:]}, "jit_text_decode") \
+        == [1000.0, 1020.0]
     context = fake_context({}, {}, traced={"dir": "x", "window_s": 5.0})
     monkeypatch.setattr(programs, "load", lambda d, w: CUT_FORM)
     assert readers.read("decode_whole_ms.lat", context) == 1010.0

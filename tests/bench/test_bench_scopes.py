@@ -22,6 +22,10 @@ from perfbench.readers import (  # noqa: E402
 )
 
 MS = 1_000_000
+TEXT_CELLS = ["ling-3.0-flash-vl.sample32", "deepseek-v2.sample16",
+              "laguna-xs.2.sample32", "solar-open2-250b.sample16",
+              "lfm2-8b-a1b.sample32"]
+DIFFUSION_CELLS = ["sdxl-1024.single", "sd15-512.single"]
 
 DECODE = """HloModule jit_text_decode, is_scheduled=true
 
@@ -121,12 +125,13 @@ def test_two_programs_sharing_an_operation_name_keep_their_own_scopes():
 
 
 def test_an_execution_the_window_cut_is_left_out():
-    assert scopes.whole_executions(MODULES, "jit_text_decode") \
+    assert program_whole.whole_executions(MODULES, "jit_text_decode") \
         == [(20 * MS, 30 * MS), (40 * MS, 50 * MS)]
-    # the rule is program_whole's
+    # the executions whose time program_whole reads
     form = json.loads((ROOT / "perfbench" / "fixtures"
                        / "trace_programs.json").read_text())
-    whole = scopes.whole_executions(form["modules"], "jit_stepper_step")
+    whole = program_whole.whole_executions(form["modules"],
+                                           "jit_stepper_step")
     assert [(b - a) * 1e-6 for a, b in whole] \
         == program_whole.whole_ms(form, "jit_stepper_step")
 
@@ -246,8 +251,11 @@ def test_each_new_metric_names_its_scope_and_program(name):
     assert spec["args"]["scope"] in scopes.SCOPES
     assert spec["args"]["scope"] in spec["what"]
     assert spec["args"]["program"] in spec["what"]
+    # every cell whose program opens the scope lists the metric
     text = entry["layer"] == "text programs"
-    assert entry["workloads"] == ([
-        "ling-3.0-flash-vl.sample32", "deepseek-v2.sample16",
-        "laguna-xs.2.sample32"] if text else [
-        "sdxl-1024.single", "sd15-512.single"])
+    for cell in (TEXT_CELLS if text else DIFFUSION_CELLS):
+        assert cell in entry["workloads"], (name, cell)
+    assert entry["layer"] == ("text programs" if name.startswith(
+        ("decode_", "prefill_")) else "model step")
+    assert spec["reader"] == ("experts_roofline" if "roofline" in name
+                              else "scope_device")

@@ -37,7 +37,9 @@ LISTED = ["hive_queue_s.lat", "upload_s.lat", "admission_s.lat",
           "moe_experts_hit.lat", "prefill_whole_ms.lat",
           "decode_whole_ms.lat", "decode_roofline.lat",
           "causal_flash_attention_roofline.lat",
-          "shared_prompt_attention_roofline.lat"]
+          "shared_prompt_attention_roofline.lat", "decode_experts_ms.lat",
+          "decode_attention_ms.lat", "prefill_experts_ms.lat",
+          "decode_experts_roofline.lat"]
 TINY_CELL = "solar-tiny-cut.sample-tiny"
 TINY_BENCH = {
     "end_to_end": [

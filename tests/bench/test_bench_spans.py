@@ -25,6 +25,12 @@ CELLS = ["sdxl-1024.single", "sd15-512.single"]
 NEW = ["lane_steps_s.lat", "lane_handoff_s.lat", "lane_wait_s.lat",
        "png_s.lat", "screen_s.lat", "job_unnamed_s.lat",
        "lane_host_ms.lat", "step_device_ms.lat"]
+LAYER = {"lane_steps_s.lat": "model step", "lane_handoff_s.lat": "model step",
+         "step_device_ms.lat": "model step",
+         "lane_wait_s.lat": "stepper lanes",
+         "lane_host_ms.lat": "stepper lanes", "png_s.lat": "worker finish",
+         "screen_s.lat": "worker finish",
+         "job_unnamed_s.lat": "worker finish"}
 LEAVES = ["format", "schedule", "encode", "lane.wait", "lane.steps",
           "lane.handoff", "decode", "screen", "png", "safety"]
 TPU = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
@@ -257,20 +263,52 @@ def test_a_trace_that_is_not_there_reads_none(tmp_path):
 
 def test_programs_are_told_apart_by_module_name():
     from perfbench import programs
+    from perfbench.readers import program_whole
 
     form = json.loads((ROOT / "perfbench" / "fixtures"
                        / "trace_programs.json").read_text())
-    totals = programs.totals(form)
-    assert set(totals) == {"jit_stepper_step", "jit_stepper_decode",
-                           "jit_stepper_encode", "jit_stepper_init",
-                           "jit_convert_element_type"}
-    assert totals["jit_stepper_step"]["count"] == 5
-    assert totals["jit_stepper_decode"]["seconds"] == pytest.approx(0.096)
-    assert programs.mean_ms(form, "jit_stepper_decode") \
-        == pytest.approx(96.0)
-    assert programs.mean_ms(form, "jit_fn") is None
+    names = [programs.program_name(name) for name, _, _ in form["modules"]]
+    assert set(names) == {"jit_stepper_step", "jit_stepper_decode",
+                          "jit_stepper_encode", "jit_stepper_init",
+                          "jit_convert_element_type"}
+    assert names.count("jit_stepper_step") == 5
+    # the first and the last step begin and end with the line: of five
+    # steps, the three between them are whole
+    assert program_whole.whole_ms(form, "jit_stepper_step") \
+        == pytest.approx([41.0, 42.0, 40.0])
+    assert program_whole.whole_ms(form, "jit_stepper_decode") \
+        == pytest.approx([96.0])
+    assert program_whole.whole_ms(form, "jit_fn") == []
     assert programs.program_name("jit_stepper_step(43167)") \
         == "jit_stepper_step"
+
+
+#: five whole steps of 40 to 44 ms between two other programs' events,
+#: which begin and end the line; a cut step is the 2 or 3 ms of it that
+#: the capture saw, before the first event or after the last
+STEPS = [["jit_stepper_init(2)", 1_000_000_000, 12_000]] + [
+    ["jit_stepper_step(4)", start, dur] for start, dur in (
+        (1_001_000_000, 40_000_000), (1_046_000_000, 41_000_000),
+        (1_091_000_000, 42_000_000), (1_136_000_000, 43_000_000),
+        (1_181_000_000, 44_000_000))] + [
+    ["jit_stepper_encode(1)", 1_230_000_000, 6_000_000]]
+CUT_STEP_FIRST = ["jit_stepper_step(4)", 997_000_000, 2_000_000]
+CUT_STEP_LAST = ["jit_stepper_step(4)", 1_237_000_000, 3_000_000]
+
+
+@pytest.mark.parametrize("cut", ["none", "start", "end", "both"])
+def test_step_device_ms_leaves_out_a_cut_step(cut, monkeypatch):
+    """A step the capture opened or closed inside is no step's time: the
+    reading is the median of the five whole steps whichever edge the
+    window cuts (counted in, a cut step would move it to 41.5 or 41)."""
+    from perfbench import programs
+
+    modules = ([CUT_STEP_FIRST] if cut in ("start", "both") else []) \
+        + STEPS + ([CUT_STEP_LAST] if cut in ("end", "both") else [])
+    monkeypatch.setattr(programs, "load", lambda d, w: {
+        "window_s": w, "modules": modules})
+    ctx = context(JOBS, traced={"dir": "x", "window_s": 0.25})
+    assert readers.read("step_device_ms.lat", ctx) == pytest.approx(42.0)
 
 
 def test_unnamed_is_what_the_leaves_leave_over():
@@ -310,6 +348,7 @@ def test_new_metric_is_listed_by_both_cells_and_names_its_source(name):
                        else ["chiaswarm_stepper_boundary_seconds"]
                        + spec["args"]["parts"])):
         assert source in spec["what"], (name, source)
-    # the accepted entries come first, untouched, the new ones after
+    # listed once, under the layer whose span, counter or program it reads
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-len(NEW):] == NEW
+    assert names.count(name) == 1
+    assert entry["layer"] == LAYER[name]

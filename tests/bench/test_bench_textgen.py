@@ -28,9 +28,14 @@ BIG = json.loads((ROOT / "perfbench" / "configs"
                   / "ling-3.0-flash-vl.json").read_text())
 TINY = json.loads((HERE / "configs" / "ling-tiny-cut.json").read_text())
 MIX = json.loads((HERE / "traffic" / "sample-tiny.json").read_text())
-NEW = ["text_prefill_s.lat", "text_decode_s.lat", "prefill_device_ms.lat",
-       "decode_device_ms.lat", "moe_tokens_per_expert.lat",
+NEW = ["text_prefill_s.lat", "text_decode_s.lat", "moe_tokens_per_expert.lat",
        "decode_hbm_roofline.lat", "moe_experts_hit.lat"]
+#: what each of them reads, and with which reader
+READ = {"text_prefill_s.lat": ("program_span", "span_median"),
+        "text_decode_s.lat": ("program_span", "span_median"),
+        "moe_tokens_per_expert.lat": ("program_counter", "counter_ratio"),
+        "decode_hbm_roofline.lat": ("device_trace", "decode_hbm_roofline"),
+        "moe_experts_hit.lat": ("program_counter", "counter_ratio")}
 TINY_CELL = "ling-tiny-cut.sample-tiny"
 TINY_BENCH = {
     "end_to_end": [
@@ -55,17 +60,23 @@ def test_the_cell_is_what_the_issue_names():
     serving = BIG["serving"]
     assert serving["temperature"] == 1.0 and serving["logprobs"] is True
     assert serving["prefill_chunk"] == 2048 and serving["dtype"] == "bfloat16"
+    # the cell reports these (never only these, nor in this order: a
+    # later PR lists its own metrics in the cell too)
     mine = [m["name"] for m in BENCH["per_layer"] if CELL in m["workloads"]]
-    assert mine == ["hive_queue_s.lat", "upload_s.lat", "admission_s.lat",
-                    "step_mfu.lat", "device_idle_pct.lat"] + NEW
-    # the new entries come after the accepted ones (a later PR appends
-    # its own after these), each for this cell alone
-    names = [m["name"] for m in BENCH["per_layer"]]
-    at = names.index(NEW[0])
-    assert at >= 15 and names[at:at + len(NEW)] == NEW
-    for m in BENCH["per_layer"][at:at + len(NEW)]:
-        assert m["workloads"] == [CELL] and m["moves"] == "job_p50_s"
-        assert m["layer"] == "text programs"
+    assert set(["hive_queue_s.lat", "upload_s.lat", "admission_s.lat",
+                "step_mfu.lat", "device_idle_pct.lat"] + NEW) <= set(mine)
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    for name in NEW:
+        m = by_name[name]
+        assert m["moves"] == "job_p50_s" and m["layer"] == "text programs"
+        spec = json.loads((ROOT / "perfbench" / "metrics"
+                           / f"{name}.json").read_text())
+        assert (m["source"], spec["reader"]) == READ[name], name
+    # the bytes' share is priced by this kind's decode_bytes: this cell's
+    assert by_name["decode_hbm_roofline.lat"]["workloads"] == [CELL]
+    # the means over cut events are gone (PERF.md 5b)
+    assert "prefill_device_ms.lat" not in by_name
+    assert "decode_device_ms.lat" not in by_name
 
 
 def test_the_configuration_keeps_every_published_width():
@@ -372,6 +383,9 @@ def test_counter_ratio_reads_the_labelled_series():
 
 
 def test_decode_hbm_roofline_is_bytes_over_device_time(monkeypatch):
+    """The bytes a job's decode has to move over the median whole decode
+    (4 and 6 ms: 5 ms) at 819 GB/s; the events that begin and end the
+    line are another program's."""
     from perfbench import programs
 
     hit = "chiaswarm_moe_experts_hit_total"
@@ -379,14 +393,61 @@ def test_decode_hbm_roofline_is_bytes_over_device_time(monkeypatch):
                            {hit: {"values": {"": 40.0}}},
                            traced={"dir": "x", "window_s": 5.0})
     monkeypatch.setattr(programs, "load", lambda d, w: {
-        "window_s": w, "modules": [["jit_text_decode(1)", 0, 4_000_000],
-                                   ["jit_text_decode(1)", 0, 6_000_000],
-                                   ["jit_text_prefill(2)", 0, 1_000_000]]})
+        "window_s": w, "modules": [["jit_text_prefill(2)", 0, 1_000_000],
+                                   ["jit_text_decode(1)", 2_000_000,
+                                    4_000_000],
+                                   ["jit_text_decode(1)", 7_000_000,
+                                    6_000_000],
+                                   ["jit_text_prefill(2)", 14_000_000,
+                                    1_000_000]]})
     job = context.ran["sent"]["a"]["job"]
     want = 100.0 * textgen.decode_bytes(BIG, job, 40.0) / (5e-3 * 819e9)
     assert readers.read("decode_hbm_roofline.lat", context) \
         == pytest.approx(want)
-    assert readers.read("decode_device_ms.lat", context) \
+    assert readers.read("decode_whole_ms.lat", context) \
         == pytest.approx(5.0)
-    assert readers.read("prefill_device_ms.lat", context) \
-        == pytest.approx(1.0)
+    # a decode that takes exactly its bytes' time reads 100
+    floor_ns = int(round(textgen.decode_bytes(BIG, job, 40.0) / 819e9 * 1e9))
+    monkeypatch.setattr(programs, "load", lambda d, w: {
+        "window_s": w, "modules": [["jit_text_prefill(2)", 0, 1_000],
+                                   ["jit_text_decode(1)", 2_000, floor_ns],
+                                   ["jit_text_prefill(2)", 3_000 + floor_ns,
+                                    1_000]]})
+    assert readers.read("decode_hbm_roofline.lat", context) \
+        == pytest.approx(100.0, rel=1e-6)
+
+
+#: whole decodes of 1000, 1020 and 1012 ms between prefill chunks; a cut
+#: decode is the 400 or 300 ms of it that the capture saw
+WHOLE = [["jit_text_prefill(2)", 500_000_000, 150_000_000],
+         ["jit_text_decode(1)", 700_000_000, 1_000_000_000],
+         ["jit_text_decode(1)", 1_800_000_000, 1_020_000_000],
+         ["jit_text_decode(1)", 2_900_000_000, 1_012_000_000],
+         ["jit_text_prefill(2)", 4_000_000_000, 150_000_000]]
+CUT_FIRST = ["jit_text_decode(1)", 1_000, 400_000_000]
+CUT_LAST = ["jit_text_decode(1)", 4_200_000_000, 300_000_000]
+
+
+@pytest.mark.parametrize("cut", ["none", "start", "end", "both"])
+def test_decode_hbm_roofline_leaves_out_the_decodes_the_trace_cut(
+        cut, monkeypatch):
+    """100 x decode_bytes / (median whole decode x 819 GB/s) whichever
+    edge the window cuts: the same as on the window with no cut decode.
+    Counted in, a cut decode would pull the time down and the share up:
+    the median to 1006 or 1000 ms, the mean by up to a quarter."""
+    from perfbench import programs
+
+    hit = "chiaswarm_moe_experts_hit_total"
+    context = fake_context({hit: {"values": {"": 0.0}}},
+                           {hit: {"values": {"": 40.0}}},
+                           traced={"dir": "x", "window_s": 5.0})
+    modules = ([CUT_FIRST] if cut in ("start", "both") else []) + WHOLE \
+        + ([CUT_LAST] if cut in ("end", "both") else [])
+    monkeypatch.setattr(programs, "load", lambda d, w: {
+        "window_s": w, "modules": modules})
+    job = context.ran["sent"]["a"]["job"]
+    want = 100.0 * textgen.decode_bytes(BIG, job, 40.0) / (1.012 * 819e9)
+    assert readers.read("decode_whole_ms.lat", context) \
+        == pytest.approx(1012.0)
+    assert readers.read("decode_hbm_roofline.lat", context) \
+        == pytest.approx(want)
